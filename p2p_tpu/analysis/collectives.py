@@ -20,11 +20,11 @@ three contracts are checked over the emitted text
   free in the sampling loop"), replicated weights and dp-replicated host
   scalars are the *declared* baseline, and everything else is a finding.
 - ``no-hidden-resharding`` — the lowered StableHLO carries no
-  sharding-changing custom calls (``@Sharding`` constraints,
-  ``@SPMDFullToShardShape``/``@SPMDShardToFullShape`` pairs): nothing in
+  sharding-changing ops (``sdy.sharding_constraint``,
+  ``sdy.manual_computation`` boundaries): nothing in
   a canonical dp program may re-spec — least of all replicate — a
   dp-sharded tensor mid-program.
-- ``no-host-boundary`` — neither text form carries infeed/outfeed or a
+- ``no-host-boundary`` — neither text form carries a
   host-callback custom call: the mesh dispatch path never round-trips
   the host (the static twin of the ``jax.transfer_guard("disallow")``
   dispatch tests).
@@ -35,7 +35,7 @@ comms table the report JSON carries — the budget the mp-axis work will
 design against (today: all zeros, and the contract keeps it that way
 until a declaration says otherwise).
 
-Unlike the jaxpr contracts this pass pays an XLA compile (the GSPMD
+Unlike the jaxpr contracts this pass pays an XLA compile (the SPMD
 partitioner only runs there), ~7s per program at TINY scale; the
 persistent compile cache makes repeats cheap. Like
 :func:`.contracts._mesh_dp`, the dp sweep degrades to the dp values the
@@ -128,11 +128,11 @@ def lower_mesh_programs(pipe=None,
                                   _sweep_jit, _sweep_phase1_jit,
                                   _sweep_phase2_jit)
     from ..serve.meshing import replicate_pipeline
-    from ..utils.cache import ensure_persistent_cache
+    from ..utils.cache import enable_persistent_cache
     from .contracts import (GATE, PROMPTS, STEPS, _edit_controller,
                             _scan_inputs, _zero_carry, tiny_pipeline)
 
-    ensure_persistent_cache()   # the compile step is real XLA work
+    enable_persistent_cache()   # the compile step is real XLA work
     if pipe is None:
         pipe = tiny_pipeline()
     ctrl = _edit_controller(pipe)
@@ -234,20 +234,20 @@ def check_collectives(pipe=None, dps: Tuple[int, ...] = SHARDCHECK_DPS,
                     f"{sig['bytes_once']}B once"))
 
         # -- no-hidden-resharding ---------------------------------------
-        changes = shlo_walk.sharding_custom_calls(prog.stablehlo)
+        changes = shlo_walk.sharding_changes(prog.stablehlo)
         if changes:
             worst = next((c for c in changes if c.forces_replication),
                          changes[0])
             results.append(ContractResult(
                 "no-hidden-resharding", prog.name, False,
-                f"{len(changes)} sharding-changing custom call(s): "
+                f"{len(changes)} sharding-changing op(s): "
                 f"{worst.describe()}"
                 + (" — full replication of a sharded tensor"
                    if worst.forces_replication else "")))
         else:
             results.append(ContractResult(
                 "no-hidden-resharding", prog.name, True,
-                "no sharding-changing custom calls"))
+                "no sharding-changing ops"))
 
         # -- no-host-boundary -------------------------------------------
         host = (shlo_walk.host_boundary_ops(prog.stablehlo)
@@ -255,7 +255,7 @@ def check_collectives(pipe=None, dps: Tuple[int, ...] = SHARDCHECK_DPS,
         results.append(ContractResult(
             "no-host-boundary", prog.name, not host,
             (f"host-boundary op(s) in a mesh program: {sorted(set(host))}"
-             if host else "no infeed/outfeed/host callbacks")))
+             if host else "no host callbacks")))
 
     # -- stale program-level declarations -------------------------------
     swept = {p.name for p in programs}

@@ -27,13 +27,17 @@ def all_eqns(jaxpr) -> list:
 
 
 def _sub_jaxprs(param) -> Iterable:
-    """Jaxprs embedded in one eqn param: a ClosedJaxpr, or a list/tuple of
-    them (cond/switch carry `branches`)."""
-    if hasattr(param, "jaxpr"):
+    """Jaxprs embedded in one eqn param: a ClosedJaxpr or raw Jaxpr
+    (``remat2`` carries one), or a list/tuple of them (cond/switch carry
+    `branches`)."""
+    def is_jaxpr(x):
+        return hasattr(x, "jaxpr") or hasattr(x, "eqns")
+
+    if is_jaxpr(param):
         yield param
     elif isinstance(param, (list, tuple)):
         for item in param:
-            if hasattr(item, "jaxpr"):
+            if is_jaxpr(item):
                 yield item
 
 
@@ -51,20 +55,22 @@ def eqn_shapes(eqns) -> List[Tuple[int, ...]]:
 
 def top_level_scans(jaxpr) -> list:
     """The outermost ``scan`` eqns of ``jaxpr`` in program order, looking
-    through a single wrapping ``pjit``/``custom_*`` level (tracing a jitted
-    entry point wraps the whole body in one pjit eqn)."""
+    through a single wrapping ``jit``/``custom_*`` level (tracing a jitted
+    entry point wraps the whole body in one jit eqn)."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
     if scans:
         return scans
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in ("pjit", "custom_vjp_call_jaxpr",
-                                  "custom_jvp_call", "remat"):
-            for sub in _sub_jaxprs(eqn.params.get("jaxpr")):
-                inner = top_level_scans(sub)
-                if inner:
-                    return inner
-            # pjit stores it under 'jaxpr'; vmap-of-jit under nothing else.
+        if eqn.primitive.name in ("jit", "custom_vjp_call",
+                                  "custom_jvp_call", "remat2"):
+            # jit/remat2 keep the body under 'jaxpr', the custom_* calls
+            # under 'call_jaxpr'.
+            for key in ("jaxpr", "call_jaxpr"):
+                for sub in _sub_jaxprs(eqn.params.get(key)):
+                    inner = top_level_scans(sub)
+                    if inner:
+                        return inner
     return scans
 
 

@@ -4,21 +4,21 @@ The jaxpr walkers (:mod:`.jaxpr_walk`) see the program *before* XLA does;
 this module reads what XLA actually emits, at two stages:
 
 - **Lowered StableHLO** (``jitted.lower(...).as_text()``) — where sharding
-  *intent* lives: ``stablehlo.custom_call @Sharding`` /
-  ``@SPMDFullToShardShape`` / ``@SPMDShardToFullShape`` annotations (a
-  ``with_sharding_constraint``, a ``shard_map`` boundary) and explicit
-  host-boundary ops. A resharding custom call in a canonical dp program is
-  someone *asking* for data movement the dp design promises not to need.
+  *intent* lives, in the Shardy (``sdy``) dialect: ``sdy.sharding_constraint``
+  (a ``with_sharding_constraint``), ``sdy.manual_computation`` (a
+  ``shard_map`` boundary) and host-callback custom calls. A resharding op
+  in a canonical dp program is someone *asking* for data movement the dp
+  design promises not to need.
 - **Compiled post-SPMD HLO** (``.compile().as_text()``) — where sharding
-  *consequence* lives: after the GSPMD partitioner runs, every implicit
+  *consequence* lives: after the partitioner runs, every implicit
   reshard has become a real collective (``all-reduce`` / ``all-gather`` /
   ``all-to-all`` / ``collective-permute`` / ``collective-broadcast``) with
   a concrete dtype, shape and replica grouping. This is the ground truth
   the declared-collective contract (:mod:`.collectives`) checks against —
   the compile-time twin of the runtime ``jax.transfer_guard`` tests.
 
-Everything here is string parsing over the textual HLO forms jax 0.4.x
-emits — deliberately: no MLIR bindings, no XLA internals, and the parsed
+Everything here is string parsing over the textual forms the installed
+jax emits — deliberately: no MLIR bindings, no XLA internals, and the parsed
 shapes are cross-checked by seeded-violation tests
 (tests/test_shardcheck.py) so a silent format drift breaks loudly.
 """
@@ -276,46 +276,49 @@ def collective_signature(ops: List[CollectiveOp]) -> dict:
 # StableHLO-side detectors (pre-partitioning intent)
 # ---------------------------------------------------------------------------
 
-_SHARDING_CALL_RE = re.compile(
-    r"stablehlo\.custom_call\s+@(Sharding|SPMDFullToShardShape|"
-    r"SPMDShardToFullShape)\b([^\n]*)")
-_MHLO_SHARDING_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
-_RESULT_TENSOR_RE = re.compile(r"->\s*tensor<([^>]*)>")
+# `%2 = sdy.sharding_constraint %1 <@mesh, [{"dp"}, {}]> : tensor<4x8xf32>`
+# (also sdy.reshard, same spelling), and the shard_map boundary
+# `sdy.manual_computation(...) in_shardings=[...] out_shardings=[...]
+# manual_axes={...} (...) {` whose result type closes the region.
+_SDY_CONSTRAINT_RE = re.compile(
+    r"sdy\.(sharding_constraint|reshard)\s+%\S+\s+<@[\w.]+,\s*"
+    r"(\[[^\n]*?\])(?:,[^>\n]*)?>\s*:\s*tensor<([^>]*)>")
+_SDY_MANUAL_RE = re.compile(
+    r"sdy\.(manual_computation)\([^\n]*?out_shardings=(\[[^\n]*?\])\s*"
+    r"manual_axes=")
+_SDY_AXIS_RE = re.compile(r'"[^"]+"')
 
 
 @dataclasses.dataclass
 class ShardingChange:
-    """One sharding-changing custom call in lowered StableHLO."""
+    """One sharding-changing op in lowered StableHLO (``sdy`` dialect)."""
 
-    target: str        # Sharding | SPMDFullToShardShape | SPMDShardToFullShape
-    sharding: str      # the mhlo.sharding attribute ("" when absent)
-    result_type: str   # e.g. "4x8x8x16xf32"
+    target: str        # sharding_constraint | reshard | manual_computation
+    sharding: str      # the per-dimension axis list, e.g. '[{"dp"}, {}]'
+    result_type: str   # e.g. "4x8x8x16xf32" ("?" for a manual computation)
 
     def describe(self) -> str:
-        return (f"@{self.target} -> tensor<{self.result_type}> "
+        return (f"sdy.{self.target} -> tensor<{self.result_type}> "
                 f"sharding={self.sharding or '?'}")
 
     @property
     def forces_replication(self) -> bool:
         """A mid-program constraint that replicates a value — the "silent
-        full replication of a dp-sharded tensor" shape of the bug."""
-        return "replicated" in self.sharding
+        full replication of a dp-sharded tensor" shape of the bug: a
+        constraint whose every dimension names no mesh axis."""
+        return (self.target != "manual_computation"
+                and not _SDY_AXIS_RE.search(self.sharding))
 
 
-def sharding_custom_calls(stablehlo_text: str) -> List[ShardingChange]:
-    """All sharding-changing custom calls in a lowered StableHLO module.
-    Input-argument shardings (``mhlo.sharding`` on the entry params) are
+def sharding_changes(stablehlo_text: str) -> List[ShardingChange]:
+    """All sharding-changing ops in a lowered StableHLO module.
+    Input-argument shardings (``sdy.sharding`` on the entry params) are
     NOT included: staging inputs under a NamedSharding is the declared
     dispatch contract, not a mid-program reshard."""
-    out = []
-    for m in _SHARDING_CALL_RE.finditer(stablehlo_text):
-        rest = m.group(2)
-        sh = _MHLO_SHARDING_RE.search(rest)
-        res = _RESULT_TENSOR_RE.search(rest)
-        out.append(ShardingChange(
-            target=m.group(1),
-            sharding=sh.group(1) if sh else "",
-            result_type=res.group(1) if res else "?"))
+    out = [ShardingChange(m.group(1), m.group(2), m.group(3))
+           for m in _SDY_CONSTRAINT_RE.finditer(stablehlo_text)]
+    out += [ShardingChange(m.group(1), m.group(2), "?")
+            for m in _SDY_MANUAL_RE.finditer(stablehlo_text)]
     return out
 
 
@@ -324,20 +327,16 @@ def sharding_custom_calls(stablehlo_text: str) -> List[ShardingChange]:
 # ---------------------------------------------------------------------------
 
 _HOST_HLO_RE = re.compile(
-    r"\b(infeed|outfeed)(?:\.\d+)?\(|"
     r'custom-call[^\n]*custom_call_target="([^"]*callback[^"]*)"')
 _HOST_SHLO_RE = re.compile(
-    r"stablehlo\.(infeed|outfeed)\b|"
     r'stablehlo\.custom_call\s+@([\w.]*callback[\w.]*)')
 
 
 def host_boundary_ops(text: str) -> List[str]:
     """Host-crossing ops in either a StableHLO or a compiled HLO module:
-    infeed/outfeed and host-callback custom calls. Each entry names the op
-    (and callback target when present)."""
-    out = []
-    for m in _HOST_HLO_RE.finditer(text):
-        out.append(m.group(1) or f"custom-call:{m.group(2)}")
-    for m in _HOST_SHLO_RE.finditer(text):
-        out.append(m.group(1) or f"custom_call:@{m.group(2)}")
+    the host-callback custom calls ``io_callback`` / ``jax.debug.callback``
+    / ``pure_callback`` lower to. Each entry names the callback target."""
+    out = [f"custom-call:{m.group(1)}" for m in _HOST_HLO_RE.finditer(text)]
+    out += [f"custom_call:@{m.group(1)}"
+            for m in _HOST_SHLO_RE.finditer(text)]
     return out

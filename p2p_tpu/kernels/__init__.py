@@ -15,7 +15,6 @@ site dispatch. Nothing here imports ``engine``.
 """
 
 from ..controllers.kernel_spec import LANE
-from .interpret import force_tpu_interpret_mode, install_discharge_fix
 from .fused_edit import (
     edit_attention,
     edit_attention_reference,
@@ -39,8 +38,6 @@ __all__ = [
     "VARIANT_USE",
     "edit_attention",
     "edit_attention_reference",
-    "force_tpu_interpret_mode",
-    "install_discharge_fix",
     "pad_to_lanes",
     "site_variant",
 ]

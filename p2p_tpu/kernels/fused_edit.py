@@ -137,8 +137,8 @@ def edit_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
     :func:`controllers.kernel_spec.edit_operands` (already indexed at the
     step). Returns ``(2B, heads, P, D)`` in ``v.dtype``. ``block_q=0``
     picks the largest VMEM-feasible query block (``models.nn.edit_block``);
-    ``interpret=True`` runs the pallas interpreter (the CPU parity surface,
-    jax-0.4.37 discharge fix installed by ``kernels.interpret``)."""
+    ``interpret=True`` runs the pallas interpreter (the CPU parity
+    surface)."""
     two_b, heads, pixels, d_head = q.shape
     b_half = two_b // 2
     num_edits = b_half - 1
@@ -155,11 +155,6 @@ def edit_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
         raise ValueError(
             f"no VMEM-feasible query block for P={pixels}, K={spec.key_len}, "
             f"D={d_head} (got block_q={block_q})")
-    if interpret:
-        from .interpret import install_discharge_fix
-
-        install_discharge_fix()
-
     k_p = pad_to_lanes(k, 2, kp)
     v_p = pad_to_lanes(v, 2, kp)
     kmask = jnp.where(jnp.arange(kp) < spec.key_len, 0.0,
@@ -207,7 +202,7 @@ def edit_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
 
     kernel = functools.partial(_edit_kernel, spec=spec, scale=scale,
                                b_half=b_half, num_edits=num_edits)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(two_b, heads, pixels // block_q),
         in_specs=in_specs,
@@ -215,7 +210,8 @@ def edit_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
         out_shape=jax.ShapeDtypeStruct((two_b, heads, pixels, d_head),
                                        v.dtype),
         interpret=interpret,
-    )(*inputs)
+    )
+    return nn.per_device(call)(*inputs)
 
 
 def edit_attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,

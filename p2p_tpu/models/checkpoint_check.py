@@ -2,8 +2,8 @@
 preset WITHOUT loading it into a model (CLI: `p2p-tpu check`, or
 `python tools/check_checkpoint.py`).
 
-First contact with real weights should be a config report, not a crash
-(VERDICT r2 item 5). For each sub-model the tool diffs the checkpoint's
+First contact with real weights should be a config report, not a crash.
+For each sub-model the tool diffs the checkpoint's
 tensor names/shapes against the mapping tables in
 `p2p_tpu/models/checkpoint.py` (both directions: mapped-but-missing and
 present-but-unmapped), using `jax.eval_shape` over the init functions so the

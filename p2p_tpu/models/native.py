@@ -115,12 +115,9 @@ def load_pipeline_native(path: str, tokenizer,
     if config is None:
         with open(os.path.join(path, "config.json")) as f:
             config = config_from_dict(json.load(f))
-    from .compat import metadata_tree
-
     ckptr = ocp.PyTreeCheckpointer()
     params_dir = os.path.join(path, "params")
-    # metadata() return shape drifted across orbax releases — shimmed.
-    meta = metadata_tree(ckptr, params_dir)
+    meta = ckptr.metadata(params_dir).item_metadata.tree
     restore_args = jax.tree.map(
         lambda _: ocp.RestoreArgs(restore_type=np.ndarray), meta)
     params = ckptr.restore(params_dir, restore_args=restore_args)

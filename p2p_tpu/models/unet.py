@@ -308,12 +308,17 @@ def _fused_edit_dispatch(ctx: _HookCtx, meta, q, k, v, scale):
     probability tensor never reaches HBM at fused sites. Compiled-kernel
     lowering only exists on TPU; ``interpret=True`` configs run the
     identical program through the pallas interpreter (the CPU parity
-    surface). Attention-STORE sites are never fused (``kernel_edit_spec``
+    surface) and anything else off-TPU is a trace-time error — a kernel
+    run that quietly took the materialized path would pass as a kernel
+    run. Attention-STORE sites are never fused (``kernel_edit_spec``
     returns None for them — the store needs the materialized tensor)."""
     if ctx.kernels is None:
         return None
     if not (ctx.kernels.interpret or nn._on_tpu()):
-        return None
+        raise RuntimeError(
+            f"KernelConfig without interpret=True on the "
+            f"{jax.default_backend()!r} backend: the fused-edit kernel only "
+            f"lowers on TPU — pass KernelConfig(interpret=True) off-chip")
     from .. import kernels as kernels_mod
 
     if not ctx.kernels.covers(kernels_mod.dispatch.site_name(meta)):

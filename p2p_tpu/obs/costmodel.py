@@ -69,7 +69,7 @@ MFU_PCT_BUCKETS = (1.0, 2.0, 5.0, 10.0, 15.0, 20.0, 30.0, 40.0, 50.0,
 
 
 # ---------------------------------------------------------------------------
-# cost_analysis / memory_analysis extraction (the shared API-drift guard)
+# cost_analysis / memory_analysis extraction
 # ---------------------------------------------------------------------------
 
 
@@ -78,19 +78,9 @@ def cost_analysis_dict(compiled) -> dict:
     dict — the shared parser behind every driver (this module,
     ``tools/profiling/prof_breakdown.py``).
 
-    Guards the known jax API drift: older releases return a *list* of
-    per-computation dicts, newer ones a plain dict; some backends return
-    None or raise. Always returns a dict ({} when nothing is available),
-    never raises."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    if isinstance(ca, (list, tuple)) and ca and isinstance(ca[0], dict):
-        return dict(ca[0])
-    return {}
+    ``cost_analysis()`` returns a plain dict, or None where the backend
+    exposes nothing ({} then)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def memory_analysis_dict(compiled) -> dict:
@@ -249,27 +239,24 @@ def _timed(fn) -> float:
 
 def detect_peaks(device=None) -> Peaks:
     """Peaks for ``device`` (default: the first local device): datasheet
-    numbers for known accelerators, calibrated microbenchmarks for the
-    CPU rehearsal host, and a calibration fallback for unknown hardware
-    (honest measured numbers beat a guessed table row). The fallback
-    keeps the device's real platform label — a microbenchmark run on an
-    unlisted accelerator is still *that* device's calibration, and
-    labeling it "cpu" would be exactly the provenance confusion the
-    ``source`` field exists to prevent (a tiny matmul cannot saturate a
-    big accelerator, so treat fallback MFU as an upper bound there)."""
+    numbers for the accelerators in :data:`PLATFORM_PEAKS`, calibrated
+    microbenchmarks for the CPU rehearsal host. An accelerator missing
+    from the table is an error naming its ``device_kind`` — a roofline
+    against made-up peaks under the device's own label would read as a
+    measurement."""
     import jax
 
     if device is None:
         device = jax.local_devices()[0]
-    if device.platform != "cpu":
-        peaks = lookup_peaks(getattr(device, "device_kind", ""))
-        if peaks is not None:
-            return peaks
-        return dataclasses.replace(
-            calibrated_cpu_peaks(),
-            platform=(getattr(device, "device_kind", "")
-                      or device.platform))
-    return calibrated_cpu_peaks()
+    if device.platform == "cpu":
+        return calibrated_cpu_peaks()
+    peaks = lookup_peaks(device.device_kind)
+    if peaks is None:
+        raise ValueError(
+            f"no datasheet peaks for device_kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add a PLATFORM_PEAKS row "
+            f"(known: {sorted(PLATFORM_PEAKS)})")
+    return peaks
 
 
 def lookup_peaks(device_kind: str) -> Optional[Peaks]:

@@ -23,8 +23,6 @@ from functools import partial
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
-
 
 def alltoall_self_attention_shard(
     q: jax.Array, k: jax.Array, v: jax.Array, scale: float, axis_name: str,
@@ -70,7 +68,7 @@ def alltoall_self_attention(
     # the varying-mesh-axes metadata shard_map's checker wants.
     from ..models import nn
 
-    f = shard_map(
+    f = jax.shard_map(
         partial(alltoall_self_attention_shard, scale=scale,
                 axis_name=axis_name),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -68,20 +68,8 @@ def initialize(
     if not explicit and not cluster:
         return False  # nothing indicates a multi-process launch
 
-    # initialize() must precede first backend use. Degrading per-process here
-    # would split the job topology (peers block on a coordinator that never
-    # starts, process_groups overlap) — fail loudly and identically instead.
-    try:
-        from jax._src import xla_bridge as _xb
-
-        backends_up = _xb.backends_are_initialized()
-    except Exception:  # private API moved; jax will raise its own clear
-        backends_up = False  # RuntimeError below if we really are late
-    if backends_up:
-        raise RuntimeError(
-            "multihost.initialize() must run before any JAX computation "
-            "(jax.devices(), device_put, ...) — call it first in main()")
-
+    # initialize() must precede first backend use; jax raises a clear
+    # RuntimeError itself when it does not.
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
-
 
 def _block_attend_einsum(q, k, v, scale):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -165,7 +163,7 @@ def ring_self_attention(
     # check_vma only off for the flash chunks: pallas_call does not yet carry
     # the varying-mesh-axes metadata shard_map's checker wants. The einsum
     # path keeps the checker on.
-    f = shard_map(
+    f = jax.shard_map(
         partial(ring_self_attention_shard, scale=scale, axis_name=axis_name,
                 use_flash=use_flash),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

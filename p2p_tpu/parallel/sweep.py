@@ -25,6 +25,7 @@ from ..controllers.base import AttnLayout, Controller
 from ..engine.sampler import (PhaseCarry, _denoise_scan, _phase1_scan,
                               _phase2_scan, resolve_gate, resolve_reuse,
                               stage_host, warn_gate_truncation)
+from ..models import nn
 from ..models import vae as vae_mod
 from ..models.config import PipelineConfig
 from ..ops import schedulers as sched_mod
@@ -32,7 +33,7 @@ from ..ops import schedulers as sched_mod
 
 @partial(jax.jit, static_argnames=("cfg", "layout", "scheduler_kind",
                                    "progress", "gate", "metrics", "reuse",
-                                   "kernels"),
+                                   "kernels", "mesh"),
          donate_argnums=())
 def _sweep_jit(
     unet_params: Any,
@@ -51,6 +52,7 @@ def _sweep_jit(
     metrics: bool = False,
     reuse=None,
     kernels=None,
+    mesh: Optional[Mesh] = None,
 ):
     def one_group(ctx, lat, ctrl, ups):
         # The scanned step index is vmap-invariant (built inside the scan,
@@ -64,7 +66,22 @@ def _sweep_jit(
         image = vae_mod.decode(vae_params, cfg.vae, lat.astype(jnp.float32))
         return vae_mod.to_uint8(image), lat
 
-    return jax.vmap(one_group)(context, latents, controllers, uncond_per_step)
+    return _vmap_groups(one_group, mesh)(context, latents, controllers,
+                                         uncond_per_step)
+
+
+def _vmap_groups(one_group, mesh: Optional[Mesh]):
+    """``vmap`` over the group axis. Under a mesh that axis is the one the
+    inputs are sharded on (``dp``), and the Pallas kernels in the body run
+    per device (``nn.kernel_mesh``) — the partitioner cannot split them."""
+    if mesh is None:
+        return jax.vmap(one_group)
+
+    def groups(*args):
+        with nn.kernel_mesh(mesh):
+            return jax.vmap(one_group, spmd_axis_name="dp")(*args)
+
+    return groups
 
 
 def _stage_replicated(tree, mesh: Mesh):
@@ -242,12 +259,12 @@ def sweep(
                           schedule, scheduler, context, latents, controllers,
                           gs, uncond_per_step, progress=progress,
                           gate=gate_step, metrics=metrics,
-                          reuse=reuse_sched, kernels=kernels)
+                          reuse=reuse_sched, kernels=kernels, mesh=mesh)
 
 
 @partial(jax.jit, static_argnames=("cfg", "layout", "scheduler_kind",
                                    "progress", "gate", "metrics", "reuse",
-                                   "kernels"),
+                                   "kernels", "mesh"),
          donate_argnums=())
 def _sweep_phase1_jit(
     unet_params: Any,
@@ -264,6 +281,7 @@ def _sweep_phase1_jit(
     metrics: bool = False,
     reuse=None,
     kernels=None,
+    mesh: Optional[Mesh] = None,
 ) -> PhaseCarry:
     """The serve layer's phase-1 POOL program: steps ``[0, gate)`` of G
     groups under full CFG + controller hooks, returning the per-group
@@ -278,12 +296,12 @@ def _sweep_phase1_jit(
                             gate=gate, progress=progress, metrics=metrics,
                             reuse=reuse, kernels=kernels)
 
-    return jax.vmap(one_group)(context, latents, controllers)
+    return _vmap_groups(one_group, mesh)(context, latents, controllers)
 
 
 @partial(jax.jit, static_argnames=("cfg", "layout", "scheduler_kind",
                                    "progress", "gate", "metrics", "reuse",
-                                   "kernels"),
+                                   "kernels", "mesh"),
          donate_argnums=())
 def _sweep_phase2_jit(
     unet_params: Any,
@@ -301,6 +319,7 @@ def _sweep_phase2_jit(
     metrics: bool = False,
     reuse=None,
     kernels=None,
+    mesh: Optional[Mesh] = None,
 ):
     """The serve layer's phase-2 POOL program: steps ``[gate, S)`` of G
     hand-off carries — single-branch U-Net off the AttnCache, fixed-
@@ -316,7 +335,7 @@ def _sweep_phase2_jit(
         image = vae_mod.decode(vae_params, cfg.vae, lat.astype(jnp.float32))
         return vae_mod.to_uint8(image), lat
 
-    return jax.vmap(one_group)(context_cond, carry, controllers)
+    return _vmap_groups(one_group, mesh)(context_cond, carry, controllers)
 
 
 def _phase_args(pipe, num_steps: int, scheduler: str, gate,
@@ -401,7 +420,7 @@ def sweep_phase1(
                                  scheduler, context, latents, controllers,
                                  gs, progress=progress, gate=gate_step,
                                  metrics=metrics, reuse=reuse_sched,
-                                 kernels=kernels)
+                                 kernels=kernels, mesh=mesh)
 
 
 def sweep_phase2(
@@ -458,7 +477,8 @@ def sweep_phase2(
                                  layout, schedule, scheduler, context_cond,
                                  carry, controllers, gs, progress=progress,
                                  gate=gate_step, metrics=metrics,
-                                 reuse=reuse_sched, kernels=kernels)
+                                 reuse=reuse_sched, kernels=kernels,
+                                 mesh=mesh)
 
 
 def artifact_replay_inputs(pipe, x_t, uncond_embeddings, source: str,

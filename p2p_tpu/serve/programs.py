@@ -10,8 +10,8 @@ what the per-request ``compile_ms`` field reports.
 
 The LRU evicts host handles only; the actual XLA executables additionally
 live in the repo-wide persistent compile cache
-(``utils.cache.default_cache_dir()``, enabled once per process via
-``utils.cache.ensure_persistent_cache``), so re-building an evicted program
+(``utils.cache.default_cache_dir()``, enabled via
+``utils.cache.enable_persistent_cache``), so re-building an evicted program
 — or the same program in the next server process — is mostly disk I/O, not
 a recompile. Counters (hits / misses / evictions) feed the per-request
 records and the bench ``serve`` block.
@@ -24,7 +24,7 @@ from collections import OrderedDict
 from typing import Callable, Tuple
 
 from ..obs import metrics as obs_metrics
-from ..utils.cache import ensure_persistent_cache
+from ..utils.cache import enable_persistent_cache
 
 
 class ProgramCache:
@@ -47,7 +47,7 @@ class ProgramCache:
         if capacity < 1:
             raise ValueError(f"program cache capacity must be >= 1, "
                              f"got {capacity}")
-        ensure_persistent_cache()
+        enable_persistent_cache()
         self.capacity = capacity
         self.retry_policy = retry_policy
         self._lru: "OrderedDict[Tuple, object]" = OrderedDict()

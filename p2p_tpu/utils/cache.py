@@ -1,87 +1,46 @@
 """Persistent XLA compile cache shared by every entry point.
 
-The SD-1.4 sampling program takes minutes of host-side XLA compilation; the
-reference pays the analogous torch/diffusers warmup every process start. With
-a persistent cache, bench.py / the CLI / the profiling tools compile each
-distinct program once per machine and reload it afterwards (works for both
+The SD-1.4 sampling program takes minutes of XLA compilation. With a
+persistent cache, bench.py / the CLI / the profiling tools compile each
+distinct program once per checkout and reload it afterwards (works for both
 the CPU and TPU backends; keyed on HLO + compile options + backend).
 
-tests/conftest.py sets the same directory via env vars before ``import jax``;
-this helper is the post-import equivalent for non-test entry points.
+One rule, one resolver (:func:`default_cache_dir`): where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself keeps its cache there and
+this module sets nothing; otherwise the single fixed ``<checkout>/.jax_cache``
+(gitignored). The path is part of the cache's key, so it never depends on
+``XLA_FLAGS`` (JAX already keys entries on the flags), the pid, the time or
+a temp name. tests/conftest.py and the tools that start children export the
+resolved directory before ``import jax`` so parent and child agree.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import sys
 
 _DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache")
 
 
-def default_cache_dir(hash_xla_flags: bool = True) -> str:
-    """The cache directory every entry point (and test conftest/subprocess
-    env) should agree on: a pre-set ``JAX_COMPILATION_CACHE_DIR`` env var
-    verbatim — so CI and multi-checkout machines can share ONE cache instead
-    of each clone growing its own ``.jax_cache`` — else the repo-local
-    default, suffixed with a hash of the ambient ``XLA_FLAGS`` (not every XLA
-    flag reaches the cache key, so two processes with different codegen flags
-    must never reload each other's executables). jax-free, so test conftests
-    can call it before their first ``import jax``."""
-    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env_dir:
-        return env_dir
-    flags = os.environ.get("XLA_FLAGS", "") if hash_xla_flags else ""
-    suffix = ("-" + hashlib.sha256(flags.encode()).hexdigest()[:12]
-              if flags else "")
-    return _DEFAULT_DIR + suffix
+def default_cache_dir() -> str:
+    """The cache directory every entry point agrees on: a pre-set
+    ``JAX_COMPILATION_CACHE_DIR`` verbatim, else ``<checkout>/.jax_cache``.
+    jax-free, so callers can resolve it before their first ``import jax``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _DEFAULT_DIR
 
 
-_ENSURED: dict = {}
+def enable_persistent_cache() -> str:
+    """Turn JAX's persistent compilation cache on at
+    :func:`default_cache_dir` and return the directory. With
+    ``JAX_COMPILATION_CACHE_DIR`` set JAX has already read it, and nothing
+    is configured here. Safe to call more than once; a directory that
+    cannot be created raises (an entry point that silently recompiles
+    minutes of XLA per start is not "working")."""
+    cache_dir = default_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
 
-
-def ensure_persistent_cache() -> str | None:
-    """:func:`enable_persistent_cache` exactly once per process.
-
-    Long-lived entry points (the serve loop's program cache, anything that
-    builds programs repeatedly) want the persistent XLA cache on without
-    re-running the setup — or re-printing its failure warning — per call.
-    Returns the cache dir of the first (and only) attempt, None if that
-    attempt failed.
-    """
-    if "dir" not in _ENSURED:
-        _ENSURED["dir"] = enable_persistent_cache()
-    return _ENSURED["dir"]
-
-
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (defaults to
-    :func:`default_cache_dir` — a pre-set ``JAX_COMPILATION_CACHE_DIR``, else
-    ``<repo>/.jax_cache``, gitignored). Safe to call more than once.
-
-    The ``JAX_PERSISTENT_CACHE_*`` env knobs are honored when set. The cache
-    is a pure optimization: any failure to set it up is reported and skipped.
-    """
-    import jax
-
-    if cache_dir is None:
-        cache_dir = default_cache_dir()
-    try:
-        # Parse everything before the first config.update so the settings
-        # apply all-or-nothing (a late parse error must not leave the cache
-        # half-enabled while we report it disabled).
-        min_secs = float(
-            os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 1.0))
-        min_bytes = int(
-            os.environ.get("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", 0))
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", min_bytes)
-    except Exception as e:  # cache must never take an entry point down
-        print(f"persistent compile cache disabled ({type(e).__name__}: {e})",
-              file=sys.stderr)
-        return None
     return cache_dir

@@ -1,4 +1,8 @@
-"""Unit tests for the persistent-compile-cache helper (`p2p_tpu/utils/cache.py`)."""
+"""Unit tests for the persistent-compile-cache helper (`p2p_tpu/utils/cache.py`).
+
+Two rules: with ``JAX_COMPILATION_CACHE_DIR`` set, that directory and no
+other ``jax_compilation_cache_dir`` update; unset, exactly
+``<checkout>/.jax_cache`` whatever ``XLA_FLAGS`` says."""
 
 import os
 
@@ -7,66 +11,66 @@ import pytest
 
 from p2p_tpu.utils import cache as cache_mod
 
-
-@pytest.fixture(autouse=True)
-def restore_cache_config(monkeypatch, tmp_path):
-    """Each test gets a scratch default dir and leaves the process-global jax
-    cache config exactly as the suite's conftest established it afterwards
-    (dir AND thresholds — a leaked threshold silently stops cache writes for
-    the rest of the in-process suite)."""
-    monkeypatch.setattr(cache_mod, "_DEFAULT_DIR", str(tmp_path / "cache"))
-    before = (jax.config.jax_compilation_cache_dir,
-              jax.config.jax_persistent_cache_min_compile_time_secs,
-              jax.config.jax_persistent_cache_min_entry_size_bytes)
-    yield
-    jax.config.update("jax_compilation_cache_dir", before[0])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", before[1])
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", before[2])
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_explicit_dir_wins(tmp_path):
-    d = str(tmp_path / "explicit")
-    assert cache_mod.enable_persistent_cache(d) == d
-    assert os.path.isdir(d)
-    assert jax.config.jax_compilation_cache_dir == d
+@pytest.fixture
+def dir_updates(monkeypatch):
+    """Every ``jax.config.update("jax_compilation_cache_dir", X)`` made while
+    the test runs, recorded and not applied (the suite's own cache config
+    stays as conftest established it)."""
+    seen = []
+    real = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        else:
+            real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    return seen
 
 
-def test_env_dir_wins_over_default(monkeypatch, tmp_path):
+def test_env_dir_is_used_and_nothing_else_is_set(monkeypatch, tmp_path,
+                                                 dir_updates):
     d = str(tmp_path / "from_env")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
-    assert cache_mod.enable_persistent_cache() == d
-
-
-def test_default_dir_hashes_xla_flags(monkeypatch):
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    monkeypatch.delenv("XLA_FLAGS", raising=False)
-    plain = cache_mod.enable_persistent_cache()
     monkeypatch.setenv("XLA_FLAGS", "--xla_tpu_scoped_vmem_limit_kib=131072")
-    flagged = cache_mod.enable_persistent_cache()
-    monkeypatch.setenv("XLA_FLAGS", "--xla_tpu_enable_latency_hiding_scheduler=true")
-    flagged2 = cache_mod.enable_persistent_cache()
-    # No flags → the plain dir; each distinct flag set → its own dir.
-    assert plain == cache_mod._DEFAULT_DIR
-    assert flagged != plain and flagged2 not in (plain, flagged)
-    assert flagged.startswith(cache_mod._DEFAULT_DIR + "-")
+    assert cache_mod.default_cache_dir() == d
+    assert cache_mod.enable_persistent_cache() == d
+    # JAX reads the variable itself: no update at all, so none to another
+    # directory — and nothing is created on its behalf.
+    assert dir_updates == []
+    assert not os.path.exists(d)
 
 
-def test_thresholds_honor_env(monkeypatch, tmp_path):
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
-    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "7.5")
-    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "123")
-    assert cache_mod.enable_persistent_cache() is not None
-    assert jax.config.jax_persistent_cache_min_compile_time_secs == 7.5
-    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 123
+@pytest.mark.parametrize("xla_flags", [
+    None, "--xla_tpu_scoped_vmem_limit_kib=131072",
+    "--xla_force_host_platform_device_count=8"])
+def test_default_dir_is_the_checkouts_whatever_xla_flags(monkeypatch,
+                                                         dir_updates,
+                                                         xla_flags):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    if xla_flags is None:
+        monkeypatch.delenv("XLA_FLAGS", raising=False)
+    else:
+        monkeypatch.setenv("XLA_FLAGS", xla_flags)
+    want = os.path.join(REPO, ".jax_cache")
+    assert cache_mod.default_cache_dir() == want
+    assert cache_mod.enable_persistent_cache() == want
+    assert cache_mod.enable_persistent_cache() == want     # idempotent
+    assert dir_updates == [want, want]
+    assert os.path.isdir(want)
 
 
-def test_failure_is_reported_not_fatal(monkeypatch, tmp_path, capsys):
-    """A bad env knob (or an uncreatable dir) must disable the cache as a
-    whole, not half-apply: parse errors surface before any config.update."""
-    before = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c2"))
-    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "not_a_float")
-    assert cache_mod.enable_persistent_cache() is None
-    assert "disabled" in capsys.readouterr().err
-    # The cache dir config was not touched by the failed call.
-    assert jax.config.jax_compilation_cache_dir == before
+def test_setup_failure_raises(monkeypatch, tmp_path, dir_updates):
+    # A cache directory that cannot be created is an error, not a warning:
+    # the default dir is pointed below a regular file.
+    blocker = tmp_path / "file"
+    blocker.write_text("")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cache_mod, "_DEFAULT_DIR", str(blocker / "cache"))
+    with pytest.raises(OSError):
+        cache_mod.enable_persistent_cache()
+    assert dir_updates == []

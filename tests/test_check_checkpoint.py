@@ -1,6 +1,6 @@
 """Checkpoint-readiness reports (`p2p_tpu.models.checkpoint_check`, surfaced
 as `p2p-tpu check` and `tools/check_checkpoint.py`) against synthetic
-diffusers-layout directories (VERDICT r2 item 5): a correct dir reports READY;
+diffusers-layout directories: a correct dir reports READY;
 shape drift, missing/unmapped tensors, scheduler-config drift, and missing
 tokenizer files each surface as a named problem instead of a load-time crash.
 """

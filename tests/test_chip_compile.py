@@ -1,0 +1,282 @@
+"""Ask the chip's compiler, without a chip: the Pallas kernels of the main
+path compiled for a *described* TPU v5e (``v5e:2x2`` topology, nothing
+attached) at SD-1.4 widths in bf16.
+
+Interpret-mode tests (tests/test_kernels.py, tests/test_flash_pallas.py)
+prove the kernels' arithmetic; they cannot see what Mosaic refuses — a
+block that does not align to the tiling, a kernel over its scoped-VMEM
+budget. These compiles can, at about a second each and no chip time:
+
+(a) the fused edit kernel (``kernels.fused_edit.fused_site_attention``) for
+    the replace, refine and reweight controllers at every distinct site
+    geometry of ``unet_layout(SD14.unet).metas`` the kernel covers (the
+    64x64 self site is not fused by design and stays on flash);
+(b) ``nn.flash_attention_tpu`` forward and its ``jax.grad`` (null-text
+    inversion differentiates through it) at the 4096- and 1024-pixel self
+    sites;
+(c) ``nn.flash_attention_residuals`` at the ring-attention chunk geometry;
+(d) both kernels inside a program partitioned over a ``dp`` mesh of the four
+    described chips, the way ``parallel.sweep`` traces its groups
+    (``nn.kernel_mesh`` + ``vmap(spmd_axis_name="dp")``) — the partitioner
+    refuses a Mosaic kernel that is not wrapped per device, which is how
+    ``serve --mesh dp=4`` first failed on four chips.
+
+Nothing runs, so nothing here says anything about results or times. The
+topology is described inside a module-scoped fixture (never at import: the
+TPU library belongs to one process, and every xdist worker imports this
+file), in this process (a child could not load the library either), with
+the persistent compilation cache off (a described-device executable cannot
+be read back from it). All of these tests live in this one file so one
+worker gets them all.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from p2p_tpu.align.words import get_equalizer
+from p2p_tpu.controllers import factory
+from p2p_tpu.controllers.kernel_spec import kernel_edit_spec
+from p2p_tpu.kernels.fused_edit import fused_site_attention
+from p2p_tpu.models import SD14, nn
+from p2p_tpu.models.config import unet_layout
+from p2p_tpu.utils.tokenizer import HashWordTokenizer
+
+STEPS = 50
+CFG_BATCH = 4          # [uncond; uncond; base; edit] of a 2-prompt edit
+MODES = ("replace", "refine", "reweight")
+
+
+def _geometries():
+    """One ``AttnMeta`` per distinct (cross?, pixels, d_head) of SD-1.4."""
+    seen = {}
+    for m in unet_layout(SD14.unet).metas:
+        seen.setdefault((m.is_cross, m.pixels, m.channels // m.heads), m)
+    return seen
+
+
+GEOMETRIES = _geometries()
+#: The sites the fused kernel covers: everything but the 64x64 self site.
+FUSED = [key for key in GEOMETRIES if key[0] or key[1] < 4096]
+
+
+def _geom_id(key):
+    cross, pixels, d_head = key
+    return f"{'cross' if cross else 'self'}-P{pixels}-d{d_head}"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def controllers():
+    tok = HashWordTokenizer(model_max_length=SD14.text.max_length)
+    kw = dict(tokenizer=tok, max_len=SD14.text.max_length,
+              self_max_pixels=32 * 32, store=False)
+    base = "a cat riding a bike"
+    eq = get_equalizer(base, ["cat"], [3.0], tok, mode="paired")
+    return {
+        "replace": factory.attention_replace(
+            [base, "the dog eating some pizza"], STEPS, 0.8, 0.4, **kw),
+        "refine": factory.attention_refine(
+            [base, "a fluffy cat riding a red bike"], STEPS, 0.8, 0.4, **kw),
+        "reweight": factory.attention_reweight(
+            [base, base], STEPS, 0.8, 0.4, eq, **kw),
+    }
+
+
+def _shapes(tree, sharding):
+    """``tree`` with every array replaced by its shape on the described
+    device (there is no device to hold an array)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        "the compiled program holds no Pallas kernel")
+    return compiled
+
+
+def _qkv(one_chip, batch, heads, pixels, key_len, d_head):
+    def sds(n):
+        return jax.ShapeDtypeStruct((batch, heads, n, d_head), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    return sds(pixels), sds(key_len), sds(key_len)
+
+
+def test_described_device_is_the_v5e_the_peaks_table_knows(topo):
+    from p2p_tpu.obs import costmodel
+
+    dev = topo.devices[0]
+    assert dev.platform == "tpu"
+    assert costmodel.lookup_peaks(dev.device_kind) is \
+        costmodel.PLATFORM_PEAKS["v5 lite"]
+
+
+def test_fused_geometries_cover_the_layout():
+    # cross P in {4096, 1024, 256, 64}, self P in {1024, 256, 64}; the
+    # 64x64 self site is the only one left to flash.
+    assert sorted(k[1] for k in FUSED if k[0]) == [64, 256, 1024, 4096]
+    assert sorted(k[1] for k in FUSED if not k[0]) == [64, 256, 1024]
+    assert [k for k in GEOMETRIES if k not in FUSED] == [(False, 4096, 40)]
+
+
+@pytest.mark.parametrize("key", FUSED, ids=_geom_id)
+@pytest.mark.parametrize("mode", MODES)
+def test_fused_edit_kernel_compiles(one_chip, controllers, mode, key):
+    meta = GEOMETRIES[key]
+    ctrl = controllers[mode]
+    d_head = key[2]
+    assert kernel_edit_spec(ctrl, meta) is not None
+    q, k, v = _qkv(one_chip, CFG_BATCH, meta.heads, meta.pixels,
+                   meta.key_len, d_head)
+
+    def site(ctrl, q, k, v, step):
+        out = fused_site_attention(q, k, v, d_head ** -0.5, ctrl, meta, step)
+        assert out is not None, "the kernel declined this site"
+        return out
+
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _compile(site, _shapes(ctrl, one_chip), q, k, v, step)
+
+
+#: (pixels, d_head) of the 64x64 and 32x32 self sites.
+FLASH_SITES = [(4096, 40), (1024, 80)]
+
+
+@pytest.mark.parametrize("pixels,d_head", FLASH_SITES)
+def test_flash_forward_compiles(one_chip, pixels, d_head):
+    blk = nn.flash_block(pixels, d_head, 2)
+    assert blk > 0
+    q, k, v = _qkv(one_chip, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
+                   d_head)
+    _compile(lambda q, k, v: nn.flash_attention_tpu(q, k, v, d_head ** -0.5,
+                                                    blk), q, k, v)
+
+
+@pytest.mark.parametrize("pixels,d_head", FLASH_SITES)
+def test_flash_backward_compiles(one_chip, pixels, d_head):
+    # Null-text inversion backpropagates through the flash sites: every
+    # backward block of nn._flash_block_sizes has to be one Mosaic accepts.
+    blk = nn.flash_block(pixels, d_head, 2)
+    q, k, v = _qkv(one_chip, 2, SD14.unet.num_heads, pixels, pixels, d_head)
+
+    def loss(q, k, v):
+        out = nn.flash_attention_tpu(q, k, v, d_head ** -0.5, blk)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+
+@pytest.mark.parametrize("sp", [2, 4])
+def test_flash_residuals_compile_at_ring_chunk(one_chip, sp):
+    # parallel/ring.py runs the residuals kernel on the local chunk of the
+    # 64x64 self site: 4096 / sp pixels, d_head 40.
+    pixels, d_head = 4096 // sp, 40
+    blk = nn.flash_block(pixels, d_head, 2)
+    assert blk > 0
+    q, k, v = _qkv(one_chip, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
+                   d_head)
+    compiled = _compile(
+        lambda q, k, v: nn.flash_attention_residuals(q, k, v, d_head ** -0.5,
+                                                     blk), q, k, v)
+    out, l, m = compiled.out_info
+    assert out.shape == q.shape and l.shape == m.shape == q.shape[:3]
+
+
+@pytest.fixture(scope="module")
+def dp_mesh(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices), ("dp",))
+
+
+def _dp_groups(dp_mesh, *shapes):
+    """One group per device: ``shapes`` with a leading, dp-sharded axis."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    grp = NamedSharding(dp_mesh, P("dp"))
+    n = dp_mesh.devices.size
+    return [jax.ShapeDtypeStruct((n,) + s.shape, s.dtype, sharding=grp)
+            for s in shapes]
+
+
+def _groups(one_group, mesh):
+    """``parallel.sweep._vmap_groups``: the program's own way of running
+    groups under a mesh."""
+    from p2p_tpu.parallel.sweep import _vmap_groups
+
+    return _vmap_groups(one_group, mesh)
+
+
+def test_flash_kernel_compiles_under_a_dp_mesh(topo, dp_mesh):
+    pixels, d_head = FLASH_SITES[0]
+    blk = nn.flash_block(pixels, d_head, 2)
+    single = _qkv(None, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
+                  d_head)
+    q, k, v = _dp_groups(dp_mesh, *single)
+
+    def one_group(q, k, v):
+        return nn.flash_attention_tpu(q, k, v, d_head ** -0.5, blk)
+
+    compiled = _compile(_groups(one_group, dp_mesh), q, k, v)
+    assert "all-gather" not in compiled.as_text()
+    # Without the per-device wrapping the partitioner refuses the kernel.
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        jax.jit(jax.vmap(one_group)).lower(q, k, v)
+
+
+def test_fused_edit_kernel_compiles_under_a_dp_mesh(topo, dp_mesh,
+                                                    controllers):
+    key = (True, 4096, 40)
+    meta, ctrl = GEOMETRIES[key], controllers["replace"]
+    single = _qkv(None, CFG_BATCH, meta.heads, meta.pixels, meta.key_len,
+                  key[2])
+    q, k, v = _dp_groups(dp_mesh, *single)
+    ctrl_g = jax.tree.map(lambda a: _dp_groups(dp_mesh, a)[0], ctrl)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    step = jax.ShapeDtypeStruct((), jnp.int32,
+                                sharding=NamedSharding(dp_mesh, P()))
+
+    def program(ctrl_g, q, k, v, step):
+        def one_group(ctrl, q, k, v):
+            return fused_site_attention(q, k, v, key[2] ** -0.5, ctrl, meta,
+                                        step)
+
+        return _groups(one_group, dp_mesh)(ctrl_g, q, k, v)
+
+    _compile(program, ctrl_g, q, k, v, step)

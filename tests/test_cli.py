@@ -151,7 +151,7 @@ def test_rejected_unknown_flag():
 
 def test_group_setup_shards_over_largest_divisor(tiny_pipe, capsys):
     """9 seeds on 8 visible devices must ride a 3-device dp mesh (largest
-    divisor), not silently fall back to one device (ADVICE r3), and say so."""
+    divisor), not silently fall back to one device, and say so."""
     import jax
 
     from p2p_tpu.cli import _group_setup

@@ -41,11 +41,7 @@ class _FakeCompiled:
     def cost_analysis(self):
         if self.shape == "dict":
             return dict(self.d)
-        if self.shape == "list":               # older jax returns [dict]
-            return [dict(self.d)]
-        if self.shape == "none":
-            return None
-        raise RuntimeError("backend exposes no cost analysis")
+        return None
 
     def memory_analysis(self):
         if self.shape == "raises":
@@ -57,13 +53,10 @@ class _FakeCompiled:
             serialized_hlo_proto=b"\xff must never leak")
 
 
-def test_cost_analysis_dict_guards_api_drift():
+def test_cost_analysis_dict_is_a_dict_or_empty():
     want = {"flops": 2.0e9, "bytes accessed": 1.0e8, "transcendentals": 7.0}
     assert costmodel.cost_analysis_dict(_FakeCompiled("dict")) == want
-    assert costmodel.cost_analysis_dict(_FakeCompiled("list")) == want
     assert costmodel.cost_analysis_dict(_FakeCompiled("none")) == {}
-    assert costmodel.cost_analysis_dict(_FakeCompiled("raises")) == {}
-    assert costmodel.cost_analysis_dict(object()) == {}
 
 
 def test_card_from_compiled_and_serializable():
@@ -143,6 +136,13 @@ def test_platform_peak_table_and_detection():
     assert peaks.source == "calibrated"
     assert peaks.flops_per_s > 0 and peaks.bytes_per_s > 0
     assert costmodel.detect_peaks() is peaks       # cached
+    # A listed accelerator gets its datasheet row; an unlisted one is an
+    # error naming the device_kind, never calibration under its label.
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert costmodel.detect_peaks(chip) is v5e
+    odd = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        costmodel.detect_peaks(odd)
 
 
 # ---------------------------------------------------------------------------
@@ -371,7 +371,7 @@ def test_sample_device_memory_labels_every_device(monkeypatch):
     devs = [_Dev(0, {"bytes_in_use": 100, "peak_bytes_in_use": 200}),
             _Dev(1, {"bytes_in_use": 300, "ignored": "str"}),
             _Dev(2, None),                       # CPU-style: no stats
-            _Dev(3, RuntimeError("wedged"))]     # never an error
+            _Dev(3, RuntimeError("stuck"))]     # never an error
     fake_jax = types.SimpleNamespace(local_devices=lambda: devs)
     monkeypatch.setitem(sys.modules, "jax", fake_jax)
     reg = metrics_mod.Registry()

@@ -39,7 +39,7 @@ torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
 
 # End-to-end torch-pipeline parity is the suite's most expensive family
-# (~10 s per case warm, minutes cold): slow lane (VERDICT r3 weak #5).
+# (~10 s per case warm, minutes cold): slow lane.
 pytestmark = pytest.mark.slow
 
 from p2p_tpu.controllers import factory
@@ -937,7 +937,7 @@ def test_replay_with_null_embeddings_matches_torch_pipeline():
 
 
 def test_text2image_short_loop_matches_torch_at_sd14_scale():
-    """The loop × scale seam (VERDICT r4 missing #2): the controlled CFG
+    """The loop × scale seam: the controlled CFG
     sampling loop at the REAL SD-1.4 topology (860M-param U-Net, 64² latent,
     77×768 context) for 2 steps, ours vs the torch reference loop — scan
     carry dtypes, scheduler constants, and controller gather shapes at real

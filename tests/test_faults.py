@@ -186,14 +186,16 @@ def test_progress_watchdog_sink_fires_on_steps_and_traces_nothing():
                 return c * 1.5, None
             out, _ = jax.lax.scan(body, x, jnp.arange(3))
             return out
-        return jax.jit(f).lower(jnp.float32(1.0)).compile().as_text()
+        # The lowered StableHLO, not the compiled text: the latter carries
+        # the call site's line numbers in its stack-frame tables.
+        return jax.jit(f).lower(jnp.float32(1.0)).as_text()
 
     base = lowered()
     beats = [0]
     progress.set_watchdog_sink(lambda: beats.__setitem__(0, beats[0] + 1))
     try:
         assert lowered() == base           # sink is host-side only
-        assert "custom-call" not in base
+        assert "custom_call" not in base
         # And when the callback IS traced in, every step beats the sink.
         def g(x):
             def body(c, i):
@@ -886,7 +888,7 @@ def test_invalid_argument_error_isolates_instead_of_draining(tiny_pipe):
 
 
 class _HungWarmRunner(FakeRunner):
-    """warm() blocks in *wall* clock — what a wedged in-band XLA compile
+    """warm() blocks in *wall* clock — what a hung in-band XLA compile
     looks like to the engine (no steps, no exception, no return)."""
 
     def warm(self, entries):
@@ -901,7 +903,7 @@ def test_hung_build_with_watchdog_times_out_and_serves_on(tiny_pipe):
     recs = _serve(tiny_pipe, [_req("a"), _req("b")],
                   runner_cls=_HungWarmRunner, max_batch=2,
                   max_wait_ms=10.0, watchdog_ms=80.0)
-    assert time.monotonic() - t0 < 5.0, "server wedged on a hung compile"
+    assert time.monotonic() - t0 < 5.0, "server blocked on a hung compile"
     by = _by_status(recs)
     assert sorted(r["request_id"] for r in by["timeout"]) == ["a", "b"]
     assert all("build/warm" in r["reason"] for r in by["timeout"])

@@ -2,8 +2,7 @@
 
 The big self-attention sites (64² pixels → S=4096) run the Pallas TPU flash
 kernel via `nn.flash_attention_tpu` (`p2p_tpu/models/nn.py`) — a path the CPU
-test suite otherwise never executes (VERDICT r2 missing #3: "TPU-only code
-paths have zero test coverage"). `force_tpu_interpret_mode()` executes the
+test suite otherwise never executes. `force_tpu_interpret_mode()` executes the
 *identical* kernel — same BlockSizes, same grid — in the Pallas interpreter
 on CPU, so parity against the materialized `attention_probs` + einsum
 reference is checked in CI.
@@ -12,14 +11,6 @@ Shapes mirror the production site: S=4096 (64² pixels), head_dim 40
 (SD-1.4's 320/8), block 1024 (what `flash_block(4096)` picks). Batch and
 heads are reduced (the kernel grid iterates them independently; geometry per
 batch·head is what the blocks tile).
-
-`force_tpu_interpret_mode` comes from `p2p_tpu.kernels`: on jax 0.4.37
-(no `pltpu.force_tpu_interpret_mode`, and a masked-load discharge bug in
-the stock interpreter) it installs the vendored discharge fix
-(`kernels/interpret.py`) and rebinds `pallas_call(interpret=True)`; on
-newer jax it defers to the native context manager. Either way the
-*identical* kernels run on CPU — these tests carried xfail markers until
-the vendored fix landed.
 
 Tolerance: the kernel accumulates softmax/matmul in f32 like the reference
 path, but blockwise online-softmax reassociates the sums — f32 inputs agree
@@ -33,7 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from p2p_tpu.kernels import force_tpu_interpret_mode
+from jax.experimental.pallas.tpu import force_tpu_interpret_mode
 from p2p_tpu.models import nn
 
 
@@ -117,7 +108,7 @@ def test_flash_interpret_grad_matches_einsum():
     actually ships at the S=4096 production sites: forward blocks 1024,
     backward blocks capped at 512 — so a numeric bug specific to unequal
     forward/backward tiling (e.g. dq accumulation across the two backward
-    k-blocks per forward block) dies here, not in a scarce chip window."""
+    k-blocks per forward block) dies here, not on the chip."""
     s, d = 1024, 40
     blk = 1024
     assert nn.flash_block(s, d, 4) == blk  # the production selection
@@ -140,7 +131,7 @@ def test_flash_interpret_grad_matches_einsum():
 def test_flash_block_sizes_specify_all_backward_blocks():
     """The shared BlockSizes geometry must stay fully backward-specified —
     any future pallas field addition that reopens the trace-time error
-    shows up here, not in a scarce chip window."""
+    shows up here, not on the chip."""
     assert nn._flash_block_sizes(1024).has_backward_blocks
     assert nn._flash_block_sizes(256).has_backward_blocks
 

@@ -380,7 +380,7 @@ def test_nan_injected_at_phase1_converts_at_completion(tiny_pipe):
 
 def test_fatal_fault_drains_phase2_pool_too(tiny_pipe):
     """A fatal fault while hand-offs wait in the phase-2 batcher resolves
-    them to error records — nothing wedges in the second pool."""
+    them to error records — nothing hangs in the second pool."""
     from p2p_tpu.serve.chaos import FaultPlan
 
     reqs = [_gated_req("a", arrival=0.0, gate=0.5),

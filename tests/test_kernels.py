@@ -1,9 +1,8 @@
 """Fused-edit Pallas kernel tests (`p2p_tpu/kernels/`, ISSUE 16).
 
 Everything runs in pallas interpret mode on CPU — the *identical* kernel
-program that lowers on TPU, executed by the interpreter (with the
-jax-0.4.37 discharge fix from `kernels/interpret.py` installed on first
-use). Three layers of coverage:
+program that lowers on TPU, executed by the interpreter. Three layers of
+coverage:
 
 1. **Static dispatch** — `KernelConfig` validation / `from_fuse_plan`,
    `kernel_edit_spec` extraction per (controller, site), and

@@ -726,15 +726,20 @@ def test_serve_cli_sigint_drains_without_traceback(tmp_path):
                 "target": "a dog riding a bike", "mode": "replace",
                 "steps": 2, "seed": i, "arrival_ms": i * 50.0}) + "\n")
     wal = str(tmp_path / "cli.wal")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "p2p_tpu.cli", "serve", "--quiet",
-         "--requests", trace_path, "--results", results,
-         "--max-batch", "8", "--max-wait-ms", "5",
-         "--journal", wal, "--snapshot-every-ms", "1000",
-         "--drain-timeout-ms", "60000"],
-        cwd=repo, text=True, stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    # The child's output goes to files, not pipes: nothing reads a pipe
+    # while this test polls, and XLA's CPU loader can write more than a
+    # pipe holds (a line per cached executable), which blocks the child.
+    err_path = str(tmp_path / "stderr.txt")
+    with open(err_path, "w") as err_f, \
+            open(str(tmp_path / "stdout.txt"), "w") as out_f:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "p2p_tpu.cli", "serve", "--quiet",
+             "--requests", trace_path, "--results", results,
+             "--max-batch", "8", "--max-wait-ms", "5",
+             "--journal", wal, "--snapshot-every-ms", "1000",
+             "--drain-timeout-ms", "60000"],
+            cwd=repo, stdout=out_f, stderr=err_f,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
     try:
         deadline = time.time() + 300
         while time.time() < deadline:
@@ -748,11 +753,13 @@ def test_serve_cli_sigint_drains_without_traceback(tmp_path):
             pytest.fail("no ok record within the startup budget")
         assert proc.poll() is None, "served everything before the signal"
         proc.send_signal(signal.SIGINT)
-        _, err = proc.communicate(timeout=240)
+        proc.wait(timeout=240)
     finally:
         if proc.poll() is None:
             proc.kill()
-            proc.communicate()
+            proc.wait()
+    with open(err_path) as f:
+        err = f.read()
     assert proc.returncode == 0, err
     assert "Traceback" not in err
     recs = [json.loads(l) for l in open(results)]

@@ -1,4 +1,4 @@
-"""Numerical parity vs torch — the real-checkpoint-path proof (VERDICT r1 #3).
+"""Numerical parity vs torch — the real-checkpoint-path proof.
 
 No SD weights exist in this environment, so parity is proven structurally:
 random-init OUR params, export through the checkpoint name tables
@@ -409,7 +409,7 @@ def test_full_vae_matches_torch_oracle():
 
 
 # ---------------------------------------------------------------------------
-# Full-scale SD-1.4 forwards vs the same oracles (VERDICT r3 missing #3):
+# Full-scale SD-1.4 forwards vs the same oracles:
 # every prior full-scale check was shapes-only (mapping-table round trips +
 # eval_shape); these run ONE ε-prediction and ONE 512² VAE round trip at the
 # real SD14 topology in f32, so a config transcription error inside the SD14

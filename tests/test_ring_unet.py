@@ -1,4 +1,4 @@
-"""Ring attention integrated into the U-Net (VERDICT r1 #7): an ``sp`` mesh
+"""Ring attention integrated into the U-Net: an ``sp`` mesh
 axis shards large self-attention sites; the forward must match the
 single-device program at tolerance on the 8-virtual-device CPU mesh."""
 
@@ -162,7 +162,7 @@ def test_alltoall_unet_matches_local(sp_mesh):
                                            sp=sp))(params, x, ctx)
 
         if label == "ring-fallback":
-            # Head-indivisible alltoall must say so (ADVICE r3): a user
+            # Head-indivisible alltoall must say so: a user
             # benchmarking alltoall must not unknowingly measure ring.
             with pytest.warns(UserWarning, match="falls back to ring"):
                 eps_sp, _ = run()

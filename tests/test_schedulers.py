@@ -220,7 +220,7 @@ def test_add_noise_interpolates():
 
 
 # ---------------------------------------------------------------------------
-# SchedulerConfig parity (VERDICT r1 item 5): the constants diffusers' SD
+# SchedulerConfig parity: the constants diffusers' SD
 # PNDM / DDIM configs produce, hand-derived from their documented formulas
 # (`/root/reference/main.py:29` pipeline PNDM has steps_offset=1;
 # `/root/reference/null_text.py:16-20` DDIM has offset 0, clip_sample=False).

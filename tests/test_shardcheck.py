@@ -1,7 +1,7 @@
 """shardcheck tests (ISSUE 11): the StableHLO/HLO walker's parsing on
 planted programs, seeded verdict-flips for every new contract class
 (undeclared all-gather via an unsharded-operand constraint, stale
-declaration, planted outfeed / host callback / hidden resharding), the
+declaration, planted host callback / hidden resharding), the
 clean-on-HEAD sweep over the real mesh canonical programs, and the report
 integration that carries the per-program bytes-per-step comms table.
 
@@ -62,13 +62,13 @@ def test_walker_finds_forced_replication_all_gather():
     assert op.payload_bytes == 16384 and op.bytes_moved == 8192
     # ...and the *intent* is visible pre-partitioning as a replicating
     # sharding constraint on the StableHLO side.
-    changes = shlo_walk.sharding_custom_calls(low.as_text())
-    assert any(c.forces_replication for c in changes)
+    changes = shlo_walk.sharding_changes(low.as_text())
+    assert [c.target for c in changes] == ["sharding_constraint"]
+    assert changes[0].forces_replication
+    assert changes[0].result_type == "4x8x8x16xf32"
 
 
 def test_walker_attributes_scan_body_collectives_per_step():
-    from jax.experimental.shard_map import shard_map
-
     mesh = _mesh2()
 
     def step(c, x):
@@ -78,9 +78,14 @@ def test_walker_attributes_scan_body_collectives_per_step():
         out, _ = jax.lax.scan(step, jnp.float32(0), xs)
         return out
 
-    sf = shard_map(scanner, mesh=mesh, in_specs=P(None, "dp"),
-                   out_specs=P(), check_rep=False)
-    hlo = jax.jit(sf).lower(jnp.zeros((3, 4, 16))).compile().as_text()
+    sf = jax.shard_map(scanner, mesh=mesh, in_specs=P(None, "dp"),
+                       out_specs=P(), check_vma=False)
+    low = jax.jit(sf).lower(jnp.zeros((3, 4, 16)))
+    # The shard_map boundary is sharding intent too, and not a replication.
+    changes = shlo_walk.sharding_changes(low.as_text())
+    assert [(c.target, c.forces_replication) for c in changes] == [
+        ("manual_computation", False)]
+    hlo = low.compile().as_text()
     ops = shlo_walk.collective_ops(hlo)
     assert [(o.kind, o.per_step) for o in ops] == [("all-reduce", True)]
     sig = shlo_walk.collective_signature(ops)
@@ -90,11 +95,11 @@ def test_walker_attributes_scan_body_collectives_per_step():
 
 def test_walker_finds_host_boundary_ops():
     def noisy(x):
-        jax.lax.outfeed(jax.lax.create_token(), x)
+        jax.debug.callback(lambda v: None, x)
         return x * 1.0
 
     hlo = jax.jit(noisy).lower(jnp.zeros((4,))).compile().as_text()
-    assert "outfeed" in shlo_walk.host_boundary_ops(hlo)
+    assert any("callback" in h for h in shlo_walk.host_boundary_ops(hlo))
 
     from jax.experimental import io_callback
 
@@ -118,15 +123,13 @@ def test_walker_finds_host_boundary_ops():
 def test_walker_finds_reduce_scatter():
     # XLA rewrites all-reduce-into-sharded-consumer as reduce-scatter:
     # missing this kind would blind the budget to real traffic.
-    from jax.experimental.shard_map import shard_map
-
     mesh = _mesh2()
 
     def f(x):
         return jax.lax.psum_scatter(x, "dp", tiled=True)
 
-    sf = shard_map(f, mesh=mesh, in_specs=P(None, "dp"), out_specs=P("dp"),
-                   check_rep=False)
+    sf = jax.shard_map(f, mesh=mesh, in_specs=P(None, "dp"),
+                       out_specs=P("dp"), check_vma=False)
     hlo = jax.jit(sf).lower(jnp.zeros((4, 8))).compile().as_text()
     ops = shlo_walk.collective_ops(hlo)
     assert [o.kind for o in ops] == ["reduce-scatter"]
@@ -303,9 +306,9 @@ def test_stale_program_level_declaration_is_a_hard_error():
     assert not r.ok and "no canonical mesh program" in r.detail
 
 
-def test_planted_outfeed_flips_host_boundary():
+def test_planted_callback_flips_host_boundary():
     def noisy(x):
-        jax.lax.outfeed(jax.lax.create_token(), x)
+        jax.debug.callback(lambda v: None, x)
         return x * 1.0
 
     prog = _planted("serve/mesh-dp2",
@@ -313,7 +316,7 @@ def test_planted_outfeed_flips_host_boundary():
     results, _ = check_collectives(
         programs=[prog], declared={"serve/mesh-dp2": {}})
     r = _by(results, "no-host-boundary", "serve/mesh-dp2")
-    assert not r.ok and "outfeed" in r.detail
+    assert not r.ok and "callback" in r.detail
     # The clean program passes the same check.
     ok = check_collectives(programs=[_planted("serve/mesh-dp2",
                                               _clean_lowered())],
@@ -322,8 +325,8 @@ def test_planted_outfeed_flips_host_boundary():
 
 
 def test_planted_resharding_flips_hidden_resharding():
-    # with_sharding_constraint to the SAME sharding still emits the
-    # @Sharding custom call: intent alone is a finding in a canonical dp
+    # with_sharding_constraint to the SAME sharding still emits an
+    # sdy.sharding_constraint: intent alone is a finding in a canonical dp
     # program (nothing may re-spec a tensor mid-program).
     mesh = _mesh2()
     shd = NamedSharding(mesh, P("dp"))
@@ -336,7 +339,8 @@ def test_planted_resharding_flips_hidden_resharding():
     results, _ = check_collectives(
         programs=[prog], declared={"serve/mesh-dp2": {}})
     r = _by(results, "no-hidden-resharding", "serve/mesh-dp2")
-    assert not r.ok and "custom call" in r.detail
+    assert not r.ok and "sdy.sharding_constraint" in r.detail
+    assert "replication" not in r.detail
 
 
 # ---------------------------------------------------------------------------

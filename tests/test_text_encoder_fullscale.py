@@ -1,6 +1,6 @@
 """Full-scale text-tower validation against a real `transformers` checkpoint.
 
-VERDICT r2 missing #2: parity had only been proven at tiny scale with custom
+Parity had only been proven at tiny scale with custom
 configs — the residual risk being config-vs-checkpoint drift (e.g. SD-2.1's
 23-layer truncation) that only real weight files would catch. No pretrained
 weights exist in this image, but `transformers.CLIPTextModel` — the exact

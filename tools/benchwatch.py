@@ -1,10 +1,10 @@
-"""Regression watch over the committed BENCH_r*.json trajectory.
+"""Regression watch over a BENCH_r*.json trajectory.
 
-Five rounds of bench history live at the repo root (``BENCH_r01.json`` …),
-each holding the round's parsed headline JSON line. The trajectory is the
-product — 0.24 → 0.97 img/s/chip — and nothing guarded it: a PR could
-halve the serve p95 budget or double the telemetry overhead and the next
-round's json would just quietly record it. This tool is the watchdog:
+An archive directory (``--root``) holds one ``BENCH_rNN.json`` per round,
+each with the round's parsed headline JSON line (the repo itself commits
+none). Without a watch a PR could halve the serve p95 budget or double the
+telemetry overhead and the next round's json would just quietly record it.
+This tool is the watchdog:
 compare the latest round against its predecessor on the headline keys and
 exit nonzero past a configurable regression threshold.
 
@@ -12,12 +12,12 @@ exit nonzero past a configurable regression threshold.
     python tools/benchwatch.py --threshold 0.05   # tighter budget
     python tools/benchwatch.py --root DIR         # a different archive
 
-Comparability rules (the committed history mixes tiny-CPU fallback rounds
-with on-chip rounds):
+Comparability rules (an archive may mix tiny-CPU rounds with on-chip
+rounds):
 
 - The predecessor is the most recent earlier round whose headline
   ``metric`` matches the latest round's — an on-chip sd14 round is never
-  diffed against a tiny-CPU fallback (a 94% "regression" that is really a
+  diffed against a tiny-CPU round (a 94% "regression" that is really a
   preset change). No comparable predecessor — an empty archive, a
   single-round trajectory, or a metric with no earlier twin — is an
   explicit "no comparable round" note and exit 0, never a silently-green
@@ -90,9 +90,9 @@ _ROUND_RE = re.compile(r"BENCH_r(\d+)\.json$")
 
 
 def load_rounds(root: str) -> List[Tuple[int, dict]]:
-    """(round number, parsed headline dict) for every committed round that
+    """(round number, parsed headline dict) for every round under ``root`` that
     has one, ascending. Rounds whose measurement never produced a parsed
-    line (r01's backend failure) are skipped — there is nothing to
+    line are skipped — there is nothing to
     compare."""
     out = []
     for path in glob.glob(os.path.join(root, "BENCH_r*.json")):

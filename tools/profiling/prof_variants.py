@@ -22,7 +22,7 @@ from p2p_tpu.utils.tokenizer import HashWordTokenizer
 # Siblings insert the script dir explicitly: when a launcher runs this file
 # by absolute path from another cwd with an inherited sys.path[0], the
 # implicit script-dir entry is not guaranteed — the _bench_common import
-# must not depend on it (ADVICE round-5 finding).
+# must not depend on it.
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _bench_common import require_accelerator
 

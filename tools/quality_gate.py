@@ -40,7 +40,7 @@ force_cpu_platform()
 from p2p_tpu.utils.cache import default_cache_dir  # noqa: E402
 
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      default_cache_dir(hash_xla_flags=False))
+                      default_cache_dir())
 
 import numpy as np  # noqa: E402
 

@@ -56,7 +56,7 @@ force_cpu_platform()
 from p2p_tpu.utils.cache import default_cache_dir  # noqa: E402
 
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      default_cache_dir(hash_xla_flags=False))
+                      default_cache_dir())
 
 
 def site_cost_shares(layout, batch: int, seq: int = None) -> dict:
