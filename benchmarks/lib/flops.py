@@ -1,0 +1,147 @@
+"""Operations and bytes the configuration's mathematics needs, counted from
+the sizes in its file. A multiply-add counts as 2 operations; only matrix
+multiplications and convolutions are counted (norms, activations, softmax and
+the sampler's arithmetic are left out, well under 1 % of the total), so a
+share of the peak worked out from these is a little low, never high.
+"""
+
+from __future__ import annotations
+
+import math
+
+
+def _conv(hw: int, cin: int, cout: int, k: int = 3, stride: int = 1) -> int:
+    """A k x k convolution onto ``hw`` output pixels of a square map, padded
+    by k // 2. Taps that fall on the zero padding are not counted (as XLA's
+    own count leaves them out): 3 n - 2 of 3 n per side at stride 1, 3 n - 1
+    at stride 2."""
+    side = math.isqrt(hw)
+    taps = hw if k == 1 else (3 * side - (2 if stride == 1 else 1)) ** 2
+    return 2 * taps * cin * cout
+
+
+def res_block_flops(hw: int, cin: int, cout: int, temb: int = 0) -> int:
+    f = _conv(hw, cin, cout) + _conv(hw, cout, cout) + 2 * temb * cout
+    if cin != cout:
+        f += _conv(hw, cin, cout, 1)
+    return f
+
+
+def self_attention_flops(pixels: int, channels: int) -> int:
+    """Projections q, k, v, out, then Q K^T and P V, for one image."""
+    return 4 * 2 * pixels * channels * channels + 2 * 2 * pixels * pixels * channels
+
+
+def cross_attention_flops(pixels: int, channels: int, ctx_len: int,
+                          ctx_dim: int) -> int:
+    """q and out over the pixels, k and v over the context, then the two
+    products over the context's tokens, for one image."""
+    return (2 * 2 * pixels * channels * channels
+            + 2 * 2 * ctx_len * ctx_dim * channels
+            + 2 * 2 * pixels * ctx_len * channels)
+
+
+def transformer_flops(uc: dict, hw: int, c: int, cross: bool = True) -> int:
+    f = 2 * _conv(hw, c, c, 1)                          # proj_in, proj_out
+    per_block = self_attention_flops(hw, c)
+    if cross:
+        per_block += cross_attention_flops(hw, c, uc["context_len"],
+                                           uc["cross_attention_dim"])
+    inner = c * uc["ff_mult"]
+    per_block += 2 * hw * c * 2 * inner + 2 * hw * inner * c     # GEGLU
+    return f + uc["transformer_depth"] * per_block
+
+
+def unet_sites(uc: dict):
+    """The attention site groups in call order: (place, level, pixels,
+    channels), one per spatial transformer."""
+    levels = len(uc["block_out_channels"])
+    out = []
+    for lvl in range(levels):
+        if uc["attention_levels"][lvl]:
+            out += [("down", lvl)] * uc["layers_per_block"]
+    out.append(("mid", levels - 1))
+    for lvl in reversed(range(levels)):
+        if uc["attention_levels"][lvl]:
+            out += [("up", lvl)] * (uc["layers_per_block"] + 1)
+    return [(place, lvl, (uc["sample_size"] >> lvl) ** 2,
+             uc["block_out_channels"][lvl]) for place, lvl in out]
+
+
+def unet_forward_flops(uc: dict, cross: bool = True) -> int:
+    """One forward of the U-Net for one row of its batch. ``cross=False``
+    leaves out the cross-attention (served from a cache past a phase gate)."""
+    chs = uc["block_out_channels"]
+    temb = chs[0] * 4
+    side = uc["sample_size"]
+    f = 2 * chs[0] * temb + 2 * temb * temb
+    f += _conv(side * side, uc["in_channels"], chs[0])
+    skips = [chs[0]]
+    cin = chs[0]
+    for lvl, cout in enumerate(chs):
+        hw = (side >> lvl) ** 2
+        for _ in range(uc["layers_per_block"]):
+            f += res_block_flops(hw, cin, cout, temb)
+            if uc["attention_levels"][lvl]:
+                f += transformer_flops(uc, hw, cout, cross)
+            cin = cout
+            skips.append(cout)
+        if lvl != len(chs) - 1:
+            f += _conv((side >> (lvl + 1)) ** 2, cout, cout, stride=2)
+            skips.append(cout)
+    hw = (side >> (len(chs) - 1)) ** 2
+    f += 2 * res_block_flops(hw, chs[-1], chs[-1], temb)
+    f += transformer_flops(uc, hw, chs[-1], cross)
+    for lvl in reversed(range(len(chs))):
+        cout = chs[lvl]
+        hw = (side >> lvl) ** 2
+        for _ in range(uc["layers_per_block"] + 1):
+            f += res_block_flops(hw, cin + skips.pop(), cout, temb)
+            if uc["attention_levels"][lvl]:
+                f += transformer_flops(uc, hw, cout, cross)
+            cin = cout
+        if lvl != 0:
+            f += _conv(4 * hw, cout, cout)
+    return f + _conv(side * side, chs[0], uc["out_channels"])
+
+
+def text_encoder_flops(tc: dict) -> int:
+    """One prompt through the text tower."""
+    n, d, inner = (tc["max_position_embeddings"], tc["hidden_size"],
+                   tc["attention_inner_dim"])
+    per_layer = (4 * 2 * n * d * inner + 2 * 2 * n * n * inner
+                 + 2 * 2 * n * d * d * tc["ff_mult"])
+    return tc["num_hidden_layers"] * per_layer
+
+
+def decode_flops(vc: dict, latent_side: int) -> int:
+    """One latent through the autoencoder's decoder."""
+    chs = [vc["base_channels"] * m for m in vc["channel_mults"]]
+    top, lat = chs[-1], vc["latent_channels"]
+    hw = latent_side ** 2
+    f = _conv(hw, lat, lat, 1) + _conv(hw, lat, top)
+    if vc["kind"] == "vq":
+        f += 2 * hw * lat * vc["num_codebook"]
+    f += 2 * res_block_flops(hw, top, top)
+    f += 4 * 2 * hw * top * top + 2 * 2 * hw * hw * top
+    cin = top
+    for lvl in reversed(range(len(chs))):
+        for _ in range(vc["layers_per_block"] + 1):
+            f += res_block_flops(hw, cin, chs[lvl])
+            cin = chs[lvl]
+        if lvl != 0:
+            hw *= 4
+            f += _conv(hw, cin, cin)
+    return f + _conv(hw, chs[0], vc["in_channels"])
+
+
+def work_flops(config: dict, unet_rows_full: int, unet_rows_cached: int,
+               prompts: int, images: int) -> int:
+    """All the work of a window: U-Net forwards by rows of their batch (with
+    cross-attention, and past a gate without), prompts encoded, images
+    decoded."""
+    uc = config["unet"]
+    return (unet_rows_full * unet_forward_flops(uc)
+            + unet_rows_cached * unet_forward_flops(uc, cross=False)
+            + prompts * text_encoder_flops(config["text_encoder"])
+            + images * decode_flops(config["vae"], uc["sample_size"]))

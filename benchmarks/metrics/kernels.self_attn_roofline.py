@@ -1,0 +1,35 @@
+"""Kernels: roofline share of the library flash attention, which runs the
+self-attention sites that no controller touches (the 64 x 64 sites of SD-1.4
+under the paper's edit). For every kernel event inside the sampling loop the
+least time the chip could take for softmax(Q K^T) V at the event's own shape
+(batch, heads, pixels, head size: the larger of 4 B H P^2 d operations over
+the bf16 peak and of q, k, v read and the output written once over the
+bandwidth), summed, over the summed device time of those events.
+Compute-bound at SD-1.4's shapes: 21.5 GFLOP against 21 MB a row.
+
+Keyed on the Mosaic kernel's events because the TPU's trace carries no scope
+names: it reads nothing in a cell whose untouched sites take XLA's own
+attention (32 x 32 sites lie under the 2048 pixels where the program
+switches to the kernel), and would read nothing if another implementation
+took the kernel's place. PERF.md lists the scopes the program has to get
+into the trace for the per-site form."""
+
+from benchmarks.lib import trace as T
+from benchmarks.lib.peaks import peaks_for
+
+
+def read(run):
+    tr = run.trace_data
+    if not run.on_chip or tr is None:
+        return None
+    lo, hi = run.trace_window
+    peaks = peaks_for(run.device["kind"])
+    least = spent = 0.0
+    for o in T.leaf_ops(tr, lo, hi):
+        if not (T.is_flash_kernel(o) and o.loop and len(o.shape) == 4):
+            continue
+        b, h, p, d = o.shape
+        ops, moved = 4 * b * h * p * p * d, 4 * b * h * p * d * 4
+        least += max(ops / peaks["flops_per_s"], moved / peaks["bytes_per_s"])
+        spent += o.dur / 1e9
+    return 100.0 * least / spent if spent else None
