@@ -109,25 +109,31 @@ def emit(**fields) -> None:
 
 class CompileClock:
     """Seconds JAX spent tracing, lowering and compiling, and the backend
-    compiles of a second or more (one per program) — read from
-    ``jax.monitoring`` so the CLI's own compiles are counted too."""
+    compiles of a second or more (one per program, a read of the persistent
+    cache included) — a reader of the program's own compile ledger
+    (``utils.cache.CompileLedger``), which listens to ``jax.monitoring``, so
+    the CLI's own compiles are counted too."""
 
-    _EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-               "/jax/core/compile/jaxpr_to_mlir_module_duration",
-               "/jax/core/compile/backend_compile_duration")
+    _KINDS = ("trace", "lower", "backend", "cache_hit")
 
     def __init__(self):
-        import jax
+        from p2p_tpu.utils.cache import compile_ledger
 
-        self.seconds = 0.0
-        self.programs = []      # backend-compile seconds, in order
-        jax.monitoring.register_event_duration_secs_listener(self._on)
+        self._ledger = compile_ledger()
+        self._since = time.monotonic()
 
-    def _on(self, name, secs, **_):
-        if name in self._EVENTS:
-            self.seconds += secs
-            if name.endswith("backend_compile_duration") and secs >= 1.0:
-                self.programs.append(round(secs, 1))
+    @property
+    def seconds(self) -> float:
+        return sum(r.seconds
+                   for r in self._ledger.rows(*self._KINDS, since=self._since))
+
+    @property
+    def programs(self) -> list:
+        """Backend-compile seconds of a second or more, in order."""
+        return [round(r.seconds, 1)
+                for r in self._ledger.rows("backend", "cache_hit",
+                                           since=self._since)
+                if r.seconds >= 1.0]
 
     def mark(self):
         return self.seconds, len(self.programs)

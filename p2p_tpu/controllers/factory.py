@@ -9,6 +9,7 @@ parameters were precomputed host-side.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import numpy as np
 
 from ..align.aligner import get_refinement_mapper, get_replacement_mapper
 from ..align.words import Bounds, get_equalizer, get_time_words_attention_alpha, get_word_inds
+from ..obs.spans import span
 from ..utils.tokenizer import Tokenizer
 from .base import Controller
 from .blend import BlendParams
@@ -37,6 +39,20 @@ def _cross_alpha(prompts, num_steps, cross_replace_steps, tokenizer, max_len):
         get_time_words_attention_alpha(prompts, num_steps, cross_replace_steps,
                                        tokenizer, max_num_words=max_len)
     )
+
+
+def _entry_span(make):
+    """``entry.controller``: the host work of building an edit's controller
+    (alignment, word indices, the arrays put on the device). A root span of
+    its own, since the caller builds the controller before the sampling
+    call."""
+    @functools.wraps(make)
+    def spanned(prompts, num_steps, *args, **kwargs):
+        with span("entry.controller", kind=make.__name__,
+                  prompts=len(prompts), steps=int(num_steps)):
+            return make(prompts, num_steps, *args, **kwargs)
+
+    return spanned
 
 
 def empty_control() -> Controller:
@@ -91,6 +107,7 @@ def local_blend(
     )
 
 
+@_entry_span
 def attention_replace(
     prompts: Sequence[str],
     num_steps: int,
@@ -120,6 +137,7 @@ def attention_replace(
     return Controller(edit=edit, blend=local_blend, store=store)
 
 
+@_entry_span
 def attention_refine(
     prompts: Sequence[str],
     num_steps: int,
@@ -147,6 +165,7 @@ def attention_refine(
     return Controller(edit=edit, blend=local_blend, store=store)
 
 
+@_entry_span
 def attention_reweight(
     prompts: Sequence[str],
     num_steps: int,

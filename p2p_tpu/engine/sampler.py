@@ -46,6 +46,8 @@ from ..models import vae as vae_mod
 from ..models.config import PipelineConfig
 from ..models.text_encoder import apply_text_encoder
 from ..models.unet import apply_unet, init_attn_cache
+from ..obs import launches
+from ..obs.spans import span
 from ..ops import schedulers as sched_mod
 from ..utils import progress as progress_mod
 from ..utils.tokenizer import Tokenizer, pad_ids
@@ -105,13 +107,20 @@ def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32) -> jax.Array:
     (`/root/reference/ptp_utils.py:144-156`)."""
     tok = pipe.tokenizer
     max_len = pipe.config.unet.context_len
-    # Token ids are the one host-born input of every dispatch: staged
-    # explicitly (stage_host) so the serve hot path stays clean under
-    # jax.transfer_guard("disallow").
-    ids = stage_host(np.asarray(
-        [pad_ids(tok.encode(p), max_len, getattr(tok, "pad_token_id", tok.eos_token_id))
-         for p in prompts], dtype=np.int32))
-    return _encode_jit(pipe.text_params, pipe.config.text, ids, dtype)
+    with span("entry.tokenize", prompts=len(prompts)):
+        ids = np.asarray(
+            [pad_ids(tok.encode(p), max_len,
+                     getattr(tok, "pad_token_id", tok.eos_token_id))
+             for p in prompts], dtype=np.int32)
+    with span("entry.encode", prompts=len(prompts), tokens=int(ids.size)):
+        # Token ids are the one host-born input of every dispatch: staged
+        # explicitly (stage_host) so the serve hot path stays clean under
+        # jax.transfer_guard("disallow").
+        args = (pipe.text_params, pipe.config.text, stage_host(ids), dtype)
+        mark = launches.built()
+        out = _encode_jit(*args)
+        launches.keep_if_built(mark, _encode_jit, args, {})
+        return out
 
 
 def init_latent(latent: Optional[jax.Array], shape: Tuple[int, ...], rng: jax.Array,
@@ -356,19 +365,23 @@ def _make_scheduled_body(
             latents, state, ms, cache, resid = carry
             progress_mod.emit_step(emit, step, phase="phase1",
                                    report=progress)
-            latent_in = jnp.concatenate([latents] * 2, axis=0)
+            with jax.named_scope("sampler/cfg"):
+                latent_in = jnp.concatenate([latents] * 2, axis=0)
             eps, state, cache = apply_unet(
                 unet_params, cfg.unet, latent_in, t, context,
                 layout=layout, controller=controller, state=state,
                 step=step, sp=sp, attn_cache=cache, site_plan=site_plan,
                 kernels=kernels)
-            eps_uncond, eps_text = eps[:b], eps[b:]
-            resid = eps_text - eps_uncond
-            eps = eps_uncond + guidance_scale * resid
-            eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-            ms, latents = ms_step(ms, eps, t, latents)
-            latents = apply_step_callback(controller, layout, state,
-                                          latents, step)
+            with jax.named_scope("sampler/cfg"):
+                eps_uncond, eps_text = eps[:b], eps[b:]
+                resid = eps_text - eps_uncond
+                eps = eps_uncond + guidance_scale * resid
+            with jax.named_scope("sampler/scheduler_step"):
+                eps = sched_mod.to_epsilon(schedule, eps, t, latents)
+                ms, latents = ms_step(ms, eps, t, latents)
+            with jax.named_scope("sampler/controller_step"):
+                latents = apply_step_callback(controller, layout, state,
+                                              latents, step)
             return (latents, state, ms, cache, resid), None
         latents, ms, cache = carry
         progress_mod.emit_step(emit, step, phase="phase2", report=progress)
@@ -376,11 +389,14 @@ def _make_scheduled_body(
             unet_params, cfg.unet, latents, t, context,
             layout=layout, controller=None, state=(), step=step, sp=sp,
             attn_cache=cache, site_plan=site_plan, kernels=kernels)
-        eps = eps_text + (guidance_scale - 1.0) * resid_const
-        eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-        ms, latents = ms_step(ms, eps, t, latents)
-        latents = apply_step_callback(controller, layout, state_const,
-                                      latents, step)
+        with jax.named_scope("sampler/cfg"):
+            eps = eps_text + (guidance_scale - 1.0) * resid_const
+        with jax.named_scope("sampler/scheduler_step"):
+            eps = sched_mod.to_epsilon(schedule, eps, t, latents)
+            ms, latents = ms_step(ms, eps, t, latents)
+        with jax.named_scope("sampler/controller_step"):
+            latents = apply_step_callback(controller, layout, state_const,
+                                          latents, step)
         return (latents, ms, cache), None
 
     return body
@@ -519,17 +535,20 @@ def _make_phase1_body(
         step, t = scan_in
         progress_mod.emit_step(emit, step, phase="phase1", report=progress)
         ctx = context
-        if uncond_per_step is not None:
-            # Null-text: substitute this step's optimized uncond embedding.
-            # Cast to the sampling dtype — the artifact stores f32 (the
-            # optimizer's dtype), and a f32 leak here would silently promote
-            # the whole CFG context (and the U-Net matmuls) on the bf16 path.
-            u = jax.lax.dynamic_index_in_dim(uncond_per_step, step, 0,
-                                             keepdims=False)
-            ctx = jnp.concatenate([jnp.broadcast_to(u.astype(context.dtype),
-                                                    context[:b].shape),
-                                   context[b:]], axis=0)
-        latent_in = jnp.concatenate([latents] * 2, axis=0)
+        with jax.named_scope("sampler/cfg"):
+            if uncond_per_step is not None:
+                # Null-text: substitute this step's optimized uncond
+                # embedding. Cast to the sampling dtype — the artifact stores
+                # f32 (the optimizer's dtype), and a f32 leak here would
+                # silently promote the whole CFG context (and the U-Net
+                # matmuls) on the bf16 path.
+                u = jax.lax.dynamic_index_in_dim(uncond_per_step, step, 0,
+                                                 keepdims=False)
+                ctx = jnp.concatenate(
+                    [jnp.broadcast_to(u.astype(context.dtype),
+                                      context[:b].shape),
+                     context[b:]], axis=0)
+            latent_in = jnp.concatenate([latents] * 2, axis=0)
         if capture:
             eps, state, cache = apply_unet(
                 unet_params, cfg.unet, latent_in, t, ctx,
@@ -540,18 +559,22 @@ def _make_phase1_body(
                 unet_params, cfg.unet, latent_in, t, ctx,
                 layout=layout, controller=controller, state=state, step=step,
                 sp=sp, kernels=kernels)
-        eps_uncond, eps_text = eps[:b], eps[b:]
-        if capture:
-            resid = eps_text - eps_uncond
-            eps = eps_uncond + guidance_scale * resid
-        else:
-            eps = eps_uncond + guidance_scale * (eps_text - eps_uncond)
-        # v-prediction models (SD-2.1 768-v): convert to ε once per step.
-        # Linear in the model output, so combining CFG first is equivalent.
-        eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-        ms, latents = ms_step(ms, eps, t, latents)
-        latents = apply_step_callback(controller, layout, state, latents,
-                                      step)
+        with jax.named_scope("sampler/cfg"):
+            eps_uncond, eps_text = eps[:b], eps[b:]
+            if capture:
+                resid = eps_text - eps_uncond
+                eps = eps_uncond + guidance_scale * resid
+            else:
+                eps = eps_uncond + guidance_scale * (eps_text - eps_uncond)
+        with jax.named_scope("sampler/scheduler_step"):
+            # v-prediction models (SD-2.1 768-v): convert to ε once per step.
+            # Linear in the model output, so combining CFG first is
+            # equivalent.
+            eps = sched_mod.to_epsilon(schedule, eps, t, latents)
+            ms, latents = ms_step(ms, eps, t, latents)
+        with jax.named_scope("sampler/controller_step"):
+            latents = apply_step_callback(controller, layout, state, latents,
+                                          step)
         if capture:
             return (latents, state, ms, cache, resid), None
         return (latents, state, ms), None
@@ -664,14 +687,17 @@ def _phase2_scan(
         # SD-Acc-style fixed extrapolation: CFG's uncond branch is gone;
         # ε = ε_text + (g−1)·(ε_text − ε_uncond)|_gate reuses the captured
         # last-phase-1 residual as the guidance direction.
-        eps = eps_text + (guidance_scale - 1.0) * resid
-        eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-        ms, latents = ms_step(ms, eps, t, latents)
+        with jax.named_scope("sampler/cfg"):
+            eps = eps_text + (guidance_scale - 1.0) * resid
+        with jax.named_scope("sampler/scheduler_step"):
+            eps = sched_mod.to_epsilon(schedule, eps, t, latents)
+            ms, latents = ms_step(ms, eps, t, latents)
         # Latent-space controller effects (LocalBlend compositing /
         # SpatialReplace injection) continue against the frozen phase-1
         # store; attention hooks are structurally gone.
-        latents = apply_step_callback(controller, layout, state, latents,
-                                      step)
+        with jax.named_scope("sampler/controller_step"):
+            latents = apply_step_callback(controller, layout, state, latents,
+                                          step)
         return (latents, ms), None
 
     num_scan = schedule.timesteps.shape[0]
@@ -917,89 +943,93 @@ def text2image(
     step stream collected must install the host sink
     (``obs.device.instrument``); the CLI ``--metrics`` flag does.
     """
-    if negative_prompt and uncond_embeddings is not None:
-        raise ValueError("negative_prompt and uncond_embeddings are mutually "
-                         "exclusive (null-text already optimized the uncond)")
-    cfg = pipe.config
-    num_steps = num_steps or cfg.num_steps
-    scheduler = scheduler or cfg.scheduler.kind
-    if uncond_embeddings is not None:
-        if scheduler != "ddim":
-            # PLMS scans T+1 steps (warm-up double-evaluation); per-step
-            # null-text embeddings are optimized against the DDIM trajectory
-            # and would silently misalign (`/root/reference/null_text.py:23`
-            # — the null-text path is DDIM-only).
-            raise ValueError("uncond_embeddings require scheduler='ddim'")
-        if uncond_embeddings.shape[0] != num_steps:
+    with span("entry.text2image"):
+        if negative_prompt and uncond_embeddings is not None:
+            raise ValueError("negative_prompt and uncond_embeddings are mutually "
+                             "exclusive (null-text already optimized the uncond)")
+        cfg = pipe.config
+        num_steps = num_steps or cfg.num_steps
+        scheduler = scheduler or cfg.scheduler.kind
+        if uncond_embeddings is not None:
+            if scheduler != "ddim":
+                # PLMS scans T+1 steps (warm-up double-evaluation); per-step
+                # null-text embeddings are optimized against the DDIM trajectory
+                # and would silently misalign (`/root/reference/null_text.py:23`
+                # — the null-text path is DDIM-only).
+                raise ValueError("uncond_embeddings require scheduler='ddim'")
+            if uncond_embeddings.shape[0] != num_steps:
+                raise ValueError(
+                    f"uncond_embeddings has {uncond_embeddings.shape[0]} steps, "
+                    f"sampling uses {num_steps}")
+        with span("entry.prepare", steps=int(num_steps), batch=len(prompts)):
+            gs = jnp.asarray(cfg.guidance_scale if guidance_scale is None else guidance_scale,
+                             dtype=jnp.float32)
+            if layout is None:
+                from ..models.config import unet_layout
+                layout = unet_layout(cfg.unet)
+            if rng is None:
+                rng = jax.random.PRNGKey(0)
+
+            tsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
+                                                    kind=scheduler)
+            num_scan = tsched.timesteps.shape[0]
+            gate_step, reuse_sched = resolve_reuse(gate, schedule, layout, num_scan,
+                                                   controller)
+            x_t, latents = init_latent(latent, pipe.latent_shape, rng, len(prompts),
+                                       dtype)
+        if gate_step < num_scan and uncond_embeddings is not None:
+            # The null-text window spans every step (validated (T,1,L,D)
+            # above): any gate < T truncates inside it. Reject loudly — a
+            # silently misaligned replay looks plausible and is wrong.
             raise ValueError(
-                f"uncond_embeddings has {uncond_embeddings.shape[0]} steps, "
-                f"sampling uses {num_steps}")
-    gs = jnp.asarray(cfg.guidance_scale if guidance_scale is None else guidance_scale,
-                     dtype=jnp.float32)
-    if layout is None:
-        from ..models.config import unet_layout
-        layout = unet_layout(cfg.unet)
-    if rng is None:
-        rng = jax.random.PRNGKey(0)
+                f"gate={gate!r} (step {gate_step}) conflicts with per-step "
+                f"null-text uncond_embeddings, which are active through all "
+                f"{num_scan} steps: CFG truncation would drop the optimized "
+                "uncond branch mid-window. Run null-text replays with "
+                "gate=None.")
+        if reuse_sched is not None and uncond_embeddings is not None:
+            # A non-uniform schedule reroutes per-site features even when its
+            # cfg_gate keeps CFG alive: the per-step optimized uncond would
+            # replay against a different trajectory — same loud rejection.
+            raise ValueError(
+                "schedule conflicts with per-step null-text "
+                "uncond_embeddings: cached/inherited sites change the "
+                "trajectory the uncond branch was optimized against. Run "
+                "null-text replays with schedule=None.")
+        if reuse_sched is None:
+            warn_gate_truncation(gate_step, num_scan, controller)
+        context_cond = encode_prompts(pipe, prompts, dtype=dtype)
+        context_uncond = encode_prompts(
+            pipe, [negative_prompt or ""] * len(prompts), dtype=dtype)
 
-    tsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
-                                            kind=scheduler)
-    num_scan = tsched.timesteps.shape[0]
-    gate_step, reuse_sched = resolve_reuse(gate, schedule, layout, num_scan,
-                                           controller)
-    if gate_step < num_scan and uncond_embeddings is not None:
-        # The null-text window spans every step (validated (T,1,L,D)
-        # above): any gate < T truncates inside it. Reject loudly — a
-        # silently misaligned replay looks plausible and is wrong.
-        raise ValueError(
-            f"gate={gate!r} (step {gate_step}) conflicts with per-step "
-            f"null-text uncond_embeddings, which are active through all "
-            f"{num_scan} steps: CFG truncation would drop the optimized "
-            "uncond branch mid-window. Run null-text replays with "
-            "gate=None.")
-    if reuse_sched is not None and uncond_embeddings is not None:
-        # A non-uniform schedule reroutes per-site features even when its
-        # cfg_gate keeps CFG alive: the per-step optimized uncond would
-        # replay against a different trajectory — same loud rejection.
-        raise ValueError(
-            "schedule conflicts with per-step null-text "
-            "uncond_embeddings: cached/inherited sites change the "
-            "trajectory the uncond branch was optimized against. Run "
-            "null-text replays with schedule=None.")
-    if reuse_sched is None:
-        warn_gate_truncation(gate_step, num_scan, controller)
-    context_cond = encode_prompts(pipe, prompts, dtype=dtype)
-    context_uncond = encode_prompts(
-        pipe, [negative_prompt or ""] * len(prompts), dtype=dtype)
+        if progress:
+            progress_mod.activate(tsched.timesteps.shape[0])
+        if metrics:
+            # Host-side run descriptors for the snapshot: the gate decomposition
+            # (per-phase ms/step arrives via the step callbacks) plus the CFG
+            # batch shape phase 1 actually runs.
+            from ..obs import metrics as obs_metrics
 
-    x_t, latents = init_latent(latent, pipe.latent_shape, rng, len(prompts), dtype)
-    if progress:
-        progress_mod.activate(tsched.timesteps.shape[0])
-    if metrics:
-        # Host-side run descriptors for the snapshot: the gate decomposition
-        # (per-phase ms/step arrives via the step callbacks) plus the CFG
-        # batch shape phase 1 actually runs.
-        from ..obs import metrics as obs_metrics
+            reg = obs_metrics.registry()
+            reg.gauge("sampler_gate_step",
+                      "first phase-2 scan step (== scan length: ungated)"
+                      ).set(float(gate_step))
+            reg.gauge("sampler_scan_steps", "scan length").set(float(num_scan))
+            reg.gauge("sampler_cfg_batch",
+                      "CFG-doubled U-Net batch in phase 1 (2B)"
+                      ).set(float(2 * len(prompts)))
 
-        reg = obs_metrics.registry()
-        reg.gauge("sampler_gate_step",
-                  "first phase-2 scan step (== scan length: ungated)"
-                  ).set(float(gate_step))
-        reg.gauge("sampler_scan_steps", "scan length").set(float(num_scan))
-        reg.gauge("sampler_cfg_batch",
-                  "CFG-doubled U-Net batch in phase 1 (2B)"
-                  ).set(float(2 * len(prompts)))
-    from ..obs.spans import span
-
-    with span("sampler.text2image", steps=int(num_scan), gate=int(gate_step),
-              batch=len(prompts)):
-        # Span covers trace/compile + async dispatch (execution completes
-        # when the caller materializes the arrays) — it marks the host
-        # region for Perfetto alignment, not device wall time.
-        image, latents_out, state = _text2image_jit(
-            pipe.unet_params, pipe.vae_params, cfg, layout, tsched,
-            scheduler, context_cond, context_uncond, latents, controller, gs,
-            uncond_embeddings, return_store, progress=progress, sp=sp,
-            gate=gate_step, metrics=metrics, reuse=reuse_sched,
-            kernels=kernels)
-    return image, x_t, state
+        with span("sampler.text2image", steps=int(num_scan), gate=int(gate_step),
+                  batch=len(prompts)):
+            # Span covers trace/compile + async dispatch (execution completes
+            # when the caller materializes the arrays) — it marks the host
+            # region for Perfetto alignment, not device wall time.
+            args = (pipe.unet_params, pipe.vae_params, cfg, layout, tsched,
+                    scheduler, context_cond, context_uncond, latents,
+                    controller, gs, uncond_embeddings, return_store)
+            kwargs = dict(progress=progress, sp=sp, gate=gate_step,
+                          metrics=metrics, reuse=reuse_sched, kernels=kernels)
+            mark = launches.built()
+            image, latents_out, state = _text2image_jit(*args, **kwargs)
+            launches.keep_if_built(mark, _text2image_jit, args, kwargs)
+        return image, x_t, state

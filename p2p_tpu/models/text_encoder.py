@@ -46,6 +46,7 @@ def init_text_encoder(key: jax.Array, cfg: TextEncoderConfig) -> Params:
     return params
 
 
+@jax.named_scope("text_encoder")     # docs/OBSERVABILITY.md, "Scope vocabulary"
 def apply_text_encoder(params: Params, cfg: TextEncoderConfig,
                        ids: jax.Array, dtype=jnp.float32) -> jax.Array:
     """ids: (B, L) int32 → (B, L, D) final-layer hidden states (post-LN)."""

@@ -267,25 +267,30 @@ class SpConfig:
                              f"(expected 'ring' or 'alltoall')")
 
 
-def _apply_attention(p: Params, x: jax.Array, context: jax.Array, heads: int,
-                     ctx: _HookCtx, is_cross: bool) -> jax.Array:
-    """One attention site. x: (B, P, C); context: (B, K, Cc).
+def _apply_attention(p: Params, ln: Params, x: jax.Array, context: jax.Array,
+                     heads: int, ctx: _HookCtx, is_cross: bool) -> jax.Array:
+    """One attention site with its pre-norm and residual:
+    ``x + attn(layer_norm(x))``. x: (B, P, C); context: (B, K, Cc).
 
     Every site's computation is wrapped in a ``jax.named_scope`` whose
     name encodes the site identity (``cross_attn/down3`` etc. — place +
-    global layer index from the :class:`AttnMeta`): the scope lands in
-    the HLO op metadata, so a Perfetto/XProf device trace splits step
-    time *per attention site* — the per-site cost attribution the
-    TAD-style reuse-schedule search (ROADMAP item 1) keys on. A trace-
-    time name only: the lowered ops, numerics and jaxpr structure are
-    identical with or without it."""
+    global layer index from the :class:`AttnMeta`), with the parts
+    ``qkv`` (pre-norm, projections, head split), ``core`` (scores,
+    softmax, the edit, P·V — whichever of XLA / flash / fused runs it) and
+    ``out`` (head merge, ``to_out``, cache store, residual) below it. The
+    scope lands in the ``op_name`` metadata of the compiled program's
+    instructions, which ``obs.traceparse.scope_index`` maps back from
+    instruction names: that join is what splits a device trace's step time
+    per site (the trace itself carries instruction names only;
+    docs/OBSERVABILITY.md). A trace-time name only: the lowered ops,
+    numerics and jaxpr structure are identical with or without it."""
     meta = ctx.next_meta()
     assert meta.is_cross == is_cross, (
         f"layout order mismatch at site {meta.layer_idx}: layout says "
         f"is_cross={meta.is_cross}, model called is_cross={is_cross}")
     with jax.named_scope(f"{'cross_attn' if is_cross else 'self_attn'}"
                          f"/{meta.place}{meta.layer_idx}"):
-        return _attention_site(p, x, context, heads, ctx, meta, is_cross)
+        return _attention_site(p, ln, x, context, heads, ctx, meta, is_cross)
 
 
 def _site_mode(ctx: _HookCtx, meta, is_cross: bool) -> str:
@@ -330,8 +335,8 @@ def _fused_edit_dispatch(ctx: _HookCtx, meta, q, k, v, scale):
                                 interpret=ctx.kernels.interpret)
 
 
-def _attention_site(p: Params, x: jax.Array, context: jax.Array, heads: int,
-                    ctx: _HookCtx, meta, is_cross: bool) -> jax.Array:
+def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
+                    heads: int, ctx: _HookCtx, meta, is_cross: bool) -> jax.Array:
     mode = _site_mode(ctx, meta, is_cross)
     if mode == "use":
         # The site's output is served from its cache: for cross sites the
@@ -346,106 +351,112 @@ def _attention_site(p: Params, x: jax.Array, context: jax.Array, heads: int,
             f"attn cache shape {cached.shape} does not match site "
             f"{meta.layer_idx} input {x.shape} — was the cache captured at a "
             "different batch/resolution?")
-        return cached
+        with jax.named_scope("out"):
+            return x + cached
 
-    b, pix, _ = x.shape
-    src = context if is_cross else x
-    q = nn.linear(p["to_q"], x)
-    k = nn.linear(p["to_k"], src)
-    v = nn.linear(p["to_v"], src)
-    d_head = q.shape[-1] // heads
-    scale = d_head ** -0.5
+    with jax.named_scope("qkv"):
+        b, pix, _ = x.shape
+        normed = nn.layer_norm(ln, x)
+        src = context if is_cross else normed
+        q = nn.linear(p["to_q"], normed)
+        k = nn.linear(p["to_k"], src)
+        v = nn.linear(p["to_v"], src)
+        d_head = q.shape[-1] // heads
+        scale = d_head ** -0.5
 
-    def split_heads(t):
-        return t.reshape(b, t.shape[1], heads, d_head).transpose(0, 2, 1, 3)
+        def split_heads(t):
+            return t.reshape(b, t.shape[1], heads, d_head).transpose(0, 2, 1, 3)
 
-    q, k, v = split_heads(q), split_heads(k), split_heads(v)
+        q, k, v = split_heads(q), split_heads(k), split_heads(v)
 
-    if controller_touches(ctx.controller, meta):
-        out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
-        if out is None:
-            probs = nn.attention_probs(q, k, scale)        # (B, heads, P, K) f32
-            ctx.state, probs = apply_attention_control(
-                ctx.controller, meta, ctx.state, probs, ctx.step)
-            out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
-    elif (ctx.sp is not None and not is_cross
-          and meta.pixels >= ctx.sp.min_pixels):
-        n = ctx.sp.mesh.shape[ctx.sp.axis]
-        if meta.pixels % n:
-            # Unsharded fallback is safe only when fused attention stays
-            # blockwise (flash-tileable: S ≥ 2048 with a power-of-two block
-            # dividing it). Otherwise the einsum path would materialize the
-            # O(P²) scores on one device — the blow-up SpConfig exists to
-            # avoid — so that case is an error, not a warning.
-            flash_ok = meta.pixels >= 2048 and any(
-                meta.pixels % b == 0 for b in (1024, 512, 256))
-            if not flash_ok:
-                raise ValueError(
-                    f"sequence-parallel site {meta.layer_idx} has "
-                    f"{meta.pixels} pixels, not divisible by mesh axis "
-                    f"{ctx.sp.axis!r}={n}, and not flash-tileable locally; "
-                    f"choose a divisor axis size or raise SpConfig.min_pixels")
-            import warnings
-
-            warnings.warn(
-                f"sequence-parallel site {meta.layer_idx}: {meta.pixels} "
-                f"pixels not divisible by mesh axis {ctx.sp.axis!r}={n}; "
-                f"running this site unsharded (local flash)", stacklevel=2)
-            out = nn.fused_attention(q, k, v, scale)
-        elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
-            from ..parallel.alltoall import alltoall_self_attention
-
-            out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
-                                          ctx.sp.axis)
-        else:
-            if ctx.sp.mode == "alltoall":
-                # Same user-visible note as the pixel-indivisible fallback
-                # above: someone benchmarking alltoall must not unknowingly
-                # measure ring (warnings module dedups per call site).
+    with jax.named_scope("core"):
+        if controller_touches(ctx.controller, meta):
+            out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
+            if out is None:
+                probs = nn.attention_probs(q, k, scale)        # (B, heads, P, K) f32
+                ctx.state, probs = apply_attention_control(
+                    ctx.controller, meta, ctx.state, probs, ctx.step)
+                out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+        elif (ctx.sp is not None and not is_cross
+              and meta.pixels >= ctx.sp.min_pixels):
+            n = ctx.sp.mesh.shape[ctx.sp.axis]
+            if meta.pixels % n:
+                # Unsharded fallback is safe only when fused attention stays
+                # blockwise (flash-tileable: S ≥ 2048 with a power-of-two block
+                # dividing it). Otherwise the einsum path would materialize the
+                # O(P²) scores on one device — the blow-up SpConfig exists to
+                # avoid — so that case is an error, not a warning.
+                flash_ok = meta.pixels >= 2048 and any(
+                    meta.pixels % b == 0 for b in (1024, 512, 256))
+                if not flash_ok:
+                    raise ValueError(
+                        f"sequence-parallel site {meta.layer_idx} has "
+                        f"{meta.pixels} pixels, not divisible by mesh axis "
+                        f"{ctx.sp.axis!r}={n}, and not flash-tileable locally; "
+                        f"choose a divisor axis size or raise SpConfig.min_pixels")
                 import warnings
 
                 warnings.warn(
-                    f"sequence-parallel site {meta.layer_idx}: "
-                    f"{q.shape[1]} heads not divisible by mesh axis "
-                    f"{ctx.sp.axis!r}={n}; alltoall falls back to ring "
-                    f"at this site", stacklevel=2)
-            from ..parallel.ring import ring_self_attention
+                    f"sequence-parallel site {meta.layer_idx}: {meta.pixels} "
+                    f"pixels not divisible by mesh axis {ctx.sp.axis!r}={n}; "
+                    f"running this site unsharded (local flash)", stacklevel=2)
+                out = nn.fused_attention(q, k, v, scale)
+            elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
+                from ..parallel.alltoall import alltoall_self_attention
 
-            out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
-    else:
-        out = nn.fused_attention(q, k, v, scale)
+                out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
+                                              ctx.sp.axis)
+            else:
+                if ctx.sp.mode == "alltoall":
+                    # Same user-visible note as the pixel-indivisible fallback
+                    # above: someone benchmarking alltoall must not unknowingly
+                    # measure ring (warnings module dedups per call site).
+                    import warnings
 
-    out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)
-    out = nn.linear(p["to_out"], out)
-    if mode == "store":
-        # Capture the conditional half of the CFG-doubled batch (rows B:).
-        # Overwritten every step, so after the scan the cache holds
-        # exactly the last stored step's outputs — no per-step select.
-        lst = list(ctx.attn_cache)
-        lst[ctx.cross_cursor] = out[out.shape[0] // 2:]
-        ctx.attn_cache = tuple(lst)
-        ctx.cross_cursor += 1
-    elif mode == "store_all":
-        # A site that flips to reuse inside its current batch regime
-        # (engine.reuse MODE_STORE_ALL) keeps the whole live batch — 2B
-        # while CFG is active, B past the gate — so the flip segment can
-        # serve it without a shape change.
-        lst = list(ctx.attn_cache)
-        lst[ctx.cross_cursor] = out
-        ctx.attn_cache = tuple(lst)
-        ctx.cross_cursor += 1
-    return out
+                    warnings.warn(
+                        f"sequence-parallel site {meta.layer_idx}: "
+                        f"{q.shape[1]} heads not divisible by mesh axis "
+                        f"{ctx.sp.axis!r}={n}; alltoall falls back to ring "
+                        f"at this site", stacklevel=2)
+                from ..parallel.ring import ring_self_attention
+
+                out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
+        else:
+            out = nn.fused_attention(q, k, v, scale)
+
+    with jax.named_scope("out"):
+        out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)
+        out = nn.linear(p["to_out"], out)
+        if mode == "store":
+            # Capture the conditional half of the CFG-doubled batch (rows B:).
+            # Overwritten every step, so after the scan the cache holds
+            # exactly the last stored step's outputs — no per-step select.
+            lst = list(ctx.attn_cache)
+            lst[ctx.cross_cursor] = out[out.shape[0] // 2:]
+            ctx.attn_cache = tuple(lst)
+            ctx.cross_cursor += 1
+        elif mode == "store_all":
+            # A site that flips to reuse inside its current batch regime
+            # (engine.reuse MODE_STORE_ALL) keeps the whole live batch — 2B
+            # while CFG is active, B past the gate — so the flip segment can
+            # serve it without a shape change.
+            lst = list(ctx.attn_cache)
+            lst[ctx.cross_cursor] = out
+            ctx.attn_cache = tuple(lst)
+            ctx.cross_cursor += 1
+        return x + out
 
 
 def _apply_transformer_block(p: Params, x: jax.Array, context: jax.Array,
                              heads: int, ctx: _HookCtx) -> jax.Array:
-    x = x + _apply_attention(p["attn1"], nn.layer_norm(p["ln1"], x), context,
-                             heads, ctx, is_cross=False)
-    x = x + _apply_attention(p["attn2"], nn.layer_norm(p["ln2"], x), context,
-                             heads, ctx, is_cross=True)
-    h = nn.linear(p["ff_in"], nn.layer_norm(p["ln3"], x))
-    val, gate = jnp.split(h, 2, axis=-1)
-    x = x + nn.linear(p["ff_out"], val * nn.gelu(gate))
+    x = _apply_attention(p["attn1"], p["ln1"], x, context, heads, ctx,
+                         is_cross=False)
+    x = _apply_attention(p["attn2"], p["ln2"], x, context, heads, ctx,
+                         is_cross=True)
+    with jax.named_scope("ff"):
+        h = nn.linear(p["ff_in"], nn.layer_norm(p["ln3"], x))
+        val, gate = jnp.split(h, 2, axis=-1)
+        x = x + nn.linear(p["ff_out"], val * nn.gelu(gate))
     return x
 
 
@@ -453,16 +464,19 @@ def _apply_spatial_transformer(p: Params, x: jax.Array, context: jax.Array,
                                cfg: UNetConfig, ctx: _HookCtx) -> jax.Array:
     b, h, w, c = x.shape
     residual = x
-    x = nn.group_norm(p["norm"], x, cfg.groups, eps=1e-6)
-    # proj_in/proj_out are 1×1 convs in the checkpoint; applied as linears in
-    # token-major space so the whole transformer stack stays (B, P, C) with no
-    # spatial relayouts between the convs and the attention matmuls.
-    x = x.reshape(b, h * w, c)
-    x = nn.linear_1x1(p["proj_in"], x)
+    with jax.named_scope("proj_in"):
+        x = nn.group_norm(p["norm"], x, cfg.groups, eps=1e-6)
+        # proj_in/proj_out are 1×1 convs in the checkpoint; applied as linears
+        # in token-major space so the whole transformer stack stays (B, P, C)
+        # with no spatial relayouts between the convs and the attention
+        # matmuls.
+        x = x.reshape(b, h * w, c)
+        x = nn.linear_1x1(p["proj_in"], x)
     for block in p["blocks"]:
         x = _apply_transformer_block(block, x, context, cfg.heads_for(c), ctx)
-    x = nn.linear_1x1(p["proj_out"], x)
-    return x.reshape(b, h, w, c) + residual
+    with jax.named_scope("proj_out"):
+        x = nn.linear_1x1(p["proj_out"], x)
+        return x.reshape(b, h, w, c) + residual
 
 
 def apply_unet(
@@ -562,44 +576,71 @@ def apply_unet(
                    site_plan=site_plan, kernels=kernels)
     g = cfg.groups
 
-    t = jnp.broadcast_to(jnp.asarray(t), (x.shape[0],))
-    temb = nn.timestep_embedding(t, cfg.freq_dim or cfg.block_channels[0],
-                                 dtype=x.dtype)
-    temb = nn.linear(params["time_fc2"], nn.silu(nn.linear(params["time_fc1"], temb)))
+    # Scopes (docs/OBSERVABILITY.md, "Scope vocabulary"): ``unet/<part>`` and
+    # ``unet/<place><n>/<part>``, n counting a place's blocks in the order
+    # they run (diffusers' ``down_blocks.n`` / ``up_blocks.n``).
+    def resnet_and_attn(block, i, h):
+        with jax.named_scope(f"res{i}"):
+            h = _apply_resnet(block["resnets"][i], h, temb, g)
+        if block["attns"]:
+            with jax.named_scope(f"attn{i}"):
+                h = _apply_spatial_transformer(block["attns"][i], h, context,
+                                               cfg, ctx)
+        return h
 
-    h = nn.conv2d(params["conv_in"], x)
-    skips = [h]
-    for level, block in enumerate(params["down"]):
-        for i, resnet in enumerate(block["resnets"]):
-            h = _apply_resnet(resnet, h, temb, g)
-            if block["attns"]:
-                h = _apply_spatial_transformer(block["attns"][i], h, context, cfg, ctx)
-            skips.append(h)
-        if "downsample" in block:
-            # Symmetric pad 1 (diffusers downsample_padding=1) — XLA SAME would
-            # pad (0,1) on even inputs and shift every downstream feature map.
-            h = nn.conv2d(block["downsample"], h, stride=2, padding=1)
-            skips.append(h)
+    with jax.named_scope("unet"):
+        with jax.named_scope("time_embed"):
+            t = jnp.broadcast_to(jnp.asarray(t), (x.shape[0],))
+            temb = nn.timestep_embedding(
+                t, cfg.freq_dim or cfg.block_channels[0], dtype=x.dtype)
+            temb = nn.linear(params["time_fc2"],
+                             nn.silu(nn.linear(params["time_fc1"], temb)))
 
-    h = _apply_resnet(params["mid"]["resnet1"], h, temb, g)
-    h = _apply_spatial_transformer(params["mid"]["attn"], h, context, cfg, ctx)
-    h = _apply_resnet(params["mid"]["resnet2"], h, temb, g)
+        with jax.named_scope("conv_in"):
+            h = nn.conv2d(params["conv_in"], x)
+        skips = [h]
+        for n, block in enumerate(params["down"]):
+            with jax.named_scope(f"down{n}"):
+                for i in range(len(block["resnets"])):
+                    h = resnet_and_attn(block, i, h)
+                    skips.append(h)
+                if "downsample" in block:
+                    # Symmetric pad 1 (diffusers downsample_padding=1) — XLA
+                    # SAME would pad (0,1) on even inputs and shift every
+                    # downstream feature map.
+                    with jax.named_scope("downsample"):
+                        h = nn.conv2d(block["downsample"], h, stride=2,
+                                      padding=1)
+                    skips.append(h)
 
-    for block in params["up"]:
-        for i, resnet in enumerate(block["resnets"]):
-            h = jnp.concatenate([h, skips.pop()], axis=-1)
-            h = _apply_resnet(resnet, h, temb, g)
-            if block["attns"]:
-                h = _apply_spatial_transformer(block["attns"][i], h, context, cfg, ctx)
-        if "upsample" in block:
-            h = nn.conv2d(block["upsample"], nn.upsample_nearest_2x(h))
+        with jax.named_scope("mid0"):
+            mid = params["mid"]
+            with jax.named_scope("res0"):
+                h = _apply_resnet(mid["resnet1"], h, temb, g)
+            with jax.named_scope("attn0"):
+                h = _apply_spatial_transformer(mid["attn"], h, context, cfg,
+                                               ctx)
+            with jax.named_scope("res1"):
+                h = _apply_resnet(mid["resnet2"], h, temb, g)
 
-    assert ctx.cursor == len(layout.metas), (
-        f"attention layout mismatch: model has {ctx.cursor} sites, "
-        f"layout has {len(layout.metas)}")
+        for n, block in enumerate(params["up"]):
+            with jax.named_scope(f"up{n}"):
+                for i in range(len(block["resnets"])):
+                    with jax.named_scope("skip_concat"):
+                        h = jnp.concatenate([h, skips.pop()], axis=-1)
+                    h = resnet_and_attn(block, i, h)
+                if "upsample" in block:
+                    with jax.named_scope("upsample"):
+                        h = nn.conv2d(block["upsample"],
+                                      nn.upsample_nearest_2x(h))
 
-    h = nn.silu(nn.group_norm(params["norm_out"], h, g))
-    eps = nn.conv2d(params["conv_out"], h)
+        assert ctx.cursor == len(layout.metas), (
+            f"attention layout mismatch: model has {ctx.cursor} sites, "
+            f"layout has {len(layout.metas)}")
+
+        with jax.named_scope("conv_out"):
+            h = nn.silu(nn.group_norm(params["norm_out"], h, g))
+            eps = nn.conv2d(params["conv_out"], h)
     if cache_mode == "store" or site_plan is not None:
         return eps, ctx.state, ctx.attn_cache
     return eps, ctx.state

@@ -190,22 +190,32 @@ def decode(params: Params, cfg: VAEConfig, latents: jax.Array) -> jax.Array:
     same function, `/root/reference/ptp_utils.py:124`)."""
     p = params["decoder"]
     g = cfg.groups
-    h = latents / cfg.scaling_factor
-    if cfg.kind == "vq":
-        h = quantize(params, cfg, h)
-    h = nn.conv2d(p["post_quant_conv"], h)
-    h = nn.conv2d(p["conv_in"], h)
-    h = _apply_resnet(p["mid"]["resnet1"], h, g)
-    h = _apply_attn(p["mid"]["attn"], h, g)
-    h = _apply_resnet(p["mid"]["resnet2"], h, g)
-    for block in p["up"]:
-        for resnet in block["resnets"]:
-            h = _apply_resnet(resnet, h, g)
-        if "upsample" in block:
-            h = nn.conv2d(block["upsample"], nn.upsample_nearest_2x(h))
-    return nn.conv2d(p["conv_out"], nn.silu(nn.group_norm(p["norm_out"], h, g)))
+    # Scopes: ``vae.decode/{conv_in,mid,up<n>,conv_out}``, n in the order the
+    # blocks run (docs/OBSERVABILITY.md, "Scope vocabulary").
+    with jax.named_scope("vae.decode"):
+        with jax.named_scope("conv_in"):
+            h = latents / cfg.scaling_factor
+            if cfg.kind == "vq":
+                h = quantize(params, cfg, h)
+            h = nn.conv2d(p["post_quant_conv"], h)
+            h = nn.conv2d(p["conv_in"], h)
+        with jax.named_scope("mid"):
+            h = _apply_resnet(p["mid"]["resnet1"], h, g)
+            h = _apply_attn(p["mid"]["attn"], h, g)
+            h = _apply_resnet(p["mid"]["resnet2"], h, g)
+        for n, block in enumerate(p["up"]):
+            with jax.named_scope(f"up{n}"):
+                for resnet in block["resnets"]:
+                    h = _apply_resnet(resnet, h, g)
+                if "upsample" in block:
+                    h = nn.conv2d(block["upsample"], nn.upsample_nearest_2x(h))
+        with jax.named_scope("conv_out"):
+            return nn.conv2d(p["conv_out"],
+                             nn.silu(nn.group_norm(p["norm_out"], h, g)))
 
 
 def to_uint8(image: jax.Array) -> jax.Array:
-    """[-1,1] float → uint8 HWC (`/root/reference/ptp_utils.py:82-84`)."""
-    return (jnp.clip(image / 2 + 0.5, 0.0, 1.0) * 255).astype(jnp.uint8)
+    """[-1,1] float → uint8 HWC (`/root/reference/ptp_utils.py:82-84`).
+    Scoped with the decoder's last convolution, which it follows."""
+    with jax.named_scope("vae.decode/conv_out"):
+        return (jnp.clip(image / 2 + 0.5, 0.0, 1.0) * 255).astype(jnp.uint8)

@@ -6,10 +6,11 @@ docs/OBSERVABILITY.md for the metric catalog and span taxonomy):
 - :mod:`.metrics` — process-global registry of counters, gauges and
   fixed-bucket histograms with labeled families, snapshot/reset semantics,
   Prometheus text exposition and JSONL export.
-- :mod:`.spans` — nested wall-clock spans in a bounded (configurable)
-  ring buffer, mirrored into ``jax.profiler.TraceAnnotation`` so host
-  spans line up with device xplane traces; ``attach()`` stamps spans
-  with request identity.
+- :mod:`.spans` — nested wall-clock spans on ``time.monotonic_ns()`` in a
+  bounded (configurable) ring buffer, mirrored into
+  ``jax.profiler.TraceAnnotation`` under the same span ids so a ring event
+  and its trace event are one record; ``attach()`` stamps spans with
+  request identity.
 - :mod:`.flight` — request-scoped flight tracing for the serve engine:
   per-request stage timelines across the two program pools (stitched
   across crash-replay), a Chrome-trace/Perfetto export, and the blackbox
@@ -30,8 +31,14 @@ docs/OBSERVABILITY.md for the metric catalog and span taxonomy):
   jax-side).
 - :mod:`.traceparse` — shared chrome-trace / WorkloadProfile parsing
   (ISSUE 18): the ``perfscope --sites`` named_scope fold, the HLO
-  op→site index that recovers measured per-site shares from bare-op
-  traces, and the ledger format helpers. Stdlib-only; safe from tools.
+  instruction→scope index (``scope_index``; ``op_site_index`` is its
+  attention-site form) that recovers measured per-scope shares from
+  traces that name instructions only, and the ledger format helpers.
+  Stdlib-only; safe from tools.
+- :mod:`.launches` — which compiled programs the entry points launched,
+  kept abstract (shapes and shardings, never arrays), and the scope index
+  of each, built from the compiled program's text when somebody asks.
+  Imported explicitly; jax only inside functions.
 - :mod:`.prodscope` — in-engine sampled device profiling (ISSUE 18):
   the deterministic sampling plan, the bounded on-disk trace ring, the
   mergeable WorkloadProfile ledger and the EWMA drift sentinels behind

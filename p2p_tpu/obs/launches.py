@@ -1,0 +1,173 @@
+"""launches — which compiled programs ran, and their scope index on request.
+
+A TPU trace names a device event by its HLO instruction and its module
+(``jit__text2image_jit``) and carries no ``named_scope`` path; the compiled
+program's text does (``metadata={op_name=...}``). This registry is how a
+reader gets from one to the other for a program that really ran:
+
+- the entry points (``text2image``, ``sweep``, ``sweep_phase1/2``,
+  ``encode_prompts``) take a mark before they call their jitted program
+  (:func:`built`) and hand the call over after it (:func:`keep_if_built`).
+  That costs one integer comparison: whether the compile ledger
+  (``utils.cache.CompileLedger``) counted a program built while the call
+  ran. Only then — the first launch of a distinct program — the registry
+  keeps what is needed to lower that program again: the jitted function,
+  its static arguments, and ``ShapeDtypeStruct``s with the arguments'
+  shardings. Shapes, never arrays.
+- :func:`scope_index` lowers, compiles and parses that program lazily, once,
+  when somebody asks (``obs.traceparse.scope_index`` on the executable's
+  text). After a launch in the same process the executable is still in
+  memory, and in a later one the compile is a read of the persistent cache;
+  the ledger shows which (``Launch.built_from``). Nothing is lowered,
+  compiled or parsed unless asked.
+
+JAX leaves metadata out of the compilation-cache key, so an executable
+cached by a tree with other scopes is served with its old ``op_name``s. An
+index that finds no scope at all in a program that has them is taken for
+such an entry and built once more past the cache (docs/OBSERVABILITY.md,
+"Stale scopes").
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import time
+from typing import Any, Dict, List, Optional, Tuple
+
+from ..utils.cache import compile_ledger
+from . import traceparse
+
+
+@dataclasses.dataclass
+class Launch:
+    """The first launch of one distinct program, kept abstract."""
+
+    module: str                 # the XLA module's name: ``jit_<function>``
+    fn: Any                     # the jitted function
+    args: tuple                 # arrays replaced by ShapeDtypeStructs
+    kwargs: dict
+    index: Optional[Dict[str, str]] = None
+    mixed: Optional[Dict[str, Dict[str, int]]] = None
+    built_from: str = ""        # "memory" | "cache_hit" | "backend" (compiled)
+
+    def _signature(self):
+        import jax
+
+        leaves, tree = jax.tree_util.tree_flatten((self.args, self.kwargs))
+        return self.module, tree, tuple(
+            (x.shape, x.dtype, x.sharding)
+            if isinstance(x, jax.ShapeDtypeStruct) else x for x in leaves)
+
+
+_launches: Dict[str, List[Launch]] = {}     # module -> distinct programs
+
+
+def built() -> int:
+    """How many programs the process has built so far, compiled or read from
+    the persistent cache: the mark a launch site takes before it calls its
+    jitted function."""
+    return compile_ledger().programs
+
+
+def keep_if_built(mark: int, fn, args: tuple, kwargs: dict) -> None:
+    """After ``fn(*args, **kwargs)``: where a program was built since
+    ``mark`` (traced and compiled, or read from the persistent cache), the
+    launch is kept for :func:`scope_index`.
+
+    Two calls around the launch and not a wrapper of it: a wrapper's frame
+    would stand under everything JAX traces and lowers in the first launch,
+    and how deep that work starts on Python's frame stack decides seconds of
+    set-up (PERF.md, Findings, PR 27)."""
+    if compile_ledger().programs != mark:
+        _keep(fn, args, kwargs)
+
+
+def _abstract(tree):
+    """Arrays to ``ShapeDtypeStruct``s that keep the sharding they were
+    committed to; None where the call was itself being traced (there is no
+    program of its own)."""
+    import jax
+    import numpy as np
+
+    traced = False
+
+    def leaf(x):
+        nonlocal traced
+        if isinstance(x, jax.core.Tracer):
+            traced = True
+        elif isinstance(x, jax.Array):
+            # An uncommitted array lowers with no sharding at all; given its
+            # single-device sharding the program would lower, and be cached,
+            # as another one.
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None)
+        elif isinstance(x, (np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    out = jax.tree_util.tree_map(leaf, tree)
+    return None if traced else out
+
+
+def _keep(fn, args, kwargs) -> None:
+    abstract = _abstract((args, kwargs))
+    if abstract is None:
+        return
+    launch = Launch("jit_" + fn.__name__, fn, *abstract)
+    known = _launches.setdefault(launch.module, [])
+    # Another thread's compile can make a warm call look like a first launch.
+    if all(launch._signature() != k._signature() for k in known):
+        known.append(launch)
+
+
+def programs(module: Optional[str] = None) -> List[Launch]:
+    """The distinct programs launched so far, of ``module`` or of all."""
+    if module is not None:
+        return list(_launches.get(module, ()))
+    return [p for ps in _launches.values() for p in ps]
+
+
+def scope_index(module: str
+                ) -> Optional[Tuple[Dict[str, str], Dict[str, Dict[str, int]]]]:
+    """``obs.traceparse.scope_index`` of the newest program of ``module``,
+    the one whose first launch came last: ``({HLO instruction: scope},
+    {fusion: {scope: members}})``, built on the first request and kept. None
+    where no such program was launched. A trace names a program by its
+    module only, and instruction names differ between two programs of one
+    module: where an older program of the module ran again in the traced
+    window (``len(programs(module)) > 1``), this index is not that
+    program's, and a reader sees it as a low share of scoped time."""
+    known = _launches.get(module)
+    if not known:
+        return None
+    launch = known[-1]
+    if launch.index is None:
+        _build(launch)
+    return launch.index, launch.mixed
+
+
+#: A compile option that changes logging only, and with it the key under
+#: which JAX caches the executable: how a program is compiled past a stale
+#: entry, in memory and on disk, and found again by the next process that asks.
+_PAST_THE_CACHE = {"xla_detailed_logging": True}
+
+
+def _build(launch: Launch) -> None:
+    index, mixed = _compile_and_parse(launch)
+    if not index and launch.built_from != "backend":
+        # Every program launched from here has scopes, so a cached
+        # executable without one was compiled before they were named (the
+        # cache's key leaves metadata out). Once more, past the cache.
+        index, mixed = _compile_and_parse(launch, _PAST_THE_CACHE)
+    launch.index, launch.mixed = index, mixed
+
+
+def _compile_and_parse(launch: Launch, compiler_options=None):
+    t0 = time.monotonic()
+    lowered = launch.fn.lower(*launch.args, **launch.kwargs)
+    text = lowered.compile(compiler_options=compiler_options).as_text()
+    # No row: the executable the launch itself built, still in memory.
+    rows = compile_ledger().rows("backend", "cache_hit", since=t0)
+    launch.built_from = rows[-1].kind if rows else "memory"
+    return traceparse.scope_index(text)

@@ -1,11 +1,11 @@
 """Nested wall-clock spans with a bounded ring buffer of structured events.
 
 ``with span("serve.batch", lanes=4): ...`` records a start and an end event
-(name, span id, parent id, nesting depth, relative timestamp, attributes,
-duration) into a fixed-capacity ring buffer — old events are evicted, never
-buffered unboundedly — and mirrors the block into
-``jax.profiler.TraceAnnotation`` so the same named region shows up on the
-host rows of an xplane/Perfetto trace captured with ``utils.progress.trace``
+(name, span id, parent id, nesting depth, timestamp, attributes, duration)
+into a fixed-capacity ring buffer — old events are evicted, never buffered
+unboundedly — and mirrors the block into a ``jax.profiler.TraceAnnotation``
+that carries the same ``span`` and ``parent`` ids, so a ring event and the
+region on the host rows of an xplane/Perfetto trace are one record by id
 (docs/OBSERVABILITY.md shows how to line the two up). Span durations are
 additionally observed into the ``span_duration_ms`` histogram of the default
 metrics registry, so the Prometheus snapshot carries the per-span-name
@@ -17,8 +17,10 @@ telemetry-disabled jaxpr-identity guarantee is unaffected by spans entirely.
 ``set_enabled(False)`` turns :func:`span` into a pure pass-through for
 callers who want zero event traffic.
 
-Timestamps are milliseconds on a module-local ``perf_counter`` epoch —
-monotonic and comparable across events of one process, not wall-clock.
+Timestamps are ``t_ns = time.monotonic_ns()``: the clock of the compile
+ledger (``utils.cache``), of the benchmark's harness and of anything else in
+the process that reads ``time.monotonic()``, so spans need no private epoch
+to be compared with them.
 """
 
 from __future__ import annotations
@@ -33,13 +35,6 @@ from typing import List, Optional
 from . import metrics as metrics_mod
 
 DEFAULT_CAPACITY = 4096
-
-_EPOCH = time.perf_counter()
-
-
-def _now_ms() -> float:
-    return (time.perf_counter() - _EPOCH) * 1000.0
-
 
 class SpanRecorder:
     """Bounded event sink. ``dropped`` counts ring-evicted events so an
@@ -136,13 +131,16 @@ def clear() -> None:
     _recorder.clear()
 
 
-def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None when jax (or
-    its profiler) is unavailable — spans must not *require* jax."""
+def _trace_annotation(name: str, sid: int, parent: Optional[int]):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` that carries the
+    span's ids (the profiler shows them as the event's ``span`` / ``parent``
+    arguments; a root's parent is 0), or None when jax (or its profiler) is
+    unavailable — spans must not *require* jax."""
     try:
         import jax
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, span=sid,
+                                            parent=parent or 0)
     except Exception:
         return None
 
@@ -161,12 +159,11 @@ def span(name: str, **attrs):
     depth = len(_stack)
     if _attached:
         attrs = {**_attached_attrs(), **attrs}
-    t0 = time.perf_counter()
+    t0 = time.monotonic_ns()
     _recorder.emit({"event": "span_start", "span": sid, "name": name,
-                    "parent": parent, "depth": depth, "ts_ms": _now_ms(),
-                    **attrs})
+                    "parent": parent, "depth": depth, "t_ns": t0, **attrs})
     _stack.append(sid)
-    ann = _trace_annotation(name)
+    ann = _trace_annotation(name, sid, parent)
     if ann is not None:
         ann.__enter__()
     try:
@@ -175,9 +172,10 @@ def span(name: str, **attrs):
         if ann is not None:
             ann.__exit__(None, None, None)
         _stack.pop()
-        dur_ms = (time.perf_counter() - t0) * 1000.0
+        t1 = time.monotonic_ns()
+        dur_ms = (t1 - t0) / 1e6
         _recorder.emit({"event": "span_end", "span": sid, "name": name,
-                        "parent": parent, "depth": depth, "ts_ms": _now_ms(),
+                        "parent": parent, "depth": depth, "t_ns": t1,
                         "dur_ms": dur_ms, **attrs})
         metrics_mod.registry().histogram(
             "span_duration_ms", "wall-clock span durations by span name",

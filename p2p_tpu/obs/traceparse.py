@@ -9,15 +9,16 @@ code path. Three layers:
   :func:`parse_site_trace`): gz-aware ``traceEvents`` extraction and the
   PR-15 per-attention-site duration fold, behavior-identical to the old
   ``tools/perfscope.py`` implementation.
-- **HLO op→site indexing** (:func:`op_site_index`,
-  :func:`fold_site_events`): on CPU (and on device backends that emit
-  bare HLO op names) trace events carry ``args.hlo_op`` — not the
-  ``named_scope`` path. But the *compiled HLO text* keeps the full scope
-  path in per-instruction ``metadata={op_name="..."}``. Indexing
-  instruction names to sites at program-build time (fusions attributed to
-  the dominant site of their called computation) lets the event fold
-  recover genuinely measured per-site durations from traces whose event
-  names alone carry no site information.
+- **HLO op→scope indexing** (:func:`scope_index`, its attention-site
+  form :func:`op_site_index`, :func:`fold_site_events`): a TPU trace
+  names a device event by its HLO instruction and a CPU trace carries
+  ``args.hlo_op`` — neither carries the ``named_scope`` path. But the
+  *compiled HLO text* keeps the full scope path in per-instruction
+  ``metadata={op_name="..."}``. Indexing instruction names to scopes
+  (fusions attributed to the dominant scope of their called computation)
+  lets a reader recover measured per-scope durations from traces whose
+  event names alone carry no scope information
+  (``obs.launches.scope_index`` offers the index of a program that ran).
 - **WorkloadProfile format** (:data:`PROFILE_FORMAT`,
   :func:`is_workload_profile`, :func:`load_workload_profile`,
   :func:`profile_sites`, :func:`validate_profile`): the durable ledger
@@ -44,17 +45,45 @@ PROFILE_FORMAT = "p2p-workload-profile/v1"
 #: op metadata: ``cross_attn/down3``, ``self_attn/mid0``, ...
 SITE_RE = re.compile(r"(cross_attn|self_attn)/(?:down|mid|up)\d+")
 
-# HLO-text structure: a computation header opens a ``{`` block, each
-# instruction line is ``%name = ... metadata={op_name="scope/path" ...}``,
-# and fusion instructions name their called computation via ``calls=``.
+_PLACE = r"(?:down|mid|up)\d+"
+#: The program's whole scope vocabulary (docs/OBSERVABILITY.md, "Scope
+#: vocabulary"), outermost first; the longest documented prefix of a path
+#: matches, so whatever JAX appends below a scope (primitive names, inner
+#: function names) is dropped.
+SCOPE_RE = re.compile(
+    r"(?<![\w.])(?:text_encoder"
+    r"|sampler/(?:cfg|scheduler_step|controller_step)"
+    r"|unet(?:/(?:time_embed|conv_in|conv_out"
+    rf"|{_PLACE}(?:/(?:res\d+|downsample|upsample|skip_concat"
+    r"|attn\d+(?:/(?:proj_in|proj_out|ff"
+    rf"|(?:self_attn|cross_attn)/{_PLACE}(?:/(?:qkv|core|out))?))?))?))?"
+    r"|vae\.decode(?:/(?:conv_in|mid|up\d+|conv_out))?)(?![\w.])")
+
+# HLO-text structure: a computation header opens a ``{`` block (its
+# parameter list may nest parentheses: a TPU layout reads ``{1,0:T(8,128)}``), each
+# instruction line is ``%name = <result> opcode(...)`` and may carry
+# ``metadata={op_name="scope/path" ...}``, and fusion instructions name
+# their called computation via ``calls=``.
 _COMP_RE = re.compile(
-    r"^(?:ENTRY\s+)?%?([A-Za-z0-9_.\-]+)\s*(?:\([^)]*\))?\s*->.*\{\s*$")
+    r"^(?:ENTRY\s+)?%?([A-Za-z0-9_.\-]+)\s*\(.*->.*\{\s*$")
 _INSTR_RE = re.compile(
-    r'^\s*(?:ROOT\s+)?%([A-Za-z0-9_.\-]+)\s*=\s.*'
-    r'metadata=\{[^}]*op_name="([^"]+)"')
-_FUSION_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([A-Za-z0-9_.\-]+)\s*=\s.*fusion\(.*"
-    r"calls=%?([A-Za-z0-9_.\-]+)")
+    r"^\s*(?:ROOT\s+)?%?([A-Za-z0-9_.\-]+)\s*=\s.*?\s([a-z][a-z\-]*)\(")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]+)"')
+_CALLS_RE = re.compile(r"calls=%?([A-Za-z0-9_.\-]+)")
+# What JAX puts into an ``op_name`` around the user's scopes: transform
+# wrappers (``vmap(unet)/down0`` wraps the first scope below it) and the
+# components a ``lax.scan`` / ``while_loop`` inside a scope adds.
+_WRAPPER_RE = re.compile(r"[A-Za-z_]+\(([^()]*)\)")
+_LOOP_PARTS_RE = re.compile(r"/(?:while|body|cond|closed_call)(?=/|$)")
+_OPERAND_RE = re.compile(r"%([A-Za-z0-9_.\-]+)")
+#: A fusion with members of these opcodes is that member's scope's on a tie.
+_HEAVY_OPCODES = ("convolution", "dot", "custom-call")
+#: Opcodes that read what many scopes made: a scope is not found through them.
+_AGGREGATES = ("tuple", "while", "call", "conditional")
+#: How far from its reader a compiler-made instruction may stand.
+_HOPS = 4
+#: Opcodes that take no time on a device: they need no scope.
+_NEVER_RUN = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
 
 #: Top-level keys a v1 ledger must carry (schema table in
 #: docs/OBSERVABILITY.md mirrors this).
@@ -96,7 +125,7 @@ def fold_site_events(events: list, op_index: Optional[Dict[str, str]]
     """Sum per-site durations over chrome-trace ``events``.
 
     Sites are resolved from the event name via :data:`SITE_RE`
-    (named_scope-instrumented device traces), falling back to
+    (CPU thunk names carry the named_scope path), falling back to
     ``op_index`` — an ``{hlo instruction name: site}`` map built by
     :func:`op_site_index` — keyed by ``args.hlo_op`` (or the bare event
     name) for backends whose trace events carry only HLO op names.
@@ -136,11 +165,11 @@ def parse_site_trace(path: str, op_index: Optional[Dict[str, str]]
     trace (ISSUE 15, the schedule search's seed input).
 
     Every attention site is wrapped in a ``jax.named_scope`` whose name
-    (``cross_attn/down3``) lands in the HLO op metadata, so device slices
-    in a ``jax.profiler`` / ``serve --trace-out`` export carry the site
-    name inside the op name; ``op_index`` (see :func:`op_site_index`)
-    additionally recovers sites on backends whose events carry only bare
-    HLO op names. Durations are summed per site, shares normalized over
+    (``cross_attn/down3``) lands in the HLO op metadata. CPU thunk names
+    carry that path, so their slices match by name; a TPU trace names a
+    slice by its HLO instruction alone, and ``op_index`` (see
+    :func:`op_site_index`) recovers the site from the compiled program's
+    text. Durations are summed per site, shares normalized over
     all matched sites. Raises ``ValueError`` when no site slice matched
     — and, loudly, when handed a WorkloadProfile ledger instead of a
     trace."""
@@ -153,42 +182,115 @@ def parse_site_trace(path: str, op_index: Optional[Dict[str, str]]
     return entries
 
 
-def op_site_index(hlo_text: str) -> Dict[str, str]:
-    """``{HLO instruction name: attention site}`` from compiled HLO text.
+def scope_of(op_name: str, pattern: "re.Pattern" = SCOPE_RE) -> Optional[str]:
+    """The scope ``pattern`` finds in an instruction's ``op_name``, with
+    JAX's transform wrappers and loop components taken out first
+    (``jit(f)/vmap(unet)/down0/while/body/closed_call/res0/sin`` reads
+    ``unet/down0/res0``); None where it finds none."""
+    prev = None
+    while prev != op_name:
+        prev, op_name = op_name, _WRAPPER_RE.sub(r"\1", op_name)
+    m = pattern.search(_LOOP_PARTS_RE.sub("", op_name))
+    return m.group(0) if m else None
 
-    Instructions whose ``metadata.op_name`` scope path contains a site
-    name map directly; fusion instructions (whose own metadata names only
-    one member op) are attributed to the *dominant* site of their called
-    computation — the site owning the most member instructions. This is
-    the join key that makes CPU traces (bare ``dot.596`` event names,
-    ``args.hlo_op``) yield measured per-site shares."""
-    instr_site: Dict[str, str] = {}
-    comp_sites: Dict[str, Counter] = {}
+
+def scope_index(hlo_text: str, pattern: "re.Pattern" = SCOPE_RE
+                ) -> Tuple[Dict[str, str], Dict[str, Dict[str, int]]]:
+    """``({HLO instruction name: scope}, {fusion name: {scope: members}})``
+    from compiled HLO text: the join key between a device trace, whose
+    events are named by instruction, and the program's ``named_scope``s.
+
+    An instruction's scope is what ``pattern`` finds in its
+    ``metadata.op_name`` (:func:`scope_of`). A fusion's own metadata names
+    only one member op, so a fusion goes to the *dominant* scope of its
+    called computation — the scope owning the most member instructions,
+    a tie going to the scope that owns a convolution, dot or custom call —
+    and to its own metadata only where no member has a scope. An
+    instruction that has metadata and no scope in it has none: it is left
+    out. One the compiler added *without any metadata* (on the TPU a
+    weight's asynchronous copy) takes the scope of the nearest instruction
+    that reads its result (:func:`_scope_of_users`). The second dict holds
+    the fusions whose members lie in more than one scope, with the member
+    count of each, so a reader can say how much device time is attributed
+    across a scope boundary."""
+    index: Dict[str, str] = {}
+    members: Dict[str, Counter] = {}     # computation -> scope -> members
+    heavy: Dict[str, set] = {}           # computation -> scopes of heavy ops
     fusions: List[Tuple[str, str]] = []
+    bare: Dict[str, str] = {}            # no metadata at all: opcode
+    users: Dict[str, List[str]] = {}     # instruction -> the ones that read it
     current = None
     for line in hlo_text.splitlines():
-        cm = _COMP_RE.match(line)
-        if cm:
-            current = cm.group(1)
-            continue
         im = _INSTR_RE.match(line)
-        if im:
-            sm = SITE_RE.search(im.group(2))
-            if sm:
-                instr_site[im.group(1)] = sm.group(0)
-                if current is not None:
-                    comp_sites.setdefault(
-                        current, Counter())[sm.group(0)] += 1
-        fm = _FUSION_RE.match(line)
-        if fm:
-            fusions.append((fm.group(1), fm.group(2)))
-    for instr, comp in fusions:
-        if instr in instr_site:
+        if im is None:
+            cm = _COMP_RE.match(line)
+            if cm:
+                current = cm.group(1)
             continue
-        ctr = comp_sites.get(comp)
-        if ctr:
-            instr_site[instr] = ctr.most_common(1)[0][0]
-    return instr_site
+        name, opcode = im.groups()
+        om = _OP_NAME_RE.search(line)
+        scope = scope_of(om.group(1), pattern) if om else None
+        if scope is not None:
+            index[name] = scope
+            if current is not None:
+                members.setdefault(current, Counter())[scope] += 1
+                if opcode in _HEAVY_OPCODES:
+                    heavy.setdefault(current, set()).add(scope)
+        elif om is None:
+            bare[name] = opcode
+        if opcode == "fusion":
+            fm = _CALLS_RE.search(line)
+            if fm:
+                fusions.append((name, fm.group(1)))
+        for operand in _OPERAND_RE.findall(line, im.end()):
+            users.setdefault(operand, []).append(name)
+    mixed: Dict[str, Dict[str, int]] = {}
+    for name, comp in fusions:
+        ctr = members.get(comp)
+        if not ctr:
+            continue
+        top = max(ctr.values())
+        tied = [s for s, n in ctr.items() if n == top]
+        index[name] = next((s for s in tied if s in heavy.get(comp, ())),
+                           tied[0])
+        if len(ctr) > 1:
+            mixed[name] = dict(ctr)
+    for name, opcode in bare.items():
+        if name not in index and opcode not in _NEVER_RUN:
+            scope = _scope_of_users(name, users, index, bare)
+            if scope is not None:
+                index[name] = scope
+    return index, mixed
+
+
+def _scope_of_users(name: str, users, index, bare) -> Optional[str]:
+    """The scope of the nearest instruction that reads ``name``'s result,
+    through at most ``_HOPS`` instructions that have no metadata either:
+    what the compiler adds in front of a scoped operation (on the TPU the
+    asynchronous copies and slices that bring a layer's weights near, the
+    ``ConcatBitcast`` that joins them) works for that operation. Not through
+    an instruction that has metadata and no scope (its work is its own), nor
+    through a tuple or a loop, which read everything."""
+    frontier = [name]
+    for _ in range(_HOPS):
+        frontier = [u for n in frontier for u in users.get(n, ())]
+        for u in frontier:
+            if u in index:
+                return index[u]
+        frontier = [u for u in frontier
+                    if u in bare and bare[u] not in _AGGREGATES]
+    return None
+
+
+def op_site_index(hlo_text: str) -> Dict[str, str]:
+    """``{HLO instruction name: attention site}`` from compiled HLO text:
+    :func:`scope_index` with :data:`SITE_RE` as the scope pattern, so a
+    fusion goes to the site owning the most member instructions, and an
+    instruction outside every site (``proj_in``, a ResNet block) is in no
+    site's time whatever reads it. This is the join key that makes CPU
+    traces (bare ``dot.596`` event names, ``args.hlo_op``) yield measured
+    per-site shares."""
+    return scope_index(hlo_text, SITE_RE)[0]
 
 
 # -- WorkloadProfile format ----------------------------------------------

@@ -28,6 +28,8 @@ from ..engine.sampler import (PhaseCarry, _denoise_scan, _phase1_scan,
 from ..models import nn
 from ..models import vae as vae_mod
 from ..models.config import PipelineConfig
+from ..obs import launches
+from ..obs.spans import span
 from ..ops import schedulers as sched_mod
 
 
@@ -174,92 +176,99 @@ def sweep(
     Pallas kernel exactly as in ``text2image`` — the edit applied inside
     the attention tile, per group, under the same vmap-over-groups program.
     """
-    cfg = pipe.config
-    if layout is None:
-        from ..models.config import unet_layout
-        layout = unet_layout(cfg.unet)
-    if uncond_per_step is not None:
-        if scheduler != "ddim":
-            # Same constraint as text2image: the embeddings are optimized
-            # against the DDIM trajectory (`/root/reference/null_text.py:23`).
-            raise ValueError("uncond_per_step requires scheduler='ddim'")
-        if uncond_per_step.ndim != 5 or uncond_per_step.shape[0] != context.shape[0]:
-            raise ValueError(
-                f"uncond_per_step must be (G, T, 1, L, D) with G="
-                f"{context.shape[0]}, got {uncond_per_step.shape}")
-        if uncond_per_step.shape[1] != num_steps:
-            raise ValueError(
-                f"uncond_per_step has {uncond_per_step.shape[1]} steps, "
-                f"sampling uses {num_steps}")
-    tsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
-                                            kind=scheduler)
-    num_scan = tsched.timesteps.shape[0]
-    # ``schedule`` (a reuse-schedule spec / resolved table — ISSUE 15)
-    # generalizes ``gate``; resolve_reuse enforces mutual exclusion,
-    # normalizes uniform tables onto the gate path and fires the per-site
-    # window-conflict warning for non-uniform ones.
-    gate_step, reuse_sched = resolve_reuse(gate, schedule, layout, num_scan,
-                                           controllers)
-    if gate_step < num_scan and uncond_per_step is not None:
-        raise ValueError(
-            f"gate={gate!r} conflicts with per-step null-text uncond "
-            "embeddings (active through every step): run null-text replay "
-            "sweeps with gate=None")
-    if reuse_sched is not None and uncond_per_step is not None:
-        raise ValueError(
-            "schedule conflicts with per-step null-text uncond embeddings:"
-            " run null-text replay sweeps with schedule=None")
-    # Same surfaced semantics as the sequential path: an explicit gate that
-    # truncates edit windows / freezes an explicit store must not be
-    # silent just because the run is batched.
-    if reuse_sched is None:
-        warn_gate_truncation(gate_step, num_scan, controllers)
-    schedule = tsched
-    # Explicit staging when the scale arrives as a host scalar: the serve
-    # loop dispatches under jax.transfer_guard("disallow"), where an
-    # implicit jnp.asarray(float) h2d would raise (already-on-device values
-    # pass through untouched). On a mesh the scalar stages replicated
-    # under an explicit NamedSharding (same contract, mesh form).
-    if lower_only:
-        # Cost-card path: lower the exact program (same static args, same
-        # avals) without staging or executing anything. A concrete host
-        # scalar stands in for the staged guidance — same dtype/shape, so
-        # the lowered HLO is the dispatched program's.
-        return _sweep_jit.lower(
-            pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
-            scheduler, context, latents, controllers,
-            np.float32(guidance_scale), uncond_per_step,
-            progress=progress, gate=gate_step, metrics=metrics,
-            reuse=reuse_sched, kernels=kernels)
-    gs = (guidance_scale if isinstance(guidance_scale, jax.Array)
-          else stage_host(np.float32(guidance_scale), mesh=mesh))
-
-    if mesh is not None:
-        gspec = NamedSharding(mesh, P("dp"))
-        context = _stage_sharded(context, gspec)
-        latents = _stage_sharded(latents, gspec)
-        schedule = _stage_replicated(schedule, mesh)
-        if controllers is not None:
-            controllers = jax.tree_util.tree_map(
-                lambda x: _stage_sharded(x, gspec), controllers)
+    with span("entry.sweep"):
+        cfg = pipe.config
+        if layout is None:
+            from ..models.config import unet_layout
+            layout = unet_layout(cfg.unet)
         if uncond_per_step is not None:
-            uncond_per_step = _stage_sharded(uncond_per_step, gspec)
+            if scheduler != "ddim":
+                # Same constraint as text2image: the embeddings are optimized
+                # against the DDIM trajectory (`/root/reference/null_text.py:23`).
+                raise ValueError("uncond_per_step requires scheduler='ddim'")
+            if (uncond_per_step.ndim != 5
+                    or uncond_per_step.shape[0] != context.shape[0]):
+                raise ValueError(
+                    f"uncond_per_step must be (G, T, 1, L, D) with G="
+                    f"{context.shape[0]}, got {uncond_per_step.shape}")
+            if uncond_per_step.shape[1] != num_steps:
+                raise ValueError(
+                    f"uncond_per_step has {uncond_per_step.shape[1]} steps, "
+                    f"sampling uses {num_steps}")
+        groups = int(context.shape[0])
+        with span("entry.prepare", steps=int(num_steps), batch=groups):
+            tsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
+                                                    kind=scheduler)
+            num_scan = tsched.timesteps.shape[0]
+            # ``schedule`` (a reuse-schedule spec / resolved table — ISSUE 15)
+            # generalizes ``gate``; resolve_reuse enforces mutual exclusion,
+            # normalizes uniform tables onto the gate path and fires the per-site
+            # window-conflict warning for non-uniform ones.
+            gate_step, reuse_sched = resolve_reuse(gate, schedule, layout,
+                                                   num_scan, controllers)
+        if gate_step < num_scan and uncond_per_step is not None:
+            raise ValueError(
+                f"gate={gate!r} conflicts with per-step null-text uncond "
+                "embeddings (active through every step): run null-text replay "
+                "sweeps with gate=None")
+        if reuse_sched is not None and uncond_per_step is not None:
+            raise ValueError(
+                "schedule conflicts with per-step null-text uncond embeddings:"
+                " run null-text replay sweeps with schedule=None")
+        # Same surfaced semantics as the sequential path: an explicit gate that
+        # truncates edit windows / freezes an explicit store must not be
+        # silent just because the run is batched.
+        if reuse_sched is None:
+            warn_gate_truncation(gate_step, num_scan, controllers)
+        schedule = tsched
+        # Explicit staging when the scale arrives as a host scalar: the serve
+        # loop dispatches under jax.transfer_guard("disallow"), where an
+        # implicit jnp.asarray(float) h2d would raise (already-on-device values
+        # pass through untouched). On a mesh the scalar stages replicated
+        # under an explicit NamedSharding (same contract, mesh form).
+        if lower_only:
+            # Cost-card path: lower the exact program (same static args, same
+            # avals) without staging or executing anything. A concrete host
+            # scalar stands in for the staged guidance — same dtype/shape, so
+            # the lowered HLO is the dispatched program's.
+            return _sweep_jit.lower(
+                pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
+                scheduler, context, latents, controllers,
+                np.float32(guidance_scale), uncond_per_step,
+                progress=progress, gate=gate_step, metrics=metrics,
+                reuse=reuse_sched, kernels=kernels)
+        with span("entry.prepare", batch=groups):        # staging
+            gs = (guidance_scale if isinstance(guidance_scale, jax.Array)
+                  else stage_host(np.float32(guidance_scale), mesh=mesh))
 
-    if progress:
-        from ..utils import progress as progress_mod
+            if mesh is not None:
+                gspec = NamedSharding(mesh, P("dp"))
+                context = _stage_sharded(context, gspec)
+                latents = _stage_sharded(latents, gspec)
+                schedule = _stage_replicated(schedule, mesh)
+                if controllers is not None:
+                    controllers = jax.tree_util.tree_map(
+                        lambda x: _stage_sharded(x, gspec), controllers)
+                if uncond_per_step is not None:
+                    uncond_per_step = _stage_sharded(uncond_per_step, gspec)
 
-        progress_mod.activate(schedule.timesteps.shape[0],
-                              f"sweep x{context.shape[0]}")
+        if progress:
+            from ..utils import progress as progress_mod
 
-    from ..obs.spans import span
+            progress_mod.activate(schedule.timesteps.shape[0],
+                                  f"sweep x{context.shape[0]}")
 
-    with span("sampler.sweep", groups=int(context.shape[0]),
-              steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
-        return _sweep_jit(pipe.unet_params, pipe.vae_params, cfg, layout,
-                          schedule, scheduler, context, latents, controllers,
-                          gs, uncond_per_step, progress=progress,
-                          gate=gate_step, metrics=metrics,
+        with span("sampler.sweep", groups=groups,
+                  steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
+            args = (pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
+                    scheduler, context, latents, controllers, gs,
+                    uncond_per_step)
+            kwargs = dict(progress=progress, gate=gate_step, metrics=metrics,
                           reuse=reuse_sched, kernels=kernels, mesh=mesh)
+            mark = launches.built()
+            out = _sweep_jit(*args, **kwargs)
+            launches.keep_if_built(mark, _sweep_jit, args, kwargs)
+            return out
 
 
 @partial(jax.jit, static_argnames=("cfg", "layout", "scheduler_kind",
@@ -347,23 +356,24 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
     ``schedule`` is a reuse-schedule spec/table (ISSUE 15): its
     ``cfg_gate`` is the pool boundary; uniform tables normalize onto the
     plain gate."""
-    cfg = pipe.config
-    if layout is None:
-        from ..models.config import unet_layout
-        layout = unet_layout(cfg.unet)
-    dsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
-                                            kind=scheduler)
-    num_scan = dsched.timesteps.shape[0]
-    gate_step, reuse_sched = resolve_reuse(gate, schedule, layout, num_scan,
-                                           controllers)
-    if not 1 <= gate_step < num_scan:
-        raise ValueError(
-            f"a phase pool program needs a real gate: resolved gate step "
-            f"{gate_step} of {num_scan} leaves a phase empty — ungated "
-            "requests take the single-pool sweep() path")
-    gs = (guidance_scale if isinstance(guidance_scale, jax.Array)
-          else stage_host(np.float32(guidance_scale), mesh=mesh))
-    return cfg, layout, dsched, gate_step, gs, reuse_sched
+    with span("entry.prepare"):
+        cfg = pipe.config
+        if layout is None:
+            from ..models.config import unet_layout
+            layout = unet_layout(cfg.unet)
+        dsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
+                                                kind=scheduler)
+        num_scan = dsched.timesteps.shape[0]
+        gate_step, reuse_sched = resolve_reuse(gate, schedule, layout, num_scan,
+                                               controllers)
+        if not 1 <= gate_step < num_scan:
+            raise ValueError(
+                f"a phase pool program needs a real gate: resolved gate step "
+                f"{gate_step} of {num_scan} leaves a phase empty — ungated "
+                "requests take the single-pool sweep() path")
+        gs = (guidance_scale if isinstance(guidance_scale, jax.Array)
+              else stage_host(np.float32(guidance_scale), mesh=mesh))
+        return cfg, layout, dsched, gate_step, gs, reuse_sched
 
 
 def sweep_phase1(
@@ -391,36 +401,38 @@ def sweep_phase1(
     sharded the same way (the hand-off stays on device).
     ``lower_only=True`` returns the program's ``Lowered`` instead of
     executing (the cost-card path — see :func:`sweep`)."""
-    cfg, layout, dsched, gate_step, gs, reuse_sched = _phase_args(
-        pipe, num_steps, scheduler, gate, guidance_scale, layout,
-        controllers, mesh=mesh, schedule=schedule)
-    if reuse_sched is None:
-        warn_gate_truncation(gate_step, dsched.timesteps.shape[0],
-                             controllers)
-    schedule = dsched
-    if lower_only:
-        return _sweep_phase1_jit.lower(
-            pipe.unet_params, cfg, layout, schedule, scheduler, context,
-            latents, controllers, np.float32(guidance_scale),
-            progress=progress, gate=gate_step, metrics=metrics,
-            reuse=reuse_sched, kernels=kernels)
-    if mesh is not None:
-        gspec = NamedSharding(mesh, P("dp"))
-        context = _stage_sharded(context, gspec)
-        latents = _stage_sharded(latents, gspec)
-        schedule = _stage_replicated(schedule, mesh)
-        if controllers is not None:
-            controllers = jax.tree_util.tree_map(
-                lambda x: _stage_sharded(x, gspec), controllers)
-    from ..obs.spans import span
-
-    with span("sampler.sweep_phase1", groups=int(context.shape[0]),
-              steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
-        return _sweep_phase1_jit(pipe.unet_params, cfg, layout, schedule,
-                                 scheduler, context, latents, controllers,
-                                 gs, progress=progress, gate=gate_step,
-                                 metrics=metrics, reuse=reuse_sched,
-                                 kernels=kernels, mesh=mesh)
+    with span("entry.sweep_phase1"):
+        cfg, layout, dsched, gate_step, gs, reuse_sched = _phase_args(
+            pipe, num_steps, scheduler, gate, guidance_scale, layout,
+            controllers, mesh=mesh, schedule=schedule)
+        if reuse_sched is None:
+            warn_gate_truncation(gate_step, dsched.timesteps.shape[0],
+                                 controllers)
+        schedule = dsched
+        if lower_only:
+            return _sweep_phase1_jit.lower(
+                pipe.unet_params, cfg, layout, schedule, scheduler, context,
+                latents, controllers, np.float32(guidance_scale),
+                progress=progress, gate=gate_step, metrics=metrics,
+                reuse=reuse_sched, kernels=kernels)
+        if mesh is not None:
+            gspec = NamedSharding(mesh, P("dp"))
+            context = _stage_sharded(context, gspec)
+            latents = _stage_sharded(latents, gspec)
+            schedule = _stage_replicated(schedule, mesh)
+            if controllers is not None:
+                controllers = jax.tree_util.tree_map(
+                    lambda x: _stage_sharded(x, gspec), controllers)
+        with span("sampler.sweep_phase1", groups=int(context.shape[0]),
+                  steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
+            args = (pipe.unet_params, cfg, layout, schedule, scheduler,
+                    context, latents, controllers, gs)
+            kwargs = dict(progress=progress, gate=gate_step, metrics=metrics,
+                          reuse=reuse_sched, kernels=kernels, mesh=mesh)
+            mark = launches.built()
+            out = _sweep_phase1_jit(*args, **kwargs)
+            launches.keep_if_built(mark, _sweep_phase1_jit, args, kwargs)
+            return out
 
 
 def sweep_phase2(
@@ -451,34 +463,35 @@ def sweep_phase2(
     target shard with an explicit device-to-device ``device_put`` — no
     host round-trip, so the transfer-guard("disallow") contract holds on
     mesh dispatch too. Returns ``(images, final latents)``."""
-    cfg, layout, schedule, gate_step, gs, reuse_sched = _phase_args(
-        pipe, num_steps, scheduler, gate, guidance_scale, layout,
-        controllers, mesh=mesh, schedule=schedule)
-    if lower_only:
-        return _sweep_phase2_jit.lower(
-            pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
-            scheduler, context_cond, carry, controllers,
-            np.float32(guidance_scale), progress=progress, gate=gate_step,
-            metrics=metrics, reuse=reuse_sched, kernels=kernels)
-    if mesh is not None:
-        gspec = NamedSharding(mesh, P("dp"))
-        context_cond = _stage_sharded(context_cond, gspec)
-        carry = jax.tree_util.tree_map(
-            lambda x: _stage_sharded(x, gspec), carry)
-        schedule = _stage_replicated(schedule, mesh)
-        if controllers is not None:
-            controllers = jax.tree_util.tree_map(
-                lambda x: _stage_sharded(x, gspec), controllers)
-    from ..obs.spans import span
-
-    with span("sampler.sweep_phase2", groups=int(context_cond.shape[0]),
-              steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
-        return _sweep_phase2_jit(pipe.unet_params, pipe.vae_params, cfg,
-                                 layout, schedule, scheduler, context_cond,
-                                 carry, controllers, gs, progress=progress,
-                                 gate=gate_step, metrics=metrics,
-                                 reuse=reuse_sched, kernels=kernels,
-                                 mesh=mesh)
+    with span("entry.sweep_phase2"):
+        cfg, layout, schedule, gate_step, gs, reuse_sched = _phase_args(
+            pipe, num_steps, scheduler, gate, guidance_scale, layout,
+            controllers, mesh=mesh, schedule=schedule)
+        if lower_only:
+            return _sweep_phase2_jit.lower(
+                pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
+                scheduler, context_cond, carry, controllers,
+                np.float32(guidance_scale), progress=progress, gate=gate_step,
+                metrics=metrics, reuse=reuse_sched, kernels=kernels)
+        if mesh is not None:
+            gspec = NamedSharding(mesh, P("dp"))
+            context_cond = _stage_sharded(context_cond, gspec)
+            carry = jax.tree_util.tree_map(
+                lambda x: _stage_sharded(x, gspec), carry)
+            schedule = _stage_replicated(schedule, mesh)
+            if controllers is not None:
+                controllers = jax.tree_util.tree_map(
+                    lambda x: _stage_sharded(x, gspec), controllers)
+        with span("sampler.sweep_phase2", groups=int(context_cond.shape[0]),
+                  steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
+            args = (pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
+                    scheduler, context_cond, carry, controllers, gs)
+            kwargs = dict(progress=progress, gate=gate_step, metrics=metrics,
+                          reuse=reuse_sched, kernels=kernels, mesh=mesh)
+            mark = launches.built()
+            out = _sweep_phase2_jit(*args, **kwargs)
+            launches.keep_if_built(mark, _sweep_phase2_jit, args, kwargs)
+            return out
 
 
 def artifact_replay_inputs(pipe, x_t, uncond_embeddings, source: str,

@@ -130,12 +130,13 @@ if not qkv_only:
 # of the scan. Exact parity (same weights, split after); identity
 # controller only (bit-exact on CPU at TINY scale: same dots, split after).
 orig_attn = unet_mod._apply_attention
-def attn_fused_qkv(p, x, context, heads, ctx, is_cross):
+def attn_fused_qkv(p, ln, x, context, heads, ctx, is_cross):
     meta = ctx.next_meta()
     assert meta.is_cross == is_cross
     assert not unet_mod.controller_touches(ctx.controller, meta), \
         "experiment assumes identity controller"
     b, pix, _ = x.shape
+    residual, x = x, nn_mod.layer_norm(ln, x)
     if is_cross:
         q = nn_mod.linear(p["to_q"], x)
         kv = context @ jnp.concatenate(
@@ -154,7 +155,7 @@ def attn_fused_qkv(p, x, context, heads, ctx, is_cross):
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
     out = nn_mod.fused_attention(q, k, v, scale)
     out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)
-    return nn_mod.linear(p["to_out"], out)
+    return residual + nn_mod.linear(p["to_out"], out)
 def _one_forward():
     x = jnp.ones((2, s, s, cfg.unet.in_channels), jnp.bfloat16)
     ctx = jnp.ones((2, cfg.unet.context_len, cfg.unet.context_dim), jnp.bfloat16)
