@@ -278,15 +278,13 @@ def expected_kernel_calls(pipe, controller, kernels, itemsize: int):
     (_, variants), = reuse.lower_kernel_plan(layout, ungated, controller,
                                              kernels, phase=1)
 
-    def flashes(pixels, d_head, size):
-        return pixels >= 2048 and nn.flash_block(pixels, d_head, size) > 0
-
     fused = sum(1 for v in variants if v == VARIANT_FUSED)
     flash = sum(1 for m, v in zip(layout.metas, variants)
                 if v == VARIANT_FLASH and not m.is_cross
-                and flashes(m.pixels, m.channels // m.heads, itemsize))
+                and nn.takes_flash_kernel(m.pixels, m.channels // m.heads,
+                                          itemsize))
     vae_ch = cfg.vae.base_channels * cfg.vae.channel_mults[-1]
-    vae = int(flashes(cfg.latent_size ** 2, vae_ch, 4))   # decode runs f32
+    vae = int(nn.takes_flash_kernel(cfg.latent_size ** 2, vae_ch, 4))  # f32
     return fused, flash, vae
 
 

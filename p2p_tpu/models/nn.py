@@ -231,49 +231,84 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
     (`/root/reference/main.py:131,170` never touches 64²-pixel maps).
 
     q,k,v: (B, heads, S, D); mask: additive, broadcastable to
-    (B, heads, Sq, Sk). Large self-attention (S ≥ 2048, e.g. the 64²-pixel
-    sites) runs the Pallas TPU flash kernel when ``flash_block`` finds a
-    VMEM-feasible block for the head geometry — blockwise, never
-    materializing the (S, S) probability tensor; measured ~3× over XLA's
-    attention at the SD-1.4 64² shape on v5e. Small maps use a plain einsum
-    chain (kernel launch would cost more than it saves)."""
-    s_q, s_k = q.shape[-2], k.shape[-2]
-    if mask is None and s_q == s_k and s_q >= 2048:
-        blk = flash_block(s_q, q.shape[-1], q.dtype.itemsize)
-        if blk and _on_tpu():
-            return flash_attention_tpu(q, k, v, scale, blk)
-        # Non-TPU accelerators, or no VMEM-feasible block for this head
-        # geometry: let XLA pick its attention lowering rather than
-        # materializing the (S, S) probabilities explicitly.
-        out = jax.nn.dot_product_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), scale=scale)
-        return out.transpose(0, 2, 1, 3)
+    (B, heads, Sq, Sk). One of two implementations, chosen from the shapes
+    alone: the library's Pallas TPU flash kernel (blockwise, the (S, S)
+    probabilities never reach HBM) where ``flash_block`` has a geometry for
+    the site, else the einsum chain over materialized probabilities. Gate
+    and geometry are the measured ones of PERF.md §6 (PR 28: a sweep of
+    both implementations on a v5e, head split and merge included)."""
+    s_q = q.shape[-2]
+    geometry = flash_block(s_q, q.shape[-1], q.dtype.itemsize)
+    if (mask is None and s_q == k.shape[-2] and geometry is not None
+            and _on_tpu()):
+        return flash_attention_tpu(q, k, v, scale, geometry)
     probs = attention_probs(q, k, scale, mask).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-# Stay under the TPU's 16 MiB scoped-VMEM budget with headroom: the flash
-# kernel's resident footprint per grid step is ~(q + k + v + double-buffered
-# k/v) blocks in the input dtype plus f32 accumulator/statistics scratch,
-# ≈ block·head_dim·(8·itemsize + 8) bytes (within ~5% of the 19 MiB the
-# compiler reports for block 1024, D=512, f32 — the VAE mid-attention shape
-# that OOMs scoped vmem if block size ignores head_dim).
+# The TPU gives a kernel 16 MiB of scoped VMEM; stay under it with headroom.
 _FLASH_VMEM_BUDGET = 14 * 2**20
 
+# The geometry table, (block_q, block_k_major, block_k), from the v5e sweep
+# (PERF.md §6, PR 28). At 4096 keys a head's K and V stay resident in VMEM
+# (block_k_major == S) under a small q block; a resident geometry is for that
+# length only, since elsewhere each q block would fetch K and V again. Any
+# other length takes the first of the general geometries that tiles it. All
+# of them pass through the VMEM guard, so a wide head steps down the list.
+_FLASH_BY_SEQ = {4096: ((256, 4096, 2048),)}
+_FLASH_GEOMETRIES = (
+    (512, 2048, 1024),
+    (1024, 1024, 1024),
+    (512, 512, 512),
+    (256, 256, 256),
+)
 
-def flash_block(seq_len: int, head_dim: int, itemsize: int) -> int:
-    """Largest power-of-two block that tiles ``seq_len`` (the Pallas kernel
-    requires seq_len % block == 0) AND keeps the kernel's scoped-VMEM
-    footprint inside the TPU budget for this ``head_dim``/``itemsize``;
-    0 → no viable block (einsum/XLA path instead). The geometry args are
-    deliberately required: a default would make the VMEM guard opt-in, and
-    a wide-head f32 call site (the VAE mid-attention shape) that omitted
-    them would compile-time-OOM scoped VMEM on the chip."""
-    for b in (1024, 512, 256):
-        if seq_len % b == 0 and b * head_dim * (8 * itemsize + 8) <= _FLASH_VMEM_BUDGET:
-            return b
-    return 0
+# Below this many keys the einsum chain is the faster of the two (same sweep).
+_FLASH_MIN_SEQ = 1024
+
+
+def _flash_vmem_bytes(geometry, head_dim: int, itemsize: int) -> int:
+    """The forward kernel's scoped-VMEM footprint per grid step, from above:
+    q, k, v and out blocks double-buffered in the input dtype with the head
+    lane-padded to 128, f32 m / l / accumulator scratch, and the f32 score
+    tiles (the library unrolls its walk over ``block_k``, so a whole
+    ``block_k_major`` of scores is live; its single-step body holds scores
+    and probabilities). Checked against what the chip's compiler accepts and
+    refuses in tests/test_chip_compile.py."""
+    block_q, block_k_major, block_k = geometry
+    lanes = -(-head_dim // 128) * 128
+    blocks = 2 * itemsize * lanes * (2 * block_q + 2 * block_k_major)
+    scratch = 4 * block_q * (lanes + 2 * 128)
+    scores = 4 * block_q * block_k_major * (2 if block_k == block_k_major else 1)
+    return blocks + scratch + scores
+
+
+def flash_block(seq_len: int, head_dim: int, itemsize: int):
+    """The flash kernel's ``(block_q, block_k_major, block_k)`` for a
+    self-attention site of ``seq_len`` keys, or None where the site keeps
+    the einsum chain: fewer than ``_FLASH_MIN_SEQ`` keys, a length no
+    geometry tiles (the kernel requires seq_len % block == 0), or a head the
+    kernel does not take or so wide that no geometry fits scoped VMEM. A
+    geometry over the budget is never returned. The shape arguments are
+    deliberately required: a default would make the VMEM guard opt-in, and a
+    wide-head f32 call site (the VAE mid-attention shape) that omitted them
+    would compile-time-OOM scoped VMEM on the chip."""
+    # The library's online-softmax body takes heads up to 128 wide or whole
+    # multiples of 128 (it raises on 160, SD-1.4's width at a 1024² image's
+    # 32² sites).
+    if seq_len < _FLASH_MIN_SEQ or (head_dim > 128 and head_dim % 128):
+        return None
+    for geometry in _FLASH_BY_SEQ.get(seq_len, ()) + _FLASH_GEOMETRIES:
+        if (all(seq_len % b == 0 for b in geometry) and _flash_vmem_bytes(
+                geometry, head_dim, itemsize) <= _FLASH_VMEM_BUDGET):
+            return geometry
+    return None
+
+
+def takes_flash_kernel(seq_len: int, head_dim: int, itemsize: int) -> bool:
+    """Whether ``fused_attention`` runs an unmasked self-attention site of
+    this shape on the flash kernel in this process (it lowers on TPU only)."""
+    return _on_tpu() and flash_block(seq_len, head_dim, itemsize) is not None
 
 
 def edit_block(pixels: int, key_len: int, head_dim: int, itemsize: int) -> int:
@@ -307,23 +342,25 @@ def edit_block(pixels: int, key_len: int, head_dim: int, itemsize: int) -> int:
     return 0
 
 
-def _flash_block_sizes(blk: int):
-    """The one BlockSizes geometry every flash call site uses — forward and
-    residuals variants must stay on the same tiling.
+def _flash_block_sizes(geometry):
+    """The one BlockSizes every flash call site uses for a ``flash_block``
+    geometry — forward and residuals variants must stay on the same tiling.
 
     ALL backward blocks (dkv AND dq passes) must be specified or
     differentiating any program containing the kernel raises at trace time
     ("not all backward blocks are specified") — null-text inversion
-    backprops through the U-Net's S=4096 flash sites, which is exactly how
+    backprops through the U-Net's flash sites, which is exactly how
     this surfaced on chip. The backward passes hold more live
     tiles than the forward, so they get a capped block; correctness of the
     spec is pinned by an interpret-mode grad test
     (tests/test_flash_pallas.py)."""
     from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
-    bwd = min(blk, 512)
+    block_q, block_k_major, block_k = geometry
+    bwd = min(block_k_major, 512)
     return _fa.BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+        block_q=block_q, block_k_major=block_k_major, block_k=block_k,
+        block_b=1,
         block_q_major_dkv=bwd, block_k_major_dkv=bwd,
         block_q_dkv=bwd, block_k_dkv=bwd,
         block_k_major_dq=bwd, block_k_dq=bwd, block_q_dq=bwd)
@@ -362,33 +399,37 @@ def per_device(kernel):
 
 
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
-                        scale: float, blk: int) -> jax.Array:
-    """The Pallas TPU flash kernel call `fused_attention` takes at the big
-    self-attention sites. Kept as a named function so the CPU suite can run
-    the identical code under `pltpu.force_tpu_interpret_mode()`
-    (tests/test_flash_pallas.py) — the kernel otherwise only executes on
-    a TPU."""
+                        scale: float, geometry) -> jax.Array:
+    """The Pallas TPU flash kernel call `fused_attention` takes at the
+    untouched self-attention sites, tiled by ``geometry`` (a ``flash_block``
+    answer). ``scale`` is folded into ``q`` ahead of the kernel, where XLA
+    fuses it into the head split, so the kernel (``sm_scale=1``) makes one
+    pass fewer over every score tile. Kept as a named function so the CPU
+    suite can run the identical code under
+    `pltpu.force_tpu_interpret_mode()` (tests/test_flash_pallas.py) — the
+    kernel otherwise only executes on a TPU."""
     from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
     def kernel(q, k, v):
-        return _fa.flash_attention(q, k, v, causal=False, sm_scale=scale,
-                                   block_sizes=_flash_block_sizes(blk))
+        return _fa.flash_attention(q * scale, k, v, causal=False, sm_scale=1.0,
+                                   block_sizes=_flash_block_sizes(geometry))
 
     return per_device(kernel)(q, k, v)
 
 
 def flash_attention_residuals(q: jax.Array, k: jax.Array, v: jax.Array,
-                              scale: float, blk: int):
+                              scale: float, geometry):
     """Flash kernel returning ``(out, l, m)`` — the normalized output plus
-    per-row softmax statistics (sum ``l`` and max ``m`` of the local logits).
+    per-row softmax statistics (sum ``l`` and max ``m`` of the local logits,
+    ``scale`` included: folded into ``q`` as in ``flash_attention_tpu``).
     These are the pieces ring attention needs to merge partial results across
     devices without ever materializing local (Sq, Sk) scores
     (`parallel/ring.py`). Semantics pinned by tests/test_flash_pallas.py in
     interpret mode."""
     from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
-    return _fa._flash_attention(q, k, v, None, None, True, False, scale,
-                                _flash_block_sizes(blk), False)
+    return _fa._flash_attention(q * scale, k, v, None, None, True, False, 1.0,
+                                _flash_block_sizes(geometry), False)
 
 
 def _on_tpu() -> bool:

@@ -32,6 +32,7 @@ from ..controllers.base import (
     apply_attention_control,
     controller_touches,
 )
+from ..obs import launches
 from .config import UNetConfig, unet_layout
 from . import nn
 
@@ -370,7 +371,10 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         q, k, v = split_heads(q), split_heads(k), split_heads(v)
 
     with jax.named_scope("core"):
+        how = ("kernel" if nn.takes_flash_kernel(pix, d_head, q.dtype.itemsize)
+               else "einsum")          # of nn.fused_attention, from the shape
         if controller_touches(ctx.controller, meta):
+            how = "edited"
             out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
             if out is None:
                 probs = nn.attention_probs(q, k, scale)        # (B, heads, P, K) f32
@@ -382,13 +386,12 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
             n = ctx.sp.mesh.shape[ctx.sp.axis]
             if meta.pixels % n:
                 # Unsharded fallback is safe only when fused attention stays
-                # blockwise (flash-tileable: S ≥ 2048 with a power-of-two block
-                # dividing it). Otherwise the einsum path would materialize the
-                # O(P²) scores on one device — the blow-up SpConfig exists to
+                # blockwise (``nn.flash_block`` has a geometry for the site).
+                # Otherwise the einsum path would materialize the O(P²)
+                # scores on one device — the blow-up SpConfig exists to
                 # avoid — so that case is an error, not a warning.
-                flash_ok = meta.pixels >= 2048 and any(
-                    meta.pixels % b == 0 for b in (1024, 512, 256))
-                if not flash_ok:
+                if nn.flash_block(meta.pixels, d_head,
+                                  q.dtype.itemsize) is None:
                     raise ValueError(
                         f"sequence-parallel site {meta.layer_idx} has "
                         f"{meta.pixels} pixels, not divisible by mesh axis "
@@ -404,6 +407,7 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
             elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
                 from ..parallel.alltoall import alltoall_self_attention
 
+                how = "sharded"
                 out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
                                               ctx.sp.axis)
             else:
@@ -420,9 +424,12 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
                         f"at this site", stacklevel=2)
                 from ..parallel.ring import ring_self_attention
 
+                how = "sharded"
                 out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
         else:
             out = nn.fused_attention(q, k, v, scale)
+        if not is_cross:
+            launches.note_self_site(meta.layer_idx, how)
 
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)

@@ -13,7 +13,10 @@ reader gets from one to the other for a program that really ran:
   ran. Only then — the first launch of a distinct program — the registry
   keeps what is needed to lower that program again: the jitted function,
   its static arguments, and ``ShapeDtypeStruct``s with the arguments'
-  shardings. Shapes, never arrays.
+  shardings. Shapes, never arrays. With them it keeps how each
+  self-attention site of the U-Net ran, as the model noted while the
+  program was traced (``note_self_site``; ``Launch.self_sites``:
+  ``{"kernel": n, "einsum": n, "edited": n}``).
 - :func:`scope_index` lowers, compiles and parses that program lazily, once,
   when somebody asks (``obs.traceparse.scope_index`` on the executable's
   text). After a launch in the same process the executable is still in
@@ -30,7 +33,9 @@ such an entry and built once more past the cache (docs/OBSERVABILITY.md,
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -49,6 +54,9 @@ class Launch:
     index: Optional[Dict[str, str]] = None
     mixed: Optional[Dict[str, Dict[str, int]]] = None
     built_from: str = ""        # "memory" | "cache_hit" | "backend" (compiled)
+    # Self-attention sites by how they ran: "kernel" / "einsum" (untouched,
+    # ``nn.fused_attention``'s two implementations), "edited", "sharded".
+    self_sites: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def _signature(self):
         import jax
@@ -60,13 +68,22 @@ class Launch:
 
 
 _launches: Dict[str, List[Launch]] = {}     # module -> distinct programs
+_traced_sites: Dict[int, str] = {}          # site -> how, since the last mark
 
 
 def built() -> int:
     """How many programs the process has built so far, compiled or read from
     the persistent cache: the mark a launch site takes before it calls its
-    jitted function."""
+    jitted function. The sites noted from here on are that launch's."""
+    _traced_sites.clear()
     return compile_ledger().programs
+
+
+def note_self_site(site: int, how: str) -> None:
+    """Trace time, from the model: self-attention site ``site`` of the
+    program being traced runs ``how``. Keyed by site, so a body traced twice
+    counts once."""
+    _traced_sites[site] = how
 
 
 def keep_if_built(mark: int, fn, args: tuple, kwargs: dict) -> None:
@@ -114,7 +131,8 @@ def _keep(fn, args, kwargs) -> None:
     abstract = _abstract((args, kwargs))
     if abstract is None:
         return
-    launch = Launch("jit_" + fn.__name__, fn, *abstract)
+    launch = Launch("jit_" + fn.__name__, fn, *abstract, self_sites=dict(
+        collections.Counter(_traced_sites.values())))
     known = _launches.setdefault(launch.module, [])
     # Another thread's compile can make a warm call look like a first launch.
     if all(launch._signature() != k._signature() for k in known):
@@ -161,6 +179,10 @@ def _build(launch: Launch) -> None:
         # cache's key leaves metadata out). Once more, past the cache.
         index, mixed = _compile_and_parse(launch, _PAST_THE_CACHE)
     launch.index, launch.mixed = index, mixed
+    # Whoever asked prints the scope tree (a traced run); this goes with it.
+    print(f"launch {launch.module}: {len(index)} instructions from "
+          f"{launch.built_from}; self-attention sites {launch.self_sites}",
+          file=sys.stderr)
 
 
 def _compile_and_parse(launch: Launch, compiler_options=None):

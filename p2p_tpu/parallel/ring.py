@@ -84,7 +84,7 @@ def _block_attend(q, k, v, scale, use_flash=False):
 
     if (use_flash and q.shape[-2] == k.shape[-2]
             and nn.flash_block(q.shape[-2], q.shape[-1],
-                               q.dtype.itemsize) > 0):
+                               q.dtype.itemsize) is not None):
         return _block_attend_flash(q, k, v, scale)
     return _block_attend_einsum(q, k, v, scale)
 
@@ -97,17 +97,6 @@ def _merge(acc1, m1, l1, acc2, m2, l2):
     acc = acc1 * a1[..., None] + acc2 * a2[..., None]
     l = l1 * a1 + l2 * a2
     return acc, m, l
-
-
-def _flash_chunk_ok(s_local: int, head_dim: int, itemsize: int) -> bool:
-    """Flash per-chunk pays off when the local chunk is big enough that
-    materializing (S_local, S_local) scores hurts, and the kernel has a
-    viable block for this geometry (tiles the grid AND fits scoped VMEM).
-    Below the threshold the einsum block is cheaper than a kernel launch
-    per ring round."""
-    from ..models import nn
-
-    return s_local >= 1024 and nn.flash_block(s_local, head_dim, itemsize) > 0
 
 
 def ring_self_attention_shard(
@@ -147,8 +136,8 @@ def ring_self_attention(
     communication, and returned with the same sharding.
 
     ``use_flash``: run each local block through the Pallas flash kernel so
-    per-shard HBM stays O(S_local·D). Default (None) auto-selects: TPU
-    backend + flash-tileable local chunk ≥ 1024.
+    per-shard HBM stays O(S_local·D). Default (None): where
+    ``nn.fused_attention`` would take the kernel for the local chunk.
     """
     n = mesh.shape[axis_name]
     if q.shape[2] % n:
@@ -157,7 +146,7 @@ def ring_self_attention(
     if use_flash is None:
         from ..models import nn
 
-        use_flash = nn._on_tpu() and _flash_chunk_ok(
+        use_flash = nn.takes_flash_kernel(
             q.shape[2] // n, q.shape[-1], q.dtype.itemsize)
     spec = P(None, None, axis_name, None)
     # check_vma only off for the flash chunks: pallas_call does not yet carry

@@ -12,9 +12,10 @@ budget. These compiles can, at about a second each and no chip time:
     geometry of ``unet_layout(SD14.unet).metas`` the kernel covers (the
     64x64 self site is not fused by design and stays on flash);
 (b) ``nn.flash_attention_tpu`` forward and its ``jax.grad`` (null-text
-    inversion differentiates through it) at the 4096- and 1024-pixel self
-    sites;
-(c) ``nn.flash_attention_residuals`` at the ring-attention chunk geometry;
+    inversion differentiates through it) at every row of the geometry table
+    ``nn.flash_block`` answers from (``FLASH_ROWS``), in the row's dtype;
+(c) ``nn.flash_attention_residuals`` at the same rows and at the
+    ring-attention chunks (``RING_CHUNKS``);
 (d) both kernels inside a program partitioned over a ``dp`` mesh of the four
     described chips, the way ``parallel.sweep`` traces its groups
     (``nn.kernel_mesh`` + ``vmap(spmd_axis_name="dp")``) — the partitioner
@@ -128,9 +129,9 @@ def _compile(fn, *args):
     return compiled
 
 
-def _qkv(one_chip, batch, heads, pixels, key_len, d_head):
+def _qkv(one_chip, batch, heads, pixels, key_len, d_head, dtype=jnp.bfloat16):
     def sds(n):
-        return jax.ShapeDtypeStruct((batch, heads, n, d_head), jnp.bfloat16,
+        return jax.ShapeDtypeStruct((batch, heads, n, d_head), dtype,
                                     sharding=one_chip)
 
     return sds(pixels), sds(key_len), sds(key_len)
@@ -172,46 +173,61 @@ def test_fused_edit_kernel_compiles(one_chip, controllers, mode, key):
     _compile(site, _shapes(ctrl, one_chip), q, k, v, step)
 
 
-#: (pixels, d_head) of the 64x64 and 32x32 self sites.
-FLASH_SITES = [(4096, 40), (1024, 80)]
+#: (pixels, heads, d_head, dtype): the shapes that take the library flash
+#: kernel — SD-1.4's 64x64 and 32x32 self sites as the benchmark runs them
+#: (f32) and as ``sweep(dtype=bfloat16)`` does, LDM-256's 32x32 site, the
+#: VAE decoder's mid attention (one 512-wide head, f32), SD14_HR's 128x128,
+#: SD-2.x's 64x64 site (head size 64).
+FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
+              (4096, 8, 40, jnp.bfloat16), (1024, 8, 80, jnp.bfloat16),
+              (1024, 5, 64, jnp.float32), (4096, 1, 512, jnp.float32),
+              (16384, 8, 40, jnp.float32), (4096, 5, 64, jnp.float32)]
+#: The 64x64 self site in bf16, which the mesh cases run, and its local chunks
+#: under parallel/ring.py at sp = 2 and 4 (the residuals kernel runs on those).
+FLASH_SITE = FLASH_ROWS[2]
+RING_CHUNKS = [(4096 // sp,) + FLASH_SITE[1:] for sp in (2, 4)]
 
 
-@pytest.mark.parametrize("pixels,d_head", FLASH_SITES)
-def test_flash_forward_compiles(one_chip, pixels, d_head):
-    blk = nn.flash_block(pixels, d_head, 2)
-    assert blk > 0
-    q, k, v = _qkv(one_chip, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
-                   d_head)
-    _compile(lambda q, k, v: nn.flash_attention_tpu(q, k, v, d_head ** -0.5,
-                                                    blk), q, k, v)
+def _row_id(row):
+    pixels, heads, d_head, dtype = row
+    return f"P{pixels}-h{heads}-d{d_head}-{jnp.dtype(dtype).name}"
 
 
-@pytest.mark.parametrize("pixels,d_head", FLASH_SITES)
-def test_flash_backward_compiles(one_chip, pixels, d_head):
+def _row(one_chip, row, batch=CFG_BATCH):
+    """``(geometry, scale, (q, k, v))`` of a table row on the described chip."""
+    pixels, heads, d_head, dtype = row
+    geometry = nn.flash_block(pixels, d_head, jnp.dtype(dtype).itemsize)
+    assert geometry is not None, "the table has no geometry for this row"
+    return geometry, d_head ** -0.5, _qkv(one_chip, batch, heads, pixels,
+                                          pixels, d_head, dtype)
+
+
+@pytest.mark.parametrize("row", FLASH_ROWS, ids=_row_id)
+def test_flash_forward_compiles(one_chip, row):
+    geometry, scale, qkv = _row(one_chip, row)
+    _compile(lambda q, k, v: nn.flash_attention_tpu(q, k, v, scale, geometry),
+             *qkv)
+
+
+@pytest.mark.parametrize("row", FLASH_ROWS, ids=_row_id)
+def test_flash_backward_compiles(one_chip, row):
     # Null-text inversion backpropagates through the flash sites: every
     # backward block of nn._flash_block_sizes has to be one Mosaic accepts.
-    blk = nn.flash_block(pixels, d_head, 2)
-    q, k, v = _qkv(one_chip, 2, SD14.unet.num_heads, pixels, pixels, d_head)
+    geometry, scale, qkv = _row(one_chip, row, batch=2)
 
     def loss(q, k, v):
-        out = nn.flash_attention_tpu(q, k, v, d_head ** -0.5, blk)
+        out = nn.flash_attention_tpu(q, k, v, scale, geometry)
         return out.astype(jnp.float32).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
 
 
-@pytest.mark.parametrize("sp", [2, 4])
-def test_flash_residuals_compile_at_ring_chunk(one_chip, sp):
-    # parallel/ring.py runs the residuals kernel on the local chunk of the
-    # 64x64 self site: 4096 / sp pixels, d_head 40.
-    pixels, d_head = 4096 // sp, 40
-    blk = nn.flash_block(pixels, d_head, 2)
-    assert blk > 0
-    q, k, v = _qkv(one_chip, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
-                   d_head)
+@pytest.mark.parametrize("row", FLASH_ROWS + RING_CHUNKS, ids=_row_id)
+def test_flash_residuals_compile(one_chip, row):
+    geometry, scale, (q, k, v) = _row(one_chip, row)
     compiled = _compile(
-        lambda q, k, v: nn.flash_attention_residuals(q, k, v, d_head ** -0.5,
-                                                     blk), q, k, v)
+        lambda q, k, v: nn.flash_attention_residuals(q, k, v, scale, geometry),
+        q, k, v)
     out, l, m = compiled.out_info
     assert out.shape == q.shape and l.shape == m.shape == q.shape[:3]
 
@@ -243,14 +259,11 @@ def _groups(one_group, mesh):
 
 
 def test_flash_kernel_compiles_under_a_dp_mesh(topo, dp_mesh):
-    pixels, d_head = FLASH_SITES[0]
-    blk = nn.flash_block(pixels, d_head, 2)
-    single = _qkv(None, CFG_BATCH, SD14.unet.num_heads, pixels, pixels,
-                  d_head)
+    geometry, scale, single = _row(None, FLASH_SITE)
     q, k, v = _dp_groups(dp_mesh, *single)
 
     def one_group(q, k, v):
-        return nn.flash_attention_tpu(q, k, v, d_head ** -0.5, blk)
+        return nn.flash_attention_tpu(q, k, v, scale, geometry)
 
     compiled = _compile(_groups(one_group, dp_mesh), q, k, v)
     assert "all-gather" not in compiled.as_text()

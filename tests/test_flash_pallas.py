@@ -1,21 +1,19 @@
 """Pallas flash-attention kernel parity, interpret mode (CPU).
 
-The big self-attention sites (64² pixels → S=4096) run the Pallas TPU flash
-kernel via `nn.flash_attention_tpu` (`p2p_tpu/models/nn.py`) — a path the CPU
-test suite otherwise never executes. `force_tpu_interpret_mode()` executes the
-*identical* kernel — same BlockSizes, same grid — in the Pallas interpreter
-on CPU, so parity against the materialized `attention_probs` + einsum
-reference is checked in CI.
-
-Shapes mirror the production site: S=4096 (64² pixels), head_dim 40
-(SD-1.4's 320/8), block 1024 (what `flash_block(4096)` picks). Batch and
+The untouched self-attention sites with 1024 keys or more (64² and 32²
+pixels of SD-1.4, the VAE decoder's mid block) run the library's Pallas TPU
+flash kernel via `nn.flash_attention_tpu` (`p2p_tpu/models/nn.py`) — a path
+the CPU test suite otherwise never executes. `force_tpu_interpret_mode()`
+executes the *identical* kernel — same BlockSizes, same grid, the softmax
+scale folded into `q` — in the Pallas interpreter on CPU, so parity against
+the materialized `attention_probs` + einsum reference is checked in CI at
+every row of the geometry table `nn.flash_block` answers from. Batch and
 heads are reduced (the kernel grid iterates them independently; geometry per
 batch·head is what the blocks tile).
 
 Tolerance: the kernel accumulates softmax/matmul in f32 like the reference
 path, but blockwise online-softmax reassociates the sums — f32 inputs agree
-to ~1e-5; bf16 inputs (the TPU production dtype) to a few 1e-2 in absolute
-terms on O(1)-scale outputs.
+to ~1e-5; bf16 inputs to a few 1e-2 in absolute terms on O(1)-scale outputs.
 """
 
 import numpy as np
@@ -25,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental.pallas.tpu import force_tpu_interpret_mode
-from p2p_tpu.models import nn
+from p2p_tpu.models import LDM256, SD14, nn
+from p2p_tpu.models.config import unet_layout
 
 
 def _ref(q, k, v, scale):
@@ -39,44 +38,41 @@ def _rand_qkv(seed, b, h, s, d, dtype):
     return mk(), mk(), mk()
 
 
-@pytest.mark.slow
-def test_flash_interpret_parity_f32_sd_shape():
-    s, d = 4096, 40  # the 64²-pixel SD-1.4 site
-    blk = nn.flash_block(s, d, 4)
-    assert blk == 1024  # the block size the production path selects
-    q, k, v = _rand_qkv(0, 1, 2, s, d, jnp.float32)
-    scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
-        out = nn.flash_attention_tpu(q, k, v, scale, blk)
-    want = _ref(q, k, v, scale)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+#: (keys, head size, dtype): the shape classes of the geometry table — the
+#: 64² and 32² self sites of SD-1.4, the 32² site of LDM-256, the VAE
+#: decoder's mid attention (one 512-wide head, f32), the bf16 sweep's 64² site.
+TABLE_ROWS = [(4096, 40, jnp.float32), (1024, 80, jnp.float32),
+              (1024, 64, jnp.float32), (4096, 512, jnp.float32),
+              (4096, 40, jnp.bfloat16)]
 
 
-@pytest.mark.slow
-def test_flash_interpret_parity_bf16_sd_shape():
-    # The production dtype on TPU: bf16 tensors, f32 softmax accumulation.
-    s, d = 4096, 40
-    blk = nn.flash_block(s, d, 2)
-    q, k, v = _rand_qkv(1, 1, 1, s, d, jnp.bfloat16)
+def _row_id(row):
+    return f"S{row[0]}-d{row[1]}-{jnp.dtype(row[2]).name}"
+
+
+@pytest.mark.parametrize("row", TABLE_ROWS, ids=_row_id)
+def test_flash_interpret_parity_at_table_row(row):
+    s, d, dtype = row
+    geometry = nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
+    assert geometry is not None         # the production path takes the kernel
+    q, k, v = _rand_qkv(0, 1, 2 if d < 512 else 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
     with force_tpu_interpret_mode():
-        out = nn.flash_attention_tpu(q, k, v, scale, blk)
-    want = _ref(q.astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32), scale)
+        out = nn.flash_attention_tpu(q, k, v, scale, geometry)
+    want = _ref(*(t.astype(jnp.float32) for t in (q, k, v)), scale)
+    tol = 1e-5 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
-                               np.asarray(want), atol=4e-2, rtol=4e-2)
+                               np.asarray(want), atol=tol, rtol=tol)
 
 
 def test_flash_interpret_parity_small_multiblock():
     # Fast case: S=512 with block 256 → a 2×2 block grid, several heads —
     # exercises the cross-block online-softmax reassociation cheaply.
     s, d = 512, 40
-    blk = 256
     q, k, v = _rand_qkv(2, 2, 4, s, d, jnp.float32)
     scale = 1.0 / np.sqrt(d)
     with force_tpu_interpret_mode():
-        out = nn.flash_attention_tpu(q, k, v, scale, blk)
+        out = nn.flash_attention_tpu(q, k, v, scale, (256, 256, 256))
     want = _ref(q, k, v, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
@@ -88,82 +84,155 @@ def test_flash_interpret_parity_vae_head_geometry():
     # framework. Reduced S keeps interpret mode fast; the block count (2×2)
     # still exercises the online-softmax merge at this width.
     s, d = 512, 512
-    blk = 256
     q, k, v = _rand_qkv(3, 1, 1, s, d, jnp.float32)
     scale = 1.0 / np.sqrt(d)
     with force_tpu_interpret_mode():
-        out = nn.flash_attention_tpu(q, k, v, scale, blk)
+        out = nn.flash_attention_tpu(q, k, v, scale, (256, 256, 256))
     want = _ref(q, k, v, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-4, rtol=1e-5)
 
 
-def test_flash_interpret_grad_matches_einsum():
+@pytest.mark.parametrize("row", TABLE_ROWS[:4], ids=_row_id)
+def test_flash_interpret_grad_matches_einsum(row):
     """Differentiating THROUGH the flash kernel must work and match the
     materialized-attention gradient: null-text inversion backprops through
-    the U-Net's S=4096 flash sites, and an under-specified BlockSizes (the
+    the U-Net's flash sites, and an under-specified BlockSizes (the
     dq backward blocks missing) raises "not all backward blocks are
     specified" at trace time — exactly how this surfaced on chip
-    (2026-08-01). blk=1024 at S=1024 exercises the MIXED tiling the fix
-    actually ships at the S=4096 production sites: forward blocks 1024,
-    backward blocks capped at 512 — so a numeric bug specific to unequal
-    forward/backward tiling (e.g. dq accumulation across the two backward
-    k-blocks per forward block) dies here, not on the chip."""
-    s, d = 1024, 40
-    blk = 1024
-    assert nn.flash_block(s, d, 4) == blk  # the production selection
-    q, k, v = _rand_qkv(5, 1, 2, s, d, jnp.float32)
+    (2026-08-01). Every row runs the MIXED tiling the production sites
+    ship: the table's forward geometry, backward blocks capped at 512 — so
+    a numeric bug specific to unequal forward/backward tiling (e.g. dq
+    accumulation across the backward k-blocks per forward block), or in the
+    scale folded into q ahead of the custom VJP, dies here, not on the
+    chip."""
+    s, d, dtype = row
+    geometry = nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
+    q, k, v = _rand_qkv(5, 1, 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
 
-    def loss_flash(q):
-        return jnp.sum(nn.flash_attention_tpu(q, k, v, scale, blk) ** 2)
+    def loss_flash(q, k, v):
+        return jnp.sum(nn.flash_attention_tpu(q, k, v, scale, geometry) ** 2)
 
-    def loss_ref(q):
+    def loss_ref(q, k, v):
         return jnp.sum(_ref(q, k, v, scale) ** 2)
 
     with force_tpu_interpret_mode():
-        g_flash = jax.grad(loss_flash)(q)
-    g_ref = jax.grad(loss_ref)(q)
-    np.testing.assert_allclose(np.asarray(g_flash), np.asarray(g_ref),
-                               atol=1e-3, rtol=1e-3)
+        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-3, rtol=1e-3)
 
 
 def test_flash_block_sizes_specify_all_backward_blocks():
     """The shared BlockSizes geometry must stay fully backward-specified —
     any future pallas field addition that reopens the trace-time error
     shows up here, not on the chip."""
-    assert nn._flash_block_sizes(1024).has_backward_blocks
-    assert nn._flash_block_sizes(256).has_backward_blocks
+    for s, d, dtype in TABLE_ROWS:
+        geometry = nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
+        sizes = nn._flash_block_sizes(geometry)
+        assert sizes.has_backward_blocks
+        assert (sizes.block_q, sizes.block_k_major, sizes.block_k) == geometry
+        assert sizes.block_q_dq == min(geometry[1], 512)     # capped as ever
+    assert nn._flash_block_sizes((256, 256, 256)).has_backward_blocks
 
 
 def test_flash_block_selection():
-    # Tiling-only selection at the narrow SD head geometry (VMEM not binding).
-    assert nn.flash_block(4096, 40, 2) == 1024
-    assert nn.flash_block(2048, 40, 2) == 1024
-    assert nn.flash_block(1024, 40, 2) == 1024
-    assert nn.flash_block(768, 40, 2) == 256
-    assert nn.flash_block(1000, 40, 2) == 0  # not tileable → einsum path
+    # Below 1024 keys there is no geometry (the einsum chain is faster there);
+    # from 1024 up the sweep's geometry for the narrow SD head.
+    assert nn.flash_block(4096, 40, 2) == (256, 4096, 2048)  # K, V resident
+    assert nn.flash_block(2048, 40, 2) == (512, 2048, 1024)
+    assert nn.flash_block(1024, 40, 2) == (1024, 1024, 1024)
+    assert nn.flash_block(768, 40, 2) is None
+    assert nn.flash_block(1000, 40, 2) is None  # not tileable → einsum path
     # Scoped-VMEM-aware selection: the SD U-Net 64² site (bf16, D=40) keeps
-    # the largest block; the VAE mid-attention shape (f32, D=512) must step
-    # down — block 1024 there is the 19 MiB > 16 MiB compile-time OOM that
-    # killed the g≥4 sweep legs on the chip.
-    assert nn.flash_block(4096, 40, 2) == 1024
-    assert nn.flash_block(4096, 512, 4) == 512
-    assert nn.flash_block(4096, 512, 2) == 1024  # bf16 halves the footprint
-    # Absurdly wide heads: no viable block → 0 → einsum/XLA path.
-    assert nn.flash_block(4096, 4096, 4) == 0
+    # the sweep's first choice; the VAE mid-attention shape (f32, D=512) must
+    # step down — block 1024 there is the 19 MiB > 16 MiB compile-time OOM
+    # that killed the g≥4 sweep legs on the chip.
+    assert nn.flash_block(4096, 40, 4) == (256, 4096, 2048)
+    assert nn.flash_block(16384, 40, 4) == (512, 2048, 1024)
+    assert nn.flash_block(4096, 512, 4) == (512, 512, 512)
+    assert nn.flash_block(4096, 512, 2) == (512, 512, 512)
+    # Absurdly wide heads: no viable block → None → einsum path; so is a
+    # head the library's online body refuses (over 128 and no multiple).
+    assert nn.flash_block(4096, 4096, 4) is None
+    assert nn.flash_block(4096, 160, 4) is None
 
 
-def test_flash_residuals_semantics():
+def _site_shapes(config):
+    """``{(pixels, head size)}`` of a preset's self-attention sites, plus its
+    VAE decoder's mid attention (one head as wide as the last level)."""
+    shapes = {(m.pixels, m.channels // m.heads)
+              for m in unet_layout(config.unet).metas if not m.is_cross}
+    vae = config.vae
+    return shapes | {(config.latent_size ** 2,
+                      vae.base_channels * vae.channel_mults[-1])}
+
+
+def test_flash_geometries_stay_inside_the_vmem_budget():
+    # Whatever the table answers tiles the sequence and passes the guard,
+    # over every shape a preset can ask about and both carrier dtypes.
+    lengths = (256, 768, 1000, 1024, 2048, 4096, 9216, 16384)
+    for s in lengths:
+        for d in (8, 40, 64, 80, 160, 512, 4096):
+            for itemsize in (2, 4):
+                geometry = nn.flash_block(s, d, itemsize)
+                if geometry is None:
+                    continue
+                block_q, block_k_major, block_k = geometry
+                assert s % block_q == 0 and s % block_k_major == 0
+                assert block_k_major % block_k == 0
+                assert nn._flash_vmem_bytes(geometry, d, itemsize) \
+                    <= nn._FLASH_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("config", [SD14, LDM256], ids=lambda c: c.name)
+def test_fused_attention_follows_the_table(config, monkeypatch):
+    """``fused_attention`` takes the kernel exactly where the table has a
+    geometry for the site's shape, and the einsum chain elsewhere — from the
+    shapes alone, for every site shape of SD-1.4 and LDM-256."""
+    taken = []
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        nn, "flash_attention_tpu",
+        lambda q, k, v, scale, geometry: taken.append(geometry) or _ref(q, k, v, scale))
+    for pixels, d_head in sorted(_site_shapes(config)):
+        q = jax.ShapeDtypeStruct((2, 1, pixels, d_head), jnp.float32)
+        want = nn.flash_block(pixels, d_head, 4)
+        assert (want is not None) == (pixels >= 1024), (pixels, d_head)
+        del taken[:]
+        jax.eval_shape(lambda q, k, v: nn.fused_attention(q, k, v, 0.1), q, q, q)
+        assert taken == ([want] if want else []), (pixels, d_head)
+        assert nn.takes_flash_kernel(pixels, d_head, 4) == bool(want)
+        # a mask, or keys of another length (cross-attention), never do
+        del taken[:]
+        ctx = jax.ShapeDtypeStruct((2, 1, 77, d_head), jnp.float32)
+        mask = jax.ShapeDtypeStruct((1, 1, pixels, pixels), jnp.float32)
+        jax.eval_shape(lambda q, c: nn.fused_attention(q, c, c, 0.1), q, ctx)
+        jax.eval_shape(lambda q, m: nn.fused_attention(q, q, q, 0.1, m), q, mask)
+        assert taken == []
+    # off the TPU nothing takes the kernel
+    monkeypatch.setattr(nn, "_on_tpu", lambda: False)
+    jax.eval_shape(lambda q: nn.fused_attention(q, q, q, 0.1),
+                   jax.ShapeDtypeStruct((2, 1, 4096, 40), jnp.float32))
+    assert taken == [] and not nn.takes_flash_kernel(4096, 40, 4)
+
+
+@pytest.mark.parametrize("row", TABLE_ROWS[:4] + [(512, 40, jnp.float32)],
+                         ids=_row_id)
+def test_flash_residuals_semantics(row):
     # (out, l, m) from the residuals variant: out normalized, l = row sum of
-    # exp(s - m), m = row max — the invariants ring attention's merge relies
-    # on (parallel/ring.py _block_attend use_flash path).
-    s, d = 512, 40
-    blk = 256
-    q, k, v = _rand_qkv(4, 1, 2, s, d, jnp.float32)
+    # exp(s - m), m = row max of the SCALED logits — the invariants ring
+    # attention's merge relies on (parallel/ring.py _block_attend use_flash
+    # path), at every table row and at a small 2×2 block grid.
+    s, d, dtype = row
+    geometry = (nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
+                or (256, 256, 256))
+    q, k, v = _rand_qkv(4, 1, 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
     with force_tpu_interpret_mode():
-        out, l, m = nn.flash_attention_residuals(q, k, v, scale, blk)
+        out, l, m = nn.flash_attention_residuals(q, k, v, scale, geometry)
     sim = np.einsum("bhqd,bhkd->bhqk", np.asarray(q), np.asarray(k)) * scale
     m_ref = sim.max(-1)
     p = np.exp(sim - m_ref[..., None])

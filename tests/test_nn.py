@@ -81,8 +81,8 @@ def test_upsample_nearest_2x_matches_jax_image_resize():
 
 
 def test_fused_attention_large_site_matches_reference_on_cpu():
-    # On a non-TPU backend, S >= 2048 routes through
-    # jax.nn.dot_product_attention — pin it against the materialized path.
+    # Off the TPU a site the flash kernel would take keeps the einsum chain
+    # (tests/test_flash_pallas.py runs the kernel itself, interpreted).
     rng = np.random.RandomState(4)
     s, d = 2048, 16
     mk = lambda: jnp.asarray(rng.randn(1, 2, s, d).astype(np.float32))

@@ -357,6 +357,28 @@ def test_scope_index_is_built_when_asked_and_once(tiny_pipe, monkeypatch, site):
     assert launches.scope_index("jit__never_launched") is None
 
 
+@pytest.mark.parametrize("site", ["text2image", "encode", "sweep", "sweep_phase2"])
+def test_a_launch_keeps_how_its_self_sites_ran(tiny_pipe, site):
+    """Counted while the program was traced: the controller's sites as
+    ``edited``, the others by the implementation ``nn.fused_attention`` chose
+    from their shape (no kernel off the TPU, and no tiny site has 1024 keys)."""
+    from p2p_tpu.controllers.base import controller_touches
+
+    module, launch_site, _ = LAUNCH_SITES[site]
+    launch_site(tiny_pipe)
+    got = launches.programs(module)[-1].self_sites
+    if site == "encode":
+        assert got == {}
+        return
+    ctrl = _ctrl(tiny_pipe, store=False)
+    if site == "sweep_phase2":
+        ctrl = phase2_controller(ctrl)
+    metas = [m for m in unet_layout(TINY.unet).metas if not m.is_cross]
+    edited = sum(1 for m in metas if controller_touches(ctrl, m))
+    want = {"edited": edited, "einsum": len(metas) - edited}
+    assert got == {k: v for k, v in want.items() if v}
+
+
 def test_a_stale_cached_executable_is_compiled_once_more(monkeypatch):
     """The cache's key leaves metadata out: an executable cached before the
     scopes were named is served with none. The index then compiles past it."""
