@@ -138,10 +138,13 @@ class Program:
 
 def _edit_controller(pipe):
     from ..cli import controller_from_opts
+    from ..models.config import unet_layout
 
-    return controller_from_opts(list(PROMPTS), pipe.tokenizer, STEPS,
-                                mode="replace", cross_steps=0.8,
-                                self_steps=0.4)
+    # the programs below are traced past ``text2image``/``sweep``, so the
+    # defaults are taken against the model here, as those entrances do
+    return unet_layout(pipe.config.unet).resolve(controller_from_opts(
+        list(PROMPTS), pipe.tokenizer, STEPS, mode="replace",
+        cross_steps=0.8, self_steps=0.4))
 
 
 def _scan_inputs(pipe):

@@ -112,13 +112,16 @@ def _parse_equalizer(spec: Optional[str]):
 
 def controller_from_opts(prompts, tokenizer, num_steps, *, mode,
                          cross_steps, self_steps, blend_words=None,
-                         equalizer=None, blend_resolution=16):
+                         equalizer=None, blend_resolution=None):
     """The one controller assembly both request surfaces share: the CLI
     subcommands (via ``_make_controller``) and the serving layer
     (``serve.request.prepare``) build edit controllers through this exact
     call, so a spec accepted by one surface is accepted — and means the
     same program — on the other. ``blend_words``/``equalizer`` use the CLI
-    string syntax ("cat,dog" / "word=scale,...")."""
+    string syntax ("cat,dog" / "word=scale,..."). The resolutions nobody
+    gave (LocalBlend's maps, the self-injection bound) become the model's
+    own levels where the controller meets the pipeline
+    (``AttnLayout.resolve``)."""
     from .controllers.factory import make_controller
 
     blend = blend_words.split(",") if blend_words else None
@@ -826,7 +829,7 @@ def build_parser() -> argparse.ArgumentParser:
         # tests/test_cli.py::test_every_cli_preset_resolves_to_a_config.
         sp.add_argument("--preset",
                         choices=("tiny", "sd14", "sd21", "sd21base",
-                                 "ldm256", "tiny_ldm"),
+                                 "ldm256", "tiny_ldm", "tiny_v"),
                         default="tiny",
                         help="model family; sd21 is the 768-v v-prediction "
                              "variant the reference marks 'Not work' "
@@ -887,7 +890,10 @@ def build_parser() -> argparse.ArgumentParser:
                         help="comma-separated words for LocalBlend masking")
         sp.add_argument("--equalizer", default=None,
                         help="word=scale[,word=scale...] reweighting")
-        sp.add_argument("--blend-resolution", type=int, default=16)
+        sp.add_argument("--blend-resolution", type=int, default=None,
+                        help="side of the cross-attention maps LocalBlend "
+                             "masks with (default: the model's level in the "
+                             "place of SD-1.4's 16: 24 for sd21)")
 
     def negative_opt(sp):
         # generate/edit only — replay's uncond comes from the inversion

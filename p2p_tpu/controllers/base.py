@@ -89,6 +89,24 @@ class StoreConfig:
         return self.store_cross if meta.is_cross else self.store_self
 
 
+#: The map side the paper's code writes as a literal (`/root/reference/main.py`:
+#: LocalBlend's 16² cross maps, the 16² self-injection bound); the 32² bounds
+#: (the store, null-text's self window) stand one level above it.
+PAPER_RESOLUTION = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class PaperLevel:
+    """A map side nobody gave: the paper's literal for SD-1.4's pyramid (16;
+    ``up=1``: its 32), standing in ``EditParams.self_max_pixels`` and
+    ``BlendParams.resolution`` until the controller meets a model and
+    ``AttnLayout.resolve`` takes it against that model's levels. It orders
+    against nothing: a controller that reaches a trace unresolved is a
+    ``TypeError`` there, not SD-1.4's sites picked on another pyramid."""
+
+    up: int = 0
+
+
 @dataclasses.dataclass(frozen=True)
 class AttnLayout:
     """The full static attention structure of a model: one AttnMeta per call
@@ -103,6 +121,55 @@ class AttnLayout:
 
     def stored_metas(self) -> Tuple[AttnMeta, ...]:
         return tuple(m for m in self.metas if m.store_slot is not None)
+
+    def edit_resolution(self) -> int:
+        """The side of the attention maps the paper's defaults stand for in
+        this model: 16 (the controllers' self window ``16²``, LocalBlend's
+        maps) wherever the pyramid has a 16² level, else the level that
+        stands where 16² stands in SD-1.4's 64/32/16/8, a quarter of the
+        largest side (24 of SD-2.1's 96/48/24/12). A model with neither is
+        an error here, at controller build time, never an empty site list."""
+        sides = sorted({m.resolution for m in self.metas})
+        if PAPER_RESOLUTION in sides:
+            return PAPER_RESOLUTION
+        level = sides[-1] // 4
+        if level not in sides:
+            raise ValueError(
+                f"no default edit resolution: the model's attention levels "
+                f"{sides} hold neither {PAPER_RESOLUTION} nor a quarter of "
+                f"the largest ({level}); pass the resolution explicitly")
+        return level
+
+    def resolve(self, controller: Optional["Controller"]) -> Optional["Controller"]:
+        """Take the resolutions nobody gave (``PaperLevel``) against this
+        model: the one place a default becomes a number, called where a
+        controller meets a pipeline (``text2image``, ``sweep`` and the phase
+        pools, ``serve.request.prepare``), before anything is traced. The
+        self bound becomes the square of ``edit_resolution()`` raised
+        ``up`` levels (16² / 32² wherever the pyramid has a 16² level, 24² /
+        48² on SD-2.1); LocalBlend's side likewise, and a side, given or
+        not, at which the model stores no cross map is refused here rather
+        than while tracing. A controller with nothing to resolve comes back
+        as it is, stacked (``sweep``) or not: only static fields change."""
+        if controller is None:
+            return None
+        edit, blend = controller.edit, controller.blend
+        if edit is not None and isinstance(edit.self_max_pixels, PaperLevel):
+            side = self.edit_resolution() << edit.self_max_pixels.up
+            edit = edit.replace(self_max_pixels=side * side)
+        if blend is not None:
+            if isinstance(blend.resolution, PaperLevel):
+                blend = blend.replace(
+                    resolution=self.edit_resolution() << blend.resolution.up)
+            if not self.blend_metas(blend.resolution):
+                stored = sorted({m.resolution for m in self.stored_metas() if m.is_cross})
+                raise ValueError(
+                    f"LocalBlend resolution {blend.resolution}: the model stores "
+                    f"no cross-attention map of that side (stored cross levels "
+                    f"{stored})")
+        if edit is controller.edit and blend is controller.blend:
+            return controller
+        return controller.replace(edit=edit, blend=blend)
 
     def blend_metas(self, resolution: int = 16) -> Tuple[AttnMeta, ...]:
         """The cross-attention maps LocalBlend consumes — all cross sites at

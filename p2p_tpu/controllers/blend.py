@@ -16,14 +16,14 @@ for the reference's ``down_cross[2:4] + up_cross[:3]`` (`main.py:37-38`).
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Optional
+from typing import TYPE_CHECKING, Optional, Union
 
 import jax
 import jax.numpy as jnp
 from flax import struct
 
 if TYPE_CHECKING:  # circular-import guard; only needed for type hints
-    from .base import AttnLayout
+    from .base import AttnLayout, PaperLevel
 
 
 @struct.dataclass
@@ -41,8 +41,9 @@ class BlendParams:
     start_blend: jax.Array = struct.field(default_factory=lambda: jnp.int32(0))
     th_pool: jax.Array = struct.field(default_factory=lambda: jnp.float32(0.3))
     th_nopool: jax.Array = struct.field(default_factory=lambda: jnp.float32(0.3))
-    # Static: selects which store slots feed the mask (a shape decision).
-    resolution: int = struct.field(pytree_node=False, default=16)
+    # Static: selects which store slots feed the mask (a shape decision). A
+    # ``base.PaperLevel`` where nobody gave one, until ``AttnLayout.resolve``.
+    resolution: Union[int, "PaperLevel"] = struct.field(pytree_node=False, default=16)
 
 
 def _max_pool_3x3(x: jax.Array) -> jax.Array:

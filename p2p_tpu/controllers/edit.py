@@ -11,11 +11,14 @@ multiply, which also expresses the reference's controller chaining
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import TYPE_CHECKING, Optional, Union
 
 import jax
 import jax.numpy as jnp
 from flax import struct
+
+if TYPE_CHECKING:  # circular-import guard; only needed for type hints
+    from .base import PaperLevel
 
 
 @struct.dataclass
@@ -42,6 +45,9 @@ class EditParams:
                          `/root/reference/null_text.py:225` (intentional
                          behavioral difference between the two variants).
                          Static: it gates which layers get edit ops at all.
+                         The factories leave a ``base.PaperLevel`` here when
+                         nobody gave a bound; ``AttnLayout.resolve`` makes
+                         it this model's number before anything is traced.
     """
 
     cross_alpha: jax.Array
@@ -51,7 +57,8 @@ class EditParams:
     self_start: jax.Array = struct.field(default_factory=lambda: jnp.int32(0))
     self_end: jax.Array = struct.field(default_factory=lambda: jnp.int32(0))
     kind: str = struct.field(pytree_node=False, default="none")
-    self_max_pixels: int = struct.field(pytree_node=False, default=16 * 16)
+    self_max_pixels: Union[int, "PaperLevel"] = struct.field(
+        pytree_node=False, default=16 * 16)
 
 
 def base_cross_transform(

@@ -19,7 +19,7 @@ from ..align.aligner import get_refinement_mapper, get_replacement_mapper
 from ..align.words import Bounds, get_equalizer, get_time_words_attention_alpha, get_word_inds
 from ..obs.spans import span
 from ..utils.tokenizer import Tokenizer
-from .base import Controller
+from .base import Controller, PaperLevel
 from .blend import BlendParams
 from .edit import EditParams
 
@@ -39,6 +39,14 @@ def _cross_alpha(prompts, num_steps, cross_replace_steps, tokenizer, max_len):
         get_time_words_attention_alpha(prompts, num_steps, cross_replace_steps,
                                        tokenizer, max_num_words=max_len)
     )
+
+
+def _or_paper(given: Optional[int], up: int = 0):
+    """A resolution as given, else the paper's literal as a ``PaperLevel``
+    (16; ``up=1``: 32), which ``AttnLayout.resolve`` takes against the model
+    where the controller meets it: 16 wherever the pyramid has a 16² level,
+    24 on SD-2.1's 96² latent."""
+    return PaperLevel(up) if given is None else given
 
 
 def _entry_span(make):
@@ -79,12 +87,15 @@ def local_blend(
     start_blend: float = 0.0,
     num_steps: int = 50,
     th: Tuple[float, float] = (0.3, 0.3),
-    resolution: int = 16,
+    resolution: Optional[int] = None,
     max_len: Optional[int] = None,
 ) -> BlendParams:
     """Build LocalBlend word masks (`/root/reference/main.py:54-66`,
     `/root/reference/null_text.py:72-102`). ``start_blend`` is a fraction of
-    ``num_steps`` as in `/root/reference/null_text.py:100`."""
+    ``num_steps`` as in `/root/reference/null_text.py:100`. ``resolution``
+    defaults to the model's level in the place of the paper's 16
+    (``_or_paper``); a side at which the model stores no cross-attention map
+    is refused by ``AttnLayout.resolve``, before anything is traced."""
     L = max_len or tokenizer.model_max_length
 
     def one_hot(word_lists) -> np.ndarray:
@@ -103,7 +114,7 @@ def local_blend(
         start_blend=jnp.int32(int(start_blend * num_steps)),
         th_pool=jnp.float32(th[0]),
         th_nopool=jnp.float32(th[1]),
-        resolution=resolution,
+        resolution=_or_paper(resolution),
     )
 
 
@@ -115,7 +126,7 @@ def attention_replace(
     self_replace_steps: Union[float, Tuple[float, float]],
     tokenizer: Tokenizer,
     local_blend: Optional[BlendParams] = None,
-    self_max_pixels: int = 16 * 16,
+    self_max_pixels: Optional[int] = None,
     max_len: Optional[int] = None,
     store: bool = True,
 ) -> Controller:
@@ -123,7 +134,10 @@ def attention_replace(
 
     ``store=True`` mirrors the reference, whose edit controllers extend
     AttentionStore and always accumulate ≤32²-pixel maps (`main.py:162`);
-    pass False to trade observability for store bandwidth."""
+    pass False to trade observability for store bandwidth.
+    ``self_max_pixels`` defaults to the paper's 16² (`main.py:170`), taken
+    against the model where the controller meets it (``_or_paper``: SD-2.1's
+    24²)."""
     L = max_len or tokenizer.model_max_length
     lo, hi = _self_window(num_steps, self_replace_steps)
     edit = EditParams(
@@ -132,7 +146,7 @@ def attention_replace(
         kind="replace",
         self_start=jnp.int32(lo),
         self_end=jnp.int32(hi),
-        self_max_pixels=self_max_pixels,
+        self_max_pixels=_or_paper(self_max_pixels),
     )
     return Controller(edit=edit, blend=local_blend, store=store)
 
@@ -145,7 +159,7 @@ def attention_refine(
     self_replace_steps: Union[float, Tuple[float, float]],
     tokenizer: Tokenizer,
     local_blend: Optional[BlendParams] = None,
-    self_max_pixels: int = 16 * 16,
+    self_max_pixels: Optional[int] = None,
     max_len: Optional[int] = None,
     store: bool = True,
 ) -> Controller:
@@ -160,7 +174,7 @@ def attention_refine(
         kind="refine",
         self_start=jnp.int32(lo),
         self_end=jnp.int32(hi),
-        self_max_pixels=self_max_pixels,
+        self_max_pixels=_or_paper(self_max_pixels),
     )
     return Controller(edit=edit, blend=local_blend, store=store)
 
@@ -175,7 +189,7 @@ def attention_reweight(
     tokenizer: Tokenizer,
     local_blend: Optional[BlendParams] = None,
     base: Optional[Controller] = None,
-    self_max_pixels: int = 16 * 16,
+    self_max_pixels: Optional[int] = None,
     max_len: Optional[int] = None,
     store: bool = True,
 ) -> Controller:
@@ -206,7 +220,7 @@ def attention_reweight(
         kind=kind,
         self_start=jnp.int32(lo),
         self_end=jnp.int32(hi),
-        self_max_pixels=self_max_pixels,
+        self_max_pixels=_or_paper(self_max_pixels),
     )
     return Controller(edit=edit, blend=local_blend, store=store)
 
@@ -220,15 +234,18 @@ def make_controller(
     num_steps: int = 50,
     blend_words=None,
     equalizer_params: Optional[dict] = None,
-    self_max_pixels: int = 32 * 32,
-    blend_resolution: int = 16,
+    self_max_pixels: Optional[int] = None,
+    blend_resolution: Optional[int] = None,
 ) -> Controller:
     """One-call controller assembly (`/root/reference/null_text.py:369-401`).
 
     Defaults follow the null-text variant (``self_max_pixels=32²``,
-    LocalBlend with 0.2 start warm-up). ``equalizer_params`` =
+    LocalBlend on the 16² maps with 0.2 start warm-up), each taken against
+    the model where the controller meets it (``_or_paper``: 48² and 24 on
+    SD-2.1's 96² latent, which has no 16² level). ``equalizer_params`` =
     ``{"words": ..., "values": ...}`` adds a Reweight stage on top.
     """
+    self_max_pixels = _or_paper(self_max_pixels, up=1)
     lb = None
     if blend_words is not None:
         lb = local_blend(prompts, blend_words, tokenizer,

@@ -312,6 +312,20 @@ def phase2_controller(controller: Optional[Controller]
     return controller.replace(edit=None, store=False)
 
 
+def _store_state(layout: AttnLayout, b: int,
+                 controller: Optional[Controller]) -> StoreState:
+    """The controller's zeroed attention store for ``b`` conditional rows
+    (f32 whatever the sampling dtype: it accumulates over the steps), ()
+    where it keeps none. Its bytes are noted for the launch being traced
+    (``Launch.store_bytes``): at a 96² latent the store's bound scales to
+    48² and five self sites hold (B, 10, 2304, 2304) each."""
+    if controller is None or not controller.needs_store:
+        return ()
+    state = init_store_state(layout, b, dtype=jnp.float32)
+    launches.note_store_bytes(sum(s.size * s.dtype.itemsize for s in state))
+    return state
+
+
 def _make_ms_step(schedule: sched_mod.DiffusionSchedule, scheduler_kind: str):
     use_plms = scheduler_kind == "plms"
     use_dpm = scheduler_kind == "dpm"
@@ -431,8 +445,7 @@ def _scheduled_phase1(
     sched1 = reuse_mod.phase1_view(reuse)
     emit = progress or metrics
     b = latents.shape[0]
-    state = (init_store_state(layout, b, dtype=jnp.float32)
-             if (controller is not None and controller.needs_store) else ())
+    state = _store_state(layout, b, controller)
     ms_state = sched_mod.init_multistep_state(scheduler_kind, latents.shape,
                                               latents.dtype)
     num_scan = schedule.timesteps.shape[0]
@@ -568,8 +581,10 @@ def _make_phase1_body(
                 eps = eps_uncond + guidance_scale * (eps_text - eps_uncond)
         with jax.named_scope("sampler/scheduler_step"):
             # v-prediction models (SD-2.1 768-v): convert to ε once per step.
-            # Linear in the model output, so combining CFG first is
-            # equivalent.
+            # Affine in the model output with the same x_t on both branches,
+            # so combining CFG first is equivalent; ``resid`` above is
+            # captured before it, in the network's output space (see
+            # ``_phase2_scan``).
             eps = sched_mod.to_epsilon(schedule, eps, t, latents)
             ms, latents = ms_step(ms, eps, t, latents)
         with jax.named_scope("sampler/controller_step"):
@@ -619,8 +634,7 @@ def _phase1_scan(
                                  kernels=kernels)
     emit = progress or metrics
     b = latents.shape[0]
-    state = (init_store_state(layout, b, dtype=jnp.float32)
-             if (controller is not None and controller.needs_store) else ())
+    state = _store_state(layout, b, controller)
     ms_state = sched_mod.init_multistep_state(scheduler_kind, latents.shape,
                                               latents.dtype)
     body = _make_phase1_body(unet_params, cfg, layout, schedule,
@@ -686,7 +700,15 @@ def _phase2_scan(
             attn_cache=cache, cache_mode="use")
         # SD-Acc-style fixed extrapolation: CFG's uncond branch is gone;
         # ε = ε_text + (g−1)·(ε_text − ε_uncond)|_gate reuses the captured
-        # last-phase-1 residual as the guidance direction.
+        # last-phase-1 residual as the guidance direction. The residual lives
+        # in the network's OUTPUT space (v for a v-prediction model, as
+        # phase 1 captured it ahead of ``to_epsilon``): that is the space
+        # guidance is applied in at every full step, so this line is the full
+        # step's formula with the uncond branch frozen, and one conversion
+        # below serves both. Held in ε-space the same residual would weigh
+        # α_gate/α_t more at step t (ε_c − ε_u = α_t·(v_c − v_u)): another
+        # approximation, not a more exact one; the plain reference
+        # (benchmarks/reference/latent_diffusion_v.py) pins this one.
         with jax.named_scope("sampler/cfg"):
             eps = eps_text + (guidance_scale - 1.0) * resid
         with jax.named_scope("sampler/scheduler_step"):
@@ -796,9 +818,7 @@ def _denoise_scan(
     if not gated:
         # Feature off: the exact pre-existing program (no cache buffers, no
         # residual carry) — gate=S is bitwise-identical by construction.
-        state = (init_store_state(layout, b, dtype=jnp.float32)
-                 if (controller is not None and controller.needs_store)
-                 else ())
+        state = _store_state(layout, b, controller)
         # Multistep-solver state carried through the scan (PLMS ring buffer
         # or DPM x0 history; None for single-step DDIM). The gated path
         # initializes its own inside ``_phase1_scan`` and hands the SAME
@@ -967,6 +987,7 @@ def text2image(
             if layout is None:
                 from ..models.config import unet_layout
                 layout = unet_layout(cfg.unet)
+            controller = layout.resolve(controller)
             if rng is None:
                 rng = jax.random.PRNGKey(0)
 

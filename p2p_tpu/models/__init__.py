@@ -10,6 +10,7 @@ the prompt-to-prompt controller provably never looks.
 from .config import (
     LDM256,
     TINY_LDM,
+    TINY_V,
     SD14_HR,
     SD21,
     SD21_BASE,
@@ -27,7 +28,7 @@ from .unet import apply_unet, init_unet
 from . import vae
 
 __all__ = [
-    "LDM256", "SD14", "SD14_HR", "SD21", "SD21_BASE", "TINY", "TINY_LDM",
+    "LDM256", "SD14", "SD14_HR", "SD21", "SD21_BASE", "TINY", "TINY_LDM", "TINY_V",
     "PipelineConfig", "TextEncoderConfig", "UNetConfig", "VAEConfig",
     "unet_attn_specs", "unet_layout",
     "apply_text_encoder", "init_text_encoder",

@@ -294,6 +294,17 @@ TINY_LDM = PipelineConfig("tiny-ldm", TINY_LDM_UNET, TINY_LDM_TEXT,
                               beta_start=0.0015, beta_end=0.0195,
                               plms_steps_offset=0))
 
+# Tiny SD-2.1-shaped backend for tests: what `sd21` forces at toy sizes —
+# v-prediction, heads by a fixed head_dim, a gelu text tower, and a latent
+# whose levels are not powers of two (12 / 6 / 3: no 16² level, so the
+# controllers' defaults come from the layout, 48² image).
+TINY_V = PipelineConfig(
+    "tiny-v",
+    dataclasses.replace(TINY_UNET, sample_size=12, num_heads=1, head_dim=16),
+    dataclasses.replace(TINY_TEXT, activation="gelu"), TINY_VAE,
+    image_size=48, num_steps=4,
+    scheduler=SchedulerConfig(prediction_type="v_prediction"))
+
 
 # The one preset-name → PipelineConfig resolution map (CLI commands,
 # `p2p-tpu check`, tools/parity_real_weights.py all resolve through it).
@@ -309,4 +320,5 @@ PRESET_CONFIGS = {
     "sd21base": SD21_BASE,
     "ldm256": LDM256,
     "tiny_ldm": TINY_LDM,
+    "tiny_v": TINY_V,
 }

@@ -249,13 +249,23 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
 # The TPU gives a kernel 16 MiB of scoped VMEM; stay under it with headroom.
 _FLASH_VMEM_BUDGET = 14 * 2**20
 
-# The geometry table, (block_q, block_k_major, block_k), from the v5e sweep
-# (PERF.md §6, PR 28). At 4096 keys a head's K and V stay resident in VMEM
-# (block_k_major == S) under a small q block; a resident geometry is for that
-# length only, since elsewhere each q block would fetch K and V again. Any
-# other length takes the first of the general geometries that tiles it. All
-# of them pass through the VMEM guard, so a wide head steps down the list.
-_FLASH_BY_SEQ = {4096: ((256, 4096, 2048),)}
+# The geometry table, (block_q, block_k_major, block_k), from the v5e sweeps
+# (PERF.md §6, PR 28 and PR 29). At 4096 keys a head's K and V stay resident
+# in VMEM (block_k_major == S) under a small q block; a resident geometry is
+# for that length only, since elsewhere each q block would fetch K and V
+# again. SD-2.1's lengths are 9 x 2^n and tile by none of the general
+# geometries but the slowest: 2304 keys (48²) take K and V resident under a
+# q block of 768 (1.29 ms a site against 2.72 at (256, 256, 256)); at 9216
+# keys (96²) K and V do not fit, every q block streams them, and the fewest
+# q blocks that leave room for a 3072-key score tile win (6.65 ms a site; a q
+# block of 256 reads K and V twice as often, 10.2). Any other length takes
+# the first of the general geometries that tiles it. All of them pass
+# through the VMEM guard, so a wide head steps down the list.
+_FLASH_BY_SEQ = {
+    4096: ((256, 4096, 2048),),
+    9216: ((512, 3072, 1536),),
+    2304: ((768, 2304, 1152),),
+}
 _FLASH_GEOMETRIES = (
     (512, 2048, 1024),
     (1024, 1024, 1024),
@@ -351,13 +361,16 @@ def _flash_block_sizes(geometry):
     ("not all backward blocks are specified") — null-text inversion
     backprops through the U-Net's flash sites, which is exactly how
     this surfaced on chip. The backward passes hold more live
-    tiles than the forward, so they get a capped block; correctness of the
-    spec is pinned by an interpret-mode grad test
-    (tests/test_flash_pallas.py)."""
+    tiles than the forward, so they get a capped block: the largest of
+    512, 384, 256, 128 that divides ``block_k_major`` and with it the
+    length (512 tiles no multiple of 2304 = 18 x 128, SD-2.1's 48² site;
+    the described chip refused it: "q_seq_len should be divisible by
+    block_q_major_dkv"). Correctness of the spec is pinned by an
+    interpret-mode grad test (tests/test_flash_pallas.py)."""
     from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
     block_q, block_k_major, block_k = geometry
-    bwd = min(block_k_major, 512)
+    bwd = next(b for b in (512, 384, 256, 128) if block_k_major % b == 0)
     return _fa.BlockSizes(
         block_q=block_q, block_k_major=block_k_major, block_k=block_k,
         block_b=1,

@@ -371,8 +371,11 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         q, k, v = split_heads(q), split_heads(k), split_heads(v)
 
     with jax.named_scope("core"):
-        how = ("kernel" if nn.takes_flash_kernel(pix, d_head, q.dtype.itemsize)
-               else "einsum")          # of nn.fused_attention, from the shape
+        # How nn.fused_attention runs the site, from the shape alone.
+        geometry = (nn.flash_block(pix, d_head, q.dtype.itemsize)
+                    if nn.takes_flash_kernel(pix, d_head, q.dtype.itemsize)
+                    else None)
+        how = "einsum" if geometry is None else "kernel"
         if controller_touches(ctx.controller, meta):
             how = "edited"
             out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
@@ -429,7 +432,8 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         else:
             out = nn.fused_attention(q, k, v, scale)
         if not is_cross:
-            launches.note_self_site(meta.layer_idx, how)
+            launches.note_self_site(meta.layer_idx, how, pix, d_head,
+                                    geometry if how == "kernel" else None)
 
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)

@@ -15,8 +15,11 @@ reader gets from one to the other for a program that really ran:
   its static arguments, and ``ShapeDtypeStruct``s with the arguments'
   shardings. Shapes, never arrays. With them it keeps how each
   self-attention site of the U-Net ran, as the model noted while the
-  program was traced (``note_self_site``; ``Launch.self_sites``:
-  ``{"kernel": n, "einsum": n, "edited": n}``).
+  program was traced (``note_self_site``; ``Launch.self_sites``: per site
+  its keys, head width, implementation and the flash kernel's geometry),
+  and the bytes of attention maps the controller's store holds
+  (``note_store_bytes``; ``Launch.store_bytes``, and the gauge
+  ``launch_store_bytes{module}`` of ``obs.metrics``).
 - :func:`scope_index` lowers, compiles and parses that program lazily, once,
   when somebody asks (``obs.traceparse.scope_index`` on the executable's
   text). After a launch in the same process the executable is still in
@@ -43,6 +46,20 @@ from ..utils.cache import compile_ledger
 from . import traceparse
 
 
+@dataclasses.dataclass(frozen=True)
+class SelfSite:
+    """How one self-attention site of a traced program runs."""
+
+    keys: int                   # pixels = keys of the site
+    head_dim: int
+    how: str                    # "kernel" | "einsum" | "edited" | "sharded"
+    geometry: Optional[Tuple[int, int, int]] = None   # the flash kernel's tile
+
+    def __str__(self):
+        tile = "" if self.geometry is None else " " + "x".join(map(str, self.geometry))
+        return f"{self.keys}x{self.head_dim} {self.how}{tile}"
+
+
 @dataclasses.dataclass
 class Launch:
     """The first launch of one distinct program, kept abstract."""
@@ -54,9 +71,24 @@ class Launch:
     index: Optional[Dict[str, str]] = None
     mixed: Optional[Dict[str, Dict[str, int]]] = None
     built_from: str = ""        # "memory" | "cache_hit" | "backend" (compiled)
-    # Self-attention sites by how they ran: "kernel" / "einsum" (untouched,
+    # Self-attention sites by layer index: "kernel" / "einsum" (untouched,
     # ``nn.fused_attention``'s two implementations), "edited", "sharded".
-    self_sites: Dict[str, int] = dataclasses.field(default_factory=dict)
+    self_sites: Dict[int, SelfSite] = dataclasses.field(default_factory=dict)
+    # Bytes of the controller's attention store in this program's carry (per
+    # group, where the program runs groups), 0 where it keeps none.
+    store_bytes: int = 0
+
+    @property
+    def self_site_counts(self) -> Dict[str, int]:
+        """``{"kernel": n, "einsum": n, "edited": n}``: sites by how."""
+        return dict(collections.Counter(s.how for s in self.self_sites.values()))
+
+    def describe_sites(self) -> str:
+        """One line for a log: the counts, then each distinct site shape."""
+        shapes = collections.Counter(str(s) for s in self.self_sites.values())
+        return (f"self-attention sites {self.self_site_counts}"
+                + "".join(f"; {n} of {shape}" for shape, n in shapes.items())
+                + f"; controller store {self.store_bytes} bytes")
 
     def _signature(self):
         import jax
@@ -68,22 +100,35 @@ class Launch:
 
 
 _launches: Dict[str, List[Launch]] = {}     # module -> distinct programs
-_traced_sites: Dict[int, str] = {}          # site -> how, since the last mark
+_traced_sites: Dict[int, SelfSite] = {}     # by site, since the last mark
+_traced_store = 0                           # store bytes, since the last mark
 
 
 def built() -> int:
     """How many programs the process has built so far, compiled or read from
     the persistent cache: the mark a launch site takes before it calls its
     jitted function. The sites noted from here on are that launch's."""
+    global _traced_store
     _traced_sites.clear()
+    _traced_store = 0
     return compile_ledger().programs
 
 
-def note_self_site(site: int, how: str) -> None:
+def note_self_site(site: int, how: str, keys: int, head_dim: int,
+                   geometry=None) -> None:
     """Trace time, from the model: self-attention site ``site`` of the
-    program being traced runs ``how``. Keyed by site, so a body traced twice
-    counts once."""
-    _traced_sites[site] = how
+    program being traced, of ``keys`` pixels and heads ``head_dim`` wide,
+    runs ``how``, the flash kernel tiled by ``geometry``. Keyed by site, so
+    a body traced twice counts once."""
+    _traced_sites[site] = SelfSite(keys, head_dim, how, geometry)
+
+
+def note_store_bytes(n: int) -> None:
+    """Trace time, from the sampler: the controller's attention store of the
+    program being traced holds ``n`` bytes (the largest, where phases of one
+    program each make their own)."""
+    global _traced_store
+    _traced_store = max(_traced_store, int(n))
 
 
 def keep_if_built(mark: int, fn, args: tuple, kwargs: dict) -> None:
@@ -131,12 +176,18 @@ def _keep(fn, args, kwargs) -> None:
     abstract = _abstract((args, kwargs))
     if abstract is None:
         return
-    launch = Launch("jit_" + fn.__name__, fn, *abstract, self_sites=dict(
-        collections.Counter(_traced_sites.values())))
+    launch = Launch("jit_" + fn.__name__, fn, *abstract,
+                    self_sites=dict(_traced_sites), store_bytes=_traced_store)
     known = _launches.setdefault(launch.module, [])
     # Another thread's compile can make a warm call look like a first launch.
     if all(launch._signature() != k._signature() for k in known):
         known.append(launch)
+        from . import metrics
+
+        metrics.registry().gauge(
+            "launch_store_bytes", "bytes of attention maps the controller's "
+            "store holds in the newest program of a module",
+            labels=("module",)).labels(module=launch.module).set(launch.store_bytes)
 
 
 def programs(module: Optional[str] = None) -> List[Launch]:
@@ -181,8 +232,7 @@ def _build(launch: Launch) -> None:
     launch.index, launch.mixed = index, mixed
     # Whoever asked prints the scope tree (a traced run); this goes with it.
     print(f"launch {launch.module}: {len(index)} instructions from "
-          f"{launch.built_from}; self-attention sites {launch.self_sites}",
-          file=sys.stderr)
+          f"{launch.built_from}; {launch.describe_sites()}", file=sys.stderr)
 
 
 def _compile_and_parse(launch: Launch, compiler_options=None):

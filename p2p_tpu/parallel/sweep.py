@@ -181,6 +181,7 @@ def sweep(
         if layout is None:
             from ..models.config import unet_layout
             layout = unet_layout(cfg.unet)
+        controllers = layout.resolve(controllers)
         if uncond_per_step is not None:
             if scheduler != "ddim":
                 # Same constraint as text2image: the embeddings are optimized
@@ -352,7 +353,8 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
                 schedule=None):
     """Shared wrapper plumbing for the two pool entry points: schedule,
     resolved+validated gate (a pool program needs both phases non-empty),
-    staged guidance (replicated over ``mesh`` when given), layout.
+    staged guidance (replicated over ``mesh`` when given), layout, and the
+    controllers with their defaults taken against it (``AttnLayout.resolve``).
     ``schedule`` is a reuse-schedule spec/table (ISSUE 15): its
     ``cfg_gate`` is the pool boundary; uniform tables normalize onto the
     plain gate."""
@@ -361,6 +363,7 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
         if layout is None:
             from ..models.config import unet_layout
             layout = unet_layout(cfg.unet)
+        controllers = layout.resolve(controllers)
         dsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
                                                 kind=scheduler)
         num_scan = dsched.timesteps.shape[0]
@@ -373,7 +376,7 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
                 "requests take the single-pool sweep() path")
         gs = (guidance_scale if isinstance(guidance_scale, jax.Array)
               else stage_host(np.float32(guidance_scale), mesh=mesh))
-        return cfg, layout, dsched, gate_step, gs, reuse_sched
+        return cfg, layout, dsched, gate_step, gs, reuse_sched, controllers
 
 
 def sweep_phase1(
@@ -402,7 +405,7 @@ def sweep_phase1(
     ``lower_only=True`` returns the program's ``Lowered`` instead of
     executing (the cost-card path — see :func:`sweep`)."""
     with span("entry.sweep_phase1"):
-        cfg, layout, dsched, gate_step, gs, reuse_sched = _phase_args(
+        cfg, layout, dsched, gate_step, gs, reuse_sched, controllers = _phase_args(
             pipe, num_steps, scheduler, gate, guidance_scale, layout,
             controllers, mesh=mesh, schedule=schedule)
         if reuse_sched is None:
@@ -464,7 +467,7 @@ def sweep_phase2(
     host round-trip, so the transfer-guard("disallow") contract holds on
     mesh dispatch too. Returns ``(images, final latents)``."""
     with span("entry.sweep_phase2"):
-        cfg, layout, schedule, gate_step, gs, reuse_sched = _phase_args(
+        cfg, layout, schedule, gate_step, gs, reuse_sched, controllers = _phase_args(
             pipe, num_steps, scheduler, gate, guidance_scale, layout,
             controllers, mesh=mesh, schedule=schedule)
         if lower_only:

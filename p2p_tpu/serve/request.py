@@ -92,7 +92,7 @@ class Request:
     self_steps: float = 0.4
     blend_words: Optional[str] = None
     equalizer: Optional[str] = None
-    blend_resolution: int = 16
+    blend_resolution: Optional[int] = None   # None: the model's own level
     seed: int = 8191
     steps: int = 50
     scheduler: str = "ddim"
@@ -252,7 +252,8 @@ def content_key(req: Request, gate_step: int, model_name: str,
     edit = (None if req.target is None else
             (req.target, req.mode, float(req.cross_steps),
              float(req.self_steps), req.blend_words, req.equalizer,
-             int(req.blend_resolution)))
+             None if req.blend_resolution is None
+             else int(req.blend_resolution)))
     # ``sched_key`` is the RESOLVED reuse table (engine.reuse key form),
     # not the raw spec: specs that resolve identically (fraction vs step,
     # different files) share a cache line, and the uniform table (None
@@ -307,13 +308,16 @@ def prepare(req: Request, pipe) -> PreparedRequest:
     from ..models.config import unet_layout
     from ..ops import schedulers as sched_mod
 
+    layout = unet_layout(pipe.config.unet)
     controller = None
     if req.target is not None:
-        controller = controller_from_opts(
+        # resolved here, at admission: a LocalBlend side the model keeps no
+        # map of is the request's error, not a batch's trace failure
+        controller = layout.resolve(controller_from_opts(
             list(req.prompts), pipe.tokenizer, req.steps,
             mode=req.mode, cross_steps=req.cross_steps,
             self_steps=req.self_steps, blend_words=req.blend_words,
-            equalizer=req.equalizer, blend_resolution=req.blend_resolution)
+            equalizer=req.equalizer, blend_resolution=req.blend_resolution))
 
     # Same scan length the sampler will run (PLMS warm-up adds one step).
     schedule = sched_mod.schedule_from_config(req.steps, pipe.config.scheduler,
@@ -324,7 +328,6 @@ def prepare(req: Request, pipe) -> PreparedRequest:
     # model's site layout, normalizes a UNIFORM table to the plain gate
     # (``reuse=None`` — pools with gate=g traffic) and fires the per-site
     # window-conflict warning for non-uniform tables.
-    layout = unet_layout(pipe.config.unet)
     gate_step, reuse_sched = resolve_reuse(req.gate, req.schedule, layout,
                                            scan_steps, controller)
     sched_key = None if reuse_sched is None else reuse_sched.key()

@@ -1,6 +1,6 @@
 """Ask the chip's compiler, without a chip: the Pallas kernels of the main
 path compiled for a *described* TPU v5e (``v5e:2x2`` topology, nothing
-attached) at SD-1.4 widths in bf16.
+attached) at SD-1.4 and SD-2.1 widths in bf16.
 
 Interpret-mode tests (tests/test_kernels.py, tests/test_flash_pallas.py)
 prove the kernels' arithmetic; they cannot see what Mosaic refuses — a
@@ -9,8 +9,10 @@ budget. These compiles can, at about a second each and no chip time:
 
 (a) the fused edit kernel (``kernels.fused_edit.fused_site_attention``) for
     the replace, refine and reweight controllers at every distinct site
-    geometry of ``unet_layout(SD14.unet).metas`` the kernel covers (the
-    64x64 self site is not fused by design and stays on flash);
+    geometry of ``unet_layout(SD14.unet).metas`` and of SD-2.1's 96x96
+    latent the kernel covers (the 64x64 and 96x96 self sites are not fused
+    by design and stay on flash; SD-2.1's 48x48 self site has no block that
+    fits, ``nn.edit_block`` answers 0, and keeps the materialized path);
 (b) ``nn.flash_attention_tpu`` forward and its ``jax.grad`` (null-text
     inversion differentiates through it) at every row of the geometry table
     ``nn.flash_block`` answers from (``FLASH_ROWS``), in the row's dtype;
@@ -42,7 +44,7 @@ from p2p_tpu.align.words import get_equalizer
 from p2p_tpu.controllers import factory
 from p2p_tpu.controllers.kernel_spec import kernel_edit_spec
 from p2p_tpu.kernels.fused_edit import fused_site_attention
-from p2p_tpu.models import SD14, nn
+from p2p_tpu.models import SD14, SD21, nn
 from p2p_tpu.models.config import unet_layout
 from p2p_tpu.utils.tokenizer import HashWordTokenizer
 
@@ -52,16 +54,20 @@ MODES = ("replace", "refine", "reweight")
 
 
 def _geometries():
-    """One ``AttnMeta`` per distinct (cross?, pixels, d_head) of SD-1.4."""
+    """One ``AttnMeta`` per distinct (cross?, pixels, d_head) of SD-1.4
+    (heads of 40 / 80 / 160) and of SD-2.1 at 768x768 (heads of 64)."""
     seen = {}
-    for m in unet_layout(SD14.unet).metas:
-        seen.setdefault((m.is_cross, m.pixels, m.channels // m.heads), m)
+    for cfg in (SD14, SD21):
+        for m in unet_layout(cfg.unet).metas:
+            seen.setdefault((m.is_cross, m.pixels, m.channels // m.heads), m)
     return seen
 
 
 GEOMETRIES = _geometries()
-#: The sites the fused kernel covers: everything but the 64x64 self site.
-FUSED = [key for key in GEOMETRIES if key[0] or key[1] < 4096]
+#: The sites the fused kernel covers: every cross site, and the self sites
+#: under 4096 pixels for which ``nn.edit_block`` has a query block.
+FUSED = [key for key, m in GEOMETRIES.items()
+         if key[0] or (key[1] < 4096 and nn.edit_block(key[1], m.key_len, key[2], 2))]
 
 
 def _geom_id(key):
@@ -149,9 +155,18 @@ def test_described_device_is_the_v5e_the_peaks_table_knows(topo):
 def test_fused_geometries_cover_the_layout():
     # cross P in {4096, 1024, 256, 64}, self P in {1024, 256, 64}; the
     # 64x64 self site is the only one left to flash.
-    assert sorted(k[1] for k in FUSED if k[0]) == [64, 256, 1024, 4096]
-    assert sorted(k[1] for k in FUSED if not k[0]) == [64, 256, 1024]
-    assert [k for k in GEOMETRIES if k not in FUSED] == [(False, 4096, 40)]
+    sd14 = [k for k in FUSED if k[2] != 64]
+    assert sorted(k[1] for k in sd14 if k[0]) == [64, 256, 1024, 4096]
+    assert sorted(k[1] for k in sd14 if not k[0]) == [64, 256, 1024]
+    # SD-2.1, 96x96 latent: cross P in {9216, 2304, 576, 144}, self P in
+    # {576, 144}; flash has the 96x96 self site, and the 48x48 one, whose
+    # 2304 x 2304 edit transform alone is over the kernel's VMEM, stays
+    # materialized when a controller edits it (ROADMAP Reach 3).
+    sd21 = [k for k in FUSED if k[2] == 64]
+    assert sorted(k[1] for k in sd21 if k[0]) == [144, 576, 2304, 9216]
+    assert sorted(k[1] for k in sd21 if not k[0]) == [144, 576]
+    assert [k for k in GEOMETRIES if k not in FUSED] == [
+        (False, 4096, 40), (False, 9216, 64), (False, 2304, 64)]
 
 
 @pytest.mark.parametrize("key", FUSED, ids=_geom_id)
@@ -177,11 +192,15 @@ def test_fused_edit_kernel_compiles(one_chip, controllers, mode, key):
 #: kernel — SD-1.4's 64x64 and 32x32 self sites as the benchmark runs them
 #: (f32) and as ``sweep(dtype=bfloat16)`` does, LDM-256's 32x32 site, the
 #: VAE decoder's mid attention (one 512-wide head, f32), SD14_HR's 128x128,
-#: SD-2.x's 64x64 site (head size 64).
+#: SD-2.x's 64x64 site (head size 64), and SD-2.1 at 768x768 (PR 29): its
+#: 96x96 and 48x48 self sites (9216 and 2304 keys, 9 x 2^n: the backward
+#: blocks have to tile them too) and the VAE's mid attention at 96x96.
 FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
               (4096, 8, 40, jnp.bfloat16), (1024, 8, 80, jnp.bfloat16),
               (1024, 5, 64, jnp.float32), (4096, 1, 512, jnp.float32),
-              (16384, 8, 40, jnp.float32), (4096, 5, 64, jnp.float32)]
+              (16384, 8, 40, jnp.float32), (4096, 5, 64, jnp.float32),
+              (9216, 5, 64, jnp.float32), (2304, 10, 64, jnp.float32),
+              (9216, 1, 512, jnp.float32)]
 #: The 64x64 self site in bf16, which the mesh cases run, and its local chunks
 #: under parallel/ring.py at sp = 2 and 4 (the residuals kernel runs on those).
 FLASH_SITE = FLASH_ROWS[2]

@@ -219,7 +219,10 @@ def test_zero_replace_steps_equals_baseline(tokenizer):
     (hyperparameter notes at /root/reference/main.py:448-460)."""
     layout = tiny_layout()
     prompts = ["a cat sat", "a dog sat", "a pig sat"]
-    c = attention_replace(prompts, 4, 0.0, 0.0, tokenizer, max_len=L)
+    # applied past ``text2image``, where a bound nobody gave is taken against
+    # the model: the paper's 16² is given here (this miniature has 8² and 4²)
+    c = attention_replace(prompts, 4, 0.0, 0.0, tokenizer, max_len=L,
+                          self_max_pixels=16 * 16)
     state = init_store_state(layout, batch_cond=B)
     for m in layout.metas:
         attn = rand_attn(jax.random.PRNGKey(m.layer_idx), m)

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental.pallas.tpu import force_tpu_interpret_mode
-from p2p_tpu.models import LDM256, SD14, nn
+from p2p_tpu.models import LDM256, SD14, SD21, nn
 from p2p_tpu.models.config import unet_layout
 
 
@@ -160,6 +160,60 @@ def test_flash_block_selection():
     assert nn.flash_block(4096, 160, 4) is None
 
 
+#: SD-2.1 at 768²: the 48² and 96² self sites, 9 x 2^n keys (PR 29).
+SD21_ROWS = [(2304, 64, jnp.float32), (9216, 64, jnp.float32)]
+
+
+def test_flash_block_selection_at_sd21_lengths():
+    # The measured geometries of PERF.md §6, PR 29: K and V resident at 2304
+    # keys; at 9216 they do not fit, and a wide q block streams them least.
+    assert nn.flash_block(2304, 64, 4) == (768, 2304, 1152)
+    assert nn.flash_block(9216, 64, 4) == (512, 3072, 1536)
+    assert nn.flash_block(9216, 64, 2) == (512, 3072, 1536)
+    assert nn.flash_block(9216, 512, 4) == (512, 512, 512)     # the VAE at 96²
+    assert nn.flash_block(576, 64, 4) is None                  # einsum chain
+    # A length's own geometry still passes the guard: a head of 256 at 2304
+    # keys does not fit K and V resident and steps down the general list.
+    assert nn.flash_block(2304, 256, 4) == (256, 256, 256)
+    # Backward blocks tile the length: 512 divides no multiple of 2304.
+    assert nn._flash_block_sizes((768, 2304, 1152)).block_q_dq == 384
+    assert nn._flash_block_sizes((512, 3072, 1536)).block_q_dq == 512
+    for geometry in ((768, 2304, 1152), (512, 3072, 1536)):
+        sizes = nn._flash_block_sizes(geometry)
+        assert sizes.has_backward_blocks
+        assert geometry[1] % sizes.block_k_major_dkv == 0
+
+
+@pytest.mark.parametrize("row", SD21_ROWS, ids=_row_id)
+def test_flash_interpret_parity_at_sd21_row(row):
+    s, d, dtype = row
+    geometry = nn.flash_block(s, d, 4)
+    q, k, v = _rand_qkv(3, 1, 1, s, d, dtype)
+    scale = 1.0 / np.sqrt(d)
+    with force_tpu_interpret_mode():
+        out = nn.flash_attention_tpu(q, k, v, scale, geometry)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_ref(q, k, v, scale)),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_flash_interpret_grad_at_2304_keys():
+    """The backward blocks of 384 (no 512 tiles 2304): gradients through the
+    kernel at SD-2.1's 48² site match the materialized attention's."""
+    s, d = 2304, 64
+    geometry = nn.flash_block(s, d, 4)
+    q, k, v = _rand_qkv(7, 1, 1, s, d, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+    with force_tpu_interpret_mode():
+        g_flash = jax.grad(lambda q, k, v: jnp.sum(
+            nn.flash_attention_tpu(q, k, v, scale, geometry) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda q, k, v: jnp.sum(_ref(q, k, v, scale) ** 2),
+                     argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-3, rtol=1e-3)
+
+
 def _site_shapes(config):
     """``{(pixels, head size)}`` of a preset's self-attention sites, plus its
     VAE decoder's mid attention (one head as wide as the last level)."""
@@ -187,11 +241,12 @@ def test_flash_geometries_stay_inside_the_vmem_budget():
                     <= nn._FLASH_VMEM_BUDGET
 
 
-@pytest.mark.parametrize("config", [SD14, LDM256], ids=lambda c: c.name)
+@pytest.mark.parametrize("config", [SD14, LDM256, SD21], ids=lambda c: c.name)
 def test_fused_attention_follows_the_table(config, monkeypatch):
     """``fused_attention`` takes the kernel exactly where the table has a
     geometry for the site's shape, and the einsum chain elsewhere — from the
-    shapes alone, for every site shape of SD-1.4 and LDM-256."""
+    shapes alone, for every site shape of SD-1.4, LDM-256 and SD-2.1 at 768²
+    (9216 and 2304 keys on the kernel, 576 and 144 on the chain)."""
     taken = []
     monkeypatch.setattr(nn, "_on_tpu", lambda: True)
     monkeypatch.setattr(

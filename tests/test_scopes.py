@@ -366,9 +366,10 @@ def test_a_launch_keeps_how_its_self_sites_ran(tiny_pipe, site):
 
     module, launch_site, _ = LAUNCH_SITES[site]
     launch_site(tiny_pipe)
-    got = launches.programs(module)[-1].self_sites
+    launch = launches.programs(module)[-1]
+    got = launch.self_site_counts
     if site == "encode":
-        assert got == {}
+        assert got == {} and launch.self_sites == {}
         return
     ctrl = _ctrl(tiny_pipe, store=False)
     if site == "sweep_phase2":
@@ -377,6 +378,9 @@ def test_a_launch_keeps_how_its_self_sites_ran(tiny_pipe, site):
     edited = sum(1 for m in metas if controller_touches(ctrl, m))
     want = {"edited": edited, "einsum": len(metas) - edited}
     assert got == {k: v for k, v in want.items() if v}
+    # each site with its keys and head width; no kernel, so no geometry
+    assert {(s.keys, s.head_dim, s.geometry) for s in launch.self_sites.values()} == {
+        (m.pixels, m.channels // m.heads, None) for m in metas}
 
 
 def test_a_stale_cached_executable_is_compiled_once_more(monkeypatch):
