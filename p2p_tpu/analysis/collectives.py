@@ -137,7 +137,7 @@ def lower_mesh_programs(pipe=None,
         pipe = tiny_pipeline()
     ctrl = _edit_controller(pipe)
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     ctx, lats, _ = _scan_inputs(pipe)

@@ -160,7 +160,7 @@ def _program_fingerprint(pipe, prep) -> str:
 
     req = prep.request
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(prep.controller)
     schedule = sched_mod.schedule_from_config(req.steps, cfg.scheduler,
                                               kind=req.scheduler)
     cond = encode_prompts(pipe, list(req.prompts))
@@ -234,7 +234,7 @@ def _phase_fingerprints(pipe, prep) -> Tuple[str, str]:
 
     req = prep.request
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(prep.controller)
     schedule = sched_mod.schedule_from_config(req.steps, cfg.scheduler,
                                               kind=req.scheduler)
     cond = encode_prompts(pipe, list(req.prompts))

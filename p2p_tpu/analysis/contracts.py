@@ -168,7 +168,7 @@ def _trace_denoise(pipe, ctrl, gate, metrics, kernels=None):
     from ..ops import schedulers as sched_mod
 
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     ctx, lats, gs = _scan_inputs(pipe)
@@ -211,7 +211,7 @@ def _trace_sweep(pipe, ctrl, bucket, gate, metrics, mesh=None, reuse=None,
     from ..parallel.sweep import _sweep_jit
 
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     ctx, lats, gs = _scan_inputs(pipe)
@@ -247,11 +247,10 @@ def _zero_carry(pipe, ctrl, reuse=None):
     from ..models.unet import init_attn_cache
     from ..ops import schedulers as sched_mod
 
-    layout = unet_layout(pipe.config.unet)
+    layout = unet_layout(pipe.config.unet).for_readers(ctrl)
     b = len(PROMPTS)
     lat = jnp.zeros((b,) + pipe.latent_shape)
-    state = (init_store_state(layout, b)
-             if (ctrl is not None and ctrl.needs_store) else ())
+    state = init_store_state(layout, b)
     if reuse is not None:
         from ..engine import reuse as reuse_mod
 
@@ -276,7 +275,7 @@ def _trace_sweep_phase1(pipe, ctrl, bucket, gate, metrics, mesh=None,
     from ..parallel.sweep import _sweep_phase1_jit
 
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     ctx, lats, gs = _scan_inputs(pipe)
@@ -308,12 +307,12 @@ def _trace_sweep_phase2(pipe, ctrl, bucket, gate, metrics, mesh=None,
     from ..parallel.sweep import _sweep_phase2_jit
 
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     cond = encode_prompts(pipe, list(PROMPTS))
     carry = _zero_carry(pipe, ctrl, reuse=reuse)
     p2 = phase2_controller(ctrl)
+    layout = unet_layout(cfg.unet).for_readers(p2)
 
     def lead(x):
         return jnp.broadcast_to(x[None], (bucket,) + x.shape)
@@ -512,12 +511,12 @@ def scheduled_programs(pipe=None, spec=None, buckets=(1,),
 def _kernel_controller(pipe):
     """The kernel-twin controller: a replace edit whose window covers every
     TINY attention site (``self_max_pixels`` at the largest level) with
-    ``store=False`` — no attention-store slots, so every controller-touched
-    site is kernel-compilable and the fused twin has ZERO materialized
-    CFG-doubled probability tensors by construction. The canonical
-    ``_edit_controller`` keeps ``store=True`` (store sites stay materialized
-    by design), which would make the no-materialized-probs detector
-    trivially fail on sites the kernel deliberately does not claim."""
+    ``store=False`` — every controller-touched site is kernel-compilable
+    and the fused twin has ZERO materialized CFG-doubled probability
+    tensors by construction. A site stored for a reader stays
+    materialized by design (``kernel_edit_spec`` declines it); these
+    programs have no reader (``AttnLayout.for_readers``), and ``store=False``
+    says so whatever the tracing helper does with the layout."""
     from ..controllers import factory
 
     size = pipe.config.unet.sample_size
@@ -861,13 +860,13 @@ def _donation_lowerings(pipe) -> Dict[str, str]:
                                   _sweep_phase2_jit)
 
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
     schedule = sched_mod.schedule_from_config(STEPS, cfg.scheduler,
                                               kind="ddim")
     ctx, lats, gs = _scan_inputs(pipe)
     b = len(PROMPTS)
     cond, uncond = ctx[b:], ctx[:b]
     ctrl = _edit_controller(pipe)
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
     carry = _zero_carry(pipe, ctrl)
     p2 = phase2_controller(ctrl)
     cond_b = encode_prompts(pipe, list(PROMPTS))

@@ -18,6 +18,8 @@ Here that becomes explicit functional state:
 - **The store is a tuple of fixed-shape arrays** (one per stored layer),
   accumulated by addition across steps — replacing the growing
   ``{down,mid,up}_{cross,self}`` lists (`/root/reference/main.py:118-142`).
+  A layer is stored only for a reader (``AttnLayout.for_readers``): the
+  caller that takes the store back, or LocalBlend.
 - **Controllers are pytrees** (`flax.struct`) passed as arguments into the
   jitted sampling loop; an "empty" controller compiles away to the identity,
   making `EmptyControl ≡ no controller` true at the XLA-program level.
@@ -171,6 +173,31 @@ class AttnLayout:
             return controller
         return controller.replace(edit=edit, blend=blend)
 
+    def for_readers(self, controller: Optional["Controller"],
+                    return_store: bool = False) -> "AttnLayout":
+        """This layout with store slots at the sites somebody reads, and
+        nowhere else: called beside ``resolve``, where a controller, a
+        layout and the caller's ``return_store`` meet, before anything is
+        traced. A stored map has two kinds of reader. The caller that takes
+        the store back (``text2image(return_store=True)``) reads every slot
+        of the ``StoreConfig``: the layout comes back as it is. LocalBlend
+        reads the cross maps of ``blend_metas(blend.resolution)`` and
+        nothing else: without ``return_store`` only those keep a slot
+        (renumbered from 0, so the state tuple holds just them). With
+        neither, no site has a slot, the state is ``()``, and a self site
+        above the edit window is untouched (``controller_touches``) and
+        runs fused, whatever ``controller.store`` says: a map nobody reads
+        is not worth a (B, heads, P, P) tensor in HBM at every step."""
+        if return_store:
+            return self
+        blend = None if controller is None else controller.blend
+        read = () if blend is None else self.blend_metas(blend.resolution)
+        slots = {m.layer_idx: i for i, m in enumerate(read)}
+        return AttnLayout(
+            tuple(dataclasses.replace(m, store_slot=slots.get(m.layer_idx))
+                  for m in self.metas),
+            self.store_cfg)
+
     def blend_metas(self, resolution: int = 16) -> Tuple[AttnMeta, ...]:
         """The cross-attention maps LocalBlend consumes — all cross sites at
         ``resolution`` (for SD-1.4 this is exactly the reference's
@@ -234,6 +261,10 @@ class Controller:
 
     @property
     def needs_store(self) -> bool:
+        """Does this controller accumulate maps at the layout's store slots?
+        Which sites have a slot is the layout's matter, and follows from who
+        reads the store (``AttnLayout.for_readers``): under ``store=True``
+        with no reader there is none, and the state is ``()``."""
         return self.store or self.blend is not None
 
 
@@ -246,7 +277,10 @@ def controller_touches(controller: Optional["Controller"], meta: AttnMeta) -> bo
     reference disabling xformers globally (`/root/reference/null_text.py:32-35`):
     only the sites prompt-to-prompt provably touches (edited self maps ≤
     ``self_max_pixels``, all cross maps under an edit, and stored slots —
-    `/root/reference/main.py:131,170`) pay for materialization.
+    `/root/reference/main.py:131,170`) pay for materialization. A site has a
+    slot only where the store has a reader (``AttnLayout.for_readers``), so
+    ``store=True`` alone touches nothing: a self site above the edit window
+    whose map nobody takes back runs fused.
     """
     if controller is None or controller.is_identity:
         return False
@@ -335,7 +369,9 @@ def init_store_state(
 ) -> StoreState:
     """Zero-initialized accumulation buffers, one per stored call site:
     ``(B_cond, heads, pixels, key_len)`` each. Fixed shapes — the jit-friendly
-    replacement for `/root/reference/main.py:118-127`'s dict of lists."""
+    replacement for `/root/reference/main.py:118-127`'s dict of lists. The
+    stored sites are ``layout``'s slots: pass the layout the program is
+    traced with (``AttnLayout.for_readers``), or state and program disagree."""
     return tuple(
         jnp.zeros((batch_cond, m.heads, m.pixels, m.key_len), dtype=dtype)
         for m in layout.stored_metas()

@@ -133,8 +133,10 @@ def attention_replace(
     """Word-swap edit (`/root/reference/main.py:215-230`).
 
     ``store=True`` mirrors the reference, whose edit controllers extend
-    AttentionStore and always accumulate ≤32²-pixel maps (`main.py:162`);
-    pass False to trade observability for store bandwidth.
+    AttentionStore and always accumulate ≤32²-pixel maps (`main.py:162`).
+    Here the maps are accumulated for a reader only
+    (``AttnLayout.for_readers``): ask for them with
+    ``text2image(return_store=True)``; otherwise the flag costs nothing.
     ``self_max_pixels`` defaults to the paper's 16² (`main.py:170`), taken
     against the model where the controller meets it (``_or_paper``: SD-2.1's
     24²)."""

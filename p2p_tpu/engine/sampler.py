@@ -316,9 +316,12 @@ def _store_state(layout: AttnLayout, b: int,
                  controller: Optional[Controller]) -> StoreState:
     """The controller's zeroed attention store for ``b`` conditional rows
     (f32 whatever the sampling dtype: it accumulates over the steps), ()
-    where it keeps none. Its bytes are noted for the launch being traced
-    (``Launch.store_bytes``): at a 96² latent the store's bound scales to
-    48² and five self sites hold (B, 10, 2304, 2304) each."""
+    where it keeps none or ``layout`` has no slot because nobody reads one
+    (``AttnLayout.for_readers``). Its bytes are noted for the launch being
+    traced (``Launch.store_bytes``): for a caller that takes the store back
+    at a 96² latent the bound scales to 48² and five self sites hold
+    (B, 10, 2304, 2304) each; for LocalBlend alone it is the blend's cross
+    maps."""
     if controller is None or not controller.needs_store:
         return ()
     state = init_store_state(layout, b, dtype=jnp.float32)
@@ -988,6 +991,7 @@ def text2image(
                 from ..models.config import unet_layout
                 layout = unet_layout(cfg.unet)
             controller = layout.resolve(controller)
+            layout = layout.for_readers(controller, return_store)
             if rng is None:
                 rng = jax.random.PRNGKey(0)
 

@@ -12,7 +12,12 @@ controller's store state through the sites in call order. Sites the controller
 provably never touches (``controller_touches`` is False) run fused attention —
 no probability tensor exists in the compiled program; touched sites
 materialize f32 probabilities, route them through
-``apply_attention_control``, then finish ``probs @ v``.
+``apply_attention_control``, then finish ``probs @ v``. Touched means edited
+(every cross site under an edit, self sites up to ``self_max_pixels``) or
+stored, and a site is stored only where the layout gives it a slot: for a
+reader of the store (``AttnLayout.for_readers``: the caller under
+``return_store=True``, LocalBlend's cross maps), never for
+``Controller.store`` alone.
 
 All tensors NHWC; params f32; compute dtype is the caller's (`x.dtype`).
 """

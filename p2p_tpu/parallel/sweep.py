@@ -182,6 +182,8 @@ def sweep(
             from ..models.config import unet_layout
             layout = unet_layout(cfg.unet)
         controllers = layout.resolve(controllers)
+        # a sweep returns no store: slots for LocalBlend's maps alone
+        layout = layout.for_readers(controllers)
         if uncond_per_step is not None:
             if scheduler != "ddim":
                 # Same constraint as text2image: the embeddings are optimized
@@ -353,8 +355,9 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
                 schedule=None):
     """Shared wrapper plumbing for the two pool entry points: schedule,
     resolved+validated gate (a pool program needs both phases non-empty),
-    staged guidance (replicated over ``mesh`` when given), layout, and the
-    controllers with their defaults taken against it (``AttnLayout.resolve``).
+    staged guidance (replicated over ``mesh`` when given), the controllers
+    with their defaults taken against the layout (``AttnLayout.resolve``) and
+    the layout with store slots for their readers (``AttnLayout.for_readers``).
     ``schedule`` is a reuse-schedule spec/table (ISSUE 15): its
     ``cfg_gate`` is the pool boundary; uniform tables normalize onto the
     plain gate."""
@@ -364,6 +367,9 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
             from ..models.config import unet_layout
             layout = unet_layout(cfg.unet)
         controllers = layout.resolve(controllers)
+        # as in ``sweep``; phase 1's full controller and phase 2's slice name
+        # the same blend, so both programs get the hand-off store's shapes
+        layout = layout.for_readers(controllers)
         dsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
                                                 kind=scheduler)
         num_scan = dsched.timesteps.shape[0]

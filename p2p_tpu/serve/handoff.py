@@ -173,11 +173,12 @@ def carry_template(pipe, prep):
 
     b = len(prep.request.prompts)
     cfg = pipe.config
-    layout = unet_layout(cfg.unet)
-    lat = jnp.zeros((b,) + pipe.latent_shape, jnp.float32)
     ctrl = prep.controller
-    state = (init_store_state(layout, b)
-             if (ctrl is not None and ctrl.needs_store) else ())
+    # the pool programs' layout (``parallel.sweep._phase_args``): the store
+    # that crosses the hand-off holds LocalBlend's maps and nothing else
+    layout = unet_layout(cfg.unet).for_readers(ctrl)
+    lat = jnp.zeros((b,) + pipe.latent_shape, jnp.float32)
+    state = init_store_state(layout, b)
     sched = getattr(prep, "schedule", None)
     if sched is not None:
         # Per-site reuse schedule (ISSUE 15): the hand-off cache holds one

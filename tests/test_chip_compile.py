@@ -24,6 +24,12 @@ budget. These compiles can, at about a second each and no chip time:
     refuses a Mosaic kernel that is not wrapped per device, which is how
     ``serve --mesh dp=4`` first failed on four chips.
 
+(e) the whole sampling program of the cell `sd21.edit-replace`
+    (``engine.sampler._text2image_jit`` at SD-2.1's sizes, the cell's
+    controller, no store taken back): the five 48x48 self sites whose store
+    nobody reads are flash calls at (4, 10, 2304, 64) beside the five at
+    9,216 keys, and no (4, 10, 2304, 2304) tensor exists (about two minutes).
+
 Nothing runs, so nothing here says anything about results or times. The
 topology is described inside a module-scoped fixture (never at import: the
 TPU library belongs to one process, and every xdist worker imports this
@@ -312,3 +318,57 @@ def test_fused_edit_kernel_compiles_under_a_dp_mesh(topo, dp_mesh,
         return _groups(one_group, dp_mesh)(ctrl_g, q, k, v)
 
     _compile(program, ctrl_g, q, k, v, step)
+
+
+def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
+                                                                   monkeypatch):
+    """`sd21.edit-replace` sends ``store=True`` and takes no store back, so
+    the store has no reader and no slot (``AttnLayout.for_readers``): the five
+    48x48 self sites above the 24x24 edit window are untouched and reach
+    ``nn.fused_attention``, which gives 2,304 keys the geometry (768, 2304,
+    1152). The compiled program then has ten flash calls in its loop and
+    neither the probabilities (4, 10, 2304, 2304) nor the store's
+    (2, 10, 2304, 2304) anywhere."""
+    import re
+
+    from p2p_tpu.engine.sampler import _text2image_jit
+    from p2p_tpu.models import init_unet
+    from p2p_tpu.models import vae as vae_mod
+    from p2p_tpu.ops import schedulers as sched_mod
+
+    # the program asks the backend, which is the CPU's here, not the device
+    # it is compiled for
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+    cfg = SD21
+    tok = HashWordTokenizer(vocab_size=cfg.text.vocab_size,
+                            model_max_length=cfg.text.max_length)
+    ctrl = factory.attention_replace(
+        ["a cat riding a bike", "a dog riding a bike"], STEPS, 0.8, 0.4, tok,
+        self_max_pixels=24 * 24, max_len=cfg.text.max_length, store=True)
+    layout = unet_layout(cfg.unet)
+    ctrl = layout.resolve(ctrl)
+    layout = layout.for_readers(ctrl)          # as ``text2image`` does
+    assert layout.num_store_slots == 0
+    key = jax.random.PRNGKey(0)
+    unet = jax.eval_shape(lambda: init_unet(key, cfg.unet))
+    vae = jax.eval_shape(lambda: vae_mod.init_vae(key, cfg.vae))
+    sched = sched_mod.schedule_from_config(STEPS, cfg.scheduler, kind="ddim")
+    side = cfg.latent_size
+    ctx = jnp.zeros((2, cfg.unet.context_len, cfg.unet.context_dim))
+    args = _shapes((unet, vae, sched, ctx, ctx,
+                    jnp.zeros((2, side, side, cfg.unet.in_channels)), ctrl,
+                    jnp.float32(7.5)), one_chip)
+    unet, vae, sched, cond, uncond, latents, ctrl, scale = args
+    text = _text2image_jit.lower(
+        unet, vae, cfg, layout, sched, "ddim", cond, uncond, latents, ctrl,
+        scale, None, False).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    by_shape = {shape: sum(1 for line in kernels
+                           if re.search(r"= f32\[%s\]" % shape, line))
+                for shape in ("4,10,2304,64", "4,5,9216,64", "2,1,9216,512")}
+    # ten self sites in the loop, and the VAE's mid attention outside it
+    assert by_shape == {"4,10,2304,64": 5, "4,5,9216,64": 5, "2,1,9216,512": 1}
+    assert len(kernels) == 11
+    assert "f32[4,10,2304,2304]" not in text
+    assert "f32[2,10,2304,2304]" not in text

@@ -361,7 +361,10 @@ def test_scope_index_is_built_when_asked_and_once(tiny_pipe, monkeypatch, site):
 def test_a_launch_keeps_how_its_self_sites_ran(tiny_pipe, site):
     """Counted while the program was traced: the controller's sites as
     ``edited``, the others by the implementation ``nn.fused_attention`` chose
-    from their shape (no kernel off the TPU, and no tiny site has 1024 keys)."""
+    from their shape (no kernel off the TPU, and no tiny site has 1024 keys).
+    None of these launches returns a store, so ``store=True`` (the
+    ``text2image`` site's) holds no site: the count is the ``store=False``
+    controller's."""
     from p2p_tpu.controllers.base import controller_touches
 
     module, launch_site, _ = LAUNCH_SITES[site]
@@ -381,6 +384,32 @@ def test_a_launch_keeps_how_its_self_sites_ran(tiny_pipe, site):
     # each site with its keys and head width; no kernel, so no geometry
     assert {(s.keys, s.head_dim, s.geometry) for s in launch.self_sites.values()} == {
         (m.pixels, m.channels // m.heads, None) for m in metas}
+
+
+@pytest.mark.parametrize("return_store", [True, False],
+                         ids=["store taken back", "no reader"])
+def test_a_stored_self_site_is_the_controllers_only_for_a_reader(tiny_pipe, return_store):
+    """``store=True`` above a 4² edit window: the three 8² self sites are
+    counted ``edited`` (and the store has bytes) for the caller that takes the
+    store back, and are ``fused_attention``'s when nobody reads it
+    (``AttnLayout.for_readers``); the launch says which it was."""
+    from p2p_tpu.controllers.base import controller_touches
+
+    ctrl = factory.attention_replace(
+        PROMPTS, STEPS, 0.8, 0.4, tiny_pipe.tokenizer, self_max_pixels=4 * 4,
+        max_len=TINY.text.max_length, store=True)
+    _, _, store = text2image(tiny_pipe, PROMPTS, ctrl, num_steps=STEPS,
+                             return_store=return_store)
+    launch = launches.programs("jit__text2image_jit")[-1]
+    layout = unet_layout(TINY.unet).for_readers(ctrl, return_store)
+    assert launch.args[3] == layout
+    metas = [m for m in layout.metas if not m.is_cross]
+    edited = sum(1 for m in metas if controller_touches(ctrl, m))
+    assert edited == (4 if return_store else 1)
+    assert launch.self_site_counts == {"edited": edited, "einsum": len(metas) - edited}
+    assert launch.store_bytes == sum(s.size * 4 for s in store)
+    assert (launch.store_bytes > 0) == return_store
+    assert f"controller store {launch.store_bytes} bytes" in launch.describe_sites()
 
 
 def test_a_stale_cached_executable_is_compiled_once_more(monkeypatch):

@@ -307,45 +307,76 @@ def test_the_cli_and_serve_leave_the_defaults_to_the_model():
 
 # -- what a launch keeps (tracing) ------------------------------------------
 
-def test_a_launch_keeps_each_self_site_and_the_stores_bytes(tiny_v):
+@pytest.mark.parametrize("return_store", [True, False], ids=["store taken back", "no reader"])
+def test_a_launch_keeps_each_self_site_and_the_stores_bytes(tiny_v, return_store):
+    """The store follows its readers (``AttnLayout.for_readers``): a caller
+    that takes it back holds every site under the bound, (12 // 2)²; with no
+    reader ``store=True`` keeps nothing, and the 6² self sites above the 3²
+    edit window are ``fused_attention``'s like the 12² ones."""
     _, pipe, _, _ = tiny_v
-    _program(_named(pipe, "tiny-v-launch", "v_prediction"))
+    pipe = _named(pipe, f"tiny-v-launch-{return_store}", "v_prediction")
+    ctrl = factory.attention_replace(
+        list(PROMPTS), EDIT["num_steps"], EDIT["cross_replace_steps"],
+        EDIT["self_replace_steps"], pipe.tokenizer, self_max_pixels=3 * 3,
+        max_len=pipe.config.text.max_length, store=True)
+    _, _, store = text2image(pipe, list(PROMPTS), ctrl, num_steps=EDIT["num_steps"],
+                             rng=jnp.asarray(KEY, jnp.uint32), return_store=return_store)
     launch = launches.programs("jit__text2image_jit")[-1]
     layout = unet_layout(TINY_V.unet)
     selfs = [m for m in layout.metas if not m.is_cross]
     assert sorted(launch.self_sites) == [m.layer_idx for m in selfs]
     for m in selfs:
         site = launch.self_sites[m.layer_idx]
-        # the store's bound is (12 // 2)²: the 12² sites stay untouched
-        how = "edited" if m.pixels <= 36 else "einsum"
+        held = m.pixels <= (36 if return_store else 9)
         assert (site.keys, site.head_dim, site.how, site.geometry) == (
-            m.pixels, 16, how, None)
-    assert launch.self_site_counts == {"einsum": 3, "edited": 4}
+            m.pixels, 16, "edited" if held else "einsum", None)
+    assert launch.self_site_counts == (
+        {"einsum": 3, "edited": 4} if return_store else {"einsum": 6, "edited": 1})
     # (B, heads, P, K) float32 of every stored site, B = 2 conditional rows
-    want = sum(2 * m.heads * m.pixels * m.key_len * 4 for m in layout.stored_metas())
-    assert launch.store_bytes == want > 0
+    want = (sum(2 * m.heads * m.pixels * m.key_len * 4 for m in layout.stored_metas())
+            if return_store else 0)
+    assert launch.store_bytes == want == sum(s.size * 4 for s in store)
+    assert (want > 0) == return_store
     assert "controller store %d bytes" % want in launch.describe_sites()
     from p2p_tpu.obs import metrics
 
     gauge = metrics.registry().get("launch_store_bytes")
     assert gauge.labels(module="jit__text2image_jit").value == want
     # a program without a controller keeps no store
-    text2image(_named(pipe, "tiny-v-plain", "v_prediction"), list(PROMPTS), None,
-               num_steps=2, rng=jax.random.PRNGKey(0))
+    text2image(_named(pipe, f"tiny-v-plain-{return_store}", "v_prediction"), list(PROMPTS),
+               None, num_steps=2, rng=jax.random.PRNGKey(0), return_store=return_store)
     assert launches.programs("jit__text2image_jit")[-1].store_bytes == 0
 
 
-def test_sd21_store_and_kernel_sites_by_the_layout():
-    """What `sd21.edit-replace` runs, from the layout alone: the store's bound
+@pytest.mark.parametrize("return_store", [True, False], ids=["store taken back", "no reader"])
+def test_sd21_store_and_kernel_sites_by_the_layout(return_store):
+    """What `sd21.edit-replace`'s controller (window 24², ``store=True``) runs,
+    from the layout alone. For a caller that takes the store back the bound
     scales to 48², so of 16 self sites the controller holds eleven and five
-    are left to ``fused_attention`` at 9,216 keys."""
-    layout = unet_layout(SD21.unet)
-    assert layout.store_cfg.max_pixels == 48 * 48
+    are left to ``fused_attention`` at 9,216 keys. The cell takes no store
+    back: the five 48² sites are released to the kernel as well."""
+    from p2p_tpu.controllers.base import init_store_state
+    from p2p_tpu.models import nn
+    from p2p_tpu.utils.tokenizer import HashWordTokenizer
+
+    whole = unet_layout(SD21.unet)
+    assert whole.store_cfg.max_pixels == 48 * 48
+    ctrl = factory.attention_replace(
+        list(PROMPTS), 50, 0.8, 0.4, HashWordTokenizer(), self_max_pixels=24 * 24,
+        max_len=SD21.text.max_length, store=True)
+    layout = whole.for_readers(whole.resolve(ctrl), return_store)
     selfs = [m for m in layout.metas if not m.is_cross]
     assert sorted({(m.pixels, m.heads, m.channels // m.heads) for m in selfs}) == [
         (144, 20, 64), (576, 20, 64), (2304, 10, 64), (9216, 5, 64)]
-    assert sum(m.store_slot is not None for m in selfs) == 11
-    assert [m.pixels for m in selfs if m.store_slot is None] == [9216] * 5
+    assert sum(m.store_slot is not None for m in selfs) == (11 if return_store else 0)
+    free = [m.pixels for m in selfs if not controller_touches(ctrl, m)]
+    assert free == ([9216] * 5 if return_store
+                    else [9216, 9216, 2304, 2304, 2304, 2304, 2304, 9216, 9216, 9216])
+    assert {nn.flash_block(p, 64, 4) for p in free} == (
+        {(512, 3072, 1536)} if return_store else {(512, 3072, 1536), (768, 2304, 1152)})
+    # the store's bytes for the two conditional rows: `Launch.store_bytes`
+    state = jax.eval_shape(lambda: init_store_state(layout, 2))
+    assert sum(s.size * 4 for s in state) == (2_500_323_840 if return_store else 0)
     sched = SchedulerConfig(prediction_type="v_prediction")
     assert SD21.scheduler == sched and SD21.unet.head_dim == 64
 
