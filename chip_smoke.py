@@ -366,7 +366,7 @@ def phase_kernels(clock) -> None:
         assert not bad, f"kernel vs reference at single sites: {bad}"
 
         # One edit group through parallel.sweep — the program the serve
-        # engine and bench.py run, and the entry point that returns the
+        # engine runs, and the entry point that returns the
         # FINAL latents (text2image hands back the initial x_T, which would
         # compare equal whatever the kernels did).
         def lead(x):

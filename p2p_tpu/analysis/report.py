@@ -49,14 +49,12 @@ SECTIONS = ("ast", "contracts", "collectives", "cost", "wal")
 #: Default lint targets, relative to the repo root: the package plus the
 #: drivers that embed repo invariants. tests/ is deliberately out — tests
 #: exercise anti-patterns on purpose (fixture snippets for these very
-#: rules would self-flag). tools/profiling/ is out too: those are
-#: standalone on-accelerator scratch harnesses whose module scope *is*
-#: their main() — import-time jax is their point, not a hazard.
+#: rules would self-flag).
 DEFAULT_LINT_PATHS = ("p2p_tpu", "tools/quality_gate.py",
                       "tools/jaxcheck.py", "tools/loadgen.py",
                       "tools/chaos_drill.py", "tools/check_checkpoint.py",
                       "tools/parity_real_weights.py", "tools/perfscope.py",
-                      "bench.py", "__graft_entry__.py")
+                      "__graft_entry__.py")
 
 DEFAULT_BASELINE = os.path.join("tools", "jaxcheck_baseline.json")
 

@@ -1013,8 +1013,7 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--single-pool", action="store_true",
                    help="disable phase-disaggregated continuous batching: "
                         "gated requests run their monolithic program in "
-                        "one pool (the pre-disaggregation engine; the A/B "
-                        "baseline bench.py compares against)")
+                        "one pool (the pre-disaggregation engine)")
     s.add_argument("--schedule", default=None, metavar="FILE",
                    help="default per-site reuse schedule artifact (JSON, "
                         "e.g. tools/schedules/default_v1.json) applied to "

@@ -26,17 +26,21 @@ the serve engine's phase boundary: the two-pool hand-off machinery
 per-site cache state with no new hand-off plumbing.
 
 The **uniform** schedule — every cross site reused from step ``g``, no self
-site ever reused, CFG dropped at ``g`` — is semantically ``gate=g``;
-:func:`ReuseSchedule.uniform_gate` detects it and callers normalize it back
-onto the exact PR-1 gate path, so uniform schedules are *bitwise-identical*
-to ``gate=g`` by construction (and pool with plain gated requests). The
-segmented executor reproducing the gate path on a uniform table is pinned
-separately (tests/test_schedule.py), the PR-6 split-equals-monolith idiom.
+site ever reused, CFG dropped at ``g`` — is ``gate=g``:
+:meth:`ReuseSchedule.uniform` builds it from a gate step (``g == S``: nothing
+gated, nothing cached — the ungated run), and under the jitted entry points
+every run is described by one table. :func:`ReuseSchedule.uniform_gate` is
+the inverse, used where a user's schedule is resolved
+(``engine.sampler.resolve_reuse``, ``serve.request.prepare``): a uniform table
+is keyed as its gate, so it shares one compile key and one pool with plain
+``gate=g`` requests.
 
 Execution model: the scan is cut into contiguous **segments** over which the
 per-site action vector is constant; each segment is one ``lax.scan`` with a
-static :func:`SitePlan` (see ``engine.sampler._scheduled_phase1/2``).
-Compile time grows with the number of distinct flip steps, not with S.
+static site plan (``engine.sampler._phase1_scan`` / ``_phase2_scan``). The
+uniform table cuts into one phase-1 segment whose cross sites ``store`` and
+one phase-2 segment whose cross sites ``use``. Compile time grows with the
+number of distinct flip steps, not with S.
 
 Resblock-level inheritance (the remaining A-SDM axis) is deliberately out of
 scope: resnets are not layout sites, so scheduling them is a layout change —
@@ -120,22 +124,25 @@ class ReuseSchedule:
         does it cross the serve engine's two-pool phase boundary?"""
         return self.cfg_gate < self.steps
 
+    @classmethod
+    def uniform(cls, gate: int, steps: int, layout) -> "ReuseSchedule":
+        """``gate=g`` as a table: CFG drops at ``gate``, every cross site is
+        served from its cache from there on, no self site ever is.
+        ``gate == steps`` is the ungated run: nothing dropped, nothing
+        cached. ``layout`` gives the site counts."""
+        n_cross = sum(1 for m in layout.metas if m.is_cross)
+        return cls(steps=steps, cfg_gate=gate, cross=(gate,) * n_cross,
+                   selfa=(steps,) * (len(layout.metas) - n_cross))
+
     @property
     def uniform_gate(self) -> Optional[int]:
-        """The gate step this schedule is exactly equivalent to, or None.
-
-        Uniform-at-g means: CFG drops at g, every cross site flips to its
-        cache at g, no self site is ever reused — the PR-1 ``gate=g``
-        program. ``g == steps`` (nothing gated, nothing cached) is the
-        ungated program, returned as ``steps`` (callers map it to
-        ``gate=None``). Callers normalize uniform schedules onto the gate
-        path so they are bitwise-identical to — and pool with — plain
-        gated requests."""
+        """The gate step this table is :meth:`uniform` of, or None.
+        ``steps`` is the ungated run. Where a user's schedule is resolved, a
+        uniform table is keyed as its gate, so that it compiles and pools
+        with plain ``gate=g`` requests."""
         g = self.cfg_gate
         if any(r != self.steps for r in self.selfa):
             return None
-        if g == self.steps:
-            return g if all(r == self.steps for r in self.cross) else None
         return g if all(r == g for r in self.cross) else None
 
     def key(self) -> Tuple:
@@ -154,22 +161,13 @@ class ReuseSchedule:
                    selfa=tuple(selfa))
 
     def sites_cached(self) -> Dict[str, int]:
-        """How many sites the schedule ever serves from cache, by kind —
-        the bench ``gate.schedule`` sub-record's histogram source."""
+        """How many sites the schedule ever serves from cache, by kind."""
         return {
             "cross": sum(1 for r in self.cross if r < self.steps),
             "self": sum(1 for r in self.selfa if r < self.steps),
             "cross_sites": len(self.cross),
             "self_sites": len(self.selfa),
         }
-
-    def cached_site_steps_fraction(self) -> float:
-        """Fraction of all (site, step) cells served from cache — the
-        scalar 'how much compute does this table skip' summary."""
-        total = (len(self.cross) + len(self.selfa)) * self.steps
-        saved = sum(self.steps - r for r in self.cross)
-        saved += sum(self.steps - r for r in self.selfa)
-        return saved / total if total else 0.0
 
 
 def phase1_view(sched: ReuseSchedule) -> ReuseSchedule:
@@ -388,6 +386,13 @@ class Segment:
     cfg: bool                  # uncond batch half present (CFG active)
     plan: Tuple[str, ...]
 
+    @property
+    def stores(self) -> bool:
+        """Does a site write its cache slot in this segment? Then the cache
+        rides the scan's carry; otherwise it is loop-invariant and the
+        scan's body closes over it (engine.sampler)."""
+        return any(m in (MODE_STORE, MODE_STORE_ALL) for m in self.plan)
+
 
 def _reuse_step(sched: ReuseSchedule, meta, cross_idx: int,
                 self_idx: int) -> int:
@@ -459,7 +464,7 @@ def lower_kernel_plan(layout, sched: ReuseSchedule, controller, kernels,
     ``kernels.dispatch.site_variant`` vocabulary (``use`` / ``flash`` /
     ``fused-edit`` / ``materialized``). Pure trace-time introspection over
     the same static inputs the executors consume: what
-    ``_scheduled_phase1/2`` + ``apply_unet`` will actually lower, without
+    ``_phase1_scan`` / ``_phase2_scan`` + ``apply_unet`` will actually lower, without
     building the program. ``use`` segments lower to the cache side-input
     (no attention math); ``store``/``store_all`` segments capture the site
     output *after* whichever attention variant runs — the fused

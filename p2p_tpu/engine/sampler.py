@@ -45,12 +45,13 @@ from ..controllers.base import (
 from ..models import vae as vae_mod
 from ..models.config import PipelineConfig
 from ..models.text_encoder import apply_text_encoder
-from ..models.unet import apply_unet, init_attn_cache
+from ..models.unet import apply_unet
 from ..obs import launches
 from ..obs.spans import span
 from ..ops import schedulers as sched_mod
 from ..utils import progress as progress_mod
 from ..utils.tokenizer import Tokenizer, pad_ids
+from . import reuse as reuse_mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,10 +212,12 @@ def resolve_reuse(gate, schedule, layout, num_scan: int,
 
     ``schedule`` is a reuse-schedule spec (JSON dict), an already-resolved
     ``engine.reuse.ReuseSchedule``, or None. The two knobs are mutually
-    exclusive — a schedule IS a generalized gate. A schedule that resolves
-    to the UNIFORM table normalizes to a plain gate step (``reuse=None``):
-    it is then bitwise-identical to — and compiles/pools as — today's
-    ``gate=g`` program. Non-uniform schedules return the static table; the
+    exclusive — a schedule IS a generalized gate. This pair is what the
+    jitted entry points and the serve layer's compile keys are keyed on, so
+    one run has one form: a schedule that resolves to the UNIFORM table is
+    returned as its gate step (``reuse=None``) and compiles and pools as
+    ``gate=g`` does (under the entry points both are the same table again:
+    :func:`_schedule_of`). Other schedules return the static table; the
     per-site window-conflict warning fires here (the generalized
     ``warn_gate_truncation``)."""
     if schedule is None:
@@ -223,8 +226,6 @@ def resolve_reuse(gate, schedule, layout, num_scan: int,
         raise ValueError("gate and schedule are mutually exclusive: a "
                          "reuse schedule generalizes the gate (its "
                          "cfg_gate is the phase boundary)")
-    from . import reuse as reuse_mod
-
     sched = reuse_mod.resolve_schedule(schedule, layout, num_scan,
                                        controller)
     u = sched.uniform_gate
@@ -343,185 +344,20 @@ def _make_ms_step(schedule: sched_mod.DiffusionSchedule, scheduler_kind: str):
     return ms_step
 
 
-def _make_scheduled_body(
-    unet_params: Any,
-    cfg: PipelineConfig,
-    layout: AttnLayout,
-    schedule: sched_mod.DiffusionSchedule,
-    scheduler_kind: str,
-    context: jax.Array,
-    b: int,
-    controller: Optional[Controller],
-    guidance_scale: jax.Array,
-    emit: bool,
-    progress: bool,
-    sp: Optional["SpConfig"],
-    *,
-    cfg_active: bool,
-    site_plan: Tuple[str, ...],
-    resid_const: Optional[jax.Array] = None,
-    state_const: Tuple = (),
-    kernels=None,
-):
-    """One reuse-schedule SEGMENT's scan body (engine.reuse): the per-site
-    action vector ``site_plan`` is constant over the segment, so each
-    segment compiles as one ``lax.scan``.
-
-    ``cfg_active`` segments run the CFG-doubled U-Net with full controller
-    hooks at computed sites, capturing the guidance residual each step —
-    the latent math of ``_make_phase1_body(capture=True)``. Past the CFG
-    boundary the body is the single-branch extrapolation of
-    ``_phase2_scan``'s ``body2`` (``resid_const``/``state_const`` are the
-    frozen hand-off values), with the cache riding the carry so sites that
-    flip to reuse *inside* phase 2 can keep storing until their step."""
-    ms_step = _make_ms_step(schedule, scheduler_kind)
-
-    def body(carry, scan_in):
-        step, t = scan_in
-        if cfg_active:
-            latents, state, ms, cache, resid = carry
-            progress_mod.emit_step(emit, step, phase="phase1",
-                                   report=progress)
-            with jax.named_scope("sampler/cfg"):
-                latent_in = jnp.concatenate([latents] * 2, axis=0)
-            eps, state, cache = apply_unet(
-                unet_params, cfg.unet, latent_in, t, context,
-                layout=layout, controller=controller, state=state,
-                step=step, sp=sp, attn_cache=cache, site_plan=site_plan,
-                kernels=kernels)
-            with jax.named_scope("sampler/cfg"):
-                eps_uncond, eps_text = eps[:b], eps[b:]
-                resid = eps_text - eps_uncond
-                eps = eps_uncond + guidance_scale * resid
-            with jax.named_scope("sampler/scheduler_step"):
-                eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-                ms, latents = ms_step(ms, eps, t, latents)
-            with jax.named_scope("sampler/controller_step"):
-                latents = apply_step_callback(controller, layout, state,
-                                              latents, step)
-            return (latents, state, ms, cache, resid), None
-        latents, ms, cache = carry
-        progress_mod.emit_step(emit, step, phase="phase2", report=progress)
-        eps_text, _, cache = apply_unet(
-            unet_params, cfg.unet, latents, t, context,
-            layout=layout, controller=None, state=(), step=step, sp=sp,
-            attn_cache=cache, site_plan=site_plan, kernels=kernels)
-        with jax.named_scope("sampler/cfg"):
-            eps = eps_text + (guidance_scale - 1.0) * resid_const
-        with jax.named_scope("sampler/scheduler_step"):
-            eps = sched_mod.to_epsilon(schedule, eps, t, latents)
-            ms, latents = ms_step(ms, eps, t, latents)
-        with jax.named_scope("sampler/controller_step"):
-            latents = apply_step_callback(controller, layout, state_const,
-                                          latents, step)
-        return (latents, ms, cache), None
-
-    return body
+def _schedule_of(gate: Optional[int], reuse, layout: AttnLayout,
+                 num_scan: int):
+    """The one description of a run under the jitted entry points: an
+    ``engine.reuse.ReuseSchedule``. ``gate=g`` is the uniform table, no gate
+    the table with ``cfg_gate == steps`` and nothing cached."""
+    if reuse is None:
+        return reuse_mod.ReuseSchedule.uniform(
+            num_scan if gate is None else gate, num_scan, layout)
+    assert gate is None or gate == reuse.cfg_gate, (gate, reuse.cfg_gate)
+    assert reuse.steps == num_scan, (reuse.steps, num_scan)
+    return reuse
 
 
-def _scheduled_phase1(
-    unet_params: Any,
-    cfg: PipelineConfig,
-    layout: AttnLayout,
-    schedule: sched_mod.DiffusionSchedule,
-    scheduler_kind: str,
-    context: jax.Array,            # (2B, L, D) [uncond; cond]
-    latents: jax.Array,            # (B, h, w, c)
-    controller: Optional[Controller],
-    guidance_scale: jax.Array,
-    *,
-    reuse,                         # engine.reuse.ReuseSchedule (static)
-    progress: bool = False,
-    metrics: bool = False,
-    sp: Optional["SpConfig"] = None,
-    kernels=None,                  # kernels.KernelConfig (static)
-) -> PhaseCarry:
-    """The generalized phase-1 executor: steps ``[0, cfg_gate)`` under full
-    CFG, cut into constant-plan segments (engine.reuse.segments). Sites
-    whose reuse step falls inside this range flip to their cache
-    mid-phase; the rest capture exactly like ``_phase1_scan``. Returns the
-    :class:`PhaseCarry` with full-batch leaves sliced to the cond half —
-    the same hand-off pytree the uniform gate produces, just with the
-    schedule's leaf set."""
-    from . import reuse as reuse_mod
-
-    sched1 = reuse_mod.phase1_view(reuse)
-    emit = progress or metrics
-    b = latents.shape[0]
-    state = _store_state(layout, b, controller)
-    ms_state = sched_mod.init_multistep_state(scheduler_kind, latents.shape,
-                                              latents.dtype)
-    num_scan = schedule.timesteps.shape[0]
-    assert sched1.steps == num_scan, (sched1.steps, num_scan)
-    steps = jnp.arange(num_scan, dtype=jnp.int32)
-    cache = reuse_mod.init_schedule_cache(layout, sched1, b, phase=1,
-                                          dtype=latents.dtype)
-    resid = jnp.zeros_like(latents)
-    carry = (latents, state, ms_state, cache, resid)
-    for seg in reuse_mod.segments(layout, sched1, phase=1):
-        body = _make_scheduled_body(unet_params, cfg, layout, schedule,
-                                    scheduler_kind, context, b, controller,
-                                    guidance_scale, emit, progress, sp,
-                                    cfg_active=True, site_plan=seg.plan,
-                                    kernels=kernels)
-        carry, _ = jax.lax.scan(
-            body, carry,
-            (steps[seg.start:seg.stop],
-             schedule.timesteps[seg.start:seg.stop]))
-    latents, state, ms_state, cache, resid = carry
-    cache = reuse_mod.slice_cache_to_cond(layout, sched1, cache, b)
-    return PhaseCarry(latents=latents, resid=resid, cache=cache,
-                      ms=ms_state, state=state)
-
-
-def _scheduled_phase2(
-    unet_params: Any,
-    cfg: PipelineConfig,
-    layout: AttnLayout,
-    schedule: sched_mod.DiffusionSchedule,
-    scheduler_kind: str,
-    context_cond: jax.Array,       # (B, L, D) — the uncond half is GONE
-    carry: PhaseCarry,
-    controller: Optional[Controller],
-    guidance_scale: jax.Array,
-    *,
-    reuse,                         # engine.reuse.ReuseSchedule (static)
-    progress: bool = False,
-    metrics: bool = False,
-    sp: Optional["SpConfig"] = None,
-    kernels=None,                  # kernels.KernelConfig (static)
-) -> jax.Array:
-    """The generalized phase-2 executor: steps ``[cfg_gate, S)`` off a
-    :class:`PhaseCarry`, segmented so sites may keep computing
-    single-branch past the CFG boundary and flip to reuse at their own
-    step (their cache slots keep storing until then). The uniform table —
-    every cross site reused from the boundary — reduces to exactly one
-    segment with every cross site in ``use``: ``_phase2_scan``'s body."""
-    from . import reuse as reuse_mod
-
-    sched2 = reuse_mod.phase2_view(reuse)
-    emit = progress or metrics
-    num_scan = schedule.timesteps.shape[0]
-    assert sched2.steps == num_scan, (sched2.steps, num_scan)
-    steps = jnp.arange(num_scan, dtype=jnp.int32)
-    c2 = (carry.latents, carry.ms, carry.cache)
-    for seg in reuse_mod.segments(layout, sched2, phase=2):
-        body = _make_scheduled_body(unet_params, cfg, layout, schedule,
-                                    scheduler_kind, context_cond,
-                                    context_cond.shape[0], controller,
-                                    guidance_scale, emit, progress, sp,
-                                    cfg_active=False, site_plan=seg.plan,
-                                    resid_const=carry.resid,
-                                    state_const=carry.state,
-                                    kernels=kernels)
-        c2, _ = jax.lax.scan(
-            body, c2,
-            (steps[seg.start:seg.stop],
-             schedule.timesteps[seg.start:seg.stop]))
-    return c2[0]
-
-
-def _make_phase1_body(
+def _make_cfg_body(
     unet_params: Any,
     cfg: PipelineConfig,
     layout: AttnLayout,
@@ -535,19 +371,25 @@ def _make_phase1_body(
     emit: bool,
     progress: bool,
     sp: Optional["SpConfig"],
-    capture: bool,
+    *,
+    site_plan: Tuple[str, ...],
+    held: Optional[Tuple],
     kernels=None,
 ):
-    """The CFG scan body — phase 1 of a gated scan (``capture=True``:
-    carries the AttnCache + CFG residual) or the whole ungated scan
-    (``capture=False``: the exact pre-gate program)."""
+    """The CFG step as the scan body of one reuse-schedule SEGMENT
+    (engine.reuse): the batch-doubled U-Net under the segment's constant
+    per-site ``site_plan`` with full controller hooks at computed sites,
+    guidance, the solver, the controller's step callback.
+
+    The carry is ``(latents, state, ms, cache, resid)``, and holds only
+    what the plan makes it hold: ``held`` is the cache the body closes over
+    where no site of the segment stores (the carry's slot is then ``()``),
+    None where the carry has it; ``resid`` is None in the carry where no
+    phase 2 follows to read the guidance residual."""
     ms_step = _make_ms_step(schedule, scheduler_kind)
 
     def body(carry, scan_in):
-        if capture:
-            latents, state, ms, cache, resid = carry
-        else:
-            latents, state, ms = carry
+        latents, state, ms, cache, resid = carry
         step, t = scan_in
         progress_mod.emit_step(emit, step, phase="phase1", report=progress)
         ctx = context
@@ -565,142 +407,70 @@ def _make_phase1_body(
                                       context[:b].shape),
                      context[b:]], axis=0)
             latent_in = jnp.concatenate([latents] * 2, axis=0)
-        if capture:
-            eps, state, cache = apply_unet(
-                unet_params, cfg.unet, latent_in, t, ctx,
-                layout=layout, controller=controller, state=state, step=step,
-                sp=sp, attn_cache=cache, cache_mode="store", kernels=kernels)
-        else:
-            eps, state = apply_unet(
-                unet_params, cfg.unet, latent_in, t, ctx,
-                layout=layout, controller=controller, state=state, step=step,
-                sp=sp, kernels=kernels)
+        eps, state, out_cache = apply_unet(
+            unet_params, cfg.unet, latent_in, t, ctx,
+            layout=layout, controller=controller, state=state, step=step,
+            sp=sp, attn_cache=cache if held is None else held,
+            site_plan=site_plan, kernels=kernels)
         with jax.named_scope("sampler/cfg"):
             eps_uncond, eps_text = eps[:b], eps[b:]
-            if capture:
-                resid = eps_text - eps_uncond
-                eps = eps_uncond + guidance_scale * resid
-            else:
-                eps = eps_uncond + guidance_scale * (eps_text - eps_uncond)
+            diff = eps_text - eps_uncond
+            eps = eps_uncond + guidance_scale * diff
         with jax.named_scope("sampler/scheduler_step"):
             # v-prediction models (SD-2.1 768-v): convert to ε once per step.
             # Affine in the model output with the same x_t on both branches,
-            # so combining CFG first is equivalent; ``resid`` above is
-            # captured before it, in the network's output space (see
-            # ``_phase2_scan``).
+            # so combining CFG first is equivalent; the residual above is
+            # taken before it, in the network's output space (see
+            # ``_make_cond_body``).
             eps = sched_mod.to_epsilon(schedule, eps, t, latents)
             ms, latents = ms_step(ms, eps, t, latents)
         with jax.named_scope("sampler/controller_step"):
             latents = apply_step_callback(controller, layout, state, latents,
                                           step)
-        if capture:
-            return (latents, state, ms, cache, resid), None
-        return (latents, state, ms), None
+        return (latents, state, ms, out_cache if held is None else (),
+                None if resid is None else diff), None
 
     return body
 
 
-def _phase1_scan(
+def _make_cond_body(
     unet_params: Any,
     cfg: PipelineConfig,
     layout: AttnLayout,
     schedule: sched_mod.DiffusionSchedule,
     scheduler_kind: str,
-    context: jax.Array,            # (2B, L, D) [uncond; cond]
-    latents: jax.Array,            # (B, h, w, c)
+    context_cond: jax.Array,
     controller: Optional[Controller],
     guidance_scale: jax.Array,
+    emit: bool,
+    progress: bool,
+    sp: Optional["SpConfig"],
     *,
-    gate: int,                     # static: first phase-2 scan step
-    progress: bool = False,
-    metrics: bool = False,
-    sp: Optional["SpConfig"] = None,
-    reuse=None,                    # engine.reuse.ReuseSchedule (static)
-    kernels=None,                  # kernels.KernelConfig (static)
-) -> PhaseCarry:
-    """Scan steps ``[0, gate)`` with full CFG + controller hooks, capturing
-    every cross-attention output and the CFG residual. Returns the
-    :class:`PhaseCarry` a phase-2 program continues from. Latent math is
-    identical to the ungated body (the capture only adds carry writes), so
-    phase-1 latents match the baseline bitwise.
-
-    ``reuse`` (a non-uniform ``engine.reuse.ReuseSchedule``) generalizes
-    the gate: the scan is segmented so sites flip to their caches at their
-    own steps (``_scheduled_phase1``). A uniform table routes back here —
-    bitwise the PR-1 program by construction."""
-    if reuse is not None and reuse.uniform_gate is None:
-        assert reuse.cfg_gate == gate, (reuse.cfg_gate, gate)
-        return _scheduled_phase1(unet_params, cfg, layout, schedule,
-                                 scheduler_kind, context, latents,
-                                 controller, guidance_scale, reuse=reuse,
-                                 progress=progress, metrics=metrics, sp=sp,
-                                 kernels=kernels)
-    emit = progress or metrics
-    b = latents.shape[0]
-    state = _store_state(layout, b, controller)
-    ms_state = sched_mod.init_multistep_state(scheduler_kind, latents.shape,
-                                              latents.dtype)
-    body = _make_phase1_body(unet_params, cfg, layout, schedule,
-                             scheduler_kind, context, b, controller,
-                             guidance_scale, None, emit, progress, sp,
-                             capture=True, kernels=kernels)
-    num_scan = schedule.timesteps.shape[0]
-    assert 1 <= gate <= num_scan, (gate, num_scan)
-    steps = jnp.arange(num_scan, dtype=jnp.int32)
-    cache = init_attn_cache(layout, b, dtype=latents.dtype)
-    resid = jnp.zeros_like(latents)
-    (latents, state, ms_state, cache, resid), _ = jax.lax.scan(
-        body, (latents, state, ms_state, cache, resid),
-        (steps[:gate], schedule.timesteps[:gate]))
-    return PhaseCarry(latents=latents, resid=resid, cache=cache,
-                      ms=ms_state, state=state)
-
-
-def _phase2_scan(
-    unet_params: Any,
-    cfg: PipelineConfig,
-    layout: AttnLayout,
-    schedule: sched_mod.DiffusionSchedule,
-    scheduler_kind: str,
-    context_cond: jax.Array,       # (B, L, D) — the uncond half is GONE
-    carry: PhaseCarry,
-    controller: Optional[Controller],
-    guidance_scale: jax.Array,
-    *,
-    gate: int,                     # static: first phase-2 scan step
-    progress: bool = False,
-    metrics: bool = False,
-    sp: Optional["SpConfig"] = None,
-    reuse=None,                    # engine.reuse.ReuseSchedule (static)
-    kernels=None,                  # kernels.KernelConfig (static)
-) -> jax.Array:
-    """Scan steps ``[gate, S)`` off a :class:`PhaseCarry`: single-branch
-    U-Net (no uncond batch half), guidance as a fixed extrapolation off the
-    captured residual (SD-Acc), cross-attention served from the cache
-    (TAD). ``controller`` here is the phase-2 slice
-    (:func:`phase2_controller` for pooled serving; the monolithic path
-    passes the full controller — both emit identical ops). ``reuse`` (a
-    non-uniform schedule) segments the scan per the table
-    (``_scheduled_phase2``)."""
-    if reuse is not None and reuse.uniform_gate is None:
-        assert reuse.cfg_gate == gate, (reuse.cfg_gate, gate)
-        return _scheduled_phase2(unet_params, cfg, layout, schedule,
-                                 scheduler_kind, context_cond, carry,
-                                 controller, guidance_scale, reuse=reuse,
-                                 progress=progress, metrics=metrics, sp=sp,
-                                 kernels=kernels)
-    emit = progress or metrics
+    site_plan: Tuple[str, ...],
+    held: Optional[Tuple],
+    resid: jax.Array,
+    state: Tuple,
+    kernels=None,
+):
+    """The single-branch step past the CFG boundary as the scan body of one
+    segment: the U-Net on the conditional half alone with no controller
+    (attention hooks are structurally gone), guidance as a fixed
+    extrapolation off the frozen hand-off ``resid``, then the CFG step's
+    tail against the frozen phase-1 store ``state``. The carry is
+    ``(latents, ms, cache)``; ``held`` as in :func:`_make_cfg_body`: sites
+    that flip to reuse inside phase 2 keep storing until their step, and a
+    segment with none closes over the cache."""
     ms_step = _make_ms_step(schedule, scheduler_kind)
-    cache, resid, state = carry.cache, carry.resid, carry.state
 
-    def body2(c2, scan_in):
-        latents, ms = c2
+    def body(carry, scan_in):
+        latents, ms, cache = carry
         step, t = scan_in
         progress_mod.emit_step(emit, step, phase="phase2", report=progress)
-        eps_text, _ = apply_unet(
+        eps_text, _, out_cache = apply_unet(
             unet_params, cfg.unet, latents, t, context_cond,
             layout=layout, controller=None, state=(), step=step, sp=sp,
-            attn_cache=cache, cache_mode="use")
+            attn_cache=cache if held is None else held,
+            site_plan=site_plan, kernels=kernels)
         # SD-Acc-style fixed extrapolation: CFG's uncond branch is gone;
         # ε = ε_text + (g−1)·(ε_text − ε_uncond)|_gate reuses the captured
         # last-phase-1 residual as the guidance direction. The residual lives
@@ -719,18 +489,124 @@ def _phase2_scan(
             ms, latents = ms_step(ms, eps, t, latents)
         # Latent-space controller effects (LocalBlend compositing /
         # SpatialReplace injection) continue against the frozen phase-1
-        # store; attention hooks are structurally gone.
+        # store.
         with jax.named_scope("sampler/controller_step"):
             latents = apply_step_callback(controller, layout, state, latents,
                                           step)
-        return (latents, ms), None
+        return (latents, ms, out_cache if held is None else ()), None
 
+    return body
+
+
+def _phase1_scan(
+    unet_params: Any,
+    cfg: PipelineConfig,
+    layout: AttnLayout,
+    schedule: sched_mod.DiffusionSchedule,
+    scheduler_kind: str,
+    context: jax.Array,            # (2B, L, D) [uncond; cond]
+    latents: jax.Array,            # (B, h, w, c)
+    controller: Optional[Controller],
+    guidance_scale: jax.Array,
+    *,
+    reuse,                         # engine.reuse.ReuseSchedule (static)
+    uncond_per_step: Optional[jax.Array] = None,
+    progress: bool = False,
+    metrics: bool = False,
+    sp: Optional["SpConfig"] = None,
+    kernels=None,                  # kernels.KernelConfig (static)
+) -> PhaseCarry:
+    """The phase-1 executor: steps ``[0, cfg_gate)`` under full CFG and the
+    controller's hooks, cut into constant-plan segments
+    (engine.reuse.segments), one ``lax.scan`` each. A site stores its output
+    every step until the step it flips to its cache, so the cache holds the
+    last stored step's values. Returns the :class:`PhaseCarry` a phase-2
+    program continues from, full-batch cache leaves sliced to the
+    conditional half.
+
+    What the carry holds follows from the table ``reuse``
+    (:func:`_schedule_of`). For ``gate=g``: one segment whose cross sites
+    store, the cache and the guidance residual in the carry. With no gate:
+    one segment, nothing cached, and no residual because no phase 2 reads
+    it — the carry is ``(latents, store state, solver state)``."""
     num_scan = schedule.timesteps.shape[0]
-    assert 1 <= gate <= num_scan, (gate, num_scan)
+    sched1 = reuse_mod.phase1_view(reuse)
+    emit = progress or metrics
+    b = latents.shape[0]
+    state = _store_state(layout, b, controller)
+    # Multistep-solver state carried through the scan (PLMS ring buffer or
+    # DPM x0 history; None for single-step DDIM), handed across the phase
+    # boundary.
+    ms_state = sched_mod.init_multistep_state(scheduler_kind, latents.shape,
+                                              latents.dtype)
     steps = jnp.arange(num_scan, dtype=jnp.int32)
-    (latents, _), _ = jax.lax.scan(
-        body2, (carry.latents, carry.ms),
-        (steps[gate:], schedule.timesteps[gate:]))
+    cache = reuse_mod.init_schedule_cache(layout, sched1, b, phase=1,
+                                          dtype=latents.dtype)
+    resid = jnp.zeros_like(latents) if sched1.gated else None
+    for seg in reuse_mod.segments(layout, sched1, phase=1):
+        body = _make_cfg_body(unet_params, cfg, layout, schedule,
+                              scheduler_kind, context, b, controller,
+                              guidance_scale, uncond_per_step, emit,
+                              progress, sp, site_plan=seg.plan,
+                              held=None if seg.stores else cache,
+                              kernels=kernels)
+        (latents, state, ms_state, kept, resid), _ = jax.lax.scan(
+            body,
+            (latents, state, ms_state, cache if seg.stores else (), resid),
+            (steps[seg.start:seg.stop],
+             schedule.timesteps[seg.start:seg.stop]))
+        if seg.stores:
+            cache = kept
+    cache = reuse_mod.slice_cache_to_cond(layout, sched1, cache, b)
+    return PhaseCarry(latents=latents, resid=resid, cache=cache,
+                      ms=ms_state, state=state)
+
+
+def _phase2_scan(
+    unet_params: Any,
+    cfg: PipelineConfig,
+    layout: AttnLayout,
+    schedule: sched_mod.DiffusionSchedule,
+    scheduler_kind: str,
+    context_cond: jax.Array,       # (B, L, D) — the uncond half is GONE
+    carry: PhaseCarry,
+    controller: Optional[Controller],
+    guidance_scale: jax.Array,
+    *,
+    reuse,                         # engine.reuse.ReuseSchedule (static)
+    progress: bool = False,
+    metrics: bool = False,
+    sp: Optional["SpConfig"] = None,
+    kernels=None,                  # kernels.KernelConfig (static)
+) -> jax.Array:
+    """The phase-2 executor: steps ``[cfg_gate, S)`` off a
+    :class:`PhaseCarry`: single-branch U-Net (no uncond batch half),
+    guidance as a fixed extrapolation off the captured residual (SD-Acc),
+    cached sites served from the cache (TAD), segmented so a site may keep
+    computing past the CFG boundary and flip to reuse at its own step.
+    ``gate=g`` is one segment with every cross site in ``use`` and the
+    cache closed over. ``controller`` here is the phase-2 slice
+    (:func:`phase2_controller` for pooled serving; the monolithic path
+    passes the full controller — both emit identical ops)."""
+    num_scan = schedule.timesteps.shape[0]
+    sched2 = reuse_mod.phase2_view(reuse)
+    emit = progress or metrics
+    steps = jnp.arange(num_scan, dtype=jnp.int32)
+    latents, ms_state, cache = carry.latents, carry.ms, carry.cache
+    for seg in reuse_mod.segments(layout, sched2, phase=2):
+        body = _make_cond_body(unet_params, cfg, layout, schedule,
+                               scheduler_kind, context_cond, controller,
+                               guidance_scale, emit, progress, sp,
+                               site_plan=seg.plan,
+                               held=None if seg.stores else cache,
+                               resid=carry.resid, state=carry.state,
+                               kernels=kernels)
+        (latents, ms_state, kept), _ = jax.lax.scan(
+            body, (latents, ms_state, cache if seg.stores else ()),
+            (steps[seg.start:seg.stop],
+             schedule.timesteps[seg.start:seg.stop]))
+        if seg.stores:
+            cache = kept
     return latents
 
 
@@ -754,106 +630,56 @@ def _denoise_scan(
 ) -> Tuple[jax.Array, StoreState]:
     """Scan over timesteps. Returns (final latents, final store state).
 
-    ``gate`` splits the scan into two phases (TAD arXiv 2404.02747 + SD-Acc
-    arXiv 2507.01309, mapped onto P2P's explicit step windows):
+    Phase 1, then phase 2 if the run's table (:func:`_schedule_of`) drops
+    the CFG branch before the end (TAD arXiv 2404.02747 + SD-Acc arXiv
+    2507.01309, mapped onto P2P's explicit step windows):
 
-    - phase 1 (steps ``0..gate``): the batch-doubled CFG U-Net with full
-      controller hooks, capturing every cross-attention output and the CFG
-      residual ``ε_text − ε_uncond`` (each overwritten per step, so the final
-      carry holds the last phase-1 step's values);
-    - phase 2 (steps ``gate..S``): a single-branch U-Net — no uncond half,
-      guidance folded into a fixed extrapolation off the captured residual,
-      cross-attention replaced by the cached outputs. The controller is
-      dropped at the U-Net level (edit windows end before the gate under
-      ``gate='auto'``); its latent-space step callback (LocalBlend /
-      SpatialReplace) still runs against the frozen phase-1 store.
+    - phase 1 (steps ``0..cfg_gate``): the batch-doubled CFG U-Net with full
+      controller hooks, storing the output of every site that will be
+      served from its cache and the CFG residual ``ε_text − ε_uncond`` (each
+      overwritten per step, so the final carry holds the last values);
+    - phase 2 (steps ``cfg_gate..S``): a single-branch U-Net — no uncond
+      half, guidance folded into a fixed extrapolation off the captured
+      residual, cached sites replaced by their cached outputs. The
+      controller is dropped at the U-Net level (edit windows end before the
+      gate under ``gate='auto'``); its latent-space step callback
+      (LocalBlend / SpatialReplace) still runs against the frozen phase-1
+      store.
 
-    ``gate=None`` (or ``gate == S``) compiles the exact pre-existing
-    single-scan program — bitwise-identical output, zero new ops.
+    These are the two programs the serve layer's disaggregated pools
+    compile separately, composed into one — op for op the split execution,
+    which is what makes a pooled hand-off bitwise-equal to a single-program
+    gated run. ``gate=None`` (or ``gate == S``) is one scan with no cache
+    buffers and no residual carry.
 
     ``metrics`` traces the per-step host callback in even when ``progress``
     is off (phase-tagged, so ``obs.device.StepCollector`` can histogram
     phase-1 vs phase-2 ms/step); with both off the program carries no
     callback at all — the telemetry-disabled jaxpr-identity contract.
     """
-    emit = progress or metrics
     b = latents.shape[0]
-    num_scan = schedule.timesteps.shape[0]
-    if reuse is not None:
-        # Per-site per-step reuse schedule (engine.reuse, ISSUE 15). The
-        # UNIFORM table is semantically gate=cfg_gate: normalize onto the
-        # gate path below, so it is bitwise-identical by construction. A
-        # non-uniform table runs the segmented executors — whose uniform
-        # reduction is additionally pinned bitwise-equal by
-        # tests/test_schedule.py (the generalization proof).
-        u = reuse.uniform_gate
-        if u is not None:
-            gate = u if gate is None else gate
-            assert gate == u, (gate, u)
-            reuse = None
-        else:
-            if uncond_per_step is not None:
-                raise ValueError(
-                    "reuse schedules cannot run under per-step null-text "
-                    "uncond embeddings (validated upstream)")
-            carry = _scheduled_phase1(
-                unet_params, cfg, layout, schedule, scheduler_kind,
-                context, latents, controller, guidance_scale, reuse=reuse,
-                progress=progress, metrics=metrics, sp=sp, kernels=kernels)
-            if reuse.cfg_gate >= num_scan:
-                # CFG never drops: the whole scan ran in the (segmented)
-                # CFG phase; cached sites still saved their compute.
-                return carry.latents, carry.state
-            latents = _scheduled_phase2(
-                unet_params, cfg, layout, schedule, scheduler_kind,
-                context[b:], carry, controller, guidance_scale,
-                reuse=reuse, progress=progress, metrics=metrics, sp=sp,
-                kernels=kernels)
-            return latents, carry.state
-    if gate is None:
-        gate = num_scan
-    assert 1 <= gate <= num_scan, (gate, num_scan)
-    gated = gate < num_scan
-    if gated and uncond_per_step is not None:
-        raise ValueError("phase-gated sampling cannot run under per-step "
-                         "null-text uncond embeddings (validated upstream)")
-
-    if not gated:
-        # Feature off: the exact pre-existing program (no cache buffers, no
-        # residual carry) — gate=S is bitwise-identical by construction.
-        state = _store_state(layout, b, controller)
-        # Multistep-solver state carried through the scan (PLMS ring buffer
-        # or DPM x0 history; None for single-step DDIM). The gated path
-        # initializes its own inside ``_phase1_scan`` and hands the SAME
-        # carry across the phase boundary.
-        ms_state = sched_mod.init_multistep_state(
-            scheduler_kind, latents.shape, latents.dtype)
-        body = _make_phase1_body(unet_params, cfg, layout, schedule,
-                                 scheduler_kind, context, b, controller,
-                                 guidance_scale, uncond_per_step, emit,
-                                 progress, sp, capture=False, kernels=kernels)
-        steps = jnp.arange(num_scan, dtype=jnp.int32)
-        (latents, state, _), _ = jax.lax.scan(
-            body, (latents, state, ms_state),
-            (steps, schedule.timesteps))
-        return latents, state
-
-    # Gated: the same two phase programs the serve layer's disaggregated
-    # pools compile separately (``_phase1_scan`` / ``_phase2_scan``),
-    # composed here into one monolithic program — op-for-op the split
-    # execution, which is what makes a pooled hand-off bitwise-equal to a
-    # single-program gated run.
+    sched = _schedule_of(gate, reuse, layout, schedule.timesteps.shape[0])
+    if uncond_per_step is not None and (
+            sched.gated or reuse_mod.cached_sites(layout, sched)):
+        raise ValueError("phase-gated sampling and reuse schedules cannot "
+                         "run under per-step null-text uncond embeddings "
+                         "(validated upstream)")
     carry = _phase1_scan(unet_params, cfg, layout, schedule, scheduler_kind,
                          context, latents, controller, guidance_scale,
-                         gate=gate, progress=progress, metrics=metrics,
-                         sp=sp, kernels=kernels)
+                         uncond_per_step=uncond_per_step, progress=progress,
+                         metrics=metrics, sp=sp, reuse=sched, kernels=kernels)
+    if not sched.gated:
+        # CFG never drops: the whole scan ran in phase 1 (cached sites
+        # still saved their compute).
+        return carry.latents, carry.state
     # Slice the conditional context half once, outside the phase-2 body: a
     # slice inside the scan would pull the full [uncond; cond] tensor into
     # the body as a constant — the uncond half must not even be an input.
     latents = _phase2_scan(unet_params, cfg, layout, schedule,
                            scheduler_kind, context[b:], carry, controller,
-                           guidance_scale, gate=gate, progress=progress,
-                           metrics=metrics, sp=sp, kernels=kernels)
+                           guidance_scale, progress=progress,
+                           metrics=metrics, sp=sp, reuse=sched,
+                           kernels=kernels)
     return latents, carry.state
 
 
@@ -945,7 +771,7 @@ def text2image(
     computing to serving its cached cross-attention output (TAD) or
     inherited self-attention feature (A-SDM) at its own step;
     ``cfg_gate`` plays the gate's role for the CFG branch. The uniform
-    table normalizes onto the exact ``gate=g`` program (bitwise).
+    table is the ``gate=g`` program.
 
     ``kernels`` (a static :class:`p2p_tpu.kernels.KernelConfig`) routes
     covered controller-edited attention sites to the fused-edit Pallas

@@ -90,8 +90,8 @@ def site_variant(kernels: Optional[KernelConfig],
                  controller: Optional[Controller],
                  meta: AttnMeta, mode: str) -> str:
     """The static attention variant for one site in one scan segment.
-    ``mode`` is the site's reuse-schedule action (``engine.reuse`` MODE_*;
-    the legacy global cache_mode lowers to the same vocabulary)."""
+    ``mode`` is the site's reuse-schedule action (``engine.reuse`` MODE_*,
+    the entries of ``apply_unet``'s ``site_plan``)."""
     if mode == "use":
         return VARIANT_USE
     if not controller_touches(controller, meta):

@@ -210,18 +210,20 @@ class _HookCtx:
     store state through the sites in call order. ``sp`` optionally names a
     mesh axis for sequence-parallel (ring) self-attention at large sites.
 
-    ``cache_mode`` is the phase-gated sampling switch (static, so each mode
-    compiles its own program): ``'off'`` — no cache interaction; ``'store'``
-    — compute every site normally and overwrite the cache slot of each cross
-    site with its conditional-half output; ``'use'`` — cross sites return
-    their cached output directly, computing nothing."""
+    ``site_plan`` is the static per-site cache action (engine.reuse), one
+    mode per layout site in call order, so each plan compiles its own
+    program: ``'off'`` — no cache interaction; ``'store'`` — compute the
+    site and overwrite its cache slot with the conditional half of its
+    output, ``'store_all'`` with the whole batch; ``'use'`` — the site
+    returns its cached output directly, computing nothing. The cache cursor
+    walks the non-``'off'`` sites, whose leaves ``attn_cache`` holds in the
+    same order."""
 
     def __init__(self, layout: AttnLayout, controller: Optional[Controller],
                  state: StoreState, step: jax.Array,
+                 site_plan: Tuple[str, ...],
                  sp: Optional["SpConfig"] = None,
                  attn_cache: Optional[AttnCache] = None,
-                 cache_mode: str = "off",
-                 site_plan: Optional[Tuple[str, ...]] = None,
                  kernels=None):
         self.layout = layout
         self.controller = controller
@@ -230,11 +232,6 @@ class _HookCtx:
         self.sp = sp
         self.cursor = 0
         self.attn_cache = attn_cache
-        self.cache_mode = cache_mode
-        # Per-site action vector (engine.reuse): one mode per layout site
-        # in call order — the generalized form the global cache_mode
-        # lowers to. The cache cursor walks the non-"off" sites, whose
-        # leaves the cache tuple holds in the same order.
         self.site_plan = site_plan
         self.cross_cursor = 0
         # Fused-kernel dispatch plan (kernels.KernelConfig or None): static,
@@ -299,18 +296,6 @@ def _apply_attention(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         return _attention_site(p, ln, x, context, heads, ctx, meta, is_cross)
 
 
-def _site_mode(ctx: _HookCtx, meta, is_cross: bool) -> str:
-    """This site's static cache action. The legacy global ``cache_mode``
-    lowers to the per-site form (all cross sites, no self sites) so both
-    surfaces run ONE code path; ``site_plan`` (engine.reuse schedules) may
-    mix actions per site and cover self sites too."""
-    if ctx.site_plan is not None:
-        return ctx.site_plan[meta.layer_idx]
-    if is_cross and ctx.cache_mode in ("store", "use"):
-        return ctx.cache_mode
-    return "off"
-
-
 def _fused_edit_dispatch(ctx: _HookCtx, meta, q, k, v, scale):
     """Route a controller-touched site to the fused-edit Pallas kernel
     (``kernels.fused_edit``) when the static dispatch plan covers it; None →
@@ -343,7 +328,7 @@ def _fused_edit_dispatch(ctx: _HookCtx, meta, q, k, v, scale):
 
 def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
                     heads: int, ctx: _HookCtx, meta, is_cross: bool) -> jax.Array:
-    mode = _site_mode(ctx, meta, is_cross)
+    mode = ctx.site_plan[meta.layer_idx]
     if mode == "use":
         # The site's output is served from its cache: for cross sites the
         # text context is untouched so the cached tensor is the TAD reuse;
@@ -507,13 +492,11 @@ def apply_unet(
     step: Optional[jax.Array] = None,
     sp: Optional[SpConfig] = None,
     attn_cache: Optional[AttnCache] = None,
-    cache_mode: str = "off",
     site_plan: Optional[Tuple[str, ...]] = None,
     kernels=None,
 ):
     """Predict ε(x_t, t, context). Returns ``(eps, controller_store_state)``,
-    plus the updated cache as a third element iff ``cache_mode='store'``
-    or a ``site_plan`` is given.
+    plus the updated cache as a third element iff a ``site_plan`` is given.
 
     ``kernels`` (a static ``kernels.KernelConfig``) routes covered
     controller-touched sites to the fused-edit Pallas kernel — the edit
@@ -527,69 +510,41 @@ def apply_unet(
     equivalence holds at the XLA-program level. ``sp`` enables ring
     (sequence-parallel) attention for large untouched self sites.
 
-    ``cache_mode`` (static) is phase-gated sampling's switch over the
-    cross-attention cache ``attn_cache`` (one ``(B_cond, P, C)`` leaf per
-    cross site): ``'store'`` runs the normal CFG-doubled forward and
-    overwrites each cross slot with the site's conditional-half output;
-    ``'use'`` runs the single-branch (no uncond half) forward with every
-    cross site replaced by its cached output — a genuinely smaller program.
-    ``'use'`` is incompatible with an active controller: cross edits and
-    stores read the probability tensor, which no longer exists.
+    ``site_plan`` (static; None: every site ``'off'``) is the per-site cache
+    action of phase-gated sampling and reuse schedules (engine.reuse) over
+    ``attn_cache``, one leaf per site that is not ``'off'``, in call order.
+    The phase-1 plan of ``gate=g`` has every cross site in ``'store'``: the
+    normal CFG-doubled forward, each slot overwritten with the site's
+    conditional-half output. Its phase-2 plan has them in ``'use'``: the
+    single-branch forward (no uncond half) with every cross site replaced
+    by its cached output — a genuinely smaller program. Edits and stores at
+    a ``'use'`` site are structurally impossible (no probability tensor is
+    computed there): the sampler passes no controller past the CFG
+    boundary, and schedule resolution warns about a site reused inside an
+    edit window (engine.reuse.warn_schedule_conflicts).
     """
-    if cache_mode not in ("off", "store", "use"):
-        raise ValueError(f"unknown cache_mode {cache_mode!r} "
-                         "(expected 'off', 'store' or 'use')")
     if layout is None:
         layout = unet_layout(cfg)
-    if site_plan is not None:
-        # The per-site generalization (engine.reuse schedules): a static
-        # action per layout site. Mutually exclusive with the legacy
-        # global switch — a caller mixing both has a bug.
-        if cache_mode != "off":
-            raise ValueError("site_plan and cache_mode are mutually "
-                             "exclusive; the plan subsumes the mode")
-        if len(site_plan) != len(layout.metas):
-            raise ValueError(
-                f"site_plan has {len(site_plan)} entries for a layout "
-                f"with {len(layout.metas)} attention sites")
-        bad = set(site_plan) - {"off", "store", "store_all", "use"}
-        if bad:
-            raise ValueError(f"unknown site_plan mode(s) {sorted(bad)}")
-        n_cached = sum(1 for m in site_plan if m != "off")
-        if (attn_cache is None and n_cached) or \
-                (attn_cache is not None and len(attn_cache) != n_cached):
-            raise ValueError(
-                f"site_plan has {n_cached} cached site(s); attn_cache has "
-                f"{None if attn_cache is None else len(attn_cache)} "
-                "leaf/leaves")
-        # Edits at a reused site are structurally impossible (no
-        # probability tensor): schedule resolution warns about window
-        # conflicts upstream (engine.reuse.warn_schedule_conflicts), so
-        # here a controller may legitimately coexist with "use" sites.
-    elif cache_mode != "off":
-        n_cross = sum(1 for m in layout.metas if m.is_cross)
-        if attn_cache is None or len(attn_cache) != n_cross:
-            raise ValueError(
-                f"cache_mode={cache_mode!r} needs an attn_cache with one "
-                f"entry per cross site ({n_cross}), got "
-                f"{None if attn_cache is None else len(attn_cache)}")
-    if cache_mode == "use" and controller is not None \
-            and not controller.is_identity:
-        # The needs_store/edit guard: a controller's cross hooks need the
-        # materialized probability tensor, which the cached path never
-        # computes. Gate resolution ('auto') keeps edit windows inside
-        # phase 1; phase 2 must drop the controller at the U-Net level and
-        # apply only the latent-space step callback with the frozen store.
+    plan = site_plan
+    if plan is None:
+        plan = ("off",) * len(layout.metas)
+    if len(plan) != len(layout.metas):
         raise ValueError(
-            "cache_mode='use' cannot run with an active controller: "
-            "cross-attention probabilities are not computed in phase 2 — "
-            "pass controller=None and keep controller effects to "
-            "apply_step_callback")
+            f"site_plan has {len(plan)} entries for a layout "
+            f"with {len(layout.metas)} attention sites")
+    bad = set(plan) - {"off", "store", "store_all", "use"}
+    if bad:
+        raise ValueError(f"unknown site_plan mode(s) {sorted(bad)}")
+    n_cached = sum(1 for m in plan if m != "off")
+    if n_cached != (0 if attn_cache is None else len(attn_cache)):
+        raise ValueError(
+            f"site_plan has {n_cached} cached site(s); attn_cache has "
+            f"{None if attn_cache is None else len(attn_cache)} "
+            "leaf/leaves")
     if step is None:
         step = jnp.int32(0)
-    ctx = _HookCtx(layout, controller, state, step, sp=sp,
-                   attn_cache=attn_cache, cache_mode=cache_mode,
-                   site_plan=site_plan, kernels=kernels)
+    ctx = _HookCtx(layout, controller, state, step, plan, sp=sp,
+                   attn_cache=attn_cache, kernels=kernels)
     g = cfg.groups
 
     # Scopes (docs/OBSERVABILITY.md, "Scope vocabulary"): ``unet/<part>`` and
@@ -657,6 +612,6 @@ def apply_unet(
         with jax.named_scope("conv_out"):
             h = nn.silu(nn.group_norm(params["norm_out"], h, g))
             eps = nn.conv2d(params["conv_out"], h)
-    if cache_mode == "store" or site_plan is not None:
+    if site_plan is not None:
         return eps, ctx.state, ctx.attn_cache
     return eps, ctx.state

@@ -75,8 +75,7 @@ MFU_PCT_BUCKETS = (1.0, 2.0, 5.0, 10.0, 15.0, 20.0, 30.0, 40.0, 50.0,
 
 def cost_analysis_dict(compiled) -> dict:
     """The ``cost_analysis()`` properties of a compiled program as one flat
-    dict — the shared parser behind every driver (this module,
-    ``tools/profiling/prof_breakdown.py``).
+    dict.
 
     ``cost_analysis()`` returns a plain dict, or None where the backend
     exposes nothing ({} then)."""

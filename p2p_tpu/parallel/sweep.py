@@ -23,8 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..controllers.base import AttnLayout, Controller
 from ..engine.sampler import (PhaseCarry, _denoise_scan, _phase1_scan,
-                              _phase2_scan, resolve_gate, resolve_reuse,
-                              stage_host, warn_gate_truncation)
+                              _phase2_scan, _schedule_of, resolve_gate,
+                              resolve_reuse, stage_host, warn_gate_truncation)
 from ..models import nn
 from ..models import vae as vae_mod
 from ..models.config import PipelineConfig
@@ -205,8 +205,8 @@ def sweep(
             num_scan = tsched.timesteps.shape[0]
             # ``schedule`` (a reuse-schedule spec / resolved table — ISSUE 15)
             # generalizes ``gate``; resolve_reuse enforces mutual exclusion,
-            # normalizes uniform tables onto the gate path and fires the per-site
-            # window-conflict warning for non-uniform ones.
+            # keys a uniform table as its gate and fires the per-site
+            # window-conflict warning for the others.
             gate_step, reuse_sched = resolve_reuse(gate, schedule, layout,
                                                    num_scan, controllers)
         if gate_step < num_scan and uncond_per_step is not None:
@@ -302,11 +302,13 @@ def _sweep_phase1_jit(
     separately scheduled phase-2 program. ``reuse`` (a non-uniform
     ``engine.reuse`` table, static) generalizes the gate: the carry's
     cache holds the schedule's leaf set instead of all-cross."""
+    sched = _schedule_of(gate, reuse, layout, schedule.timesteps.shape[0])
+
     def one_group(ctx, lat, ctrl):
         return _phase1_scan(unet_params, cfg, layout, schedule,
                             scheduler_kind, ctx, lat, ctrl, guidance_scale,
-                            gate=gate, progress=progress, metrics=metrics,
-                            reuse=reuse, kernels=kernels)
+                            reuse=sched, progress=progress, metrics=metrics,
+                            kernels=kernels)
 
     return _vmap_groups(one_group, mesh)(context, latents, controllers)
 
@@ -339,11 +341,13 @@ def _sweep_phase2_jit(
     *different* requests (different phase-1 batches): everything request-
     specific rides the carry and the cond context. Returns
     ``(images (G,B,H,W,3) uint8, final latents)``."""
+    sched = _schedule_of(gate, reuse, layout, schedule.timesteps.shape[0])
+
     def one_group(ctx_c, car, ctrl):
         lat = _phase2_scan(unet_params, cfg, layout, schedule,
                            scheduler_kind, ctx_c, car, ctrl, guidance_scale,
-                           gate=gate, progress=progress, metrics=metrics,
-                           reuse=reuse, kernels=kernels)
+                           reuse=sched, progress=progress, metrics=metrics,
+                           kernels=kernels)
         image = vae_mod.decode(vae_params, cfg.vae, lat.astype(jnp.float32))
         return vae_mod.to_uint8(image), lat
 
@@ -359,8 +363,8 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
     with their defaults taken against the layout (``AttnLayout.resolve``) and
     the layout with store slots for their readers (``AttnLayout.for_readers``).
     ``schedule`` is a reuse-schedule spec/table (ISSUE 15): its
-    ``cfg_gate`` is the pool boundary; uniform tables normalize onto the
-    plain gate."""
+    ``cfg_gate`` is the pool boundary; a uniform table is keyed as its
+    gate."""
     with span("entry.prepare"):
         cfg = pipe.config
         if layout is None:

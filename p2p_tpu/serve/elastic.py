@@ -230,8 +230,7 @@ class ElasticController:
         return entry
 
     def stats(self) -> dict:
-        """The summary's ``elastic`` block / bench ``serve.elastic``
-        sub-record (frozen keys — tests/test_bench_rehearsal.py)."""
+        """The summary's ``elastic`` block."""
         return {"resizes_up": self.resizes_up,
                 "resizes_down": self.resizes_down,
                 "deferred_slo": self.deferred_slo,

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache shared by every entry point.
 
 The SD-1.4 sampling program takes minutes of XLA compilation. With a
-persistent cache, bench.py / the CLI / the profiling tools compile each
+persistent cache, the CLI, the tools and the benchmark compile each
 distinct program once per checkout and reload it afterwards (works for both
 the CPU and TPU backends; keyed on HLO + compile options + backend).
 

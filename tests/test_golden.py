@@ -137,7 +137,7 @@ def _case_ldm(tiny):
 
 
 def _case_dpm(tiny):
-    """The quality-matched operating point (bench.py's DPM-Solver++(2M)
+    """The quality-matched operating point (the DPM-Solver++(2M)
     secondary): same Replace edit, dpm multistep scheduler."""
     ctrl = factory.attention_replace(
         PROMPTS, STEPS, cross_replace_steps=0.8, self_replace_steps=0.4,

@@ -288,7 +288,7 @@ def test_cross_tool_seed_stability_pins():
     silently shifted every tool's seeded workload once — this pin makes
     the next loadgen RNG refactor loud instead. Audit of every in-repo
     trace constructor (chaos_drill.standard_trace / slo_overload_drill /
-    cache_parity_drill, tools/soak.py, bench.py serve blocks): all ride
+    cache_parity_drill, tools/soak.py): all ride
     ``generate_trace``/``generate_stream``, which share one per-request
     draw path (``generate_trace`` IS ``list(generate_stream(n=K))``), so
     pinning (a) the tool-level trace bytes for the drills' own default

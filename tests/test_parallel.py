@@ -165,9 +165,8 @@ def test_dp_sweep_matches_sequential(tiny_pipe, devices):
 
 
 def test_sweep_dpm_scheduler_matches_text2image(tiny_pipe):
-    """sweep(scheduler="dpm") — the program bench.py's DPM batched secondary
-    times — must match the single-group text2image DPM path on the same
-    latent and controller."""
+    """sweep(scheduler="dpm") must match the single-group text2image DPM
+    path on the same latent and controller."""
     from p2p_tpu.engine.sampler import text2image
 
     cfg = TINY

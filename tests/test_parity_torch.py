@@ -188,7 +188,7 @@ def test_transformer_block_matches_torch_oracle():
     metas = (AttnMeta(0, "down", False, 3, heads, 9),
              AttnMeta(1, "down", True, 3, heads, 7))
     layout = AttnLayout(metas, StoreConfig())
-    hook = _HookCtx(layout, None, (), jnp.int32(0))
+    hook = _HookCtx(layout, None, (), jnp.int32(0), ("off",) * len(metas))
     got = np.asarray(_apply_transformer_block(p, jnp.asarray(x),
                                               jnp.asarray(context), heads, hook))
 

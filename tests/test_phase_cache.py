@@ -252,21 +252,56 @@ def test_phase2_scan_has_no_uncond_batch_half(tiny_pipe):
     assert len(body2) < len(body1), (len(body2), len(body1))
 
 
-def test_apply_unet_use_mode_rejects_active_controller(tiny_pipe):
+def test_apply_unet_use_sites_read_their_cache(tiny_pipe):
+    """A ``use`` site of the plan returns its cached tensor and leaves the
+    cache as it was. A controller is accepted beside ``use`` sites (a
+    schedule may reuse a site under CFG while the controller acts on the
+    others), and its cross edit has no effect at a site that is ``use``:
+    no probability tensor is computed there, and
+    ``engine.reuse.warn_schedule_conflicts`` is the one place that says so.
+    The two causes are held apart: one cache with and without the
+    controller, one controller over two caches. A cache that does not fit
+    the plan is refused."""
     from p2p_tpu.models.unet import apply_unet, init_attn_cache
 
     layout = unet_layout(TINY.unet)
-    cache = init_attn_cache(layout, 2)
-    ctrl = _ctrl(tiny_pipe.tokenizer)
-    x = jnp.zeros((2,) + tiny_pipe.latent_shape)
-    ctx = jnp.zeros((2, TINY.unet.context_len, TINY.unet.context_dim))
-    with pytest.raises(ValueError, match="controller"):
-        apply_unet(tiny_pipe.unet_params, TINY.unet, x, jnp.int32(0), ctx,
-                   layout=layout, controller=ctrl, attn_cache=cache,
-                   cache_mode="use")
+    plan = tuple("use" if m.is_cross else "off" for m in layout.metas)
+    # The CFG-doubled batch: the controller edits the conditional half.
+    two_b = 2 * len(PROMPTS)
+    zeros = init_attn_cache(layout, two_b)
+    ones = tuple(jnp.ones_like(c) for c in zeros)
+    x = jnp.zeros((two_b,) + tiny_pipe.latent_shape)
+    ctx = jnp.concatenate([encode_prompts(tiny_pipe, [""] * len(PROMPTS)),
+                           encode_prompts(tiny_pipe, PROMPTS)], axis=0)
+    # Cross edit only (no self window), so every site it acts on is `use`.
+    ctrl = layout.resolve(factory.attention_replace(
+        PROMPTS, STEPS, cross_replace_steps=1.0, self_replace_steps=0.0,
+        tokenizer=tiny_pipe.tokenizer, max_len=TINY.text.max_length,
+        store=False))
+
+    def run(cache, ctrl=None, plan=plan):
+        return apply_unet(tiny_pipe.unet_params, TINY.unet, x, jnp.int32(0),
+                          ctx, layout=layout, controller=ctrl,
+                          attn_cache=cache, site_plan=plan)
+
+    # Not vacuous: where the cross sites compute, this controller moves eps.
+    assert not np.array_equal(np.asarray(run(None, plan=None)[0]),
+                              np.asarray(run(None, ctrl, plan=None)[0]))
+    eps_zeros, _, out_zeros = run(zeros)
+    eps_zeros_ctrl, _, out_zeros_ctrl = run(zeros, ctrl)
+    eps_ones_ctrl, _, out_ones_ctrl = run(ones, ctrl)
+    assert all(a is b for a, b in zip(out_zeros, zeros))
+    assert all(a is b for a, b in zip(out_zeros_ctrl, zeros))
+    assert all(a is b for a, b in zip(out_ones_ctrl, ones))
+    # Same cache, with and without the controller: the edit is dropped.
+    assert np.array_equal(np.asarray(eps_zeros), np.asarray(eps_zeros_ctrl))
+    # Same controller, another cache: the cache is what the site returns.
+    assert not np.array_equal(np.asarray(eps_zeros_ctrl),
+                              np.asarray(eps_ones_ctrl))
     with pytest.raises(ValueError, match="attn_cache"):
-        apply_unet(tiny_pipe.unet_params, TINY.unet, x, jnp.int32(0), ctx,
-                   layout=layout, cache_mode="use")
+        run(None)
+    with pytest.raises(ValueError, match="attn_cache"):
+        run(zeros, plan=None)
 
 
 # ---------------------------------------------------------------------------

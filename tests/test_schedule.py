@@ -2,10 +2,9 @@
 
 The generalization contract, pinned from both ends:
 
-- the UNIFORM table is the PR-1 gate: it normalizes onto the exact gate
-  path (bitwise + identical compile keys, pooling with plain gated
-  traffic), and the segmented executor itself reproduces the gate path
-  bitwise when handed a uniform table (the split-equals-monolith idiom);
+- the UNIFORM table is the PR-1 gate: bitwise, with identical compile
+  keys, pooling with plain gated traffic (one executor runs both: that
+  they trace to one program is pinned in tests/test_sampler_programs.py);
 - a NON-uniform table is one compiled program whose key is the table
   CONTENTS: one-cell differences split keys, identical tables loaded
   from different files pool, and the per-phase key projections keep
@@ -26,11 +25,9 @@ import jax.numpy as jnp
 
 from p2p_tpu.controllers import factory
 from p2p_tpu.engine import reuse as R
-from p2p_tpu.engine import sampler as S
 from p2p_tpu.engine.sampler import encode_prompts, resolve_reuse, text2image
 from p2p_tpu.models import TINY
 from p2p_tpu.models.config import unet_layout
-from p2p_tpu.ops import schedulers as sched_mod
 from p2p_tpu.parallel import seed_latents
 from p2p_tpu.parallel.sweep import sweep
 
@@ -53,11 +50,7 @@ def _ctrl(tokenizer, steps=STEPS):
 
 
 def _uniform(gate=GATE, steps=STEPS):
-    lay = _layout()
-    n_cross = sum(1 for m in lay.metas if m.is_cross)
-    n_self = len(lay.metas) - n_cross
-    return R.ReuseSchedule(steps=steps, cfg_gate=gate,
-                           cross=(gate,) * n_cross, selfa=(steps,) * n_self)
+    return R.ReuseSchedule.uniform(gate, steps, _layout())
 
 
 # ---------------------------------------------------------------------------
@@ -213,7 +206,7 @@ def test_partial_site_cache_sizing():
 
 
 # ---------------------------------------------------------------------------
-# The generalization proof: uniform table ≡ gate, both routes
+# The uniform table is the gate
 # ---------------------------------------------------------------------------
 
 
@@ -228,44 +221,6 @@ def test_uniform_schedule_normalizes_to_gate_bitwise(tiny_pipe):
                                     schedule={"cfg_gate": GATE}, **kw)
     assert np.array_equal(np.asarray(img_g), np.asarray(img_u))
     assert np.array_equal(np.asarray(xt_g), np.asarray(xt_u))
-
-
-def test_segmented_executor_uniform_table_bitwise_equals_gate(tiny_pipe):
-    """The PR-6 split-equals-monolith idiom for the schedule executor:
-    forcing the SEGMENTED path onto the uniform table must reproduce the
-    legacy gate path bit for bit — the refactor is provably a
-    generalization, not a reimplementation."""
-    lay = _layout()
-    ctrl = _ctrl(tiny_pipe.tokenizer)
-    tsched = sched_mod.schedule_from_config(STEPS, TINY.scheduler,
-                                            kind="ddim")
-    cond = encode_prompts(tiny_pipe, PROMPTS)
-    unc = encode_prompts(tiny_pipe, [""] * 2)
-    ctx = jnp.concatenate([unc, cond], axis=0)
-    _, lats = S.init_latent(None, tiny_pipe.latent_shape,
-                            jax.random.PRNGKey(7), 2)
-    gs = jnp.float32(7.5)
-    uni = _uniform()
-
-    @jax.jit
-    def legacy(ctx, lats, gs):
-        carry = S._phase1_scan(tiny_pipe.unet_params, TINY, lay, tsched,
-                               "ddim", ctx, lats, ctrl, gs, gate=GATE)
-        return S._phase2_scan(tiny_pipe.unet_params, TINY, lay, tsched,
-                              "ddim", ctx[2:], carry, ctrl, gs, gate=GATE)
-
-    @jax.jit
-    def segmented(ctx, lats, gs):
-        carry = S._scheduled_phase1(tiny_pipe.unet_params, TINY, lay,
-                                    tsched, "ddim", ctx, lats, ctrl, gs,
-                                    reuse=uni)
-        return S._scheduled_phase2(tiny_pipe.unet_params, TINY, lay,
-                                   tsched, "ddim", ctx[2:], carry, ctrl,
-                                   gs, reuse=uni)
-
-    a = np.asarray(legacy(ctx, lats, gs))
-    b = np.asarray(segmented(ctx, lats, gs))
-    assert np.array_equal(a, b), float(np.abs(a - b).max())
 
 
 # ---------------------------------------------------------------------------

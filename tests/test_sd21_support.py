@@ -129,7 +129,9 @@ def _analytic_unet(prediction_type):
         if prediction_type == "v_prediction":
             a = acp[t]
             eps = (eps - jnp.sqrt(1.0 - a) * x) / jnp.sqrt(a)
-        return eps, kw.get("state", ())
+        out = (eps, kw.get("state", ()))
+        # apply_unet's arity: the cache is third where a site_plan is given
+        return out + (kw.get("attn_cache"),) if "site_plan" in kw else out
 
     return fake
 

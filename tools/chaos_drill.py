@@ -31,7 +31,7 @@ history (asserted, not just measured). ``--kill-mid-drain`` arms a chaos
 
 The whole drill is virtual-clock deterministic on the random-init tiny
 pipeline (no checkpoints), so it doubles as the ``fault_drill`` check in
-``tools/quality_gate.py`` and the ``resilience`` block in ``bench.py``.
+``tools/quality_gate.py``.
 
     python tools/chaos_drill.py                      # standard drill
     python tools/chaos_drill.py --n 32 --fault-rate 0.4 --seed 7
@@ -54,8 +54,8 @@ if _REPO not in sys.path:
 def _pin_cpu():
     """Deterministic CPU backend (same scrub as quality_gate: the drill's
     contract is bitwise, so the platform must be pinned). Called from
-    ``main()`` only — importers like bench.py choose their own backend and
-    must not have theirs scrubbed at import time."""
+    ``main()`` only — importers (tools/quality_gate.py, the tests) choose
+    their own backend and must not have theirs scrubbed at import time."""
     from p2p_tpu.utils.cache import default_cache_dir
 
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -558,8 +558,7 @@ def slo_overload_drill(pipe, *, n=192, seed=11, steps=4, overload=2.0,
     3. **Exactly-once** — every admitted request resolves to exactly one
        terminal record, preemptions and sheds included.
 
-    Returns the ``serve.slo`` bench sub-record (frozen keys pinned in
-    tests/test_bench_rehearsal.py)."""
+    Returns the drill's ``slo`` record."""
     import importlib.util
 
     from p2p_tpu.serve import DegradeConfig, SloConfig, serve_forever
@@ -1033,8 +1032,7 @@ def elastic_resize_drill(pipe, journal_path=None, *, n=192, seed=19,
        with ok-outputs bitwise-identical to the uninterrupted elastic
        run.
 
-    Returns the ``serve.elastic`` bench sub-record (frozen keys pinned in
-    tests/test_bench_rehearsal.py)."""
+    Returns the drill's ``elastic`` record."""
     import importlib.util
 
     import jax
