@@ -94,9 +94,28 @@ def norm_init(dim: int, dtype=jnp.float32) -> Params:
     return {"scale": jnp.ones((dim,), dtype), "bias": jnp.zeros((dim,), dtype)}
 
 
-def group_norm(p: Params, x: jax.Array, groups: int = 32, eps: float = 1e-5
-               ) -> jax.Array:
+def _over_space(v: jax.Array, x: jax.Array) -> jax.Array:
+    """A per-(sample, channel) vector ``(N, C)`` against ``x`` ``(N, ..., C)``."""
+    return v[(slice(None),) + (None,) * (x.ndim - 2)]
+
+
+def _group_mean(v: jax.Array, groups: int) -> jax.Array:
+    """``(N, C)``: every channel's entry replaced by the mean over the
+    channels of its group."""
+    n, c = v.shape
+    m = v.reshape(n, groups, c // groups).mean(-1, keepdims=True)
+    return jnp.broadcast_to(m, (n, groups, c // groups)).reshape(n, c)
+
+
+def group_norm(p: Params, x: jax.Array, groups: int = 32, eps: float = 1e-5,
+               shift: Optional[jax.Array] = None) -> jax.Array:
     """GroupNorm over an NHWC (or N...C) tensor.
+
+    ``shift`` (``(N, C)``, optional) is a per-(sample, channel) vector the
+    input carries without its having been added: the result is GroupNorm of
+    ``x + shift[:, None, None, :]`` (a ResNet block's time-embedding shift
+    feeds nothing but the block's second norm, so it is folded in here and
+    never broadcast to the activation's size).
 
     Statistics accumulate in f32 regardless of carrier dtype; the
     normalization arithmetic stays in the carrier dtype. On the bf16 TPU path
@@ -106,16 +125,26 @@ def group_norm(p: Params, x: jax.Array, groups: int = 32, eps: float = 1e-5
     SD-1.4 shapes). f32 inputs are unaffected (stats math is then pure f32).
     """
     if x.dtype == jnp.float32:
-        # Full-precision path (CPU tests / parity harness): all math in f32.
-        c = x.shape[-1]
-        g = min(groups, c)
-        xg = x.reshape(x.shape[:-1] + (g, c // g))
-        red = tuple(range(1, xg.ndim - 2)) + (xg.ndim - 1,)
-        mean = xg.mean(axis=red, keepdims=True)
-        var = xg.var(axis=red, keepdims=True)
-        xg = (xg - mean) * jax.lax.rsqrt(var + eps)
-        return xg.reshape(x.shape) * p["scale"] + p["bias"]
+        # All math in f32, and everything that is per (sample, channel) stays
+        # an (N, C) vector in the activation's own channels-minor layout: both
+        # moments are spatial reductions per channel, the channels of a group
+        # are combined on the vector, and the tensor sees one centre and one
+        # scale. ``var`` of a (N, H, W, groups, C/groups) view of the
+        # activation made XLA:TPU transpose it whole to a W-minor layout first
+        # (a group of 10 channels fills no lane tile: PERF.md §6, PR 32).
+        # Two passes, the second moment about the group's mean: nothing
+        # cancels, whatever the mean.
+        g = min(groups, x.shape[-1])
+        space = tuple(range(1, x.ndim - 1))
+        offset = 0.0 if shift is None else shift
+        centre = _group_mean(x.mean(axis=space) + offset, g) - offset
+        xc = x - _over_space(centre, x)
+        var = _group_mean(jnp.square(xc).mean(axis=space), g)
+        inv = jax.lax.rsqrt(var + eps) * p["scale"]
+        return xc * _over_space(inv, x) + p["bias"]
 
+    if shift is not None:
+        return group_norm(p, x + _over_space(shift, x), groups, eps)
     c = x.shape[-1]
     g = min(groups, c)
     xg = x.reshape(x.shape[:-1] + (g, c // g))

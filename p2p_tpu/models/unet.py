@@ -169,8 +169,11 @@ def init_unet(key: jax.Array, cfg: UNetConfig) -> Params:
 
 def _apply_resnet(p: Params, x: jax.Array, temb: jax.Array, groups: int) -> jax.Array:
     h = nn.conv2d(p["conv1"], nn.silu(nn.group_norm(p["norm1"], x, groups)))
-    h = h + nn.linear(p["time_proj"], nn.silu(temb))[:, None, None, :]
-    h = nn.conv2d(p["conv2"], nn.silu(nn.group_norm(p["norm2"], h, groups)))
+    # The time-embedding shift feeds nothing but norm2: it joins that norm's
+    # (N, C) vectors and is never added at the activation's size.
+    shift = nn.linear(p["time_proj"], nn.silu(temb))
+    h = nn.conv2d(p["conv2"], nn.silu(
+        nn.group_norm(p["norm2"], h, groups, shift=shift)))
     if "skip" in p:
         x = nn.conv2d(p["skip"], x)
     return x + h

@@ -30,6 +30,10 @@ budget. These compiles can, at about a second each and no chip time:
     nobody reads are flash calls at (4, 10, 2304, 64) beside the five at
     9,216 keys, and no (4, 10, 2304, 2304) tensor exists (about two minutes).
 
+(f) two ResNet blocks of `sd14`'s first level (``unet._apply_resnet``, f32,
+    320 wide at 64x64): GroupNorm's statistics leave the activation
+    channels-minor, so no copy of it to a W-minor layout is compiled (PR 32).
+
 Nothing runs, so nothing here says anything about results or times. The
 topology is described inside a module-scoped fixture (never at import: the
 TPU library belongs to one process, and every xdist worker imports this
@@ -372,3 +376,36 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
     assert len(kernels) == 11
     assert "f32[4,10,2304,2304]" not in text
     assert "f32[2,10,2304,2304]" not in text
+
+
+def test_resnet_blocks_keep_the_activation_channels_minor(one_chip):
+    """Two ``unet._apply_resnet`` blocks 320 -> 320 -> 320 on f32[4,64,64,320]
+    (`sd14`'s ``down0``): GroupNorm takes its moments per channel and combines
+    a group's channels on the (N, C) vector, so no (N, H, W, 32, 10) view of
+    the activation is reduced and XLA copies the activation to no layout whose
+    minor dimension is W (``{2,1,3,0}``), which PR 31's tree did four times
+    (PERF.md §6, PR 32; `tools/hlo_costs.py` lists them)."""
+    import re
+
+    from p2p_tpu.models import unet
+
+    key = jax.random.PRNGKey(0)
+    blocks = [jax.eval_shape(lambda: unet._resnet_init(key, 320, 320, 1280))
+              for _ in range(2)]
+
+    def chain(blocks, x, temb):
+        for i, p in enumerate(blocks):
+            with jax.named_scope(f"unet/down0/res{i}"):
+                x = unet._apply_resnet(p, x, temb, 32)
+        return x
+
+    x = jax.ShapeDtypeStruct((4, 64, 64, 320), jnp.float32, sharding=one_chip)
+    temb = jax.ShapeDtypeStruct((4, 1280), jnp.float32, sharding=one_chip)
+    text = jax.jit(chain).lower(_shapes(blocks, one_chip), x, temb
+                                ).compile().as_text()
+    assert text.count(" convolution(") >= 4
+    w_minor = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if re.search(r"= f32\[4,64,64,[0-9,]+\]\{2,[0-9,]*[:}].* copy\(",
+                            line)]
+    assert w_minor == []
+    assert "f32[4,64,64,32,10]" not in text
