@@ -3,6 +3,14 @@ the sizes in its file. A multiply-add counts as 2 operations; only matrix
 multiplications and convolutions are counted (norms, activations, softmax and
 the sampler's arithmetic are left out, well under 1 % of the total), so a
 share of the peak worked out from these is a little low, never high.
+
+The U-Net is counted as a member of diffusers' ``UNet2DConditionModel``
+family, from keys that may be absent (PR 33): ``unet.transformer_depth`` is
+one int (every attentive level and the mid block) or a list with one int a
+level (0: that level has no transformer; the mid block takes the last
+level's); ``text_encoder`` is one tower or a list of towers, summed;
+``unet.addition_embed_in`` is the width of a vector embedded by two linear
+layers and added to the time embedding.
 """
 
 from __future__ import annotations
@@ -41,7 +49,27 @@ def cross_attention_flops(pixels: int, channels: int, ctx_len: int,
             + 2 * 2 * pixels * ctx_len * channels)
 
 
-def transformer_flops(uc: dict, hw: int, c: int, cross: bool = True) -> int:
+def depth_at(uc: dict, lvl: int) -> int:
+    """Transformer blocks of one spatial transformer at level ``lvl``; 0
+    where the level has none (``attention_levels`` says so, or its depth)."""
+    depth = uc["transformer_depth"]
+    if not uc["attention_levels"][lvl]:
+        return 0
+    return depth if isinstance(depth, int) else depth[lvl]
+
+
+def mid_depth(uc: dict) -> int:
+    """The mid block's: the last level's entry, or the one int."""
+    depth = uc["transformer_depth"]
+    return depth if isinstance(depth, int) else depth[-1]
+
+
+def transformer_flops(uc: dict, hw: int, c: int, cross: bool = True,
+                      depth: int = 1) -> int:
+    """One spatial transformer of ``depth`` blocks; nothing where it has
+    none (a level without attention)."""
+    if not depth:
+        return 0
     f = 2 * _conv(hw, c, c, 1)                          # proj_in, proj_out
     per_block = self_attention_flops(hw, c)
     if cross:
@@ -49,23 +77,29 @@ def transformer_flops(uc: dict, hw: int, c: int, cross: bool = True) -> int:
                                            uc["cross_attention_dim"])
     inner = c * uc["ff_mult"]
     per_block += 2 * hw * c * 2 * inner + 2 * hw * inner * c     # GEGLU
-    return f + uc["transformer_depth"] * per_block
+    return f + depth * per_block
 
 
 def unet_sites(uc: dict):
-    """The attention site groups in call order: (place, level, pixels,
-    channels), one per spatial transformer."""
+    """The transformer blocks in call order, each a self site and then a
+    cross site: (place, level, pixels, channels), ``depth`` of them a
+    spatial transformer (the order of ``models/config.py:unet_attn_specs``)."""
     levels = len(uc["block_out_channels"])
     out = []
     for lvl in range(levels):
-        if uc["attention_levels"][lvl]:
-            out += [("down", lvl)] * uc["layers_per_block"]
-    out.append(("mid", levels - 1))
+        out += [("down", lvl)] * uc["layers_per_block"] * depth_at(uc, lvl)
+    out += [("mid", levels - 1)] * mid_depth(uc)
     for lvl in reversed(range(levels)):
-        if uc["attention_levels"][lvl]:
-            out += [("up", lvl)] * (uc["layers_per_block"] + 1)
+        out += [("up", lvl)] * (uc["layers_per_block"] + 1) * depth_at(uc, lvl)
     return [(place, lvl, (uc["sample_size"] >> lvl) ** 2,
              uc["block_out_channels"][lvl]) for place, lvl in out]
+
+
+def self_site_names(uc: dict):
+    """The self sites' scope names in call order, as the program builds them:
+    the place and the site's index among all attention sites (a block's self
+    site, then its cross site)."""
+    return [f"{place}{2 * i}" for i, (place, *_) in enumerate(unet_sites(uc))]
 
 
 def unet_forward_flops(uc: dict, cross: bool = True) -> int:
@@ -75,6 +109,8 @@ def unet_forward_flops(uc: dict, cross: bool = True) -> int:
     temb = chs[0] * 4
     side = uc["sample_size"]
     f = 2 * chs[0] * temb + 2 * temb * temb
+    if uc.get("addition_embed_in"):
+        f += 2 * uc["addition_embed_in"] * temb + 2 * temb * temb
     f += _conv(side * side, uc["in_channels"], chs[0])
     skips = [chs[0]]
     cin = chs[0]
@@ -82,8 +118,7 @@ def unet_forward_flops(uc: dict, cross: bool = True) -> int:
         hw = (side >> lvl) ** 2
         for _ in range(uc["layers_per_block"]):
             f += res_block_flops(hw, cin, cout, temb)
-            if uc["attention_levels"][lvl]:
-                f += transformer_flops(uc, hw, cout, cross)
+            f += transformer_flops(uc, hw, cout, cross, depth_at(uc, lvl))
             cin = cout
             skips.append(cout)
         if lvl != len(chs) - 1:
@@ -91,22 +126,23 @@ def unet_forward_flops(uc: dict, cross: bool = True) -> int:
             skips.append(cout)
     hw = (side >> (len(chs) - 1)) ** 2
     f += 2 * res_block_flops(hw, chs[-1], chs[-1], temb)
-    f += transformer_flops(uc, hw, chs[-1], cross)
+    f += transformer_flops(uc, hw, chs[-1], cross, mid_depth(uc))
     for lvl in reversed(range(len(chs))):
         cout = chs[lvl]
         hw = (side >> lvl) ** 2
         for _ in range(uc["layers_per_block"] + 1):
             f += res_block_flops(hw, cin + skips.pop(), cout, temb)
-            if uc["attention_levels"][lvl]:
-                f += transformer_flops(uc, hw, cout, cross)
+            f += transformer_flops(uc, hw, cout, cross, depth_at(uc, lvl))
             cin = cout
         if lvl != 0:
             f += _conv(4 * hw, cout, cout)
     return f + _conv(side * side, chs[0], uc["out_channels"])
 
 
-def text_encoder_flops(tc: dict) -> int:
-    """One prompt through the text tower."""
+def text_encoder_flops(tc) -> int:
+    """One prompt through the text tower, or through each of a list."""
+    if isinstance(tc, (list, tuple)):
+        return sum(text_encoder_flops(t) for t in tc)
     n, d, inner = (tc["max_position_embeddings"], tc["hidden_size"],
                    tc["attention_inner_dim"])
     per_layer = (4 * 2 * n * d * inner + 2 * 2 * n * n * inner

@@ -1,18 +1,20 @@
 """Kernels: roofline share of the library flash attention, which runs the
-self-attention sites that no controller touches (the 64 x 64 sites of SD-1.4
-under the paper's edit). For every kernel event inside the sampling loop the
-least time the chip could take for softmax(Q K^T) V at the event's own shape
-(batch, heads, pixels, head size: the larger of 4 B H P^2 d operations over
-the bf16 peak and of q, k, v read and the output written once over the
-bandwidth), summed, over the summed device time of those events.
+self-attention sites from 1,024 keys up that no controller touches (ten
+events a step in both cells since PR 30). For every kernel event inside the
+sampling loop the least time the chip could take for softmax(Q K^T) V at the
+event's own shape (batch, heads, pixels, head size: the larger of
+4 B H P^2 d operations over the bf16 peak and of q, k, v read and the output
+written once over the bandwidth), summed, over the summed device time of
+those events.
 Compute-bound at SD-1.4's shapes: 21.5 GFLOP against 21 MB a row.
 
 Keyed on the Mosaic kernel's events because the TPU's trace carries no scope
 names: it reads nothing in a cell whose untouched sites take XLA's own
-attention (32 x 32 sites lie under the 2048 pixels where the program
-switches to the kernel), and would read nothing if another implementation
-took the kernel's place. PERF.md lists the scopes the program has to get
-into the trace for the per-site form."""
+attention (sites under the 1,024 keys from which the program switches to
+the kernel), and would read nothing if another implementation took the
+kernel's place. ``model.self_attn_kernel_ms_per_step`` is the per-scope form
+of its denominator; keying this reader on the scope is an open question of
+PERF.md."""
 
 from benchmarks.lib import trace as T
 from benchmarks.lib.peaks import peaks_for

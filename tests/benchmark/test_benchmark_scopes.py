@@ -4,8 +4,9 @@ program's own scope index for the instructions that ran in them (one TPU v5e;
 ``benchmarks/tools/record_scoped.py`` wrote the pair), on the program's span
 ring and compile ledger, against a program that offers none of them, and in
 the CPU rehearsal of the benchmark, where every reader is loaded and reads
-nothing (the manifest for that is composed here: the rehearsal's own file is
-the accepted benchmark's, and a program PR adds no entry to it)."""
+nothing (the rehearsal's own manifest lists them since PR 33). Entries of a
+manifest are found by name (``per_layer_named``): nothing here depends on
+where an entry stands in its list or on how many a later PR appends."""
 
 import gzip
 import json
@@ -26,6 +27,23 @@ STEP_METRICS = ("model.resblock_ms_per_step", "model.self_attn_ms_per_step",
                 "sampler.outside_unet_ms_per_step")
 SCOPE_METRICS = STEP_METRICS + ("model.vae_decode_scope_ms_per_image",
                                 "model.scoped_pct")
+#: PR 27's ten entries, and PR 33's three that read the program's launch
+#: registry: what a program without registry, spans or ledger leaves silent.
+PR27_METRICS = SCOPE_METRICS + ("entry.span_ms_per_call", "compile.setup_trace_lower_s",
+                                "compile.setup_uncached_programs")
+LAUNCH_METRICS = ("model.self_attn_kernel_ms_per_step",
+                  "model.self_attn_edited_ms_per_step", "device.program_temp_gib")
+
+
+def named(entries, name):
+    """The one entry of a manifest's list that carries ``name``."""
+    found = [e for e in entries if e["name"] == name]
+    assert [e["name"] for e in found] == [name], f"{name}: {found}"
+    return found[0]
+
+
+def per_layer_named(manifest, names):
+    return [named(manifest["per_layer"], n) for n in names]
 
 
 def _read(name, run):
@@ -203,9 +221,8 @@ def test_a_program_without_registry_spans_or_ledger_reads_nothing(recorded, monk
                                               "parent": None, "name": "sampler.text2image",
                                               "ts_ms": 3.0, "dur_ms": 1.0}])
     manifest = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
-    new = [m["name"] for m in manifest["per_layer"]][9:]
-    assert len(new) == 10
-    assert [_read(m, run) for m in new] == [None] * 10
+    silent = [m["name"] for m in per_layer_named(manifest, PR27_METRICS + LAUNCH_METRICS)]
+    assert {m: _read(m, run) for m in silent} == dict.fromkeys(silent)
     assert _read("sampler.step_ms", run) is not None     # the old ones still read
 
 
@@ -215,14 +232,12 @@ REHEARSAL_CELLS = ("tiny.edit-replace", "tiny_ldm.edit-batch4", "tiny.serve-back
 
 @pytest.mark.parametrize("cell", REHEARSAL_CELLS)
 def test_cpu_rehearsal_loads_every_new_reader(cell, monkeypatch):
-    """The rehearsal's manifest plus this PR's ten entries, each listed for
-    the toy cells: a traced run calls every new reader, none raises, and off
-    the chip none prints a number."""
+    """The rehearsal's manifest as it is lists PR 27's ten entries and PR 33's
+    three for the toy cells: a traced run calls every one of their readers,
+    none raises, and off the chip none prints a number."""
     manifest = harness.load_json(os.path.join(REHEARSAL, "BENCHMARK.json"))
-    before = {m["name"] for m in manifest["per_layer"]}
-    new = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))["per_layer"][9:]
-    assert len(new) == 10 and not before & {m["name"] for m in new}
-    manifest["per_layer"] += [dict(m, workloads=list(REHEARSAL_CELLS)) for m in new]
+    new = per_layer_named(manifest, PR27_METRICS + LAUNCH_METRICS)
+    assert all(cell in m["workloads"] for m in new)
     loaded, load = [], harness.load_module
 
     def noting(kind, name):
@@ -235,4 +250,55 @@ def test_cpu_rehearsal_loads_every_new_reader(cell, monkeypatch):
     assert r["correct"] is True and r["failed"] == 0
     assert {("metrics", m["name"]) for m in new} <= set(loaded)
     assert not set(r["metrics"]) & {m["name"] for m in new}
-    assert set(r["metrics"]) <= before
+    assert set(r["metrics"]) <= {m["name"] for m in manifest["per_layer"]}
+
+
+# -- entries are found by name, wherever they stand --------------------------
+
+DUMMY = {"name": "dummy.metric_of_a_later_pr", "unit": "ms", "better": "lower",
+         "source": "device_trace", "layer": "Model", "moves": "images_per_s",
+         "workloads": ["sd14.edit-replace", "sd21.edit-replace"]}
+
+
+@pytest.mark.parametrize("where", ("appended", "in_front", "in_the_middle"))
+def test_an_entry_of_a_later_pr_moves_no_helper(where):
+    """One more per-layer entry in a copy of the manifest, at the end, at the
+    front or between two others: every helper of these tests finds the same
+    entries as without it, and the harness hands the cell one metric more."""
+    import copy
+
+    from test_benchmark_sd21 import READ_IN_THE_CELL
+
+    manifest = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
+    grown = copy.deepcopy(manifest)
+    entries = grown["per_layer"]
+    {"appended": entries.append, "in_front": lambda e: entries.insert(0, e),
+     "in_the_middle": lambda e: entries.insert(9, e)}[where](dict(DUMMY))
+    for names in (PR27_METRICS, LAUNCH_METRICS, READ_IN_THE_CELL):
+        assert per_layer_named(grown, names) == per_layer_named(manifest, names)
+    assert named(grown["per_layer"], DUMMY["name"]) == DUMMY
+    for cell in manifest["workloads"]:
+        before = harness.metrics_of(manifest, cell, "per_layer")
+        after = harness.metrics_of(grown, cell, "per_layer")
+        assert [m for m in after if m != DUMMY] == before and DUMMY in after
+        assert harness.metrics_of(grown, cell, "end_to_end") == \
+            harness.metrics_of(manifest, cell, "end_to_end")
+
+
+def test_no_test_takes_a_manifests_entries_by_position_or_count():
+    """No slice, index or length of a manifest's ``per_layer``, ``end_to_end``
+    or ``workloads`` in any test of the benchmark: PR 27's ``[9:]`` and
+    ``len(new) == 10`` kept every later PR from listing a per-layer metric."""
+    import glob
+    import re
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    group = r'\[\s*"(?:per_layer|end_to_end|workloads)"\s*\]'
+    pinned = re.compile(group + r"\s*\)?\s*\[|len\([^()\n]*" + group + r"\s*\)")
+    found = [(os.path.basename(path), n + 1, line.strip())
+             for path in sorted(glob.glob(os.path.join(here, "test_*.py")))
+             for n, line in enumerate(open(path)) if pinned.search(line)]
+    assert found == []
+    assert pinned.search('new = manifest["per_layer"' + "][9:]")
+    assert pinned.search('assert len(MANIFEST["workloads"' + "]) == 2")
+    assert pinned.search('json.load(f)["end_to_end"' + "][-1]")

@@ -5,7 +5,10 @@ v-prediction (``reference/latent_diffusion_v.py``) and its blocked attention,
 the cell's CPU rehearsal at a toy v-prediction preset (``rehearsal_v/``) with
 ``correct`` true, and false under the control and each planted fault, and the
 two readers of self-attention time by site class (``lib/self_sites.py``) on
-the recorded SD-1.4 trace and in the rehearsal."""
+the recorded SD-1.4 trace and in the rehearsal. Since PR 33 the classes are
+what a launch recorded (``Launch.self_sites``), handed in with the recorded
+trace, and the temporaries of the launched program are read beside them
+(``lib/launched.py``, ``device.program_temp_gib``)."""
 
 import gzip
 import json
@@ -15,7 +18,8 @@ import time
 import numpy as np
 import pytest
 
-from benchmarks.lib import controls, controls_v, flops, harness, pipeline, scopes, self_sites
+from benchmarks.lib import (controls, controls_v, flops, harness, launched, pipeline,
+                            scopes, self_sites)
 from benchmarks.lib import trace as T
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -25,8 +29,26 @@ MANIFEST = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
 SD21 = harness.load_json(os.path.join(harness.HERE, "configs", "sd21.json"))
 SD21_MIX = harness.load_json(os.path.join(harness.HERE, "traffic", "sd21.edit-replace.json"))
 SD14 = harness.load_json(os.path.join(harness.HERE, "configs", "sd14.json"))
-SD14_MIX = harness.load_json(os.path.join(harness.HERE, "traffic", "sd14.edit-replace.json"))
-NEW_READERS = ("model.self_attn_kernel_ms_per_step", "model.self_attn_stored_ms_per_step")
+from test_benchmark_flops import THREE_LEVEL  # noqa: E402
+from test_benchmark_scopes import fake_run, named  # noqa: E402
+NEW_READERS = ("model.self_attn_kernel_ms_per_step", "model.self_attn_edited_ms_per_step")
+MODULE = "jit__text2image_jit"
+SITES = ("down0", "down2", "down4", "down6", "down8", "down10", "mid12", "up14",
+         "up16", "up18", "up20", "up22", "up24", "up26", "up28", "up30")
+
+
+def hows(kernel, sites=SITES):
+    """A launch's record by site index: ``kernel`` for the named sites, the
+    others the controller's."""
+    return {2 * i: "kernel" if s in kernel else "edited" for i, s in enumerate(sites)}
+
+
+#: What the launches of both cells said when the trace under ``data/`` was
+#: recorded (PR 27: the store kept every map up to half the latent's side),
+#: and what they say since PR 30 (a stored map is kept only for a reader).
+LAUNCH_PR27 = hows(("down0", "down2", "up26", "up28", "up30"))
+LAUNCH_PR30 = hows(("down0", "down2", "down4", "down6", "up20", "up22", "up24",
+                    "up26", "up28", "up30"))
 
 
 # -- the configuration -------------------------------------------------------
@@ -64,21 +86,15 @@ READ_IN_THE_CELL = (
     "compile.setup_uncached_programs")
 
 
-def _named(entries, name):
-    found = [e for e in entries if e["name"] == name]
-    assert len(found) == 1, name
-    return found[0]
-
-
 def test_sd21_and_its_cell_are_in_the_manifest():
-    entry = _named(MANIFEST["configs"], "sd21")
+    entry = named(MANIFEST["configs"], "sd21")
     assert (entry["file"], entry["reduced"]) == ("benchmarks/configs/sd21.json", [])
     assert entry["source"] == SD21["source"] == \
         "https://huggingface.co/stabilityai/stable-diffusion-2-1"
-    cell = _named(MANIFEST["workloads"], "sd21.edit-replace")
+    cell = named(MANIFEST["workloads"], "sd21.edit-replace")
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("sd21", "edit-replace", 1)
     for name in READ_IN_THE_CELL:
-        assert "sd21.edit-replace" in _named(MANIFEST["per_layer"], name)["workloads"], name
+        assert "sd21.edit-replace" in named(MANIFEST["per_layer"], name)["workloads"], name
     edit = SD21_MIX["edit"]
     assert (SD21_MIX["driver"], edit["self_max_pixels"], edit["store"],
             edit["num_steps"], SD21_MIX["trace_calls"]) == ("closed_edit", 576, True, 50, 1)
@@ -191,15 +207,12 @@ def test_reference_told_epsilon_is_not_correct(monkeypatch):
 
 
 def test_rehearsal_loads_both_new_readers(monkeypatch):
-    """The two readers are in no manifest yet (PERF.md §7: a test of the
-    accepted benchmark pins the number of entries after the ninth): composed
-    here, a traced run loads them, none raises, and off the chip none prints
-    a number."""
+    """The cell's rehearsal manifest as it is lists the two readers (PR 33):
+    a traced run loads them, none raises, and off the chip none prints a
+    number."""
     manifest = harness.load_json(os.path.join(REHEARSAL_V, "BENCHMARK.json"))
-    manifest["per_layer"] += [
-        {"name": n, "unit": "ms", "better": "lower", "source": "device_trace",
-         "layer": "Model", "moves": "images_per_s", "workloads": [CELL_V]}
-        for n in NEW_READERS]
+    for n in NEW_READERS:
+        assert CELL_V in named(manifest["per_layer"], n)["workloads"]
     loaded, load = [], harness.load_module
     monkeypatch.setattr(harness, "load_module",
                         lambda kind, name: loaded.append((kind, name)) or load(kind, name))
@@ -209,23 +222,89 @@ def test_rehearsal_loads_both_new_readers(monkeypatch):
     assert not set(r["metrics"]) & set(NEW_READERS)
 
 
+def test_classes_and_temporaries_of_a_program_this_process_launched(monkeypatch):
+    """After a rehearsal run the program's own registry holds the toy cell's
+    launch: the classes are its ``self_sites`` under the layout's names, and
+    XLA's temporaries of that program are a count of bytes. The metric prints
+    none of it off the chip."""
+    from types import SimpleNamespace
+
+    from p2p_tpu.obs import launches
+
+    assert run(trace=True)["correct"] is True
+    config = harness.load_json(os.path.join(REHEARSAL_V, "bench", "configs", "tiny_v.json"))
+    launch = launches.programs(MODULE)[-1]
+    assert launched.newest(MODULE) is launch and launch.self_sites
+    got = self_sites.classes(SimpleNamespace(config=config), MODULE)
+    assert list(got) == flops.self_site_names(config["unet"])
+    assert list(got.values()) == [s.how for _, s in sorted(launch.self_sites.items())]
+    assert set(got.values()) <= {"kernel", "einsum", "edited"}
+    assert launched.temp_bytes(MODULE) > 0
+    assert launched.newest("jit_no_such_program") is None
+    assert launched.temp_bytes("jit_no_such_program") is None
+    # the reader: the largest over the modules whose loop ran in the window
+    read = harness.load_module("metrics", "device.program_temp_gib").read
+    trace = T.Trace.from_dict({
+        "devices": {"/device:TPU:0": [["while.1", 0, 900, "while", MODULE, []],
+                                      ["fusion.1", 100, 200, "fusion:kLoop", MODULE, []],
+                                      ["copy.2", 950, 20, "copy", "jit_other", []]]},
+        "modules": {"/device:TPU:0": [[MODULE, 0, 900]]}, "spans": []})
+    on = SimpleNamespace(on_chip=True, trace_data=trace, trace_window=(0, 1000))
+    assert launched.loop_modules(on) == [MODULE]
+    assert read(on) == launched.temp_bytes(MODULE) / 2 ** 30
+    assert read(SimpleNamespace(on_chip=False, trace_data=trace,
+                                trace_window=(0, 1000))) is None
+    assert read(SimpleNamespace(on_chip=True, trace_data=None)) is None
+    monkeypatch.setattr(launches, "programs", lambda module=None: [])
+    assert read(on) is None                        # nothing launched: nothing read
+
+
 # -- self-attention time by site class ---------------------------------------
 
-def test_site_classes_from_the_layout():
-    for config, mix in ((SD14, SD14_MIX), (SD21, SD21_MIX)):
-        classes = self_sites.classes(config, mix)
-        assert list(classes) == [
-            "down0", "down2", "down4", "down6", "down8", "down10", "mid12", "up14",
-            "up16", "up18", "up20", "up22", "up24", "up26", "up28", "up30"]
-        kernel = [s for s, c in classes.items() if c == "kernel"]
-        assert kernel == ["down0", "down2", "up26", "up28", "up30"]
-        assert sorted(set(classes.values())) == ["kernel", "stored"]
-    # without the store only the edit's own sites are the controller's; a
-    # site under the kernel's 1024 keys that nobody touches takes the chain
-    bare = dict(SD14_MIX, edit=dict(SD14_MIX["edit"], store=False, self_max_pixels=64))
-    got = self_sites.classes(SD14, bare)
-    assert [got[s] for s in ("down0", "down4", "down8", "mid12")] == [
-        "kernel", "kernel", "einsum", "stored"]
+def _run_with(config, record):
+    from types import SimpleNamespace
+
+    return SimpleNamespace(config=config, self_site_hows={MODULE: record})
+
+
+@pytest.mark.parametrize("config", (SD14, SD21), ids=("sd14", "sd21"))
+@pytest.mark.parametrize("record,kernel", [
+    (LAUNCH_PR27, ["down0", "down2", "up26", "up28", "up30"]),
+    (LAUNCH_PR30, ["down0", "down2", "down4", "down6", "up20", "up22", "up24",
+                   "up26", "up28", "up30"])], ids=("pr27", "pr30"))
+def test_site_classes_from_a_recorded_launch(config, record, kernel):
+    """The class is the launch's word for the site, the layout gives the
+    name: nothing is worked out from the traffic's ``store``."""
+    classes = self_sites.classes(_run_with(config, record), MODULE)
+    assert list(classes) == list(SITES)
+    assert [s for s, c in classes.items() if c == "kernel"] == kernel
+    assert sorted(set(classes.values())) == ["edited", "kernel"]
+
+
+def test_site_classes_keep_every_word_of_the_launch_and_refuse_another_layout():
+    mixed = {**LAUNCH_PR30, 8: "einsum", 24: "sharded"}
+    got = self_sites.classes(_run_with(SD14, mixed), MODULE)
+    assert [got[s] for s in ("down0", "down8", "mid12", "up24")] == [
+        "kernel", "einsum", "edited", "sharded"]
+    # a launch of other sites than the configuration's layout: not this program
+    fewer = {i: h for i, h in LAUNCH_PR30.items() if i < 24}
+    assert self_sites.classes(_run_with(SD14, fewer), MODULE) is None
+    assert self_sites.classes(_run_with(SD14, {}), MODULE) is None
+    assert self_sites.classes(_run_with(SD14, LAUNCH_PR30), "jit_other") is None
+
+
+def test_site_names_where_depth_differs_by_level():
+    """A three-level member whose top level has no attention and whose
+    transformers are 2 and 10 blocks deep: 70 self sites, a block's self
+    site then its cross site, in call order."""
+    names = flops.self_site_names(THREE_LEVEL)
+    assert len(names) == 70 == 2 * 2 + 2 * 10 + 10 + 3 * 10 + 3 * 2
+    assert names[:5] == ["down0", "down2", "down4", "down6", "down8"]
+    assert names[23:26] == ["down46", "mid48", "mid50"] and names[-1] == "up138"
+    record = hows(names[:4] + names[-6:], sites=names)
+    classes = self_sites.classes(_run_with({"unet": THREE_LEVEL}, record), MODULE)
+    assert list(classes) == names
+    assert [s for s, c in classes.items() if c == "kernel"] == names[:4] + names[-6:]
 
 
 def _recorded():
@@ -238,35 +317,60 @@ def _recorded():
                    load("trace_sd14_scoped_index.json.gz").items()}
 
 
-def test_kernel_and_stored_account_for_self_attention_on_the_recorded_pair(capsys):
-    """Two steps of `sd14.edit-replace` recorded on a v5e (PR 27): the five
-    64 x 64 sites' ``core`` (2.08-2.09 ms each then) and the eleven stored
-    sites' sum with the sites' ``qkv`` and ``out`` to the whole part."""
-    from test_benchmark_scopes import fake_run
-
+def test_kernel_and_edited_account_for_self_attention_on_the_recorded_pair(capsys):
+    """Two steps of `sd14.edit-replace` recorded on a v5e (PR 27) with the
+    launch of that time handed in: the five 64 x 64 sites' ``core`` (2.08-2.09
+    ms each then) and the eleven the controller had sum with the sites'
+    ``qkv`` and ``out`` to the whole part."""
     trace, indexes = _recorded()
-    run_ = fake_run(trace, indexes, config=SD14, traffic=SD14_MIX)
-    kernel, stored = (harness.load_module("metrics", n).read(run_) for n in NEW_READERS)
+    run_ = fake_run(trace, indexes, config=SD14, self_site_hows={MODULE: LAUNCH_PR27})
+    kernel, edited = (harness.load_module("metrics", n).read(run_) for n in NEW_READERS)
     whole = harness.load_module("metrics", "model.self_attn_ms_per_step").read(run_)
-    capsys.readouterr()                                # the scope tree
+    err = capsys.readouterr().err                      # the scope tree, then the classes
+    assert err.count("self-attention core by how the site ran") == 1
     assert kernel == pytest.approx(5 * 2.08, rel=0.01)
-    assert stored == pytest.approx(5 * 0.588 + 6 * 0.012, rel=0.05)
+    assert edited == pytest.approx(5 * 0.588 + 6 * 0.012, rel=0.05)
     scoped = scopes.load(run_)
     rest = sum(r.op.dur for r in scoped.rows if r.op.loop and r.part == "self_attn"
                and r.scope.rsplit("/", 1)[-1] in ("qkv", "out")) / scoped.steps / 1e6
-    assert kernel + stored + rest == pytest.approx(whole, rel=1e-6)
+    assert kernel + edited + rest == pytest.approx(whole, rel=1e-6)
 
 
-def test_readers_read_nothing_from_a_program_without_the_table(monkeypatch):
-    """Laid over a parent whose ``nn`` has no ``flash_block``, or with no
-    scope index at all, both return None and do not raise."""
-    from test_benchmark_scopes import fake_run
+def test_the_same_trace_under_another_launch_moves_time_between_the_classes(capsys):
+    """The reader follows the record, not the trace: told that the five
+    32 x 32 sites ran on the kernel too, it counts their ``core`` there, the
+    sum stays, and a class no site has is not printed as 0."""
+    trace, indexes = _recorded()
+    then = fake_run(trace, indexes, config=SD14, self_site_hows={MODULE: LAUNCH_PR27})
+    now = fake_run(trace, indexes, config=SD14, self_site_hows={MODULE: LAUNCH_PR30})
+    k0, e0, k1, e1 = (harness.load_module("metrics", n).read(r)
+                      for r in (then, now) for n in NEW_READERS)
+    capsys.readouterr()
+    assert k1 - k0 == pytest.approx(5 * 0.588, rel=0.05)
+    assert k0 + e0 == pytest.approx(k1 + e1, rel=1e-9)
+    assert self_sites.core_ms_per_step(now, "einsum") is None
+    assert self_sites.core_ms_per_step(now, "sharded") is None
 
-    from p2p_tpu.models import nn
+
+@pytest.mark.parametrize("why", ("no_index", "no_record", "no_registry", "nothing_launched"))
+def test_readers_read_nothing_without_the_launchs_record(why, monkeypatch):
+    """Laid over a parent that records no launch or no sites, or with no scope
+    index at all, both return None and do not raise."""
+    import sys
+
+    import p2p_tpu.obs
+    from p2p_tpu.obs import launches
 
     trace, indexes = _recorded()
-    no_index = fake_run(trace, {}, config=SD14, traffic=SD14_MIX)
-    assert [harness.load_module("metrics", n).read(no_index) for n in NEW_READERS] == [None] * 2
-    monkeypatch.delattr(nn, "flash_block")
-    run_ = fake_run(trace, indexes, config=SD14, traffic=SD14_MIX)
+    if why == "no_index":
+        run_ = fake_run(trace, {}, config=SD14, self_site_hows={MODULE: LAUNCH_PR27})
+    elif why == "no_record":
+        run_ = fake_run(trace, indexes, config=SD14, self_site_hows={})
+    else:
+        run_ = fake_run(trace, indexes, config=SD14)
+        if why == "no_registry":
+            monkeypatch.setitem(sys.modules, "p2p_tpu.obs.launches", None)   # ImportError
+            monkeypatch.delattr(p2p_tpu.obs, "launches", raising=False)
+        else:
+            monkeypatch.setattr(launches, "programs", lambda module=None: [])
     assert [harness.load_module("metrics", n).read(run_) for n in NEW_READERS] == [None] * 2
