@@ -1,40 +1,83 @@
 """The system under test, built from a configuration file: the program's
 ``Pipeline`` for the preset the file names, checked size by size against the
-file, holding the benchmark's weights."""
+file, holding the benchmark's weights.
+
+The file's schema is ``lib/flops.py``'s, and a preset may be any member of
+the ``UNet2DConditionModel`` family as the program presents it:
+``_sizes_of_program`` states, attribute by attribute, what the harness reads
+of one."""
 
 from __future__ import annotations
 
 import jax
 
 
+def _towers(pc) -> tuple:
+    """The preset's text towers in order: ``pc.text`` is one tower's
+    configuration or a sequence of them."""
+    return tuple(pc.text) if isinstance(pc.text, (list, tuple)) else (pc.text,)
+
+
+def _by_tower(pc, f):
+    """``f`` of the one tower, or the list of ``f`` of each of a sequence."""
+    each = [f(t) for t in _towers(pc)]
+    return each if isinstance(pc.text, (list, tuple)) else each[0]
+
+
+def _sizes_of_tower(t) -> dict:
+    sizes = {
+        "arch": t.arch, "vocab_size": t.vocab_size,
+        "hidden_size": t.hidden_dim, "num_hidden_layers": t.num_layers,
+        "num_attention_heads": t.num_heads,
+        "attention_inner_dim": t.inner_dim,
+        "max_position_embeddings": t.max_length, "ff_mult": t.ff_mult,
+        "hidden_act": t.activation, "causal": t.causal,
+        "qkv_bias": t.attn_qkv_bias,
+    }
+    if getattr(t, "projection_dim", None) is not None:
+        sizes["projection_dim"] = t.projection_dim
+    return sizes
+
+
 def _sizes_of_program(pc) -> dict:
-    """The program's preset in the configuration file's own keys."""
-    u, t, v, s = pc.unet, pc.text, pc.vae, pc.scheduler
+    """The program's preset in the configuration file's own keys.
+
+    The contract a preset is held to: a key stands for an attribute the
+    preset has, and none appears for one it lacks, so a key on one side only
+    is a refusal.
+
+    - ``unet.transformer_depth``: ``UNetConfig.transformer_depth`` as it is
+      where it is an int; a list where it is a sequence, one int a level, 0
+      where the level has no transformer (the mid block takes the last).
+    - ``text_encoder``: one tower's dict where ``PipelineConfig.text`` is one
+      tower's configuration; a list of such dicts, in order, where it is a
+      sequence of them. A tower's dict has ``projection_dim`` only where the
+      tower's configuration has one that is not None.
+    - ``unet.addition_embed_in``: only where the U-Net's configuration has an
+      attribute of that name that is not None.
+    """
+    u, v, s = pc.unet, pc.vae, pc.scheduler
+    depth = u.transformer_depth
+    unet = {
+        "sample_size": u.sample_size, "in_channels": u.in_channels,
+        "out_channels": u.out_channels,
+        "block_out_channels": list(u.block_channels),
+        "attention_levels": list(u.attn_levels),
+        "layers_per_block": u.layers_per_block,
+        "transformer_depth": depth if isinstance(depth, int) else list(depth),
+        "num_attention_heads": None if u.head_dim else u.num_heads,
+        "attention_head_size": u.head_dim,
+        "cross_attention_dim": u.context_dim, "context_len": u.context_len,
+        "norm_num_groups": u.groups, "ff_mult": u.ff_mult,
+    }
+    if getattr(u, "addition_embed_in", None) is not None:
+        unet["addition_embed_in"] = u.addition_embed_in
     return {
         "image_size": pc.image_size,
         "guidance_scale": pc.guidance_scale,
         "num_inference_steps": pc.num_steps,
-        "unet": {
-            "sample_size": u.sample_size, "in_channels": u.in_channels,
-            "out_channels": u.out_channels,
-            "block_out_channels": list(u.block_channels),
-            "attention_levels": list(u.attn_levels),
-            "layers_per_block": u.layers_per_block,
-            "transformer_depth": u.transformer_depth,
-            "num_attention_heads": None if u.head_dim else u.num_heads,
-            "attention_head_size": u.head_dim,
-            "cross_attention_dim": u.context_dim, "context_len": u.context_len,
-            "norm_num_groups": u.groups, "ff_mult": u.ff_mult,
-        },
-        "text_encoder": {
-            "arch": t.arch, "vocab_size": t.vocab_size,
-            "hidden_size": t.hidden_dim, "num_hidden_layers": t.num_layers,
-            "num_attention_heads": t.num_heads,
-            "attention_inner_dim": t.inner_dim,
-            "max_position_embeddings": t.max_length, "ff_mult": t.ff_mult,
-            "hidden_act": t.activation, "causal": t.causal,
-            "qkv_bias": t.attn_qkv_bias,
-        },
+        "unet": unet,
+        "text_encoder": _by_tower(pc, _sizes_of_tower),
         "vae": {
             "kind": v.kind, "in_channels": v.in_channels,
             "latent_channels": v.latent_channels,
@@ -68,29 +111,43 @@ def program_config(config: dict):
 
 
 def weight_shapes(pc):
+    """The shapes and types of the program's own initialisers. ``"text"`` is
+    the tower's tree, or a list of trees in the towers' order."""
     from p2p_tpu.models import init_text_encoder, init_unet
     from p2p_tpu.models import vae as vae_mod
 
     key = jax.random.PRNGKey(0)
     return {
         "unet": jax.eval_shape(lambda: init_unet(key, pc.unet)),
-        "text": jax.eval_shape(lambda: init_text_encoder(key, pc.text)),
+        "text": _by_tower(pc, lambda t: jax.eval_shape(
+            lambda: init_text_encoder(key, t))),
         "vae": jax.eval_shape(lambda: vae_mod.init_vae(key, pc.vae)),
     }
 
 
 def build(config: dict, seed: int):
-    """``(pipeline, weights)``: the weights tree is shared, not copied."""
+    """``(pipeline, weights)``: the weights tree is shared, not copied.
+    ``Pipeline.text_params`` is handed what ``weights["text"]`` is, a tree or
+    a list of trees. Every tower reads the ids of one tokenizer, the first
+    tower's."""
     from p2p_tpu.engine.sampler import Pipeline
     from p2p_tpu.utils.tokenizer import HashWordTokenizer
 
     from .weights import make_weights
 
     pc = program_config(config)
+    first, *others = _towers(pc)
+    for t in others:
+        if (t.vocab_size, t.max_length) != (first.vocab_size, first.max_length):
+            raise ValueError(
+                f"configuration {config['name']!r}: the harness has one "
+                f"tokenizer, and a tower of {t.vocab_size} ids and "
+                f"{t.max_length} positions stands beside one of "
+                f"{first.vocab_size} and {first.max_length}")
     weights = make_weights(seed, weight_shapes(pc),
                            config["assumed"]["attention_logit_gain"])
-    tok = HashWordTokenizer(vocab_size=pc.text.vocab_size,
-                            model_max_length=pc.text.max_length)
+    tok = HashWordTokenizer(vocab_size=first.vocab_size,
+                            model_max_length=first.max_length)
     pipe = Pipeline(config=pc, unet_params=weights["unet"],
                     text_params=weights["text"], vae_params=weights["vae"],
                     tokenizer=tok)

@@ -34,13 +34,17 @@ def warm_up(run, state, call) -> None:
 
 def controller(pipe, edit: dict, kind: str, prompts):
     """The edit's controller as the paper's code and ``bench.py`` build it:
-    ``controllers.factory.attention_<kind>``, found by the kind's name."""
+    ``controllers.factory.attention_<kind>``, found by the kind's name.
+    ``max_len`` is the tokenizer's ``model_max_length``: the controller
+    aligns the ids that tokenizer makes, and ``pipe.config.text`` is one
+    tower's configuration in some presets and a sequence of them in others
+    (``lib/pipeline.py`` builds the tokenizer from the first tower)."""
     from p2p_tpu.controllers import factory
 
     return getattr(factory, "attention_" + kind)(list(prompts), edit["num_steps"], edit["cross_replace_steps"],
                 edit["self_replace_steps"], pipe.tokenizer,
                 self_max_pixels=edit["self_max_pixels"],
-                max_len=pipe.config.text.max_length, store=edit["store"])
+                max_len=pipe.tokenizer.model_max_length, store=edit["store"])
 
 
 def closed_loop(run, call) -> None:
