@@ -267,7 +267,8 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
     and geometry are the measured ones of PERF.md §6 (PR 28: a sweep of
     both implementations on a v5e, head split and merge included)."""
     s_q = q.shape[-2]
-    geometry = flash_block(s_q, q.shape[-1], q.dtype.itemsize)
+    geometry = flash_block(s_q, q.shape[-1],
+                           flash_operand_dtype(q.dtype).itemsize)
     if (mask is None and s_q == k.shape[-2] and geometry is not None
             and _on_tpu()):
         return flash_attention_tpu(q, k, v, scale, geometry)
@@ -276,23 +277,34 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
 
 
 # The TPU gives a kernel 16 MiB of scoped VMEM; stay under it with headroom.
-_FLASH_VMEM_BUDGET = 14 * 2**20
+# 14.75 MiB is what ``_flash_vmem_bytes`` counts for (512, 4608, 1536) at 2 B,
+# the largest count of any tile the chip has compiled (forward, grad and
+# residuals) and run; at a count of 15.0 MiB its compiler refused (512, 2048,
+# 1024) for a 256-wide f32 head, over the limit by 76 KB (PERF.md §6, PR 35).
+_FLASH_VMEM_BUDGET = 59 * 2**18
+# The fused-edit kernel's count (``edit_block``) is another one: it keeps the
+# 14 MiB its sites were compiled under.
+_EDIT_VMEM_BUDGET = 14 * 2**20
 
 # The geometry table, (block_q, block_k_major, block_k), from the v5e sweeps
-# (PERF.md §6, PR 28 and PR 29). At 4096 keys a head's K and V stay resident
-# in VMEM (block_k_major == S) under a small q block; a resident geometry is
-# for that length only, since elsewhere each q block would fetch K and V
-# again. SD-2.1's lengths are 9 x 2^n and tile by none of the general
-# geometries but the slowest: 2304 keys (48²) take K and V resident under a
-# q block of 768 (1.29 ms a site against 2.72 at (256, 256, 256)); at 9216
-# keys (96²) K and V do not fit, every q block streams them, and the fewest
-# q blocks that leave room for a 3072-key score tile win (6.65 ms a site; a q
-# block of 256 reads K and V twice as often, 10.2). Any other length takes
+# (PERF.md §6, PR 28 and PR 29 with f32 operands, PR 35 with bfloat16 ones,
+# which is what ``flash_attention_tpu`` hands the kernel for f32 arrays; f32
+# operands still reach ``flash_attention_residuals``). A length's row is a
+# preference order; the guard picks by the operands' width. At 4096 keys a
+# head's K and V stay resident in VMEM (block_k_major == S) under a small q
+# block; a resident geometry is for that length only, since elsewhere each q
+# block would fetch K and V again. SD-2.1's lengths are 9 x 2^n and tile by
+# none of the general geometries but the slowest: 2304 keys (48²) take K and
+# V resident under a q block of 768 (1.29 ms a site against 2.72 at (256,
+# 256, 256)); at 9216 keys (96²) K and V do not fit, every q block streams
+# them, and the fewest q blocks that leave room for the largest score tile
+# win: 4608 keys at 2 B (6.39 ms a site against 6.51), 3072 at 4 B (6.65; a
+# q block of 256 reads K and V twice as often, 10.2). Any other length takes
 # the first of the general geometries that tiles it. All of them pass
 # through the VMEM guard, so a wide head steps down the list.
 _FLASH_BY_SEQ = {
     4096: ((256, 4096, 2048),),
-    9216: ((512, 3072, 1536),),
+    9216: ((512, 4608, 1536), (512, 3072, 1536)),
     2304: ((768, 2304, 1152),),
 }
 _FLASH_GEOMETRIES = (
@@ -361,9 +373,8 @@ def edit_block(pixels: int, key_len: int, head_dim: int, itemsize: int) -> int:
     — and each instance holds its own + the base row's tiles. Per block:
     3 q/out tiles (own q, base q, out) + 3 key-axis tiles (k, base k, v) in
     the carrier dtype, 3 f32 probability tiles (own, base, edited), the
-    ``(Kp, Kp)`` f32 edit transform, and f32 matmul accumulators. Same
-    14 MiB budget (of the 16 MiB scoped VMEM) as the flash geometry —
-    see the headroom note above ``_FLASH_VMEM_BUDGET``."""
+    ``(Kp, Kp)`` f32 edit transform, and f32 matmul accumulators, held to
+    ``_EDIT_VMEM_BUDGET`` (14 of the 16 MiB of scoped VMEM)."""
     kp = max(128, -(-key_len // 128) * 128)
 
     def vmem(bq: int) -> int:
@@ -371,12 +382,12 @@ def edit_block(pixels: int, key_len: int, head_dim: int, itemsize: int) -> int:
                 + 3 * bq * kp * 4 + kp * kp * 4 + 2 * bq * head_dim * 4)
 
     for bq in (512, 256, 128):
-        if pixels % bq == 0 and vmem(bq) <= _FLASH_VMEM_BUDGET:
+        if pixels % bq == 0 and vmem(bq) <= _EDIT_VMEM_BUDGET:
             return bq
     # Small or non-power-of-two maps (edited self sites, tiny test configs):
     # one block over the whole query axis if it fits.
     if pixels < 128 or all(pixels % bq for bq in (512, 256, 128)):
-        if vmem(pixels) <= _FLASH_VMEM_BUDGET:
+        if vmem(pixels) <= _EDIT_VMEM_BUDGET:
             return pixels
     return 0
 
@@ -440,21 +451,51 @@ def per_device(kernel):
                          out_specs=PartitionSpec(), check_vma=False)
 
 
+def flash_operand_dtype(dtype) -> jnp.dtype:
+    """The dtype in which ``flash_attention_tpu`` hands the kernel arrays of
+    ``dtype``, and so the width ``flash_block`` is asked at for such a site.
+
+    The kernel's two products carry no precision attribute, so at the
+    process's default matmul precision the MXU multiplies float32 operands
+    as bfloat16: the kernel rounded them itself, K and V at every q block,
+    after streaming them from HBM at twice the bytes. They are rounded once
+    in front of it instead. A process that asked for more
+    (``jax_default_matmul_precision`` above the default reaches the kernel's
+    products too) and arrays of any other dtype keep their own."""
+    name = jax.config.jax_default_matmul_precision
+    try:
+        default = name is None or (
+            jax.lax.Precision(name) == jax.lax.Precision.DEFAULT)
+    except ValueError:      # a dot algorithm by name: somebody chose
+        default = False
+    if default and dtype == jnp.float32:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(dtype)
+
+
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array,
                         scale: float, geometry) -> jax.Array:
     """The Pallas TPU flash kernel call `fused_attention` takes at the
     untouched self-attention sites, tiled by ``geometry`` (a ``flash_block``
     answer). ``scale`` is folded into ``q`` ahead of the kernel, where XLA
     fuses it into the head split, so the kernel (``sm_scale=1``) makes one
-    pass fewer over every score tile. Kept as a named function so the CPU
-    suite can run the identical code under
-    `pltpu.force_tpu_interpret_mode()` (tests/test_flash_pallas.py) — the
-    kernel otherwise only executes on a TPU."""
+    pass fewer over every score tile. The kernel takes ``q * scale``, ``k``
+    and ``v`` in ``flash_operand_dtype`` of the arrays' and its output comes
+    back in the arrays' own: the casts fuse into the head split and the merge
+    around the site, softmax statistics and accumulator stay f32 inside.
+    Kept as a named function so the CPU suite can run the identical code
+    under `pltpu.force_tpu_interpret_mode()` (tests/test_flash_pallas.py) —
+    the kernel otherwise only executes on a TPU."""
     from jax.experimental.pallas.ops.tpu import flash_attention as _fa
 
+    operand = flash_operand_dtype(q.dtype)
+
     def kernel(q, k, v):
-        return _fa.flash_attention(q * scale, k, v, causal=False, sm_scale=1.0,
-                                   block_sizes=_flash_block_sizes(geometry))
+        out = _fa.flash_attention(
+            (q * scale).astype(operand), k.astype(operand), v.astype(operand),
+            causal=False, sm_scale=1.0,
+            block_sizes=_flash_block_sizes(geometry))
+        return out.astype(q.dtype)
 
     return per_device(kernel)(q, k, v)
 

@@ -364,9 +364,11 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         q, k, v = split_heads(q), split_heads(k), split_heads(v)
 
     with jax.named_scope("core"):
-        # How nn.fused_attention runs the site, from the shape alone.
-        geometry = (nn.flash_block(pix, d_head, q.dtype.itemsize)
-                    if nn.takes_flash_kernel(pix, d_head, q.dtype.itemsize)
+        # How nn.fused_attention runs the site, from the shape alone: the
+        # tile is the one for the width the kernel is handed its operands in.
+        operand = nn.flash_operand_dtype(q.dtype)
+        geometry = (nn.flash_block(pix, d_head, operand.itemsize)
+                    if nn.takes_flash_kernel(pix, d_head, operand.itemsize)
                     else None)
         how = "einsum" if geometry is None else "kernel"
         if controller_touches(ctx.controller, meta):
@@ -387,7 +389,7 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
                 # scores on one device — the blow-up SpConfig exists to
                 # avoid — so that case is an error, not a warning.
                 if nn.flash_block(meta.pixels, d_head,
-                                  q.dtype.itemsize) is None:
+                                  operand.itemsize) is None:
                     raise ValueError(
                         f"sequence-parallel site {meta.layer_idx} has "
                         f"{meta.pixels} pixels, not divisible by mesh axis "
@@ -425,8 +427,10 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         else:
             out = nn.fused_attention(q, k, v, scale)
         if not is_cross:
+            on_kernel = how == "kernel"
             launches.note_self_site(meta.layer_idx, how, pix, d_head,
-                                    geometry if how == "kernel" else None)
+                                    geometry if on_kernel else None,
+                                    operand.name if on_kernel else "")
 
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)

@@ -16,7 +16,8 @@ reader gets from one to the other for a program that really ran:
   shardings. Shapes, never arrays. With them it keeps how each
   self-attention site of the U-Net ran, as the model noted while the
   program was traced (``note_self_site``; ``Launch.self_sites``: per site
-  its keys, head width, implementation and the flash kernel's geometry),
+  its keys, head width, implementation and the flash kernel's geometry and
+  operand dtype),
   and the bytes of attention maps the controller's store holds
   (``note_store_bytes``; ``Launch.store_bytes``, and the gauge
   ``launch_store_bytes{module}`` of ``obs.metrics``).
@@ -46,6 +47,9 @@ from ..utils.cache import compile_ledger
 from . import traceparse
 
 
+_SHORT = {"bfloat16": "bf16", "float32": "f32"}    # as the HLO text has them
+
+
 @dataclasses.dataclass(frozen=True)
 class SelfSite:
     """How one self-attention site of a traced program runs."""
@@ -54,10 +58,12 @@ class SelfSite:
     head_dim: int
     how: str                    # "kernel" | "einsum" | "edited" | "sharded"
     geometry: Optional[Tuple[int, int, int]] = None   # the flash kernel's tile
+    operand: str = ""           # dtype the kernel is handed q, k, v in
 
     def __str__(self):
         tile = "" if self.geometry is None else " " + "x".join(map(str, self.geometry))
-        return f"{self.keys}x{self.head_dim} {self.how}{tile}"
+        width = self.operand and " " + _SHORT.get(self.operand, self.operand)
+        return f"{self.keys}x{self.head_dim} {self.how}{tile}{width}"
 
 
 @dataclasses.dataclass
@@ -115,12 +121,13 @@ def built() -> int:
 
 
 def note_self_site(site: int, how: str, keys: int, head_dim: int,
-                   geometry=None) -> None:
+                   geometry=None, operand: str = "") -> None:
     """Trace time, from the model: self-attention site ``site`` of the
     program being traced, of ``keys`` pixels and heads ``head_dim`` wide,
-    runs ``how``, the flash kernel tiled by ``geometry``. Keyed by site, so
-    a body traced twice counts once."""
-    _traced_sites[site] = SelfSite(keys, head_dim, how, geometry)
+    runs ``how``, the flash kernel tiled by ``geometry`` and handed q, k, v
+    as ``operand`` (a dtype's name). Keyed by site, so a body traced twice
+    counts once."""
+    _traced_sites[site] = SelfSite(keys, head_dim, how, geometry, operand)
 
 
 def note_store_bytes(n: int) -> None:

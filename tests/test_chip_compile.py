@@ -15,9 +15,12 @@ budget. These compiles can, at about a second each and no chip time:
     fits, ``nn.edit_block`` answers 0, and keeps the materialized path);
 (b) ``nn.flash_attention_tpu`` forward and its ``jax.grad`` (null-text
     inversion differentiates through it) at every row of the geometry table
-    ``nn.flash_block`` answers from (``FLASH_ROWS``), in the row's dtype;
+    ``nn.flash_block`` answers from (``FLASH_ROWS``): arrays in the row's
+    dtype, the kernel handed bfloat16 in every row (f32 arrays are narrowed
+    in front of it, PR 35) and tiled by the table's answer at 2 B;
 (c) ``nn.flash_attention_residuals`` at the same rows and at the
-    ring-attention chunks (``RING_CHUNKS``);
+    ring-attention chunks (``RING_CHUNKS``), operands as they come: the f32
+    rows are the table's answers at 4 B, which only this variant still runs;
 (d) both kernels inside a program partitioned over a ``dp`` mesh of the four
     described chips, the way ``parallel.sweep`` traces its groups
     (``nn.kernel_mesh`` + ``vmap(spmd_axis_name="dp")``) — the partitioner
@@ -204,13 +207,19 @@ def test_fused_edit_kernel_compiles(one_chip, controllers, mode, key):
 #: VAE decoder's mid attention (one 512-wide head, f32), SD14_HR's 128x128,
 #: SD-2.x's 64x64 site (head size 64), and SD-2.1 at 768x768 (PR 29): its
 #: 96x96 and 48x48 self sites (9216 and 2304 keys, 9 x 2^n: the backward
-#: blocks have to tile them too) and the VAE's mid attention at 96x96.
+#: blocks have to tile them too) and the VAE's mid attention at 96x96; the
+#: two SD-2.1 self sites in bf16 as well (PR 35), so that every length with
+#: a row of its own in the table is asked at both widths; and a 256-wide
+#: bf16 head at 2,304 keys, which no preset has: the one other answer that
+#: the VMEM budget's move to 14.75 MiB changed (PERF.md §6, PR 35).
 FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
               (4096, 8, 40, jnp.bfloat16), (1024, 8, 80, jnp.bfloat16),
               (1024, 5, 64, jnp.float32), (4096, 1, 512, jnp.float32),
               (16384, 8, 40, jnp.float32), (4096, 5, 64, jnp.float32),
               (9216, 5, 64, jnp.float32), (2304, 10, 64, jnp.float32),
-              (9216, 1, 512, jnp.float32)]
+              (9216, 1, 512, jnp.float32),
+              (9216, 5, 64, jnp.bfloat16), (2304, 10, 64, jnp.bfloat16),
+              (2304, 2, 256, jnp.bfloat16)]
 #: The 64x64 self site in bf16, which the mesh cases run, and its local chunks
 #: under parallel/ring.py at sp = 2 and 4 (the residuals kernel runs on those).
 FLASH_SITE = FLASH_ROWS[2]
@@ -222,10 +231,14 @@ def _row_id(row):
     return f"P{pixels}-h{heads}-d{d_head}-{jnp.dtype(dtype).name}"
 
 
-def _row(one_chip, row, batch=CFG_BATCH):
-    """``(geometry, scale, (q, k, v))`` of a table row on the described chip."""
+def _row(one_chip, row, batch=CFG_BATCH, residuals=False):
+    """``(geometry, scale, (q, k, v))`` of a table row on the described chip.
+    The geometry is the table's answer at the width the kernel is handed the
+    row's arrays in: ``nn.flash_operand_dtype`` of theirs through
+    ``flash_attention_tpu``, their own through the residuals variant."""
     pixels, heads, d_head, dtype = row
-    geometry = nn.flash_block(pixels, d_head, jnp.dtype(dtype).itemsize)
+    handed = jnp.dtype(dtype) if residuals else nn.flash_operand_dtype(dtype)
+    geometry = nn.flash_block(pixels, d_head, handed.itemsize)
     assert geometry is not None, "the table has no geometry for this row"
     return geometry, d_head ** -0.5, _qkv(one_chip, batch, heads, pixels,
                                           pixels, d_head, dtype)
@@ -234,8 +247,16 @@ def _row(one_chip, row, batch=CFG_BATCH):
 @pytest.mark.parametrize("row", FLASH_ROWS, ids=_row_id)
 def test_flash_forward_compiles(one_chip, row):
     geometry, scale, qkv = _row(one_chip, row)
-    _compile(lambda q, k, v: nn.flash_attention_tpu(q, k, v, scale, geometry),
-             *qkv)
+    compiled = _compile(
+        lambda q, k, v: nn.flash_attention_tpu(q, k, v, scale, geometry), *qkv)
+    # f32 arrays or bf16: the kernel takes bfloat16 and writes bfloat16, and
+    # the caller gets its own dtype back.
+    kernel, = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    shapes = kernel.split("custom_call_target")[0]    # result and operands
+    assert " = bf16[" in shapes and "f32[" not in shapes
+    out, = jax.tree.leaves(compiled.out_info)
+    assert out.dtype == row[3]
 
 
 @pytest.mark.parametrize("row", FLASH_ROWS, ids=_row_id)
@@ -253,7 +274,7 @@ def test_flash_backward_compiles(one_chip, row):
 
 @pytest.mark.parametrize("row", FLASH_ROWS + RING_CHUNKS, ids=_row_id)
 def test_flash_residuals_compile(one_chip, row):
-    geometry, scale, (q, k, v) = _row(one_chip, row)
+    geometry, scale, (q, k, v) = _row(one_chip, row, residuals=True)
     compiled = _compile(
         lambda q, k, v: nn.flash_attention_residuals(q, k, v, scale, geometry),
         q, k, v)
@@ -368,8 +389,10 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
         scale, None, False).compile().as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
+    # f32 arrays at the default matmul precision: the kernel's operands and
+    # its output are bfloat16 (PR 35)
     by_shape = {shape: sum(1 for line in kernels
-                           if re.search(r"= f32\[%s\]" % shape, line))
+                           if re.search(r"= bf16\[%s\]" % shape, line))
                 for shape in ("4,10,2304,64", "4,5,9216,64", "2,1,9216,512")}
     # ten self sites in the loop, and the VAE's mid attention outside it
     assert by_shape == {"4,10,2304,64": 5, "4,5,9216,64": 5, "2,1,9216,512": 1}

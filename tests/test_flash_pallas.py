@@ -12,9 +12,21 @@ heads are reduced (the kernel grid iterates them independently; geometry per
 batch·head is what the blocks tile).
 
 Tolerance: the kernel accumulates softmax/matmul in f32 like the reference
-path, but blockwise online-softmax reassociates the sums — f32 inputs agree
-to ~1e-5; bf16 inputs to a few 1e-2 in absolute terms on O(1)-scale outputs.
+path, but blockwise online-softmax reassociates the sums — f32 operands agree
+to ~1e-5; bf16 operands to a few 1e-2 in absolute terms on O(1)-scale outputs.
+
+Operand width (PR 35): at the process's default matmul precision
+`flash_attention_tpu` hands the kernel float32 arrays as bfloat16 (what the
+MXU multiplied them as anyway) and returns float32. So the parity tests run
+in both forms (`form`): ``narrowed`` is the production call and agrees to
+bf16 tolerance; ``highest`` (a process that set
+``jax_default_matmul_precision``) is the parent's call on f32 operands and
+keeps the 1e-5 that pins the tiling arithmetic. The interpreter multiplies
+f32 exactly, so that the chip's one-pass products lose nothing by the
+narrowing is the chip's check (PERF.md §6, PR 35), not the CPU's.
 """
+
+import contextlib
 
 import numpy as np
 import pytest
@@ -38,6 +50,27 @@ def _rand_qkv(seed, b, h, s, d, dtype):
     return mk(), mk(), mk()
 
 
+FORMS = ("narrowed", "highest")
+
+
+def _precision(name):
+    """A process that set ``jax_default_matmul_precision`` to ``name``; its
+    own default for None."""
+    return (jax.default_matmul_precision(name) if name
+            else contextlib.nullcontext())
+
+
+def _form(form):
+    """The process a call is traced in: its own default, or one that asked
+    for ``highest`` products (f32 operands then reach the kernel)."""
+    return _precision("highest" if form == "highest" else None)
+
+
+def _tol(form, dtype, f32_tol=1e-5):
+    narrow = dtype == jnp.bfloat16 or form == "narrowed"
+    return 4e-2 if narrow else f32_tol
+
+
 #: (keys, head size, dtype): the shape classes of the geometry table — the
 #: 64² and 32² self sites of SD-1.4, the 32² site of LDM-256, the VAE
 #: decoder's mid attention (one 512-wide head, f32), the bf16 sweep's 64² site.
@@ -50,35 +83,46 @@ def _row_id(row):
     return f"S{row[0]}-d{row[1]}-{jnp.dtype(row[2]).name}"
 
 
+def _geometry(s, d, dtype):
+    """The table's answer for arrays of ``dtype`` in the process as it is:
+    asked at the width the kernel is handed them in, as the sites ask."""
+    return nn.flash_block(s, d, nn.flash_operand_dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("row", TABLE_ROWS, ids=_row_id)
-def test_flash_interpret_parity_at_table_row(row):
+def test_flash_interpret_parity_at_table_row(row, form):
     s, d, dtype = row
-    geometry = nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
-    assert geometry is not None         # the production path takes the kernel
     q, k, v = _rand_qkv(0, 1, 2 if d < 512 else 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
+    with force_tpu_interpret_mode(), _form(form):
+        geometry = _geometry(s, d, dtype)
+        assert geometry is not None     # the production path takes the kernel
         out = nn.flash_attention_tpu(q, k, v, scale, geometry)
+    assert out.dtype == dtype
     want = _ref(*(t.astype(jnp.float32) for t in (q, k, v)), scale)
-    tol = 1e-5 if dtype == jnp.float32 else 4e-2
+    tol = _tol(form, dtype)
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                np.asarray(want), atol=tol, rtol=tol)
 
 
-def test_flash_interpret_parity_small_multiblock():
+@pytest.mark.parametrize("form", FORMS)
+def test_flash_interpret_parity_small_multiblock(form):
     # Fast case: S=512 with block 256 → a 2×2 block grid, several heads —
     # exercises the cross-block online-softmax reassociation cheaply.
     s, d = 512, 40
     q, k, v = _rand_qkv(2, 2, 4, s, d, jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
+    with force_tpu_interpret_mode(), _form(form):
         out = nn.flash_attention_tpu(q, k, v, scale, (256, 256, 256))
     want = _ref(q, k, v, scale)
+    tol = _tol(form, jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+                               atol=tol, rtol=tol)
 
 
-def test_flash_interpret_parity_vae_head_geometry():
+@pytest.mark.parametrize("form", FORMS)
+def test_flash_interpret_parity_vae_head_geometry(form):
     # The VAE decoder's mid-block attention runs the kernel with a single
     # 512-wide head in f32 (models/vae.py) — the widest-head site in the
     # framework. Reduced S keeps interpret mode fast; the block count (2×2)
@@ -86,15 +130,128 @@ def test_flash_interpret_parity_vae_head_geometry():
     s, d = 512, 512
     q, k, v = _rand_qkv(3, 1, 1, s, d, jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
+    with force_tpu_interpret_mode(), _form(form):
         out = nn.flash_attention_tpu(q, k, v, scale, (256, 256, 256))
     want = _ref(q, k, v, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               atol=1e-4, rtol=1e-5)
+                               atol=_tol(form, jnp.float32, 1e-4), rtol=1e-5)
 
 
+#: (keys, head size, tile): the four kernel sites of the two cells at toy
+#: lengths, heads of 40 and 80 (`sd14`), 64 and 64 (`sd21`, whose lengths
+#: are 9 x 2^n), each a grid of several q and k blocks.
+NARROWED_SITES = [(512, 40, (256, 512, 256)), (512, 80, (256, 256, 256)),
+                  (1152, 64, (384, 1152, 384)), (768, 64, (384, 768, 768))]
+
+
+def _hand_rounded(q, k, v, scale, geometry):
+    """What ``flash_attention_tpu`` says it does to f32 arrays, spelled out:
+    the library kernel on ``q * scale``, ``k``, ``v`` rounded to bfloat16,
+    its output cast back."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    bf = jnp.bfloat16
+    out = fa.flash_attention((q * scale).astype(bf), k.astype(bf), v.astype(bf),
+                             causal=False, sm_scale=1.0,
+                             block_sizes=nn._flash_block_sizes(geometry))
+    return out.astype(q.dtype)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("site", NARROWED_SITES,
+                         ids=lambda t: f"S{t[0]}-d{t[1]}")
+def test_flash_f32_arrays_reach_the_kernel_as_bfloat16(site):
+    """f32 in, f32 out; bit for bit the kernel on operands rounded by hand
+    and cast back; and within one bf16 rounding of the operands of a
+    ``Precision.HIGHEST`` einsum reference (2^-9 a rounding, three operands
+    and the output: a relative error of a few 1e-3 of the output's norm, and
+    not the 1e-6 of exact operands either, so the rounding is seen)."""
+    s, d, geometry = site
+    q, k, v = _rand_qkv(11, 2, 2, s, d, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+    with force_tpu_interpret_mode():
+        out = nn.flash_attention_tpu(q, k, v, scale, geometry)
+        by_hand = _hand_rounded(q, k, v, scale, geometry)
+    assert out.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(by_hand))
+    hi = jax.lax.Precision.HIGHEST
+    probs = jax.nn.softmax(
+        jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=hi) * scale, axis=-1)
+    want = jnp.einsum("bhqk,bhkd->bhqd", probs, v, precision=hi)
+    assert 1e-4 < _rel(out, want) < 2 ** -7
+
+
+@pytest.mark.parametrize("site", NARROWED_SITES[::2],
+                         ids=lambda t: f"S{t[0]}-d{t[1]}")
+def test_flash_grad_through_the_narrowed_call(site):
+    """``jax.grad`` through the new form is the gradient of the hand-rounded
+    chain bit for bit (the casts' transposes round the cotangent going in and
+    widen dq, dk, dv coming out), f32 like its arguments."""
+    s, d, geometry = site
+    q, k, v = _rand_qkv(12, 1, 2, s, d, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    def grads(call):
+        return jax.grad(lambda q, k, v: jnp.sum(call(q, k, v, scale, geometry) ** 2),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    with force_tpu_interpret_mode():
+        got, by_hand = grads(nn.flash_attention_tpu), grads(_hand_rounded)
+    for g, h, arg in zip(got, by_hand, (q, k, v)):
+        assert g.dtype == arg.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(h))
+
+
+#: (arrays' dtype, jax_default_matmul_precision, dtype the kernel is handed)
+OPERAND_CASES = [
+    (jnp.float32, None, jnp.bfloat16), (jnp.float32, "default", jnp.bfloat16),
+    (jnp.float32, "bfloat16", jnp.bfloat16), (jnp.float32, "tensorfloat32", jnp.float32),
+    (jnp.float32, "highest", jnp.float32), (jnp.float32, "float32", jnp.float32),
+    (jnp.float32, "high", jnp.float32), (jnp.float32, "BF16_BF16_F32", jnp.float32),
+    (jnp.bfloat16, None, jnp.bfloat16), (jnp.bfloat16, "highest", jnp.bfloat16),
+    (jnp.float16, None, jnp.float16),
+]
+
+
+@pytest.mark.parametrize(
+    "case", OPERAND_CASES,
+    ids=lambda c: f"{jnp.dtype(c[0]).name}-{c[1]}")
+def test_flash_operand_width_follows_dtype_and_precision(case, monkeypatch):
+    """Only f32 arrays in a process at the default matmul precision are
+    narrowed. A process that asked for more (its setting reaches the kernel's
+    own products) and arrays that are bfloat16 already take the parent's
+    call: operands and output in the arrays' dtype, ``q`` scaled, the same
+    BlockSizes. The output is the arrays' dtype in every case."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    dtype, precision, operand = case
+    handed = []
+
+    def kernel(q, k, v, **kw):
+        handed.append(((q.dtype, k.dtype, v.dtype), kw))
+        return q
+
+    monkeypatch.setattr(fa, "flash_attention", kernel)
+    q = jax.ShapeDtypeStruct((2, 2, 1024, 40), dtype)
+    with _precision(precision):
+        assert nn.flash_operand_dtype(dtype) == operand
+        out = jax.eval_shape(
+            lambda q, k, v: nn.flash_attention_tpu(q, k, v, 0.25, (512, 512, 512)),
+            q, q, q)
+    assert out.dtype == dtype and out.shape == q.shape
+    (dtypes, kw), = handed
+    assert dtypes == (operand,) * 3
+    assert kw == dict(causal=False, sm_scale=1.0,
+                      block_sizes=nn._flash_block_sizes((512, 512, 512)))
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("row", TABLE_ROWS[:4], ids=_row_id)
-def test_flash_interpret_grad_matches_einsum(row):
+def test_flash_interpret_grad_matches_einsum(row, form):
     """Differentiating THROUGH the flash kernel must work and match the
     materialized-attention gradient: null-text inversion backprops through
     the U-Net's flash sites, and an under-specified BlockSizes (the
@@ -107,22 +264,35 @@ def test_flash_interpret_grad_matches_einsum(row):
     scale folded into q ahead of the custom VJP, dies here, not on the
     chip."""
     s, d, dtype = row
-    geometry = nn.flash_block(s, d, jnp.dtype(dtype).itemsize)
     q, k, v = _rand_qkv(5, 1, 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(nn.flash_attention_tpu(q, k, v, scale, geometry) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(_ref(q, k, v, scale) ** 2)
 
-    with force_tpu_interpret_mode():
+    with force_tpu_interpret_mode(), _form(form):
+        geometry = _geometry(s, d, dtype)
+
+        def loss_flash(q, k, v):
+            return jnp.sum(nn.flash_attention_tpu(q, k, v, scale, geometry) ** 2)
+
         g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    _assert_grads_close(g_flash, g_ref, form)
+
+
+def _assert_grads_close(g_flash, g_ref, form):
+    """f32 operands: element by element, as ever. Narrowed operands: the
+    gradients carry the operands' and the cotangent's bf16 roundings, so each
+    is held to a hundredth of the reference's norm (a missing scale or a
+    wrong backward block is off by its whole size)."""
     for got, want in zip(g_flash, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=1e-3, rtol=1e-3)
+        assert got.dtype == want.dtype
+        if form == "highest":
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-3, rtol=1e-3)
+        else:
+            assert _rel(got, want) < 1e-2
 
 
 def test_flash_block_sizes_specify_all_backward_blocks():
@@ -167,9 +337,12 @@ SD21_ROWS = [(2304, 64, jnp.float32), (9216, 64, jnp.float32)]
 def test_flash_block_selection_at_sd21_lengths():
     # The measured geometries of PERF.md §6, PR 29: K and V resident at 2304
     # keys; at 9216 they do not fit, and a wide q block streams them least.
+    # At 2 B (PR 35's sweep) a 4608-key score tile fits beside them, at 4 B
+    # it does not and the row's second entry answers.
     assert nn.flash_block(2304, 64, 4) == (768, 2304, 1152)
+    assert nn.flash_block(2304, 64, 2) == (768, 2304, 1152)
     assert nn.flash_block(9216, 64, 4) == (512, 3072, 1536)
-    assert nn.flash_block(9216, 64, 2) == (512, 3072, 1536)
+    assert nn.flash_block(9216, 64, 2) == (512, 4608, 1536)
     assert nn.flash_block(9216, 512, 4) == (512, 512, 512)     # the VAE at 96²
     assert nn.flash_block(576, 64, 4) is None                  # einsum chain
     # A length's own geometry still passes the guard: a head of 256 at 2304
@@ -178,40 +351,40 @@ def test_flash_block_selection_at_sd21_lengths():
     # Backward blocks tile the length: 512 divides no multiple of 2304.
     assert nn._flash_block_sizes((768, 2304, 1152)).block_q_dq == 384
     assert nn._flash_block_sizes((512, 3072, 1536)).block_q_dq == 512
-    for geometry in ((768, 2304, 1152), (512, 3072, 1536)):
+    for geometry in ((768, 2304, 1152), (512, 3072, 1536), (512, 4608, 1536)):
         sizes = nn._flash_block_sizes(geometry)
         assert sizes.has_backward_blocks
         assert geometry[1] % sizes.block_k_major_dkv == 0
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("row", SD21_ROWS, ids=_row_id)
-def test_flash_interpret_parity_at_sd21_row(row):
+def test_flash_interpret_parity_at_sd21_row(row, form):
     s, d, dtype = row
-    geometry = nn.flash_block(s, d, 4)
     q, k, v = _rand_qkv(3, 1, 1, s, d, dtype)
     scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
-        out = nn.flash_attention_tpu(q, k, v, scale, geometry)
+    with force_tpu_interpret_mode(), _form(form):
+        out = nn.flash_attention_tpu(q, k, v, scale, _geometry(s, d, dtype))
+    tol = _tol(form, dtype)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_ref(q, k, v, scale)),
-                               atol=1e-5, rtol=1e-5)
+                               atol=tol, rtol=tol)
 
 
-def test_flash_interpret_grad_at_2304_keys():
+@pytest.mark.parametrize("form", FORMS)
+def test_flash_interpret_grad_at_2304_keys(form):
     """The backward blocks of 384 (no 512 tiles 2304): gradients through the
     kernel at SD-2.1's 48² site match the materialized attention's."""
     s, d = 2304, 64
-    geometry = nn.flash_block(s, d, 4)
     q, k, v = _rand_qkv(7, 1, 1, s, d, jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    with force_tpu_interpret_mode():
+    with force_tpu_interpret_mode(), _form(form):
+        geometry = _geometry(s, d, jnp.float32)
         g_flash = jax.grad(lambda q, k, v: jnp.sum(
             nn.flash_attention_tpu(q, k, v, scale, geometry) ** 2),
             argnums=(0, 1, 2))(q, k, v)
     g_ref = jax.grad(lambda q, k, v: jnp.sum(_ref(q, k, v, scale) ** 2),
                      argnums=(0, 1, 2))(q, k, v)
-    for got, want in zip(g_flash, g_ref):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=1e-3, rtol=1e-3)
+    _assert_grads_close(g_flash, g_ref, form)
 
 
 def _site_shapes(config):
@@ -241,12 +414,20 @@ def test_flash_geometries_stay_inside_the_vmem_budget():
                     <= nn._FLASH_VMEM_BUDGET
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("config", [SD14, LDM256, SD21], ids=lambda c: c.name)
-def test_fused_attention_follows_the_table(config, monkeypatch):
+def test_fused_attention_follows_the_table(config, form, monkeypatch):
     """``fused_attention`` takes the kernel exactly where the table has a
     geometry for the site's shape, and the einsum chain elsewhere — from the
     shapes alone, for every site shape of SD-1.4, LDM-256 and SD-2.1 at 768²
-    (9216 and 2304 keys on the kernel, 576 and 144 on the chain)."""
+    (9216 and 2304 keys on the kernel, 576 and 144 on the chain). The table
+    is asked at the width the kernel is handed the f32 arrays in: 2 B, or 4
+    in a process that set ``highest``."""
+    with _form(form):
+        _follows_the_table(config, 2 if form == "narrowed" else 4, monkeypatch)
+
+
+def _follows_the_table(config, itemsize, monkeypatch):
     taken = []
     monkeypatch.setattr(nn, "_on_tpu", lambda: True)
     monkeypatch.setattr(
@@ -254,12 +435,12 @@ def test_fused_attention_follows_the_table(config, monkeypatch):
         lambda q, k, v, scale, geometry: taken.append(geometry) or _ref(q, k, v, scale))
     for pixels, d_head in sorted(_site_shapes(config)):
         q = jax.ShapeDtypeStruct((2, 1, pixels, d_head), jnp.float32)
-        want = nn.flash_block(pixels, d_head, 4)
+        want = nn.flash_block(pixels, d_head, itemsize)
         assert (want is not None) == (pixels >= 1024), (pixels, d_head)
         del taken[:]
         jax.eval_shape(lambda q, k, v: nn.fused_attention(q, k, v, 0.1), q, q, q)
         assert taken == ([want] if want else []), (pixels, d_head)
-        assert nn.takes_flash_kernel(pixels, d_head, 4) == bool(want)
+        assert nn.takes_flash_kernel(pixels, d_head, itemsize) == bool(want)
         # a mask, or keys of another length (cross-attention), never do
         del taken[:]
         ctx = jax.ShapeDtypeStruct((2, 1, 77, d_head), jnp.float32)

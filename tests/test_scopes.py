@@ -412,6 +412,73 @@ def test_a_stored_self_site_is_the_controllers_only_for_a_reader(tiny_pipe, retu
     assert f"controller store {launch.store_bytes} bytes" in launch.describe_sites()
 
 
+@pytest.mark.parametrize("site, line", [
+    (launches.SelfSite(4096, 40, "kernel", (256, 4096, 2048), "bfloat16"),
+     "4096x40 kernel 256x4096x2048 bf16"),
+    (launches.SelfSite(9216, 64, "kernel", (512, 3072, 1536), "float32"),
+     "9216x64 kernel 512x3072x1536 f32"),
+    (launches.SelfSite(1024, 80, "kernel", (1024, 1024, 1024), "float16"),
+     "1024x80 kernel 1024x1024x1024 float16"),
+    # a record of before the field, and the sites off the kernel: no word
+    (launches.SelfSite(4096, 40, "kernel", (256, 4096, 2048)),
+     "4096x40 kernel 256x4096x2048"),
+    (launches.SelfSite(256, 160, "edited"), "256x160 edited"),
+    (launches.SelfSite(576, 64, "einsum"), "576x64 einsum"),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_launch_line_names_a_kernel_sites_operand_width(site, line):
+    assert str(site) == line
+    launch = launches.Launch("jit_f", None, (), {}, self_sites={0: site, 1: site})
+    assert f"; 2 of {line}; controller store 0 bytes" in launch.describe_sites()
+
+
+@pytest.mark.parametrize("precision, word", [(None, "bf16"), ("highest", "f32")])
+def test_a_traced_kernel_site_records_the_width_it_was_handed(
+        tiny_pipe, monkeypatch, precision, word):
+    """Traced as on a TPU at a 64² latent, whose three 64² and three 32² self
+    sites have 4,096 and 1,024 keys: they take the flash kernel, and the
+    record (and the launch line) says in what dtype the kernel was handed f32
+    arrays: bfloat16 at the process's default matmul precision, their own
+    where the process asked for more. The tile is the table's answer at that
+    width."""
+    import dataclasses
+
+    from p2p_tpu.models import init_unet, nn
+    from p2p_tpu.models import vae as vae_mod
+    from p2p_tpu.ops import schedulers as sched_mod
+
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(
+        TINY, name="tiny-64", unet=dataclasses.replace(TINY.unet, sample_size=64))
+    ctrl = _ctrl(tiny_pipe, store=False)
+    layout = unet_layout(cfg.unet)
+    ctrl = layout.resolve(ctrl)
+    layout = layout.for_readers(ctrl, False)
+    key = jax.random.PRNGKey(0)
+    unet = jax.eval_shape(lambda: init_unet(key, cfg.unet))
+    vae = jax.eval_shape(lambda: vae_mod.init_vae(key, cfg.vae))
+    sched = sched_mod.schedule_from_config(STEPS, cfg.scheduler, kind="ddim")
+    ctx = jnp.zeros((2, cfg.unet.context_len, cfg.unet.context_dim))
+    lat = jnp.zeros((2, 64, 64, cfg.unet.in_channels))
+    launches.built()                        # the sites noted from here on
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        sampler._text2image_jit.trace(unet, vae, cfg, layout, sched, "ddim", ctx,
+                                      ctx, lat, ctrl, jnp.float32(7.5), None, False)
+        operand = nn.flash_operand_dtype(jnp.float32)
+    sites = dict(launches._traced_sites)
+    on_kernel = [s for s in sites.values() if s.how == "kernel"]
+    shapes = [(4096, 16), (1024, 32)]       # keys, head width: three sites each
+    tiles = {shape: nn.flash_block(*shape, operand.itemsize) for shape in shapes}
+    assert sorted((s.keys, s.head_dim, s.geometry, s.operand) for s in on_kernel) == sorted(
+        [shape + (tiles[shape], operand.name) for shape in shapes] * 3)
+    assert all(s.operand == "" and s.geometry is None
+               for s in sites.values() if s.how != "kernel")
+    line = launches.Launch("jit_f", None, (), {}, self_sites=sites).describe_sites()
+    for (keys, d_head), tile in tiles.items():
+        assert (f"; 3 of {keys}x{d_head} kernel {'x'.join(map(str, tile))} {word};"
+                in line)
+
+
 def test_a_stale_cached_executable_is_compiled_once_more(monkeypatch):
     """The cache's key leaves metadata out: an executable cached before the
     scopes were named is served with none. The index then compiles past it."""
