@@ -54,7 +54,7 @@ def _build_pipeline(args):
         from .models.checkpoint import load_pipeline
 
         return load_pipeline(args.checkpoint, cfg)
-    tok = HashWordTokenizer(model_max_length=cfg.text.max_length)
+    tok = HashWordTokenizer(model_max_length=cfg.unet.context_len)
     return Pipeline(
         config=cfg,
         unet_params=init_unet(jax.random.PRNGKey(0), cfg.unet),
@@ -224,13 +224,14 @@ def _group_setup(pipe, prompts, seeds, negative_prompt):
     import jax.numpy as jnp
 
     from .engine.sampler import encode_prompts
+    from .models.conditioning import cfg_rows
     from .parallel import make_mesh
 
     g = len(seeds)
     cond = encode_prompts(pipe, prompts)
     uncond = encode_prompts(pipe, [negative_prompt or ""] * len(prompts))
-    ctx = jnp.concatenate([uncond, cond], axis=0)
-    ctx = jnp.broadcast_to(ctx[None], (g,) + ctx.shape)
+    ctx = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (g,) + a.shape),
+                       cfg_rows(uncond, cond))
     base = jnp.stack([jax.random.normal(jax.random.PRNGKey(s),
                                         (1,) + pipe.latent_shape)
                       for s in seeds])
@@ -829,7 +830,8 @@ def build_parser() -> argparse.ArgumentParser:
         # tests/test_cli.py::test_every_cli_preset_resolves_to_a_config.
         sp.add_argument("--preset",
                         choices=("tiny", "sd14", "sd21", "sd21base",
-                                 "ldm256", "tiny_ldm", "tiny_v"),
+                                 "ldm256", "tiny_ldm", "tiny_v", "sdxl",
+                                 "tiny_xl"),
                         default="tiny",
                         help="model family; sd21 is the 768-v v-prediction "
                              "variant the reference marks 'Not work' "
@@ -1205,7 +1207,7 @@ def build_parser() -> argparse.ArgumentParser:
                       "or --static: the jaxcheck static analyzer")
     c.add_argument("checkpoint_dir", nargs="?", default=None)
     c.add_argument("--preset", default=None,
-                   choices=("sd14", "sd21", "sd21base", "ldm256"))
+                   choices=("sd14", "sd21", "sd21base", "ldm256", "sdxl"))
     c.add_argument("--static", action="store_true",
                    help="run the three-pass static analyzer instead (AST "
                         "lints + traced-program contracts + the "

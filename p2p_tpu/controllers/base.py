@@ -116,6 +116,10 @@ class AttnLayout:
 
     metas: Tuple[AttnMeta, ...]
     store_cfg: StoreConfig
+    # The latent's side, where the layout was built from a model's
+    # configuration (its top level may hold no attention site); None for a
+    # hand-built layout, which stands for its largest site's.
+    latent_size: Optional[int] = None
 
     @property
     def num_store_slots(self) -> int:
@@ -129,17 +133,18 @@ class AttnLayout:
         this model: 16 (the controllers' self window ``16²``, LocalBlend's
         maps) wherever the pyramid has a 16² level, else the level that
         stands where 16² stands in SD-1.4's 64/32/16/8, a quarter of the
-        largest side (24 of SD-2.1's 96/48/24/12). A model with neither is
-        an error here, at controller build time, never an empty site list."""
+        latent's side (24 of SD-2.1's 96/48/24/12, 32 of SDXL's 128, whose
+        attention levels are 64/32). A model with neither is an error here,
+        at controller build time, never an empty site list."""
         sides = sorted({m.resolution for m in self.metas})
         if PAPER_RESOLUTION in sides:
             return PAPER_RESOLUTION
-        level = sides[-1] // 4
+        level = (self.latent_size or sides[-1]) // 4
         if level not in sides:
             raise ValueError(
                 f"no default edit resolution: the model's attention levels "
                 f"{sides} hold neither {PAPER_RESOLUTION} nor a quarter of "
-                f"the largest ({level}); pass the resolution explicitly")
+                f"the latent's side ({level}); pass the resolution explicitly")
         return level
 
     def resolve(self, controller: Optional["Controller"]) -> Optional["Controller"]:
@@ -196,7 +201,7 @@ class AttnLayout:
         return AttnLayout(
             tuple(dataclasses.replace(m, store_slot=slots.get(m.layer_idx))
                   for m in self.metas),
-            self.store_cfg)
+            self.store_cfg, self.latent_size)
 
     def blend_metas(self, resolution: int = 16) -> Tuple[AttnMeta, ...]:
         """The cross-attention maps LocalBlend consumes — all cross sites at
@@ -212,12 +217,14 @@ class AttnLayout:
 def build_layout(
     specs: Sequence[Tuple],
     store_cfg: StoreConfig = StoreConfig(),
+    latent_size: Optional[int] = None,
 ) -> AttnLayout:
     """Assemble an :class:`AttnLayout` from ``(place, is_cross, resolution,
     heads, key_len[, channels])`` tuples in call order, assigning store slots
     to the sites the :class:`StoreConfig` wants. The optional 6th element is
     the site's feature-map channel count (needed by the phase-2 attention
-    cache); 5-tuples remain valid and get ``channels=0``."""
+    cache); 5-tuples remain valid and get ``channels=0``. ``latent_size`` is
+    the model's latent side (``AttnLayout.latent_size``)."""
     metas = []
     slot = 0
     for idx, spec in enumerate(specs):
@@ -229,7 +236,7 @@ def build_layout(
             meta = dataclasses.replace(meta, store_slot=slot)
             slot += 1
         metas.append(meta)
-    return AttnLayout(tuple(metas), store_cfg)
+    return AttnLayout(tuple(metas), store_cfg, latent_size)
 
 
 @struct.dataclass

@@ -29,8 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import vae as vae_mod
+from ..models.conditioning import context_of, with_context
 from ..models.config import PipelineConfig
-from ..models.unet import apply_unet
+from ..models.unet import apply_unet, embed_added
 from ..ops import schedulers as sched_mod
 from ..utils import progress as progress_mod
 from .sampler import Pipeline, encode_prompts
@@ -94,10 +95,11 @@ def load_image(path: str, size: int = 512, left: int = 0, right: int = 0,
 @partial(jax.jit, static_argnames=("cfg", "progress", "sp", "metrics"))
 def _ddim_invert_jit(unet_params, vae_params, cfg: PipelineConfig,
                      schedule: sched_mod.DiffusionSchedule,
-                     image: jax.Array, cond: jax.Array,
+                     image: jax.Array, cond,
                      progress: bool = False, sp=None, metrics: bool = False):
     """image (1,H,W,3) in [-1,1] → all T+1 latents, ascending noise."""
     latent0 = vae_mod.encode(vae_params, cfg.vae, image)
+    cond = embed_added(unet_params, cfg.unet, cond)
 
     # Ascending timesteps: reversed sampling order
     # (`/root/reference/null_text.py:555-560` uses timesteps[-(i+1)]).
@@ -132,8 +134,8 @@ def _adam_update(g, m, v, j, lr, b1=0.9, b2=0.999, eps=1e-8):
 def _null_optimize_jit(unet_params, cfg: PipelineConfig,
                        schedule: sched_mod.DiffusionSchedule,
                        latents: jax.Array,        # (T+1, 1, h, w, c) ascending
-                       uncond0: jax.Array,        # (1, L, D) "" embedding
-                       cond: jax.Array,           # (1, L, D) prompt embedding
+                       uncond0,                   # (1, L, D) "" embedding
+                       cond,                      # (1, L, D) prompt embedding
                        guidance_scale: jax.Array,
                        num_inner_steps: int,
                        epsilon: jax.Array,
@@ -147,13 +149,24 @@ def _null_optimize_jit(unet_params, cfg: PipelineConfig,
     embedding is cast to the model dtype at each U-Net application. This is
     also what keeps the while_loop carry well-typed on the bf16 TPU path —
     Adam's f32 scalar schedule would otherwise promote the update and break
-    the carry contract."""
+    the carry contract.
+
+    ``uncond0`` and ``cond`` are the preset's conditioning
+    (``models.conditioning``). What is optimised is the hidden states; a
+    pooled vector beside them stays as the empty prompt's was encoded."""
     t_count = schedule.timesteps.shape[0]
-    model_dtype = cond.dtype
-    uncond0 = uncond0.astype(jnp.float32)
+    model_dtype = context_of(cond).dtype
+    cond = embed_added(unet_params, cfg.unet, cond)
+    null = embed_added(unet_params, cfg.unet, uncond0)
+
+    def uncond(u):
+        """The unconditional conditioning with hidden states ``u``."""
+        return with_context(null, u.astype(model_dtype))
+
+    uncond0 = context_of(uncond0).astype(jnp.float32)
 
     def outer(carry, scan_in):
-        latent_cur, uncond = carry
+        latent_cur, u_cur = carry
         i, t = scan_in
         progress_mod.emit_step(progress or metrics, i, phase="null_text",
                                report=progress)
@@ -181,7 +194,7 @@ def _null_optimize_jit(unet_params, cfg: PipelineConfig,
 
         def loss_fn(u):
             eps_u, _ = apply_unet(unet_params, cfg.unet, latent_cur, t,
-                                  u.astype(model_dtype), sp=sp)
+                                  uncond(u), sp=sp)
             eps = eps_u + guidance_scale * (eps_cond - eps_u)
             eps = sched_mod.to_epsilon(schedule, eps, t, latent_cur)
             prev = sched_mod.ddim_step(schedule, eps, t, latent_f)
@@ -200,7 +213,7 @@ def _null_optimize_jit(unet_params, cfg: PipelineConfig,
             # unconditionally and re-test in inner_cond, same fixed point.
             return (u + upd, m, v, j + 1.0, loss)
 
-        init = (uncond, jnp.zeros_like(uncond), jnp.zeros_like(uncond),
+        init = (u_cur, jnp.zeros_like(u_cur), jnp.zeros_like(u_cur),
                 jnp.float32(0.0), jnp.float32(jnp.inf))
         u_opt, _, _, j_done, _ = jax.lax.while_loop(inner_cond, inner_body,
                                                     init)
@@ -213,7 +226,7 @@ def _null_optimize_jit(unet_params, cfg: PipelineConfig,
         # Advance with the optimized uncond under full CFG
         # (`/root/reference/null_text.py:602-604`).
         eps_u, _ = apply_unet(unet_params, cfg.unet, latent_cur, t,
-                              u_opt.astype(model_dtype), sp=sp)
+                              uncond(u_opt), sp=sp)
         eps = eps_u + guidance_scale * (eps_cond - eps_u)
         eps = sched_mod.to_epsilon(schedule, eps, t, latent_cur)
         latent_next = sched_mod.ddim_step(schedule, eps, t, latent_cur)

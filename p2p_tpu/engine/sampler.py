@@ -43,9 +43,10 @@ from ..controllers.base import (
     init_store_state,
 )
 from ..models import vae as vae_mod
+from ..models.conditioning import cfg_rows, context_of, with_context
 from ..models.config import PipelineConfig
-from ..models.text_encoder import apply_text_encoder
-from ..models.unet import apply_unet
+from ..models.text_encoder import apply_text_encoder, apply_text_towers
+from ..models.unet import apply_unet, embed_added
 from ..obs import launches
 from ..obs.spans import span
 from ..ops import schedulers as sched_mod
@@ -63,7 +64,7 @@ class Pipeline:
 
     config: PipelineConfig
     unet_params: Any
-    text_params: Any
+    text_params: Any      # one tower's tree, or a list of trees (config.text)
     vae_params: Any
     tokenizer: Tokenizer
 
@@ -74,8 +75,13 @@ class Pipeline:
 
 
 @partial(jax.jit, static_argnames=("cfg", "dtype"))
-def _encode_jit(params, cfg, ids, dtype):
-    return apply_text_encoder(params, cfg, ids, dtype=dtype)
+def _encode_jit(text_params, cfg, ids, dtype, eos=None):
+    """``cfg`` is ``PipelineConfig.text``: one tower's configuration, or a
+    tuple of them (then ``eos`` (B,) is each prompt's end-of-text position,
+    where the pooled text is read)."""
+    if isinstance(cfg, tuple):
+        return apply_text_towers(text_params, cfg, ids, eos, dtype=dtype)
+    return apply_text_encoder(text_params, cfg, ids, dtype=dtype)
 
 
 def stage_host(x, mesh=None):
@@ -103,9 +109,12 @@ def stage_host(x, mesh=None):
     return jax.device_put(x)
 
 
-def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32) -> jax.Array:
-    """Tokenize + encode to (B, L, D) hidden states
-    (`/root/reference/ptp_utils.py:144-156`)."""
+def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32):
+    """Tokenize + encode to the preset's conditioning
+    (``models.conditioning``): (B, L, D) hidden states
+    (`/root/reference/ptp_utils.py:144-156`), or for a preset of several
+    towers a ``Conditioning`` of their concatenated states and the pooled
+    text."""
     tok = pipe.tokenizer
     max_len = pipe.config.unet.context_len
     with span("entry.tokenize", prompts=len(prompts)):
@@ -118,9 +127,14 @@ def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32) -> jax.Array:
         # explicitly (stage_host) so the serve hot path stays clean under
         # jax.transfer_guard("disallow").
         args = (pipe.text_params, pipe.config.text, stage_host(ids), dtype)
+        kwargs = {}
+        if isinstance(pipe.config.text, tuple):
+            # first end-of-text id of each prompt (padding repeats it)
+            kwargs["eos"] = stage_host(
+                (ids == tok.eos_token_id).argmax(axis=1).astype(np.int32))
         mark = launches.built()
-        out = _encode_jit(*args)
-        launches.keep_if_built(mark, _encode_jit, args, {})
+        out = _encode_jit(*args, **kwargs)
+        launches.keep_if_built(mark, _encode_jit, args, kwargs)
         return out
 
 
@@ -387,6 +401,9 @@ def _make_cfg_body(
     None where the carry has it; ``resid`` is None in the carry where no
     phase 2 follows to read the guidance residual."""
     ms_step = _make_ms_step(schedule, scheduler_kind)
+    # What of the conditioning does not depend on the step, once ahead of
+    # the scan (a conditioning with nothing of the kind comes back as it is).
+    context = embed_added(unet_params, cfg.unet, context)
 
     def body(carry, scan_in):
         latents, state, ms, cache, resid = carry
@@ -396,16 +413,18 @@ def _make_cfg_body(
         with jax.named_scope("sampler/cfg"):
             if uncond_per_step is not None:
                 # Null-text: substitute this step's optimized uncond
-                # embedding. Cast to the sampling dtype — the artifact stores
+                # embedding (the hidden states; a pooled vector stays as
+                # encoded). Cast to the sampling dtype — the artifact stores
                 # f32 (the optimizer's dtype), and a f32 leak here would
                 # silently promote the whole CFG context (and the U-Net
                 # matmuls) on the bf16 path.
                 u = jax.lax.dynamic_index_in_dim(uncond_per_step, step, 0,
                                                  keepdims=False)
-                ctx = jnp.concatenate(
-                    [jnp.broadcast_to(u.astype(context.dtype),
-                                      context[:b].shape),
-                     context[b:]], axis=0)
+                states = context_of(context)
+                ctx = with_context(context, jnp.concatenate(
+                    [jnp.broadcast_to(u.astype(states.dtype),
+                                      states[:b].shape),
+                     states[b:]], axis=0))
             latent_in = jnp.concatenate([latents] * 2, axis=0)
         eps, state, out_cache = apply_unet(
             unet_params, cfg.unet, latent_in, t, ctx,
@@ -461,6 +480,7 @@ def _make_cond_body(
     that flip to reuse inside phase 2 keep storing until their step, and a
     segment with none closes over the cache."""
     ms_step = _make_ms_step(schedule, scheduler_kind)
+    context_cond = embed_added(unet_params, cfg.unet, context_cond)
 
     def body(carry, scan_in):
         latents, ms, cache = carry
@@ -676,7 +696,9 @@ def _denoise_scan(
     # slice inside the scan would pull the full [uncond; cond] tensor into
     # the body as a constant — the uncond half must not even be an input.
     latents = _phase2_scan(unet_params, cfg, layout, schedule,
-                           scheduler_kind, context[b:], carry, controller,
+                           scheduler_kind,
+                           jax.tree.map(lambda c: c[b:], context), carry,
+                           controller,
                            guidance_scale, progress=progress,
                            metrics=metrics, sp=sp, reuse=sched,
                            kernels=kernels)
@@ -707,7 +729,7 @@ def _text2image_jit(
     reuse=None,
     kernels=None,
 ):
-    context = jnp.concatenate([context_uncond, context_cond], axis=0)
+    context = cfg_rows(context_uncond, context_cond)
     latents, state = _denoise_scan(
         unet_params, cfg, layout, schedule, scheduler_kind, context, latents,
         controller, guidance_scale, uncond_per_step, progress=progress, sp=sp,

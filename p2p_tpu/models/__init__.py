@@ -15,7 +15,9 @@ from .config import (
     SD21,
     SD21_BASE,
     SD14,
+    SDXL,
     TINY,
+    TINY_XL,
     PipelineConfig,
     TextEncoderConfig,
     UNetConfig,
@@ -23,12 +25,14 @@ from .config import (
     unet_attn_specs,
     unet_layout,
 )
+from .conditioning import Conditioning
 from .text_encoder import apply_text_encoder, init_text_encoder
 from .unet import apply_unet, init_unet
 from . import vae
 
 __all__ = [
-    "LDM256", "SD14", "SD14_HR", "SD21", "SD21_BASE", "TINY", "TINY_LDM", "TINY_V",
+    "LDM256", "SD14", "SD14_HR", "SD21", "SD21_BASE", "SDXL", "TINY", "TINY_LDM",
+    "TINY_V", "TINY_XL", "Conditioning",
     "PipelineConfig", "TextEncoderConfig", "UNetConfig", "VAEConfig",
     "unet_attn_specs", "unet_layout",
     "apply_text_encoder", "init_text_encoder",

@@ -363,6 +363,18 @@ def _find_subdir(checkpoint_dir: str, names: Tuple[str, ...]) -> str:
         f"no {'/'.join(names)} directory in {checkpoint_dir}")
 
 
+def one_tower(config):
+    """The text tower's configuration of a preset this module has key maps
+    for: one tower, no added embedding."""
+    if len(config.towers) != 1 or config.unet.addition_embed_in is not None:
+        raise NotImplementedError(
+            f"preset {config.name!r}: loading a checkpoint of several text "
+            "towers and an added embedding waits until such files are on "
+            "this machine (it takes key maps for a second tower and for "
+            "`add_embedding`)")
+    return config.text
+
+
 def load_pipeline(checkpoint_dir: str, config, tokenizer=None):
     """Load a full checkpoint directory into a Pipeline.
 
@@ -379,17 +391,18 @@ def load_pipeline(checkpoint_dir: str, config, tokenizer=None):
     from .unet import init_unet
     from . import vae as vae_mod
 
+    text = one_tower(config)
     unet_params = load_unet(init_unet(jax.random.PRNGKey(0), config.unet),
                             config.unet, _find_subdir(checkpoint_dir, ("unet",)))
     text_params = load_text_encoder(
-        init_text_encoder(jax.random.PRNGKey(0), config.text), config.text,
+        init_text_encoder(jax.random.PRNGKey(0), text), text,
         _find_subdir(checkpoint_dir, ("text_encoder", "bert")))
     vae_params = load_vae(vae_mod.init_vae(jax.random.PRNGKey(0), config.vae),
                           config.vae, _find_subdir(checkpoint_dir, ("vae", "vqvae")))
     if tokenizer is None:
         tok_dir = os.path.join(checkpoint_dir, "tokenizer")
-        max_len = config.text.max_length
-        if config.text.arch == "ldmbert":
+        max_len = text.max_length
+        if text.arch == "ldmbert":
             from ..utils.tokenizer import BertWordPieceTokenizer
 
             tokenizer = BertWordPieceTokenizer.from_dir(

@@ -242,29 +242,29 @@ def check_checkpoint(dirpath: str, preset: str, config=None) -> Report:
     """``config`` overrides the preset's PipelineConfig (tests use tiny
     configs against synthetic checkpoint dirs)."""
     from . import vae as vae_mod
-    from .checkpoint import (ldm_text_encoder_entries, text_encoder_entries,
-                             unet_entries, vae_entries)
+    from .checkpoint import (ldm_text_encoder_entries, one_tower,
+                             text_encoder_entries, unet_entries, vae_entries)
     from .config import PRESET_CONFIGS
     from .text_encoder import init_text_encoder
     from .unet import init_unet
 
     cfg = config if config is not None else PRESET_CONFIGS[preset]
-    text_entries = (ldm_text_encoder_entries(cfg.text)
-                    if cfg.text.arch == "ldmbert"
-                    else text_encoder_entries(cfg.text))
+    text = one_tower(cfg)
+    text_entries = (ldm_text_encoder_entries(text) if text.arch == "ldmbert"
+                    else text_encoder_entries(text))
 
     rep = Report(preset=preset)
     rep.submodels = [
         _check_submodel("unet", dirpath, unet_entries(cfg.unet),
                         lambda k: init_unet(k, cfg.unet)),
         _check_submodel("text_encoder", dirpath, text_entries,
-                        lambda k: init_text_encoder(k, cfg.text)),
+                        lambda k: init_text_encoder(k, text)),
         _check_submodel("vae", dirpath, vae_entries(cfg.vae),
                         lambda k: vae_mod.init_vae(k, cfg.vae)),
     ]
     rep.scheduler_diffs, rep.scheduler_error = _check_scheduler(
         dirpath, cfg.scheduler)
-    rep.tokenizer_error = _check_tokenizer(dirpath, cfg.text.arch)
+    rep.tokenizer_error = _check_tokenizer(dirpath, text.arch)
     return rep
 
 

@@ -11,7 +11,7 @@ layer bookkeeping is settled before tracing and costs nothing at runtime.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 from ..controllers.base import AttnLayout, StoreConfig, build_layout
 
@@ -34,10 +34,24 @@ class UNetConfig:
     head_dim: Optional[int] = None
     context_dim: int = 768                 # text-encoder hidden size
     context_len: int = 77
-    transformer_depth: int = 1             # transformer blocks per attn site group
+    # Transformer blocks per attention site group: one int for every
+    # attentive level and the mid block, or one int a level (0: no transformer
+    # there, whatever ``attn_levels`` says; the mid block takes the last).
+    transformer_depth: Union[int, Tuple[int, ...]] = 1
     groups: int = 32
     ff_mult: int = 4
     freq_dim: Optional[int] = None         # sinusoidal dim; default block_channels[0]
+    # Width of the vector embedded beside the time step (diffusers'
+    # ``addition_embed_type="text_time"``): the pooled text, then each of
+    # ``addition_sizes`` (the image's original height and width, the crop's
+    # top and left, the target height and width) embedded
+    # ``addition_time_dim`` wide. None: no such embedding.
+    addition_embed_in: Optional[int] = None
+    addition_time_dim: int = 256
+    addition_sizes: Tuple[int, ...] = ()
+    # The dtype kernels are stored in (leaves that are only ever an operand
+    # of a product or convolution); biases and norm parameters stay float32.
+    kernel_dtype: str = "float32"
 
     @property
     def time_embed_dim(self) -> int:
@@ -49,6 +63,19 @@ class UNetConfig:
 
     def resolution_at(self, level: int) -> int:
         return self.sample_size >> level
+
+    def depth_at(self, level: int) -> int:
+        """Transformer blocks of one site group at ``level``; 0 where the
+        level has none."""
+        if not self.attn_levels[level]:
+            return 0
+        depth = self.transformer_depth
+        return depth if isinstance(depth, int) else depth[level]
+
+    @property
+    def mid_depth(self) -> int:
+        depth = self.transformer_depth
+        return depth if isinstance(depth, int) else depth[-1]
 
     def heads_for(self, channels: int) -> int:
         if self.head_dim is not None:
@@ -90,23 +117,21 @@ def unet_attn_specs(cfg: UNetConfig):
     cache buffers before tracing."""
     specs = []
 
-    def site(place, level):
+    def site(place, level, depth):
         res = cfg.resolution_at(level)
         ch = cfg.block_channels[level]
         heads = cfg.heads_for(ch)
-        for _ in range(cfg.transformer_depth):
+        for _ in range(depth):
             specs.append((place, False, res, heads, res * res, ch))       # self
             specs.append((place, True, res, heads, cfg.context_len, ch))  # cross
 
     for level in range(cfg.levels):                      # down
-        if cfg.attn_levels[level]:
-            for _ in range(cfg.layers_per_block):
-                site("down", level)
-    site("mid", cfg.levels - 1)                          # mid
+        for _ in range(cfg.layers_per_block):
+            site("down", level, cfg.depth_at(level))
+    site("mid", cfg.levels - 1, cfg.mid_depth)           # mid
     for level in reversed(range(cfg.levels)):            # up
-        if cfg.attn_levels[level]:
-            for _ in range(cfg.layers_per_block + 1):
-                site("up", level)
+        for _ in range(cfg.layers_per_block + 1):
+            site("up", level, cfg.depth_at(level))
     return specs
 
 
@@ -117,7 +142,8 @@ def unet_layout(cfg: UNetConfig, store_cfg: Optional[StoreConfig] = None
         # scale that bound with the latent size so tiny test models store their
         # two lower pyramid levels the same way SD stores 32²/16²/8².
         store_cfg = StoreConfig(max_pixels=(cfg.sample_size // 2) ** 2)
-    return build_layout(unet_attn_specs(cfg), store_cfg)
+    return build_layout(unet_attn_specs(cfg), store_cfg,
+                        latent_size=cfg.sample_size)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +165,16 @@ class TextEncoderConfig:
     attn_qkv_bias: bool = True
     # Checkpoint-name architecture: 'clip' (CLIPTextModel) | 'ldmbert'.
     arch: str = "clip"
+    # Which layer's output the tower returns, as an index into the layers'
+    # outputs (-1 the last, -2 the penultimate), and whether the final
+    # LayerNorm is applied to it.
+    output_layer: int = -1
+    final_norm: bool = True
+    # Width of the pooled output (``CLIPTextModelWithProjection``): the final
+    # LayerNorm of the last layer's state at the first end-of-text position,
+    # through a bias-free projection. None: the tower has no pooled output.
+    projection_dim: Optional[int] = None
+    kernel_dtype: str = "float32"          # as ``UNetConfig.kernel_dtype``
 
     @property
     def inner_dim(self) -> int:
@@ -166,6 +202,7 @@ class VAEConfig:
     scaling_factor: float = 0.18215        # `/root/reference/ptp_utils.py:80`
     kind: str = "kl"                       # 'kl' | 'vq'
     num_codebook: int = 16384              # VQ only: codebook entries
+    kernel_dtype: str = "float32"          # as ``UNetConfig.kernel_dtype``
 
 SD14_VAE = VAEConfig()
 TINY_VAE = VAEConfig(base_channels=16, channel_mults=(1, 2, 2), layers_per_block=1,
@@ -204,7 +241,10 @@ class PipelineConfig:
 
     name: str
     unet: UNetConfig
-    text: TextEncoderConfig
+    # One tower's configuration, or a sequence of them whose outputs are
+    # concatenated into one context (``Pipeline.text_params`` is then a list
+    # of trees in the same order).
+    text: Union[TextEncoderConfig, Tuple[TextEncoderConfig, ...]]
     vae: VAEConfig
     image_size: int = 512
     guidance_scale: float = 7.5            # `/root/reference/main.py:20`
@@ -214,6 +254,11 @@ class PipelineConfig:
     @property
     def latent_size(self) -> int:
         return self.unet.sample_size
+
+    @property
+    def towers(self) -> Tuple[TextEncoderConfig, ...]:
+        """The text towers in order, whichever form ``text`` has."""
+        return self.text if isinstance(self.text, tuple) else (self.text,)
 
 
 SD14 = PipelineConfig("sd-v1.4", SD14_UNET, SD14_TEXT, SD14_VAE, image_size=512)
@@ -305,6 +350,53 @@ TINY_V = PipelineConfig(
     image_size=48, num_steps=4,
     scheduler=SchedulerConfig(prediction_type="v_prediction"))
 
+# SDXL-base-1.0 (arXiv 2307.01952; the published unet / text_encoder /
+# text_encoder_2 / vae config.json): three levels with no attention at the
+# top and transformers 2 and 10 blocks deep below it (the mid block 10), heads
+# of 64, two text towers whose penultimate states (no final LayerNorm) are
+# concatenated into a 2048-wide context, and the second tower's pooled text
+# with six embedded sizes added to the time embedding. Kernels are stored in
+# bfloat16: 3.47 B parameters are 13.9 GB in float32, which leaves a 16 GB
+# chip nothing beside them.
+SDXL_UNET = UNetConfig(
+    sample_size=128, block_channels=(320, 640, 1280),
+    attn_levels=(False, True, True), transformer_depth=(0, 2, 10),
+    head_dim=64, context_dim=2048, addition_embed_in=2816,
+    addition_sizes=(1024, 1024, 0, 0, 1024, 1024), kernel_dtype="bfloat16")
+SDXL_TEXT = (
+    TextEncoderConfig(output_layer=-2, final_norm=False,
+                      kernel_dtype="bfloat16"),
+    TextEncoderConfig(hidden_dim=1280, num_layers=32, num_heads=20,
+                      activation="gelu", output_layer=-2, final_norm=False,
+                      projection_dim=1280, kernel_dtype="bfloat16"))
+SDXL = PipelineConfig(
+    "sdxl-base-1.0", SDXL_UNET, SDXL_TEXT,
+    dataclasses.replace(SD14_VAE, scaling_factor=0.13025,
+                        kernel_dtype="bfloat16"),
+    image_size=1024, guidance_scale=5.0)
+
+# Tiny SDXL-shaped backend for tests: what `sdxl` forces at toy sizes — no
+# attention at the top level, depth by level, two towers of different widths
+# into one context, the pooled text and the sizes beside the time step,
+# bfloat16 kernels, and a latent (24 / 12 / 6) whose attentive levels start below its own side and hold no 16 (the edit's
+# default side is a quarter of the latent's, 6).
+TINY_XL_UNET = dataclasses.replace(
+    TINY_UNET, sample_size=24, attn_levels=(False, True, True),
+    transformer_depth=(0, 1, 2), num_heads=1, head_dim=16, context_dim=80,
+    addition_embed_in=48 + 6 * 8, addition_time_dim=8,
+    addition_sizes=(96, 96, 0, 0, 96, 96), kernel_dtype="bfloat16")
+TINY_XL_TEXT = (
+    dataclasses.replace(TINY_TEXT, output_layer=-2, final_norm=False,
+                        kernel_dtype="bfloat16"),
+    dataclasses.replace(TINY_TEXT, hidden_dim=48, num_layers=3, num_heads=3,
+                        activation="gelu", output_layer=-2, final_norm=False,
+                        projection_dim=48, kernel_dtype="bfloat16"))
+TINY_XL = PipelineConfig(
+    "tiny-xl", TINY_XL_UNET, TINY_XL_TEXT,
+    dataclasses.replace(TINY_VAE, scaling_factor=0.13025,
+                        kernel_dtype="bfloat16"),
+    image_size=96, num_steps=4, guidance_scale=5.0)
+
 
 # The one preset-name → PipelineConfig resolution map (CLI commands,
 # `p2p-tpu check`, tools/parity_real_weights.py all resolve through it).
@@ -321,4 +413,6 @@ PRESET_CONFIGS = {
     "ldm256": LDM256,
     "tiny_ldm": TINY_LDM,
     "tiny_v": TINY_V,
+    "sdxl": SDXL,
+    "tiny_xl": TINY_XL,
 }

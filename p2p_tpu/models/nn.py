@@ -31,13 +31,21 @@ def _split(key, n):
 # ---------------------------------------------------------------------------
 
 
+def _kernel_init(key, shape, fan_in: int, kernel_dtype) -> jax.Array:
+    """A kernel drawn in float32 and stored in ``kernel_dtype``: a kernel is
+    only ever an operand of a product or convolution, which the MXU rounds
+    to bfloat16 at the default precision whatever it is stored in."""
+    scale = 1.0 / math.sqrt(fan_in)
+    return jax.random.uniform(key, shape, jnp.float32, -scale, scale).astype(
+        kernel_dtype)
+
+
 def linear_init(key, in_dim: int, out_dim: int, bias: bool = True,
-                dtype=jnp.float32) -> Params:
+                kernel_dtype=jnp.float32) -> Params:
     kk, _ = _split(key, 2)
-    scale = 1.0 / math.sqrt(in_dim)
-    p = {"kernel": jax.random.uniform(kk, (in_dim, out_dim), dtype, -scale, scale)}
+    p = {"kernel": _kernel_init(kk, (in_dim, out_dim), in_dim, kernel_dtype)}
     if bias:
-        p["bias"] = jnp.zeros((out_dim,), dtype)
+        p["bias"] = jnp.zeros((out_dim,), jnp.float32)
     return p
 
 
@@ -58,14 +66,12 @@ def linear_1x1(p: Params, x: jax.Array) -> jax.Array:
 
 
 def conv_init(key, in_ch: int, out_ch: int, kernel: int = 3, bias: bool = True,
-              dtype=jnp.float32) -> Params:
+              kernel_dtype=jnp.float32) -> Params:
     kk, _ = _split(key, 2)
-    fan_in = in_ch * kernel * kernel
-    scale = 1.0 / math.sqrt(fan_in)
-    p = {"kernel": jax.random.uniform(kk, (kernel, kernel, in_ch, out_ch), dtype,
-                                      -scale, scale)}
+    p = {"kernel": _kernel_init(kk, (kernel, kernel, in_ch, out_ch),
+                                in_ch * kernel * kernel, kernel_dtype)}
     if bias:
-        p["bias"] = jnp.zeros((out_ch,), dtype)
+        p["bias"] = jnp.zeros((out_ch,), jnp.float32)
     return p
 
 

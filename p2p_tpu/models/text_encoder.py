@@ -15,15 +15,23 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from .conditioning import Conditioning
 from .config import TextEncoderConfig
 from . import nn
 
 Params = Dict[str, Any]
 
 
-def init_text_encoder(key: jax.Array, cfg: TextEncoderConfig) -> Params:
+def init_text_encoder(key: jax.Array, cfg) -> Params:
+    """One tower's parameters for one tower's configuration; for a sequence
+    of configurations (``PipelineConfig.text`` of a preset with several
+    towers) the list of their trees, in order."""
+    if isinstance(cfg, (list, tuple)):
+        return [init_text_encoder(k, c)
+                for k, c in zip(jax.random.split(key, len(cfg)), cfg)]
     keys = iter(jax.random.split(key, 4 + cfg.num_layers))
     d = cfg.hidden_dim
+    kd = cfg.kernel_dtype
     params: Params = {
         "token_embed": jax.random.normal(next(keys), (cfg.vocab_size, d)) * 0.02,
         "pos_embed": jax.random.normal(next(keys), (cfg.max_length, d)) * 0.01,
@@ -35,21 +43,25 @@ def init_text_encoder(key: jax.Array, cfg: TextEncoderConfig) -> Params:
         k1, k2, k3, k4, k5, k6 = jax.random.split(next(keys), 6)
         params["layers"].append({
             "ln1": nn.norm_init(d),
-            "q": nn.linear_init(k1, d, inner, bias=cfg.attn_qkv_bias),
-            "k": nn.linear_init(k2, d, inner, bias=cfg.attn_qkv_bias),
-            "v": nn.linear_init(k3, d, inner, bias=cfg.attn_qkv_bias),
-            "out": nn.linear_init(k4, inner, d),
+            "q": nn.linear_init(k1, d, inner, bias=cfg.attn_qkv_bias, kernel_dtype=kd),
+            "k": nn.linear_init(k2, d, inner, bias=cfg.attn_qkv_bias, kernel_dtype=kd),
+            "v": nn.linear_init(k3, d, inner, bias=cfg.attn_qkv_bias, kernel_dtype=kd),
+            "out": nn.linear_init(k4, inner, d, kernel_dtype=kd),
             "ln2": nn.norm_init(d),
-            "fc1": nn.linear_init(k5, d, d * cfg.ff_mult),
-            "fc2": nn.linear_init(k6, d * cfg.ff_mult, d),
+            "fc1": nn.linear_init(k5, d, d * cfg.ff_mult, kernel_dtype=kd),
+            "fc2": nn.linear_init(k6, d * cfg.ff_mult, d, kernel_dtype=kd),
         })
+    if cfg.projection_dim is not None:
+        params["projection"] = nn.linear_init(next(keys), d, cfg.projection_dim,
+                                              bias=False, kernel_dtype=kd)
     return params
 
 
-@jax.named_scope("text_encoder")     # docs/OBSERVABILITY.md, "Scope vocabulary"
-def apply_text_encoder(params: Params, cfg: TextEncoderConfig,
-                       ids: jax.Array, dtype=jnp.float32) -> jax.Array:
-    """ids: (B, L) int32 → (B, L, D) final-layer hidden states (post-LN)."""
+def _tower(params: Params, cfg: TextEncoderConfig, ids: jax.Array, dtype):
+    """One tower: the hidden states it conditions with (the output of layer
+    ``cfg.output_layer``, under the final LayerNorm where ``cfg.final_norm``)
+    and the state after the last layer it ran. A tower with no projection
+    runs no layer past the one it returns."""
     b, length = ids.shape
     x = params["token_embed"][ids].astype(dtype)
     x = x + params["pos_embed"][:length].astype(dtype)
@@ -67,7 +79,12 @@ def apply_text_encoder(params: Params, cfg: TextEncoderConfig,
     def split_heads(t):
         return t.reshape(b, length, heads, d_head).transpose(0, 2, 1, 3)
 
-    for layer in params["layers"]:
+    returned = cfg.num_layers + 1 + cfg.output_layer     # layers under the output
+    layers = params["layers"]
+    if cfg.projection_dim is None:                       # nothing reads the rest
+        layers = layers[:returned]
+    hidden = x
+    for n, layer in enumerate(layers, 1):
         h = nn.layer_norm(layer["ln1"], x)
         q = split_heads(nn.linear(layer["q"], h))
         k = split_heads(nn.linear(layer["k"], h))
@@ -79,5 +96,40 @@ def apply_text_encoder(params: Params, cfg: TextEncoderConfig,
         h = nn.layer_norm(layer["ln2"], x)
         act = nn.quick_gelu if cfg.activation == "quick_gelu" else nn.gelu
         x = x + nn.linear(layer["fc2"], act(nn.linear(layer["fc1"], h)))
+        if n == returned:
+            hidden = x
 
-    return nn.layer_norm(params["final_ln"], x)
+    if cfg.final_norm:
+        hidden = nn.layer_norm(params["final_ln"], hidden)
+    return hidden, x
+
+
+@jax.named_scope("text_encoder")     # docs/OBSERVABILITY.md, "Scope vocabulary"
+def apply_text_encoder(params: Params, cfg: TextEncoderConfig,
+                       ids: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """ids: (B, L) int32 → (B, L, D) hidden states of one tower (the final
+    layer's, post-LN, unless the configuration says otherwise)."""
+    return _tower(params, cfg, ids, dtype)[0]
+
+
+@jax.named_scope("text_encoder")
+def apply_text_towers(params, cfgs, ids: jax.Array, eos: jax.Array,
+                      dtype=jnp.float32) -> Conditioning:
+    """Several towers over the same ids (B, L): their hidden states
+    concatenated over the feature axis, and the pooled text of the one tower
+    that has a projection: the final LayerNorm of its last layer's output at
+    ``eos`` (B,) int32, each prompt's first end-of-text position, projected."""
+    hidden, pooled = [], []
+    for n, (p, cfg) in enumerate(zip(params, cfgs)):
+        with jax.named_scope(f"tower{n}"):
+            h, last = _tower(p, cfg, ids, dtype)
+        hidden.append(h)
+        if cfg.projection_dim is not None:
+            with jax.named_scope("pool"):
+                at_eos = last[jnp.arange(last.shape[0]), eos]
+                pooled.append(nn.linear(
+                    p["projection"], nn.layer_norm(p["final_ln"], at_eos)))
+    if len(pooled) != 1:
+        raise ValueError(f"{len(pooled)} of {len(cfgs)} towers have a "
+                         "projection: the pooled text is one tower's")
+    return Conditioning(jnp.concatenate(hidden, axis=-1), pooled[0])

@@ -38,6 +38,7 @@ from ..controllers.base import (
     controller_touches,
 )
 from ..obs import launches
+from .conditioning import Conditioning, context_of
 from .config import UNetConfig, unet_layout
 from . import nn
 
@@ -49,73 +50,86 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def _attn_init(key, query_dim: int, context_dim: int, inner_dim: int) -> Params:
+def _attn_init(key, query_dim: int, context_dim: int, inner_dim: int,
+               kd="float32") -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
-        "to_q": nn.linear_init(k1, query_dim, inner_dim, bias=False),
-        "to_k": nn.linear_init(k2, context_dim, inner_dim, bias=False),
-        "to_v": nn.linear_init(k3, context_dim, inner_dim, bias=False),
-        "to_out": nn.linear_init(k4, inner_dim, query_dim),
+        "to_q": nn.linear_init(k1, query_dim, inner_dim, bias=False, kernel_dtype=kd),
+        "to_k": nn.linear_init(k2, context_dim, inner_dim, bias=False, kernel_dtype=kd),
+        "to_v": nn.linear_init(k3, context_dim, inner_dim, bias=False, kernel_dtype=kd),
+        "to_out": nn.linear_init(k4, inner_dim, query_dim, kernel_dtype=kd),
     }
 
 
-def _transformer_block_init(key, dim: int, context_dim: int, ff_mult: int) -> Params:
+def _transformer_block_init(key, dim: int, context_dim: int, ff_mult: int,
+                            kd="float32") -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
     ff_inner = dim * ff_mult
     return {
         "ln1": nn.norm_init(dim),
-        "attn1": _attn_init(k1, dim, dim, dim),
+        "attn1": _attn_init(k1, dim, dim, dim, kd),
         "ln2": nn.norm_init(dim),
-        "attn2": _attn_init(k2, dim, context_dim, dim),
+        "attn2": _attn_init(k2, dim, context_dim, dim, kd),
         "ln3": nn.norm_init(dim),
         # GEGLU: one projection to 2·ff_inner (value ‖ gate), then back.
-        "ff_in": nn.linear_init(jax.random.split(k3)[0], dim, ff_inner * 2),
-        "ff_out": nn.linear_init(jax.random.split(k3)[1], ff_inner, dim),
+        "ff_in": nn.linear_init(jax.random.split(k3)[0], dim, ff_inner * 2,
+                                kernel_dtype=kd),
+        "ff_out": nn.linear_init(jax.random.split(k3)[1], ff_inner, dim,
+                                 kernel_dtype=kd),
     }
 
 
-def _spatial_transformer_init(key, ch: int, cfg: UNetConfig) -> Params:
-    keys = jax.random.split(key, cfg.transformer_depth + 2)
+def _spatial_transformer_init(key, ch: int, cfg: UNetConfig, depth: int) -> Params:
+    """One site group: a norm and a pair of projections around ``depth``
+    transformer blocks."""
+    kd = cfg.kernel_dtype
+    keys = jax.random.split(key, depth + 2)
     return {
         "norm": nn.norm_init(ch),
-        "proj_in": nn.conv_init(keys[0], ch, ch, kernel=1),
+        "proj_in": nn.conv_init(keys[0], ch, ch, kernel=1, kernel_dtype=kd),
         "blocks": [
-            _transformer_block_init(keys[1 + i], ch, cfg.context_dim, cfg.ff_mult)
-            for i in range(cfg.transformer_depth)
+            _transformer_block_init(keys[1 + i], ch, cfg.context_dim,
+                                    cfg.ff_mult, kd)
+            for i in range(depth)
         ],
-        "proj_out": nn.conv_init(keys[-1], ch, ch, kernel=1),
+        "proj_out": nn.conv_init(keys[-1], ch, ch, kernel=1, kernel_dtype=kd),
     }
 
 
-def _resnet_init(key, in_ch: int, out_ch: int, temb_dim: int) -> Params:
+def _resnet_init(key, in_ch: int, out_ch: int, temb_dim: int,
+                 kd="float32") -> Params:
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
         "norm1": nn.norm_init(in_ch),
-        "conv1": nn.conv_init(k1, in_ch, out_ch),
-        "time_proj": nn.linear_init(k2, temb_dim, out_ch),
+        "conv1": nn.conv_init(k1, in_ch, out_ch, kernel_dtype=kd),
+        "time_proj": nn.linear_init(k2, temb_dim, out_ch, kernel_dtype=kd),
         "norm2": nn.norm_init(out_ch),
-        "conv2": nn.conv_init(k3, out_ch, out_ch),
+        "conv2": nn.conv_init(k3, out_ch, out_ch, kernel_dtype=kd),
     }
     if in_ch != out_ch:
-        p["skip"] = nn.conv_init(k4, in_ch, out_ch, kernel=1)
+        p["skip"] = nn.conv_init(k4, in_ch, out_ch, kernel=1, kernel_dtype=kd)
     return p
 
 
 def init_unet(key: jax.Array, cfg: UNetConfig) -> Params:
-    """Random-init parameter pytree with SD-faithful shapes."""
+    """Random-init parameter pytree with SD-faithful shapes, kernels stored
+    in ``cfg.kernel_dtype``."""
     n_levels = cfg.levels
     keys = iter(jax.random.split(key, 64))
     ch0 = cfg.block_channels[0]
     temb = cfg.time_embed_dim
+    kd = cfg.kernel_dtype
 
     params: Params = {
-        "time_fc1": nn.linear_init(next(keys), cfg.freq_dim or ch0, temb),
-        "time_fc2": nn.linear_init(next(keys), temb, temb),
-        "conv_in": nn.conv_init(next(keys), cfg.in_channels, ch0),
+        "time_fc1": nn.linear_init(next(keys), cfg.freq_dim or ch0, temb,
+                                   kernel_dtype=kd),
+        "time_fc2": nn.linear_init(next(keys), temb, temb, kernel_dtype=kd),
+        "conv_in": nn.conv_init(next(keys), cfg.in_channels, ch0, kernel_dtype=kd),
         "down": [],
         "up": [],
         "norm_out": nn.norm_init(ch0),
-        "conv_out": nn.conv_init(next(keys), ch0, cfg.out_channels),
+        "conv_out": nn.conv_init(next(keys), ch0, cfg.out_channels,
+                                 kernel_dtype=kd),
     }
 
     # Down path. Skip-channel bookkeeping mirrors diffusers exactly so up-block
@@ -124,41 +138,52 @@ def init_unet(key: jax.Array, cfg: UNetConfig) -> Params:
     in_ch = ch0
     for level in range(n_levels):
         out_ch = cfg.block_channels[level]
+        depth = cfg.depth_at(level)
         block: Params = {"resnets": [], "attns": []}
         for _ in range(cfg.layers_per_block):
-            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch, temb))
-            if cfg.attn_levels[level]:
-                block["attns"].append(_spatial_transformer_init(next(keys), out_ch, cfg))
+            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch, temb, kd))
+            if depth:
+                block["attns"].append(
+                    _spatial_transformer_init(next(keys), out_ch, cfg, depth))
             in_ch = out_ch
             skip_chs.append(out_ch)
         if level != n_levels - 1:
-            block["downsample"] = nn.conv_init(next(keys), out_ch, out_ch)
+            block["downsample"] = nn.conv_init(next(keys), out_ch, out_ch,
+                                               kernel_dtype=kd)
             skip_chs.append(out_ch)
         params["down"].append(block)
 
     mid_ch = cfg.block_channels[-1]
     params["mid"] = {
-        "resnet1": _resnet_init(next(keys), mid_ch, mid_ch, temb),
-        "attn": _spatial_transformer_init(next(keys), mid_ch, cfg),
-        "resnet2": _resnet_init(next(keys), mid_ch, mid_ch, temb),
+        "resnet1": _resnet_init(next(keys), mid_ch, mid_ch, temb, kd),
+        "attn": _spatial_transformer_init(next(keys), mid_ch, cfg, cfg.mid_depth),
+        "resnet2": _resnet_init(next(keys), mid_ch, mid_ch, temb, kd),
     }
 
     # Up path (reverse level order).
     in_ch = mid_ch
     for level in reversed(range(n_levels)):
         out_ch = cfg.block_channels[level]
+        depth = cfg.depth_at(level)
         block = {"resnets": [], "attns": []}
         for _ in range(cfg.layers_per_block + 1):
             skip_ch = skip_chs.pop()
             block["resnets"].append(
-                _resnet_init(next(keys), in_ch + skip_ch, out_ch, temb))
-            if cfg.attn_levels[level]:
-                block["attns"].append(_spatial_transformer_init(next(keys), out_ch, cfg))
+                _resnet_init(next(keys), in_ch + skip_ch, out_ch, temb, kd))
+            if depth:
+                block["attns"].append(
+                    _spatial_transformer_init(next(keys), out_ch, cfg, depth))
             in_ch = out_ch
         if level != 0:
-            block["upsample"] = nn.conv_init(next(keys), out_ch, out_ch)
+            block["upsample"] = nn.conv_init(next(keys), out_ch, out_ch,
+                                             kernel_dtype=kd)
         params["up"].append(block)
 
+    if cfg.addition_embed_in is not None:
+        params["add_fc1"] = nn.linear_init(next(keys), cfg.addition_embed_in,
+                                           temb, kernel_dtype=kd)
+        params["add_fc2"] = nn.linear_init(next(keys), temb, temb,
+                                           kernel_dtype=kd)
     return params
 
 
@@ -487,12 +512,34 @@ def _apply_spatial_transformer(p: Params, x: jax.Array, context: jax.Array,
         return x.reshape(b, h, w, c) + residual
 
 
+def embed_added(params: Params, cfg: UNetConfig, cond):
+    """``cond`` with the embedding that is added to the time embedding
+    filled in (``Conditioning.added``): ``add = W2 silu(W1 a + b1) + b2`` over
+    ``a = [pooled | e(s0) | ... | e(s5)]``, ``e`` the sinusoidal embedding of
+    the time step at ``cfg.addition_time_dim`` and ``s`` the preset's
+    ``cfg.addition_sizes``. It does not depend on the step, so a sampler
+    calls this once, ahead of its scan. A conditioning that has none (a
+    one-tower preset's array) or has it already comes back as it is."""
+    if not isinstance(cond, Conditioning) or cond.added is not None:
+        return cond
+    with jax.named_scope("unet/add_embed"):
+        pooled = cond.pooled
+        sizes = jnp.asarray(cfg.addition_sizes, jnp.float32)
+        e = nn.timestep_embedding(sizes, cfg.addition_time_dim,
+                                  dtype=pooled.dtype).reshape(-1)
+        a = jnp.concatenate(
+            [pooled, jnp.broadcast_to(e, pooled.shape[:-1] + e.shape)], axis=-1)
+        added = nn.linear(params["add_fc2"],
+                          nn.silu(nn.linear(params["add_fc1"], a)))
+    return cond._replace(added=added)
+
+
 def apply_unet(
     params: Params,
     cfg: UNetConfig,
     x: jax.Array,                  # (B, H, W, C) latents, NHWC
     t: jax.Array,                  # scalar or (B,) timestep
-    context: jax.Array,            # (B, K, Cc) text embeddings
+    context,                       # (B, K, Cc) text embeddings, or a Conditioning
     layout: Optional[AttnLayout] = None,
     controller: Optional[Controller] = None,
     state: StoreState = (),
@@ -504,6 +551,11 @@ def apply_unet(
 ):
     """Predict ε(x_t, t, context). Returns ``(eps, controller_store_state)``,
     plus the updated cache as a third element iff a ``site_plan`` is given.
+
+    ``context`` is the preset's conditioning (``models.conditioning``): the
+    text tower's hidden states, or a ``Conditioning`` of them and the pooled
+    text where ``cfg.addition_embed_in`` is set, whose embedding joins the
+    time embedding that every ResNet block takes.
 
     ``kernels`` (a static ``kernels.KernelConfig``) routes covered
     controller-touched sites to the fused-edit Pallas kernel — the edit
@@ -553,6 +605,15 @@ def apply_unet(
     ctx = _HookCtx(layout, controller, state, step, plan, sp=sp,
                    attn_cache=attn_cache, kernels=kernels)
     g = cfg.groups
+    if (cfg.addition_embed_in is not None) != isinstance(context, Conditioning):
+        raise ValueError(
+            f"a U-Net with addition_embed_in={cfg.addition_embed_in} is "
+            f"conditioned on a {type(context).__name__}: one that embeds a "
+            "pooled vector takes a Conditioning, any other the hidden states")
+    launches.note_unet_depth(tuple(map(cfg.depth_at, range(cfg.levels))))
+    added = (embed_added(params, cfg, context).added
+             if isinstance(context, Conditioning) else None)
+    context = context_of(context)
 
     # Scopes (docs/OBSERVABILITY.md, "Scope vocabulary"): ``unet/<part>`` and
     # ``unet/<place><n>/<part>``, n counting a place's blocks in the order
@@ -560,7 +621,7 @@ def apply_unet(
     def resnet_and_attn(block, i, h):
         with jax.named_scope(f"res{i}"):
             h = _apply_resnet(block["resnets"][i], h, temb, g)
-        if block["attns"]:
+        if block["attns"]:      # none at a level without a transformer
             with jax.named_scope(f"attn{i}"):
                 h = _apply_spatial_transformer(block["attns"][i], h, context,
                                                cfg, ctx)
@@ -573,6 +634,8 @@ def apply_unet(
                 t, cfg.freq_dim or cfg.block_channels[0], dtype=x.dtype)
             temb = nn.linear(params["time_fc2"],
                              nn.silu(nn.linear(params["time_fc1"], temb)))
+            if added is not None:
+                temb = temb + added
 
         with jax.named_scope("conv_in"):
             h = nn.conv2d(params["conv_in"], x)

@@ -10,27 +10,29 @@ sample, for inversion), decode with the inverse scale
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs import launches
 from .config import VAEConfig
 from . import nn
 
 Params = Dict[str, Any]
 
 
-def _resnet_init(key, in_ch, out_ch):
+def _resnet_init(key, in_ch, out_ch, kd):
     k1, k2, k3 = jax.random.split(key, 3)
     p = {
         "norm1": nn.norm_init(in_ch),
-        "conv1": nn.conv_init(k1, in_ch, out_ch),
+        "conv1": nn.conv_init(k1, in_ch, out_ch, kernel_dtype=kd),
         "norm2": nn.norm_init(out_ch),
-        "conv2": nn.conv_init(k2, out_ch, out_ch),
+        "conv2": nn.conv_init(k2, out_ch, out_ch, kernel_dtype=kd),
     }
     if in_ch != out_ch:
-        p["skip"] = nn.conv_init(k3, in_ch, out_ch, kernel=1)
+        p["skip"] = nn.conv_init(k3, in_ch, out_ch, kernel=1, kernel_dtype=kd)
     return p
 
 
@@ -42,14 +44,14 @@ def _apply_resnet(p, x, groups):
     return x + h
 
 
-def _attn_init(key, ch):
+def _attn_init(key, ch, kd):
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
         "norm": nn.norm_init(ch),
-        "q": nn.linear_init(k1, ch, ch),
-        "k": nn.linear_init(k2, ch, ch),
-        "v": nn.linear_init(k3, ch, ch),
-        "out": nn.linear_init(k4, ch, ch),
+        "q": nn.linear_init(k1, ch, ch, kernel_dtype=kd),
+        "k": nn.linear_init(k2, ch, ch, kernel_dtype=kd),
+        "v": nn.linear_init(k3, ch, ch, kernel_dtype=kd),
+        "out": nn.linear_init(k4, ch, ch, kernel_dtype=kd),
     }
 
 
@@ -71,39 +73,41 @@ def init_vae(key: jax.Array, cfg: VAEConfig) -> Params:
     chs = [cfg.base_channels * m for m in cfg.channel_mults]
     top = chs[-1]
     lat = cfg.latent_channels
+    kd = cfg.kernel_dtype
+    conv = partial(nn.conv_init, kernel_dtype=kd)
 
-    enc: Params = {"conv_in": nn.conv_init(next(keys), cfg.in_channels, chs[0]),
+    enc: Params = {"conv_in": conv(next(keys), cfg.in_channels, chs[0]),
                    "down": []}
     in_ch = chs[0]
     for level, out_ch in enumerate(chs):
         block = {"resnets": []}
         for _ in range(cfg.layers_per_block):
-            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch))
+            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch, kd))
             in_ch = out_ch
         if level != len(chs) - 1:
-            block["downsample"] = nn.conv_init(next(keys), out_ch, out_ch)
+            block["downsample"] = conv(next(keys), out_ch, out_ch)
         enc["down"].append(block)
     enc["mid"] = {
-        "resnet1": _resnet_init(next(keys), top, top),
-        "attn": _attn_init(next(keys), top),
-        "resnet2": _resnet_init(next(keys), top, top),
+        "resnet1": _resnet_init(next(keys), top, top, kd),
+        "attn": _attn_init(next(keys), top, kd),
+        "resnet2": _resnet_init(next(keys), top, top, kd),
     }
     enc["norm_out"] = nn.norm_init(top)
     if cfg.kind == "vq":
         # VQ encoder emits the embedding directly; KL emits mean ‖ logvar.
-        enc["conv_out"] = nn.conv_init(next(keys), top, lat)
-        enc["quant_conv"] = nn.conv_init(next(keys), lat, lat, kernel=1)
+        enc["conv_out"] = conv(next(keys), top, lat)
+        enc["quant_conv"] = conv(next(keys), lat, lat, kernel=1)
     else:
-        enc["conv_out"] = nn.conv_init(next(keys), top, 2 * lat)
-        enc["quant_conv"] = nn.conv_init(next(keys), 2 * lat, 2 * lat, kernel=1)
+        enc["conv_out"] = conv(next(keys), top, 2 * lat)
+        enc["quant_conv"] = conv(next(keys), 2 * lat, 2 * lat, kernel=1)
 
     dec: Params = {
-        "post_quant_conv": nn.conv_init(next(keys), lat, lat, kernel=1),
-        "conv_in": nn.conv_init(next(keys), lat, top),
+        "post_quant_conv": conv(next(keys), lat, lat, kernel=1),
+        "conv_in": conv(next(keys), lat, top),
         "mid": {
-            "resnet1": _resnet_init(next(keys), top, top),
-            "attn": _attn_init(next(keys), top),
-            "resnet2": _resnet_init(next(keys), top, top),
+            "resnet1": _resnet_init(next(keys), top, top, kd),
+            "attn": _attn_init(next(keys), top, kd),
+            "resnet2": _resnet_init(next(keys), top, top, kd),
         },
         "up": [],
     }
@@ -112,13 +116,13 @@ def init_vae(key: jax.Array, cfg: VAEConfig) -> Params:
         out_ch = chs[level]
         block = {"resnets": []}
         for _ in range(cfg.layers_per_block + 1):
-            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch))
+            block["resnets"].append(_resnet_init(next(keys), in_ch, out_ch, kd))
             in_ch = out_ch
         if level != 0:
-            block["upsample"] = nn.conv_init(next(keys), out_ch, out_ch)
+            block["upsample"] = conv(next(keys), out_ch, out_ch)
         dec["up"].append(block)
     dec["norm_out"] = nn.norm_init(chs[0])
-    dec["conv_out"] = nn.conv_init(next(keys), chs[0], cfg.in_channels)
+    dec["conv_out"] = conv(next(keys), chs[0], cfg.in_channels)
 
     params = {"encoder": enc, "decoder": dec}
     if cfg.kind == "vq":
@@ -187,7 +191,39 @@ def decode(params: Params, cfg: VAEConfig, latents: jax.Array) -> jax.Array:
     """latents (B,h,w,4) → image (B,H,W,3) in [-1,1]
     (`/root/reference/ptp_utils.py:79-84`: input scaled by 1/0.18215 — the
     reference routes BOTH the SD KL-VAE and the LDM VQ decode through this
-    same function, `/root/reference/ptp_utils.py:124`)."""
+    same function, `/root/reference/ptp_utils.py:124`).
+
+    The batch is decoded in ``decode_chunks`` equal chunks, one after the
+    other in the same program (an unrolled loop: nothing here is a ``while``),
+    so that the widest activation alive is a chunk's and not the batch's."""
+    n = decode_chunks(cfg, latents.shape)
+    launches.note_decode_chunks(n)
+    if n == 1:      # the program of every batch that fits is the one it was
+        return _decode(params, cfg, latents)
+    return jnp.concatenate([_decode(params, cfg, chunk)
+                            for chunk in jnp.split(latents, n, axis=0)], axis=0)
+
+
+#: The widest activation of one decode chunk (float32, at the image's size
+#: and ``base_channels`` wide) stays under this many bytes: the two images of
+#: an edit at 768² (2 x 768² x 128 x 4 B = 604 MB) are one chunk as they
+#: always were, at 1024² (1.07 GB) they are two.
+DECODE_CHUNK_BYTES = 640 * 10**6
+
+
+def decode_chunks(cfg: VAEConfig, latent_shape) -> int:
+    """How many chunks ``decode`` takes a batch of ``latent_shape`` (B, h, w,
+    c) in: the fewest equal ones whose widest activation is at most
+    ``DECODE_CHUNK_BYTES``, a single image where even that is more."""
+    b, h, w, _ = latent_shape
+    up = 2 ** (len(cfg.channel_mults) - 1)
+    image_bytes = 4 * h * up * w * up * cfg.base_channels
+    return next(n for n in range(1, b + 1)
+                if b % n == 0 and (b // n * image_bytes <= DECODE_CHUNK_BYTES
+                                   or n == b))
+
+
+def _decode(params: Params, cfg: VAEConfig, latents: jax.Array) -> jax.Array:
     p = params["decoder"]
     g = cfg.groups
     # Scopes: ``vae.decode/{conv_in,mid,up<n>,conv_out}``, n in the order the

@@ -18,9 +18,13 @@ reader gets from one to the other for a program that really ran:
   program was traced (``note_self_site``; ``Launch.self_sites``: per site
   its keys, head width, implementation and the flash kernel's geometry and
   operand dtype),
-  and the bytes of attention maps the controller's store holds
+  the bytes of attention maps the controller's store holds
   (``note_store_bytes``; ``Launch.store_bytes``, and the gauge
-  ``launch_store_bytes{module}`` of ``obs.metrics``).
+  ``launch_store_bytes{module}`` of ``obs.metrics``), the U-Net's transformer
+  blocks per site group by level (``note_unet_depth``) and the chunks the
+  decode takes its batch in (``note_decode_chunks``). The bytes of the
+  weights the program is handed, by part and dtype, are read off the kept
+  shapes (``Launch.weights_bytes``).
 - :func:`scope_index` lowers, compiles and parses that program lazily, once,
   when somebody asks (``obs.traceparse.scope_index`` on the executable's
   text). After a launch in the same process the executable is still in
@@ -39,6 +43,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import inspect
 import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -83,6 +88,31 @@ class Launch:
     # Bytes of the controller's attention store in this program's carry (per
     # group, where the program runs groups), 0 where it keeps none.
     store_bytes: int = 0
+    # Transformer blocks per site group of the U-Net, one int a level (0: the
+    # level has no attention); () where the program runs no U-Net.
+    unet_depth: Tuple[int, ...] = ()
+    # Chunks the autoencoder's decode takes its batch in; 0: no decode.
+    decode_chunks: int = 0
+
+    @property
+    def weights_bytes(self) -> Dict[str, Dict[str, int]]:
+        """Bytes of the weights the program is handed, ``{part: {dtype:
+        bytes}}``: a part is a positional argument of the jitted function
+        whose name ends in ``_params`` (``unet_params`` is part ``unet``)."""
+        import jax
+
+        out = {}
+        try:
+            names = list(inspect.signature(self.fn).parameters)
+        except (TypeError, ValueError):         # not a function with a signature
+            return out
+        for name, arg in zip(names, self.args):
+            if name.endswith("_params"):
+                by = collections.Counter()
+                for leaf in jax.tree_util.tree_leaves(arg):
+                    by[str(leaf.dtype)] += leaf.size * leaf.dtype.itemsize
+                out[name[:-len("_params")]] = dict(by)
+        return out
 
     @property
     def self_site_counts(self) -> Dict[str, int]:
@@ -96,6 +126,17 @@ class Launch:
                 + "".join(f"; {n} of {shape}" for shape, n in shapes.items())
                 + f"; controller store {self.store_bytes} bytes")
 
+    def describe_model(self) -> str:
+        """One line for a log: depth by level, decode chunks, weights."""
+        parts = self.weights_bytes
+        weights = "; ".join(
+            f"{part} " + " ".join(f"{_SHORT.get(d, d)}:{n}" for d, n in sorted(by.items()))
+            for part, by in parts.items())
+        return (f"transformer depth by level {self.unet_depth}; "
+                f"decode_chunks {self.decode_chunks}; weights_bytes "
+                f"{sum(n for by in parts.values() for n in by.values())}"
+                f" ({weights})")
+
     def _signature(self):
         import jax
 
@@ -108,6 +149,7 @@ class Launch:
 _launches: Dict[str, List[Launch]] = {}     # module -> distinct programs
 _traced_sites: Dict[int, SelfSite] = {}     # by site, since the last mark
 _traced_store = 0                           # store bytes, since the last mark
+_traced_model = {}                          # unet_depth, decode_chunks, likewise
 
 
 def built() -> int:
@@ -116,6 +158,7 @@ def built() -> int:
     jitted function. The sites noted from here on are that launch's."""
     global _traced_store
     _traced_sites.clear()
+    _traced_model.clear()
     _traced_store = 0
     return compile_ledger().programs
 
@@ -136,6 +179,18 @@ def note_store_bytes(n: int) -> None:
     program each make their own)."""
     global _traced_store
     _traced_store = max(_traced_store, int(n))
+
+
+def note_unet_depth(depth: Tuple[int, ...]) -> None:
+    """Trace time, from the model: the U-Net being traced has ``depth[l]``
+    transformer blocks a site group at level ``l``."""
+    _traced_model["unet_depth"] = tuple(depth)
+
+
+def note_decode_chunks(n: int) -> None:
+    """Trace time, from the autoencoder: the decode being traced takes its
+    batch in ``n`` chunks."""
+    _traced_model["decode_chunks"] = int(n)
 
 
 def keep_if_built(mark: int, fn, args: tuple, kwargs: dict) -> None:
@@ -184,7 +239,8 @@ def _keep(fn, args, kwargs) -> None:
     if abstract is None:
         return
     launch = Launch("jit_" + fn.__name__, fn, *abstract,
-                    self_sites=dict(_traced_sites), store_bytes=_traced_store)
+                    self_sites=dict(_traced_sites), store_bytes=_traced_store,
+                    **_traced_model)
     known = _launches.setdefault(launch.module, [])
     # Another thread's compile can make a warm call look like a first launch.
     if all(launch._signature() != k._signature() for k in known):
@@ -239,7 +295,8 @@ def _build(launch: Launch) -> None:
     launch.index, launch.mixed = index, mixed
     # Whoever asked prints the scope tree (a traced run); this goes with it.
     print(f"launch {launch.module}: {len(index)} instructions from "
-          f"{launch.built_from}; {launch.describe_sites()}", file=sys.stderr)
+          f"{launch.built_from}; {launch.describe_sites()}; "
+          f"{launch.describe_model()}", file=sys.stderr)
 
 
 def _compile_and_parse(launch: Launch, compiler_options=None):

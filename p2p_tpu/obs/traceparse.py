@@ -51,9 +51,9 @@ _PLACE = r"(?:down|mid|up)\d+"
 #: matches, so whatever JAX appends below a scope (primitive names, inner
 #: function names) is dropped.
 SCOPE_RE = re.compile(
-    r"(?<![\w.])(?:text_encoder"
+    r"(?<![\w.])(?:text_encoder(?:/(?:tower\d+|pool))?"
     r"|sampler/(?:cfg|scheduler_step|controller_step)"
-    r"|unet(?:/(?:time_embed|conv_in|conv_out"
+    r"|unet(?:/(?:time_embed|add_embed|conv_in|conv_out"
     rf"|{_PLACE}(?:/(?:res\d+|downsample|upsample|skip_concat"
     r"|attn\d+(?:/(?:proj_in|proj_out|ff"
     rf"|(?:self_attn|cross_attn)/{_PLACE}(?:/(?:qkv|core|out))?))?))?))?"

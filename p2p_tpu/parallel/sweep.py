@@ -27,6 +27,7 @@ from ..engine.sampler import (PhaseCarry, _denoise_scan, _phase1_scan,
                               resolve_reuse, stage_host, warn_gate_truncation)
 from ..models import nn
 from ..models import vae as vae_mod
+from ..models.conditioning import rows
 from ..models.config import PipelineConfig
 from ..obs import launches
 from ..obs.spans import span
@@ -138,7 +139,8 @@ def sweep(
 ) -> Tuple[jax.Array, jax.Array]:
     """Run G independent edit groups; shard the group axis over ``dp``.
 
-    ``context``: (G, 2B, L, D); ``latents``: (G, B, h, w, c);
+    ``context``: (G, 2B, L, D), or the preset's ``Conditioning`` with those
+    leading axes on every leaf; ``latents``: (G, B, h, w, c);
     ``controllers``: a Controller pytree whose array leaves carry a leading
     G axis (same static structure per group — e.g. one edit with G equalizer
     rows or G cross-window schedules), or None.
@@ -190,15 +192,15 @@ def sweep(
                 # against the DDIM trajectory (`/root/reference/null_text.py:23`).
                 raise ValueError("uncond_per_step requires scheduler='ddim'")
             if (uncond_per_step.ndim != 5
-                    or uncond_per_step.shape[0] != context.shape[0]):
+                    or uncond_per_step.shape[0] != rows(context)):
                 raise ValueError(
                     f"uncond_per_step must be (G, T, 1, L, D) with G="
-                    f"{context.shape[0]}, got {uncond_per_step.shape}")
+                    f"{rows(context)}, got {uncond_per_step.shape}")
             if uncond_per_step.shape[1] != num_steps:
                 raise ValueError(
                     f"uncond_per_step has {uncond_per_step.shape[1]} steps, "
                     f"sampling uses {num_steps}")
-        groups = int(context.shape[0])
+        groups = rows(context)
         with span("entry.prepare", steps=int(num_steps), batch=groups):
             tsched = sched_mod.schedule_from_config(num_steps, cfg.scheduler,
                                                     kind=scheduler)
@@ -246,20 +248,16 @@ def sweep(
 
             if mesh is not None:
                 gspec = NamedSharding(mesh, P("dp"))
-                context = _stage_sharded(context, gspec)
-                latents = _stage_sharded(latents, gspec)
+                context, latents, controllers, uncond_per_step = jax.tree.map(
+                    lambda x: _stage_sharded(x, gspec),
+                    (context, latents, controllers, uncond_per_step))
                 schedule = _stage_replicated(schedule, mesh)
-                if controllers is not None:
-                    controllers = jax.tree_util.tree_map(
-                        lambda x: _stage_sharded(x, gspec), controllers)
-                if uncond_per_step is not None:
-                    uncond_per_step = _stage_sharded(uncond_per_step, gspec)
 
         if progress:
             from ..utils import progress as progress_mod
 
             progress_mod.activate(schedule.timesteps.shape[0],
-                                  f"sweep x{context.shape[0]}")
+                                  f"sweep x{groups}")
 
         with span("sampler.sweep", groups=groups,
                   steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
@@ -430,13 +428,11 @@ def sweep_phase1(
                 reuse=reuse_sched, kernels=kernels)
         if mesh is not None:
             gspec = NamedSharding(mesh, P("dp"))
-            context = _stage_sharded(context, gspec)
-            latents = _stage_sharded(latents, gspec)
+            context, latents, controllers = jax.tree.map(
+                lambda x: _stage_sharded(x, gspec),
+                (context, latents, controllers))
             schedule = _stage_replicated(schedule, mesh)
-            if controllers is not None:
-                controllers = jax.tree_util.tree_map(
-                    lambda x: _stage_sharded(x, gspec), controllers)
-        with span("sampler.sweep_phase1", groups=int(context.shape[0]),
+        with span("sampler.sweep_phase1", groups=rows(context),
                   steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
             args = (pipe.unet_params, cfg, layout, schedule, scheduler,
                     context, latents, controllers, gs)
@@ -488,14 +484,11 @@ def sweep_phase2(
                 metrics=metrics, reuse=reuse_sched, kernels=kernels)
         if mesh is not None:
             gspec = NamedSharding(mesh, P("dp"))
-            context_cond = _stage_sharded(context_cond, gspec)
-            carry = jax.tree_util.tree_map(
-                lambda x: _stage_sharded(x, gspec), carry)
+            context_cond, carry, controllers = jax.tree.map(
+                lambda x: _stage_sharded(x, gspec),
+                (context_cond, carry, controllers))
             schedule = _stage_replicated(schedule, mesh)
-            if controllers is not None:
-                controllers = jax.tree_util.tree_map(
-                    lambda x: _stage_sharded(x, gspec), controllers)
-        with span("sampler.sweep_phase2", groups=int(context_cond.shape[0]),
+        with span("sampler.sweep_phase2", groups=rows(context_cond),
                   steps=int(schedule.timesteps.shape[0]), gate=int(gate_step)):
             args = (pipe.unet_params, pipe.vae_params, cfg, layout, schedule,
                     scheduler, context_cond, carry, controllers, gs)
@@ -526,8 +519,9 @@ def artifact_replay_inputs(pipe, x_t, uncond_embeddings, source: str,
         raise ValueError(f"{len(controllers)} controllers for {g} targets")
     ctrls = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *controllers)
     enc = encode_prompts(pipe, ["", source] + list(targets))
-    ctx_g = jnp.stack([jnp.stack([enc[0], enc[0], enc[1], enc[2 + i]])
-                       for i in range(g)])
+    # per group [uncond, uncond, source, target i]: rows of one encode
+    pick = np.asarray([[0, 0, 1, 2 + i] for i in range(g)])
+    ctx_g = jax.tree.map(lambda e: e[pick], enc)
     x_t = jnp.asarray(x_t)
     lats = jnp.broadcast_to(x_t[None], (g, 2) + x_t.shape[1:])
     ups = jnp.broadcast_to(jnp.asarray(uncond_embeddings)[None],

@@ -157,7 +157,8 @@ def stack_carries(carries: List[Any], bucket: int, mesh=None) -> Any:
 def carry_template(pipe, prep):
     """The hand-off unit this request's phase-1 runner produces — derived
     from the *request* (shapes only, zero-valued), never from a live carry.
-    ``{"carry": PhaseCarry, "ctx": (B, L, D) cond context}``: the encoded
+    ``{"carry": PhaseCarry, "ctx": the cond half of the conditioning}``
+    ((B, L, D) hidden states, or the preset's ``Conditioning``): the encoded
     conditional half rides the hand-off so phase 2 (and a journal-resumed
     lane) never re-runs the text encoder. This is the pinned-treedef
     source :func:`load_carry` validates a spill against: the spec a spill
@@ -167,6 +168,7 @@ def carry_template(pipe, prep):
 
     from ..controllers.base import init_store_state
     from ..engine.sampler import PhaseCarry
+    from ..models.conditioning import zeros_for
     from ..models.config import unet_layout
     from ..models.unet import init_attn_cache
     from ..ops import schedulers as sched_mod
@@ -199,9 +201,7 @@ def carry_template(pipe, prep):
         ms=sched_mod.init_multistep_state(prep.request.scheduler, lat.shape,
                                           lat.dtype),
         state=state)
-    ctx = jnp.zeros((b, cfg.unet.context_len, cfg.unet.context_dim),
-                    jnp.float32)
-    return {"carry": carry, "ctx": ctx}
+    return {"carry": carry, "ctx": zeros_for(cfg, b)}
 
 
 def spill_carry(carry: Any, path: str) -> str:

@@ -197,6 +197,7 @@ class SweepRunner:
         import numpy as np
 
         from ..engine.sampler import encode_prompts, init_latent, stage_host
+        from ..models.conditioning import cfg_rows
 
         def encode(prompts):
             if self.semcache is None:
@@ -211,7 +212,7 @@ class SweepRunner:
             cond = encode(req.prompts)
             uncond = encode(tuple([req.negative_prompt or ""]
                                   * len(req.prompts)))
-            ctxs.append(jnp.concatenate([uncond, cond], axis=0))
+            ctxs.append(cfg_rows(uncond, cond))
             # The seed is staged explicitly (np.int32 is exactly what
             # PRNGKey(int) resolves to under x64-off, so keys — and lanes —
             # stay bitwise-identical): PRNGKey(python_int) is an implicit
@@ -230,12 +231,12 @@ class SweepRunner:
             ctxs.append(ctxs[-1])
             lats.append(lats[-1])
             ctrls.append(ctrls[-1])
-        ctx = jnp.stack(ctxs)
+        ctx = jax.tree.map(lambda *xs: jnp.stack(xs), *ctxs)
         lat = jnp.stack(lats)
         ctrl = (None if ctrls[0] is None else
                 jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ctrls))
         if zeros:
-            ctx, lat = jnp.zeros_like(ctx), jnp.zeros_like(lat)
+            ctx, lat = jax.tree.map(jnp.zeros_like, (ctx, lat))
         return ctx, lat, ctrl
 
     def warm(self, entries) -> None:
@@ -311,7 +312,7 @@ def _cond_half(ctx, group_batch: int):
 
         @functools.partial(jax.jit, static_argnames=("b",))
         def cut(x, b):
-            return x[:, b:]
+            return jax.tree.map(lambda leaf: leaf[:, b:], x)
 
         _COND_HALF_JIT = cut
     return _COND_HALF_JIT(ctx, b=group_batch)
@@ -458,8 +459,7 @@ class Phase2Runner:
         ctrl = (None if ctrls[0] is None else
                 jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ctrls))
         if zeros:
-            ctx = jnp.zeros_like(ctx)
-            carry = jax.tree_util.tree_map(jnp.zeros_like, carry)
+            ctx, carry = jax.tree.map(jnp.zeros_like, (ctx, carry))
         return ctx, carry, ctrl
 
     def _reuse_kw(self) -> dict:

@@ -161,9 +161,12 @@ class SemCache:
             self._note("l1", "hits")
             return self._l1[key][0]
         self._note("l1", "misses")
+        import jax
+
         value = build()
-        nbytes = int(getattr(value, "size", 0)) * int(
-            getattr(getattr(value, "dtype", None), "itemsize", 0) or 0)
+        nbytes = sum(int(getattr(leaf, "size", 0)) * int(
+            getattr(getattr(leaf, "dtype", None), "itemsize", 0) or 0)
+            for leaf in jax.tree_util.tree_leaves(value))
         self._l1[key] = (value, nbytes)
         self._l1_used += nbytes
         self._note("l1", "inserts")

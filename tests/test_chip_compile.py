@@ -33,6 +33,11 @@ budget. These compiles can, at about a second each and no chip time:
     nobody reads are flash calls at (4, 10, 2304, 64) beside the five at
     9,216 keys, and no (4, 10, 2304, 2304) tensor exists (about two minutes).
 
+(g) the whole sampling program of the cell `sdxl.edit-replace` (PR 36): ten
+    flash calls at (4, 10, 4096, 64) in its loop, the sixty sites at 1,024
+    keys the controller's, two decode chunks, and arguments, temporaries and
+    code within 15 GiB by XLA's own count (about three minutes).
+
 (f) two ResNet blocks of `sd14`'s first level (``unet._apply_resnet``, f32,
     320 wide at 64x64): GroupNorm's statistics leave the activation
     channels-minor, so no copy of it to a W-minor layout is compiled (PR 32).
@@ -211,7 +216,9 @@ def test_fused_edit_kernel_compiles(one_chip, controllers, mode, key):
 #: two SD-2.1 self sites in bf16 as well (PR 35), so that every length with
 #: a row of its own in the table is asked at both widths; and a 256-wide
 #: bf16 head at 2,304 keys, which no preset has: the one other answer that
-#: the VMEM budget's move to 14.75 MiB changed (PERF.md §6, PR 35).
+#: the VMEM budget's move to 14.75 MiB changed (PERF.md §6, PR 35); and SDXL
+#: at 1024x1024 (PR 36): its 64x64 and 32x32 self sites (10 and 20 heads of
+#: 64) and the VAE's mid attention at 128x128.
 FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
               (4096, 8, 40, jnp.bfloat16), (1024, 8, 80, jnp.bfloat16),
               (1024, 5, 64, jnp.float32), (4096, 1, 512, jnp.float32),
@@ -219,7 +226,9 @@ FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
               (9216, 5, 64, jnp.float32), (2304, 10, 64, jnp.float32),
               (9216, 1, 512, jnp.float32),
               (9216, 5, 64, jnp.bfloat16), (2304, 10, 64, jnp.bfloat16),
-              (2304, 2, 256, jnp.bfloat16)]
+              (2304, 2, 256, jnp.bfloat16),
+              (4096, 10, 64, jnp.float32), (1024, 20, 64, jnp.float32),
+              (16384, 1, 512, jnp.float32)]
 #: The 64x64 self site in bf16, which the mesh cases run, and its local chunks
 #: under parallel/ring.py at sp = 2 and 4 (the residuals kernel runs on those).
 FLASH_SITE = FLASH_ROWS[2]
@@ -399,6 +408,74 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
     assert len(kernels) == 11
     assert "f32[4,10,2304,2304]" not in text
     assert "f32[2,10,2304,2304]" not in text
+
+
+def test_sdxl_cell_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
+    """`sdxl.edit-replace`'s sampling program for the described v5e: kernels
+    arrive in bfloat16 (4.9 GiB of arguments for the U-Net and the
+    autoencoder; the towers are another program's), the ten 64x64 self sites
+    are flash calls, the sixty 32x32 sites are the controller's and hold
+    (4, 20, 1024, 1024) probabilities, the decode is two chunks of one image
+    with its mid attention on the kernel, and nothing in it is a second
+    ``while``. By ``memory_analysis()`` the program's arguments, temporaries
+    and code come to under 15 GiB of the chip's 16: the widening of a kernel
+    stays at its use and is not hoisted into float32 copies of the tree."""
+    import re
+
+    from p2p_tpu.engine.sampler import _text2image_jit
+    from p2p_tpu.models import init_unet
+    from p2p_tpu.models import vae as vae_mod
+    from p2p_tpu.models.conditioning import zeros_for
+    from p2p_tpu.models.config import SDXL
+    from p2p_tpu.obs import launches
+    from p2p_tpu.ops import schedulers as sched_mod
+
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+    cfg = SDXL
+    tok = HashWordTokenizer(model_max_length=cfg.unet.context_len)
+    ctrl = factory.attention_replace(
+        ["a cat riding a bike", "a dog riding a bike"], STEPS, 0.8, 0.4, tok,
+        max_len=cfg.unet.context_len, store=True)
+    layout = unet_layout(cfg.unet)
+    ctrl = layout.resolve(ctrl)                # the self window from the model: 32²
+    assert ctrl.edit.self_max_pixels == 32 * 32
+    layout = layout.for_readers(ctrl)
+    key = jax.random.PRNGKey(0)
+    unet = jax.eval_shape(lambda: init_unet(key, cfg.unet))
+    vae = jax.eval_shape(lambda: vae_mod.init_vae(key, cfg.vae))
+    sched = sched_mod.schedule_from_config(STEPS, cfg.scheduler, kind="ddim")
+    side = cfg.latent_size
+    cond = zeros_for(cfg, 2)
+    args = _shapes((unet, vae, sched, cond, cond,
+                    jnp.zeros((2, side, side, cfg.unet.in_channels)), ctrl,
+                    jnp.float32(cfg.guidance_scale)), one_chip)
+    unet, vae, sched, cond, uncond, latents, ctrl, scale = args
+    launches.built()                           # the sites noted from here on
+    compiled = _text2image_jit.lower(
+        unet, vae, cfg, layout, sched, "ddim", cond, uncond, latents, ctrl,
+        scale, None, False).compile()
+    hows = launches._traced_sites
+    assert sorted((s.keys, s.how) for s in hows.values()) == \
+        [(1024, "edited")] * 60 + [(4096, "kernel")] * 10
+    assert {s.geometry for s in hows.values() if s.how == "kernel"} == {(256, 4096, 2048)}
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    by_shape = {shape: sum(1 for line in kernels
+                           if re.search(r"= bf16\[%s\]" % shape, line))
+                for shape in ("4,10,4096,64", "1,1,16384,512")}
+    assert by_shape == {"4,10,4096,64": 10, "1,1,16384,512": 2}
+    assert len(kernels) == 12
+    assert "f32[4,20,1024,1024]" in text and "f32[4,10,4096,4096]" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+    stats = compiled.memory_analysis()
+    gib = 2.0 ** 30
+    assert 4.7 < stats.argument_size_in_bytes / gib < 5.1
+    assert (stats.argument_size_in_bytes + stats.temp_size_in_bytes
+            + stats.generated_code_size_in_bytes) / gib <= 15.0
+    # one image at a time: two images at once would hold 1 GiB at every
+    # full-size activation (PERF.md §6, PR 36 has XLA's count of both)
+    assert stats.temp_size_in_bytes / gib < 4.5
 
 
 def test_resnet_blocks_keep_the_activation_channels_minor(one_chip):
