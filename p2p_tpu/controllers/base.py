@@ -300,6 +300,18 @@ def controller_touches(controller: Optional["Controller"], meta: AttnMeta) -> bo
     return False
 
 
+def controller_only_injects(controller: Optional["Controller"],
+                            meta: AttnMeta) -> bool:
+    """Static: the controller touches this site only to inject the source
+    prompt's self map into the edit rows (``edit_self_attention``): a self
+    site within ``self_max_pixels`` whose map nobody stores. Such a site
+    needs no probabilities: ``inject_self_operands`` gives attention the
+    base row's q and k in the edit rows, and where the flash kernel takes
+    its shape the model runs it there (``models/unet.py``)."""
+    return (controller_touches(controller, meta) and not meta.is_cross
+            and not (meta.store_slot is not None and controller.needs_store))
+
+
 def controller_step_window(controller: Optional["Controller"],
                            num_steps: int) -> int:
     """Host-side: the last scan step (exclusive) at which this controller can

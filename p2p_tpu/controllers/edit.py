@@ -11,7 +11,7 @@ multiply, which also expresses the reference's controller chaining
 
 from __future__ import annotations
 
-from typing import TYPE_CHECKING, Optional, Union
+from typing import TYPE_CHECKING, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +119,33 @@ def edit_self_attention(
     The size gate is static; the step window is a traced predicate."""
     if pixels > params.self_max_pixels:
         return attn_edit
-    in_window = jnp.logical_and(step >= params.self_start, step < params.self_end)
+    in_window = _in_self_window(params, step)
     injected = jnp.broadcast_to(attn_base[None], attn_edit.shape)
     return jnp.where(in_window, injected, attn_edit)
+
+
+def inject_self_operands(
+    params: EditParams,
+    q: jax.Array,
+    k: jax.Array,
+    step: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """The same injection on a self site's operands instead of its maps: an
+    edit row's map inside the window is the base row's, ``softmax(q_base
+    k_baseᵀ)``, so its output is that map times its own ``v``. Handing
+    attention the base row's q and k in the edit rows computes exactly that
+    with no map in memory. For a site within the size gate
+    (``controllers.base.controller_only_injects``). q, k: ``(2B, heads, P,
+    D)``, rows ``[:B]`` the unconditional half, row ``B`` the source prompt;
+    returned as they came outside the window, or with no edit row (B = 1)."""
+    b = q.shape[0] // 2
+    if b <= 1:
+        return q, k
+    edit_row = (jnp.arange(q.shape[0]) > b)[:, None, None, None]
+    take = jnp.logical_and(_in_self_window(params, step), edit_row)
+    return jnp.where(take, q[b], q), jnp.where(take, k[b], k)
+
+
+def _in_self_window(params: EditParams, step: jax.Array) -> jax.Array:
+    """The self-injection window ``[self_start, self_end)``, traced."""
+    return jnp.logical_and(step >= params.self_start, step < params.self_end)

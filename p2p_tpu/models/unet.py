@@ -12,7 +12,10 @@ controller's store state through the sites in call order. Sites the controller
 provably never touches (``controller_touches`` is False) run fused attention —
 no probability tensor exists in the compiled program; touched sites
 materialize f32 probabilities, route them through
-``apply_attention_control``, then finish ``probs @ v``. Touched means edited
+``apply_attention_control``, then finish ``probs @ v`` — except a self site
+the controller only injects into (``controller_only_injects``) whose shape
+the flash kernel takes, which runs the kernel on the base row's q and k in
+its edit rows. Touched means edited
 (every cross site under an edit, self sites up to ``self_max_pixels``) or
 stored, and a site is stored only where the layout gives it a slot: for a
 reader of the store (``AttnLayout.for_readers``: the caller under
@@ -35,8 +38,10 @@ from ..controllers.base import (
     Controller,
     StoreState,
     apply_attention_control,
+    controller_only_injects,
     controller_touches,
 )
+from ..controllers.edit import inject_self_operands
 from ..obs import launches
 from .conditioning import Conditioning, context_of
 from .config import UNetConfig, unet_layout
@@ -399,11 +404,22 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         if controller_touches(ctx.controller, meta):
             how = "edited"
             out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
-            if out is None:
-                probs = nn.attention_probs(q, k, scale)        # (B, heads, P, K) f32
-                ctx.state, probs = apply_attention_control(
-                    ctx.controller, meta, ctx.state, probs, ctx.step)
-                out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+            if (out is None and geometry is not None
+                    and controller_only_injects(ctx.controller, meta)):
+                # The edit rows take the base row's map: the flash kernel on
+                # the base row's q and k over their own v, no map in memory.
+                # Where the einsum chain would run, the map exists anyway and
+                # the edit fuses into P·V, which a substitution of q and k
+                # does not (PERF.md §6, PR 37): such a site stays below.
+                q, k = inject_self_operands(ctx.controller.edit, q, k, ctx.step)
+                out = nn.fused_attention(q, k, v, scale)
+            else:
+                geometry = None             # the fused-edit kernel's, or none
+                if out is None:
+                    probs = nn.attention_probs(q, k, scale)    # (B, heads, P, K) f32
+                    ctx.state, probs = apply_attention_control(
+                        ctx.controller, meta, ctx.state, probs, ctx.step)
+                    out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
         elif (ctx.sp is not None and not is_cross
               and meta.pixels >= ctx.sp.min_pixels):
             n = ctx.sp.mesh.shape[ctx.sp.axis]
@@ -430,7 +446,7 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
             elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
                 from ..parallel.alltoall import alltoall_self_attention
 
-                how = "sharded"
+                how, geometry = "sharded", None
                 out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
                                               ctx.sp.axis)
             else:
@@ -447,15 +463,14 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
                         f"at this site", stacklevel=2)
                 from ..parallel.ring import ring_self_attention
 
-                how = "sharded"
+                how, geometry = "sharded", None
                 out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
         else:
             out = nn.fused_attention(q, k, v, scale)
         if not is_cross:
-            on_kernel = how == "kernel"
-            launches.note_self_site(meta.layer_idx, how, pix, d_head,
-                                    geometry if on_kernel else None,
-                                    operand.name if on_kernel else "")
+            # the tile and operand width wherever the site reached the kernel
+            launches.note_self_site(meta.layer_idx, how, pix, d_head, geometry,
+                                    operand.name if geometry else "")
 
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)

@@ -62,7 +62,9 @@ class SelfSite:
     keys: int                   # pixels = keys of the site
     head_dim: int
     how: str                    # "kernel" | "einsum" | "edited" | "sharded"
-    geometry: Optional[Tuple[int, int, int]] = None   # the flash kernel's tile
+    # The flash kernel's tile, where the site reached it: a "kernel" site, or
+    # an "edited" one the controller only injects into (ISSUE 37).
+    geometry: Optional[Tuple[int, int, int]] = None
     operand: str = ""           # dtype the kernel is handed q, k, v in
 
     def __str__(self):

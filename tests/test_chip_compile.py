@@ -34,9 +34,10 @@ budget. These compiles can, at about a second each and no chip time:
     9,216 keys, and no (4, 10, 2304, 2304) tensor exists (about two minutes).
 
 (g) the whole sampling program of the cell `sdxl.edit-replace` (PR 36): ten
-    flash calls at (4, 10, 4096, 64) in its loop, the sixty sites at 1,024
-    keys the controller's, two decode chunks, and arguments, temporaries and
-    code within 15 GiB by XLA's own count (about three minutes).
+    flash calls at (4, 10, 4096, 64) in its loop, and sixty at (4, 20, 1024,
+    64), the sites at 1,024 keys the controller only injects into (PR 37);
+    two decode chunks, and arguments, temporaries and code within 15 GiB by
+    XLA's own count (about three minutes).
 
 (f) two ResNet blocks of `sd14`'s first level (``unet._apply_resnet``, f32,
     320 wide at 64x64): GroupNorm's statistics leave the activation
@@ -414,10 +415,11 @@ def test_sdxl_cell_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
     """`sdxl.edit-replace`'s sampling program for the described v5e: kernels
     arrive in bfloat16 (4.9 GiB of arguments for the U-Net and the
     autoencoder; the towers are another program's), the ten 64x64 self sites
-    are flash calls, the sixty 32x32 sites are the controller's and hold
-    (4, 20, 1024, 1024) probabilities, the decode is two chunks of one image
-    with its mid attention on the kernel, and nothing in it is a second
-    ``while``. By ``memory_analysis()`` the program's arguments, temporaries
+    are flash calls, the sixty 32x32 sites are the controller's, which only
+    injects into them, so they are flash calls too, on the base row's q and
+    k in the edit rows (ISSUE 37), and no (4, 20, 1024, 1024) probabilities
+    exist; the decode is two chunks of one image with its mid attention on
+    the kernel, and nothing in it is a second ``while``. By ``memory_analysis()`` the program's arguments, temporaries
     and code come to under 15 GiB of the chip's 16: the widening of a kernel
     stays at its use and is not hoisted into float32 copies of the tree."""
     import re
@@ -458,15 +460,17 @@ def test_sdxl_cell_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
     assert sorted((s.keys, s.how) for s in hows.values()) == \
         [(1024, "edited")] * 60 + [(4096, "kernel")] * 10
     assert {s.geometry for s in hows.values() if s.how == "kernel"} == {(256, 4096, 2048)}
+    assert {(s.geometry, s.operand) for s in hows.values() if s.how == "edited"} == {
+        ((1024, 1024, 1024), "bfloat16")}
     text = compiled.as_text()
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     by_shape = {shape: sum(1 for line in kernels
                            if re.search(r"= bf16\[%s\]" % shape, line))
-                for shape in ("4,10,4096,64", "1,1,16384,512")}
-    assert by_shape == {"4,10,4096,64": 10, "1,1,16384,512": 2}
-    assert len(kernels) == 12
-    assert "f32[4,20,1024,1024]" in text and "f32[4,10,4096,4096]" not in text
+                for shape in ("4,20,1024,64", "4,10,4096,64", "1,1,16384,512")}
+    assert by_shape == {"4,20,1024,64": 60, "4,10,4096,64": 10, "1,1,16384,512": 2}
+    assert len(kernels) == 72
+    assert "f32[4,20,1024,1024]" not in text and "f32[4,10,4096,4096]" not in text
     assert len(re.findall(r" while\(", text)) == 1
     stats = compiled.memory_analysis()
     gib = 2.0 ** 30

@@ -341,3 +341,86 @@ def test_controller_is_pytree_and_jittable(tokenizer):
     s2, o2 = apply_attention_control(c, meta, state, attn, jnp.int32(0))
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(s1[0]), np.asarray(s2[0]), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# injected self sites: the base row's q and k, no map (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+
+def _injecting(kind, tokenizer, pixels):
+    """A ``kind`` controller over three prompts (two edit rows) whose self
+    window is steps [0, 2) of 4 and takes maps up to ``pixels``; no store."""
+    prompts = ["a cat sat", "a dog sat", "a pig sat"]
+    args, kw = (prompts, 4, 0.8, 0.5), dict(self_max_pixels=pixels, max_len=L,
+                                             store=False)
+    if kind == "reweight":
+        return attention_reweight(*args, jnp.full((E, L), 2.0), tokenizer, **kw)
+    return {"replace": attention_replace,
+            "refine": attention_refine}[kind](*args, tokenizer, **kw)
+
+
+@pytest.mark.parametrize("site", ["einsum", "kernel"])
+@pytest.mark.parametrize("step", [0, 3], ids=["in-window", "after-window"])
+@pytest.mark.parametrize("kind", ["replace", "refine", "reweight"])
+def test_injected_self_site_equals_the_materialized_path(tokenizer, monkeypatch,
+                                                         kind, step, site):
+    """A self site the controller only injects into, at a shape the flash
+    kernel takes (32², 1,024 keys; the interpreter stands in for the chip),
+    runs the kernel on the base row's q and k in its edit rows
+    (``controllers.edit.inject_self_operands``), notes the kernel's tile, and
+    gives the site output the materialized path gives (``attention_probs`` →
+    ``apply_attention_control`` → P·V) to the bf16 operands' rounding. Below
+    1,024 keys (8², as `sd14`'s and `sd21`'s window sites) the site keeps the
+    materialized path, the parent's program; the substitution on the einsum
+    chain gives its rows bit for bit all the same."""
+    from jax.experimental.pallas.tpu import force_tpu_interpret_mode
+
+    from p2p_tpu.controllers.base import AttnMeta, controller_only_injects
+    from p2p_tpu.controllers.edit import inject_self_operands
+    from p2p_tpu.models import nn, unet
+    from p2p_tpu.obs import launches
+
+    side, heads, dim = (8, HEADS, 32) if site == "einsum" else (32, 1, 64)
+    meta = AttnMeta(0, "mid", False, side, heads, side * side)
+    ctrl = _injecting(kind, tokenizer, meta.pixels)
+    assert int(ctrl.edit.self_start) <= 0 < int(ctrl.edit.self_end) <= 3
+    assert controller_only_injects(ctrl, meta)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    p, ln = unet._attn_init(k1, dim, dim, dim), unet.nn.norm_init(dim)
+    x = jax.random.normal(k2, (2 * B, meta.pixels, dim))
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+
+    def site_fn(x, ctrl=ctrl):
+        ctx = unet._HookCtx(None, ctrl, (), jnp.int32(step), ("off",))
+        with force_tpu_interpret_mode():
+            return unet._attention_site(p, ln, x, x, heads, ctx, meta, False)
+
+    def run(ctrl=ctrl):
+        launches.built()
+        return np.asarray(site_fn(x, ctrl)), launches._traced_sites[0]
+
+    new, noted = run()
+    new_program = str(jax.make_jaxpr(site_fn)(x))
+    monkeypatch.setattr(unet, "controller_only_injects", lambda c, m: False)
+    old, old_noted = run()
+    assert noted.how == old_noted.how == "edited" and old_noted.geometry is None
+    if site == "einsum":
+        assert noted.geometry is None
+        assert new_program == str(jax.make_jaxpr(site_fn)(x))
+        q, k, v = jax.random.normal(k1, (3, 2 * B, heads, meta.pixels, dim // heads))
+        scale = (dim // heads) ** -0.5
+        _, probs = apply_attention_control(ctrl, meta, (), nn.attention_probs(q, k, scale),
+                                           jnp.int32(step))
+        want = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        qs, ks = inject_self_operands(ctrl.edit, q, k, jnp.int32(step))
+        np.testing.assert_array_equal(np.asarray(nn.fused_attention(qs, ks, v, scale)),
+                                      np.asarray(want))
+        return
+    assert (noted.geometry, noted.operand) == ((1024, 1024, 1024), "bfloat16")
+    np.testing.assert_allclose(new, old, atol=2e-3, rtol=0)    # 3e-4 read
+    # not vacuous: the edit rows leave plain attention inside the window only
+    plain = run(None)[0]
+    np.testing.assert_array_equal(new[:B + 1], plain[:B + 1])
+    moved = np.abs(new[B + 1:] - plain[B + 1:]).max()
+    assert moved > 0.05 if step == 0 else moved == 0

@@ -158,6 +158,32 @@ def test_return_store_keeps_the_whole_store_and_the_parents_program(tiny_pipe, r
     assert launch.self_site_counts == {"edited": 4, "einsum": 3}
 
 
+def test_a_read_store_keeps_the_injected_site_on_the_parents_path(pipe, runs,
+                                                                  monkeypatch):
+    """Under ``return_store=True`` the window's self site has a slot, so its
+    reader gets the post-edit map: the site stays edited on the materialized
+    path, and images and store are those of the rule before ISSUE 37 (every
+    edited site materialized), bit for bit."""
+    from p2p_tpu.models import unet
+
+    images, store, launch = runs["caller"]
+    window = [m for m in LAYOUT.metas if not m.is_cross and m.pixels <= WINDOW]
+    assert [m.resolution for m in window] == [4]
+    for m in window:
+        assert m.store_slot is not None
+        noted = launch.self_sites[m.layer_idx]
+        assert (noted.how, noted.geometry) == ("edited", None)
+    monkeypatch.setattr(unet, "controller_only_injects", lambda c, m: False)
+    parent = dataclasses.replace(
+        pipe, config=dataclasses.replace(TINY, name="tiny-materialized"))
+    p_images, p_store, p_launch = _run(parent, *_case(parent, "caller"))
+    np.testing.assert_array_equal(images, p_images)
+    assert len(store) == len(p_store) > 0
+    for a, b in zip(store, p_store):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert p_launch.self_sites == launch.self_sites
+
+
 # -- (b) nobody reads -------------------------------------------------------------
 
 def test_store_without_a_reader_is_no_store(tiny_pipe, runs):
@@ -219,6 +245,31 @@ def test_no_probabilities_of_a_store_only_site_in_the_jaxpr(tiny_pipe, monkeypat
     assert sorted(set(free)) == [(4, 2, 256, 16), (4, 2, 256, 256),
                                  (4, 2, 1024, 16), (4, 2, 4096, 16)]
     assert sorted(set(kept)) == sorted(set(free) | {site})
+
+
+@pytest.mark.parametrize("return_store", [False, True], ids=["nobody", "caller"])
+def test_an_injected_site_reaches_the_kernel_unless_its_map_is_read(
+        tiny_pipe, monkeypatch, return_store):
+    """A 32² window at a 64² latent: the three 32² self sites are injected
+    into. With nobody reading the store they run the flash kernel on the base
+    row's q and k (ISSUE 37): edited, with the kernel's tile, and no (4, 2,
+    1024, 1024) map in the program. The caller who takes the store back keeps
+    them materialized, as before."""
+    ctrl = factory.attention_replace(
+        PROMPTS, STEPS, 0.8, 0.4, tiny_pipe.tokenizer, self_max_pixels=32 * 32,
+        max_len=TINY.text.max_length, store=True)
+    launches.built()
+    shapes = _probs_shapes(tiny_pipe, ctrl, return_store, monkeypatch)
+    window = [s for s in launches._traced_sites.values() if s.keys == 32 * 32]
+    assert len(window) == 3 and {s.how for s in window} == {"edited"}
+    site = (4, 2, 1024, 1024)
+    if return_store:
+        assert shapes.count(site) == 3
+        assert {(s.geometry, s.operand) for s in window} == {(None, "")}
+    else:
+        assert site not in shapes
+        assert {(s.geometry, s.operand) for s in window} == {
+            ((1024, 1024, 1024), "bfloat16")}
 
 
 # -- (c) LocalBlend reads -----------------------------------------------------------
