@@ -45,6 +45,10 @@ docs/OBSERVABILITY.md for the metric catalog and span taxonomy):
   ``serve --profile``. Imported explicitly (``from p2p_tpu.obs import
   prodscope``) — module import is jax-free, but capture methods pull
   jax, and its only consumer is the serve engine.
+- :mod:`.collector` — the collector watch: every collection of the cyclic
+  garbage collector on ``time.monotonic()``, started once per process with
+  the compile ledger (``utils.cache.compile_ledger``). Imported
+  explicitly; jax only inside functions.
 
 The TPU-native discipline: disabling telemetry traces *nothing* into any
 XLA program (the ``emit_step(enabled=False)`` contract, pinned by jaxpr

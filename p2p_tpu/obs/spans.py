@@ -184,6 +184,22 @@ def span(name: str, **attrs):
         ).labels(name=name).observe(dur_ms)
 
 
+def completed(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    """Record a block that has already ended, timed by the caller on
+    ``time.monotonic_ns()``'s clock, as one ``span_end`` event with no
+    parent: the innermost open span is kept as ``within``, so that the block
+    comes off no span's self time. For a caller that cannot wrap the block
+    in :func:`span`: the collector watch (``obs.collector``) runs inside a
+    collection, which can start in any allocation, so this takes no lock,
+    feeds no histogram, and mirrors nothing into the profiler."""
+    if not _enabled:
+        return
+    _recorder.emit({"event": "span_end", "span": next(_ids), "name": name,
+                    "parent": None, "within": _stack[-1] if _stack else None,
+                    "depth": len(_stack), "t_ns": t1_ns,
+                    "dur_ms": (t1_ns - t0_ns) / 1e6, **attrs})
+
+
 def write_jsonl(fp) -> int:
     """Dump the ring buffer as JSONL to an open file; returns lines written.
     A leading meta line records capacity/total/dropped so consumers know

@@ -18,8 +18,12 @@ resolved directory before ``import jax`` so parent and child agree.
 for every jaxpr trace, lowering, backend compile and persistent-cache read,
 on ``time.monotonic()`` — the clock of ``obs.spans`` and of the benchmark's
 harness. It answers what no counter did: how long set-up traces and lowers
-before each cache read, and which programs are compiled in every process
-because JAX never caches them.
+before each cache read, how a read splits into the key and the read itself,
+and which programs are compiled in every process because JAX never caches
+them. Started with it, the collector watch (``obs.collector``) times every
+collection of Python's cyclic garbage collector on the same clock.
+``CompileLedger.started_at`` is when both began: the first instant the
+program is in charge of the process.
 """
 
 from __future__ import annotations
@@ -43,7 +47,7 @@ def default_cache_dir() -> str:
 
 
 class CompileRow(NamedTuple):
-    kind: str          # trace | lower | backend | cache_hit | cache_miss
+    kind: str          # one of CompileLedger.KINDS
     seconds: float
     ended_at: float    # time.monotonic()
     name: str          # the function or module JAX names in the event
@@ -56,20 +60,34 @@ class CompileLedger:
     - ``trace``: tracing a function to a jaxpr; ``lower``: jaxpr to MLIR;
     - ``backend``: a backend compile that really compiled;
     - ``cache_hit``: a "backend compile" that was a read of the persistent
-      cache (JAX times both under one event), ``seconds`` the read;
+      cache (JAX times both under one event), ``seconds`` the whole of it:
+      the cache key (serializing and hashing the module), the read, and
+      JAX's bookkeeping around them;
+    - ``cache_read``: the read inside a ``cache_hit``, as JAX times it
+      (``/jax/compilation_cache/cache_retrieval_time_sec``: the entry read,
+      decompressed and loaded as an executable). It is written just ahead
+      of its hit, under the hit's name, and lies inside it;
     - ``cache_miss``: the cache was asked and had no entry; ``seconds`` is the
       compile that followed, which also has its ``backend`` row. A ``backend``
       row without one was never offered to the cache.
+
+    A ``backend`` row's ``name`` says which program compiled although the
+    cache is on. ``started_at`` is the ``time.monotonic()`` at which the
+    ledger was made, and :func:`compile_ledger` starts listening at once.
 
     Every row also feeds ``compiles_total`` / ``compile_ms`` of
     ``obs.device.record_compile`` under ``what`` = the row's kind."""
 
     CAPACITY = 1 << 16
+    KINDS = ("trace", "lower", "backend", "cache_hit", "cache_miss",
+             "cache_read")
     _DURATIONS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
                   "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-                  "/jax/core/compile/backend_compile_duration": "backend"}
+                  "/jax/core/compile/backend_compile_duration": "backend",
+                  "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read"}
 
     def __init__(self):
+        self.started_at = time.monotonic()
         self._rows: deque = deque(maxlen=self.CAPACITY)
         self._pending = threading.local()   # cache events of the open compile
         #: Programs built so far, compiled or read: ``obs.launches`` compares
@@ -86,21 +104,28 @@ class CompileLedger:
         kind = self._DURATIONS.get(event)
         if kind is None:
             return
-        name = str(kw.get("fun_name", ""))
+        if kind == "cache_read":    # the backend event that closes it names it
+            self._pending.read = (float(seconds), time.monotonic())
+            return
+        name, now = str(kw.get("fun_name", "")), time.monotonic()
         if kind == "backend":
-            asked = self._pending.__dict__.pop("asked", False)
-            if self._pending.__dict__.pop("hit", False):
+            pending = self._pending.__dict__
+            asked = pending.pop("asked", False)
+            read = pending.pop("read", None)
+            if pending.pop("hit", False):
                 kind = "cache_hit"
+                if read is not None:
+                    self._append("cache_read", read[0], read[1], name)
             elif asked:
-                self._append("cache_miss", seconds, name)
+                self._append("cache_miss", seconds, now, name)
             self.programs += 1
-        self._append(kind, seconds, name)
+        self._append(kind, seconds, now, name)
 
-    def _append(self, kind: str, seconds: float, name: str) -> None:
+    def _append(self, kind: str, seconds: float, ended_at: float,
+                name: str) -> None:
         from ..obs.device import record_compile
 
-        self._rows.append(CompileRow(kind, float(seconds), time.monotonic(),
-                                     name))
+        self._rows.append(CompileRow(kind, float(seconds), ended_at, name))
         record_compile(seconds * 1e3, what=kind)
 
     def rows(self, *kinds: str, since: float = float("-inf"),
@@ -116,23 +141,28 @@ _ledger: Optional[CompileLedger] = None
 
 
 def compile_ledger() -> CompileLedger:
-    """The process's ledger, listening from the first call on."""
+    """The process's ledger, listening from the first call on; the collector
+    watch (``obs.collector``) starts with it."""
     global _ledger
     if _ledger is None:
         import jax
+
+        from ..obs import collector
 
         _ledger = CompileLedger()
         jax.monitoring.register_event_listener(_ledger._on_event)
         jax.monitoring.register_event_duration_secs_listener(
             _ledger._on_duration)
+        collector.start()
     return _ledger
 
 
 def enable_persistent_cache() -> str:
     """Turn JAX's persistent compilation cache on at
-    :func:`default_cache_dir`, start the compile ledger, and return the
-    directory. With ``JAX_COMPILATION_CACHE_DIR`` set JAX has already read
-    it, and no directory is configured here. Safe to call more than once; a
+    :func:`default_cache_dir`, start the compile ledger and the collector
+    watch, and return the directory. With ``JAX_COMPILATION_CACHE_DIR`` set
+    JAX has already read it, and no directory is configured here. Safe to
+    call more than once; a
     directory that cannot be created raises (an entry point that silently
     recompiles minutes of XLA per start is not "working")."""
     compile_ledger()

@@ -1,5 +1,6 @@
-"""The program's compile ledger (``utils/cache.py``, ISSUE 27): one
-``jax.monitoring`` listener, rows on ``time.monotonic()``, nothing per call."""
+"""The program's compile ledger (``utils/cache.py``): one ``jax.monitoring``
+listener, rows on ``time.monotonic()``, nothing per call; a read of the
+persistent cache split into JAX's own time of the read and the rest."""
 
 import random
 import time
@@ -53,10 +54,11 @@ def test_fresh_jit_second_lowering_and_cached_call(every_program_is_cached):
                      and "fresh_for_the_ledger" in r.name]
     assert miss.seconds == backend.seconds
 
-    # a second, identical lowering: the backend "compile" is a cache read
+    # a second, identical lowering: the backend "compile" is a cache read,
+    # and JAX's own time of the read is written ahead of it
     jax.jit(_program(salt))(x).block_until_ready()
     second = ledger.rows(since=t1)
-    assert _kinds(second) == ["trace", "lower", "cache_hit"]
+    assert _kinds(second) == ["trace", "lower", "cache_read", "cache_hit"]
     assert ledger.programs - built0 >= 2
     assert counted.labels(what="cache_hit").value - hits0 >= 1
 
@@ -101,3 +103,60 @@ def test_chip_smoke_clock_reads_the_ledger():
     seconds1, programs1 = clock.mark()
     assert seconds1 - seconds0 == pytest.approx(61.96)
     assert programs1 - programs0 == 1 and clock.programs[programs0:] == [61.3]
+
+
+def test_a_cache_hit_holds_one_read_no_longer_than_it(every_program_is_cached):
+    """Cleared in memory, the program is read back from the persistent
+    cache: one ``cache_hit`` row with exactly one ``cache_read`` row inside
+    it, under its name; the ledger was listening before either."""
+    ledger = cache_mod.compile_ledger()
+    salt, x = random.random(), jnp.ones((8, 8))
+    t_first = time.monotonic()
+    jax.jit(_program(salt))(x).block_until_ready()
+    jax.clear_caches()
+    t0 = time.monotonic()
+    jax.jit(_program(salt))(x).block_until_ready()
+    rows = [r for r in ledger.rows(since=t0) if "fresh_for_the_ledger" in r.name]
+    hits = [r for r in rows if r.kind == "cache_hit"]
+    reads = [r for r in rows if r.kind == "cache_read"]
+    assert len(hits) == 1 and len(reads) == 1
+    hit, read = hits[0], reads[0]
+    assert 0.0 < read.seconds <= hit.seconds
+    assert hit.ended_at - hit.seconds <= read.ended_at - read.seconds
+    assert read.ended_at <= hit.ended_at
+    assert all(ledger.started_at < r.ended_at - r.seconds
+               for r in ledger.rows(since=t_first))
+
+
+def test_a_read_is_written_ahead_of_its_hit_under_its_name(monkeypatch):
+    clock = [100.0]
+    monkeypatch.setattr(time, "monotonic", lambda: clock[0])
+    ledger = cache_mod.CompileLedger()
+    assert ledger.started_at == 100.0 and "cache_read" in ledger.KINDS
+    ledger._on_event("/jax/compilation_cache/compile_requests_use_cache")
+    ledger._on_event("/jax/compilation_cache/cache_hits")
+    clock[0] = 103.0
+    ledger._on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 2.5)
+    assert ledger.rows() == []                    # named by the event that closes it
+    clock[0] = 103.25
+    ledger._on_duration("/jax/core/compile/backend_compile_duration", 3.0,
+                        fun_name="jit_f")
+    assert [tuple(r) for r in ledger.rows()] == [
+        ("cache_read", 2.5, 103.0, "jit_f"), ("cache_hit", 3.0, 103.25, "jit_f")]
+    assert ledger.programs == 1
+    # a miss: one row of the compile and one of the miss, on the same instant
+    ledger._on_event("/jax/compilation_cache/compile_requests_use_cache")
+    clock[0] = 110.0
+    ledger._on_duration("/jax/core/compile/backend_compile_duration", 1.5,
+                        fun_name="jit_g")
+    miss, backend = ledger.rows(since=103.25)
+    assert (miss.kind, backend.kind) == ("cache_miss", "backend")
+    assert miss[1:] == backend[1:] == (1.5, 110.0, "jit_g")
+    assert ledger.rows("cache_read") == ledger.rows("cache_read", before=103.0)
+
+
+def test_every_kind_the_ledger_writes_is_declared():
+    assert set(cache_mod.CompileLedger.KINDS) == {
+        "trace", "lower", "backend", "cache_hit", "cache_miss", "cache_read"}
+    assert set(cache_mod.CompileLedger._DURATIONS.values()) <= set(
+        cache_mod.CompileLedger.KINDS)

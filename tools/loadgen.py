@@ -54,8 +54,7 @@ poisson trace has different arrivals/seeds than the same invocation
 produced before the lifecycle PR. Every in-repo consumer compares
 within-run (drills, parity legs, bench A/B), but committed BENCH rounds
 recorded before the change ran a *different seeded workload* for their
-``serve``/``resilience`` blocks than post-change rounds will — treat the
-bench-trend comparison across that boundary accordingly.
+``serve``/``resilience`` blocks than post-change rounds will.
 
 Two optional schedule sections make a trace a chaos drill
 (tools/chaos_drill.py):

@@ -963,15 +963,6 @@ def main(argv=None) -> int:
                          "deployment samples 1/N of multi-second "
                          "dispatches. The bench 'serve.profile' block "
                          "records the trustworthy per-round number")
-    ap.add_argument("--bench-trend", action="store_true",
-                    help="also run the opt-in bench_trend check: diff the "
-                         "latest committed BENCH_r*.json round against its "
-                         "like-for-like predecessor on the headline keys "
-                         "(tools/benchwatch.py) and fail past "
-                         "--bench-trend-threshold")
-    ap.add_argument("--bench-trend-threshold", type=float, default=0.10,
-                    help="regression budget for --bench-trend (fraction; "
-                         "default 0.10)")
     ap.add_argument("--skip-fault-drill", action="store_true",
                     help="skip the chaos/crash-replay resilience check "
                          "(ISSUE 4; ~35s: it serves the standard trace "
@@ -1037,7 +1028,7 @@ def main(argv=None) -> int:
         unknown = only - set(cases) - {"phase_gate", "serve_parity",
                                        "obs_overhead", "fault_drill",
                                        "static_analysis", "flight_parity",
-                                       "bench_trend", "lifecycle", "soak",
+                                       "lifecycle", "soak",
                                        "mesh_parity", "slo", "cache_parity",
                                        "cost_regression", "schedule",
                                        "kernel_parity", "profile_parity",
@@ -1046,7 +1037,7 @@ def main(argv=None) -> int:
             ap.error(f"unknown config(s) {sorted(unknown)}; "
                      f"valid: {', '.join(cases)}, phase_gate, serve_parity, "
                      f"obs_overhead, fault_drill, static_analysis, "
-                     f"flight_parity, bench_trend, lifecycle, soak, "
+                     f"flight_parity, lifecycle, soak, "
                      f"mesh_parity, slo, cache_parity, cost_regression, "
                      f"schedule, kernel_parity, profile_parity, elastic, "
                      f"wal_protocol")
@@ -1147,22 +1138,6 @@ def main(argv=None) -> int:
               f"overhead +{overhead:.0f}% {'ok' if ok else 'DRIFT'}")
         if not ok:
             drifted.append("profile_parity")
-
-    if args.bench_trend or (only is not None and "bench_trend" in only):
-        # Opt-in: the committed BENCH trajectory is only diffable when the
-        # latest round has a like-for-like predecessor, and most gate runs
-        # happen mid-round — so the trend watch runs on request, not by
-        # default.
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "p2p_benchwatch", os.path.join(_REPO, "tools", "benchwatch.py"))
-        benchwatch = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(benchwatch)
-        report = benchwatch.watch(_REPO, args.bench_trend_threshold)
-        print(benchwatch.render(report))
-        if report["regressions"]:
-            drifted.append("bench_trend")
 
     if not args.skip_mesh and (only is None or "mesh_parity" in only):
         try:
