@@ -398,6 +398,56 @@ def edit_block(pixels: int, key_len: int, head_dim: int, itemsize: int) -> int:
     return 0
 
 
+# The GEGLU kernel (``kernels.geglu``) asks the compiler for more scoped VMEM
+# than the 16 MiB a kernel gets by default (the v5e has 128 MiB of it): a
+# row tile's f32 residual and output stay resident across the walk over the
+# inner width. ``ff_block`` never answers a tile it counts over the budget.
+_FF_VMEM_LIMIT = 64 * 2**20
+_FF_VMEM_BUDGET = 48 * 2**20
+
+# (pixels, channels, inner) of one image's block → (row tile, inner chunk)
+# from a sweep on the v5e, where the kernel ran the block faster than XLA's
+# two fusions and its cell ran faster end to end (PERF.md §6): `sdxl`'s two
+# levels. Any other block keeps the formula, `sd14`'s and `sd21`'s among
+# them: the sweep has the kernel faster there too, but at `sdxl` the levels
+# it runs at gave a third of the gain back in the operations around it, so
+# a block's own time does not settle a cell. Keyed per image, so the batch
+# (prompts, CFG, a serve pool's slots) does not change the decision.
+_FF_BY_SHAPE = {
+    (1024, 1280, 5120): (256, 1280),
+    (4096, 640, 2560): (1024, 512),
+}
+
+
+def _ff_vmem_bytes(tile, channels: int, itemsize: int) -> int:
+    """The GEGLU kernel's scoped-VMEM footprint per grid step, from above:
+    the normed rows, the value, gate and ``ff_out`` weight tiles and the
+    bias rows double-buffered (channels lane-padded to 128, rows of one
+    sublane-padded to 8), the f32 residual and output tiles double-buffered,
+    and the chunk's f32 value, gate, GELU and product tiles with the product
+    narrowed and the chunk's f32 ``ff_out`` partial."""
+    rows, chunk = tile
+    lanes = -(-channels // 128) * 128
+    blocks = 2 * (rows * lanes * itemsize + 3 * lanes * chunk * itemsize
+                  + 2 * 8 * chunk * 4 + 8 * lanes * 4 + 2 * rows * lanes * 4)
+    chunk_tiles = rows * chunk * (4 * 4 + itemsize) + rows * lanes * 4
+    return blocks + chunk_tiles
+
+
+def ff_block(pixels: int, channels: int, inner: int, itemsize: int):
+    """The GEGLU kernel's ``(row tile, inner chunk)`` for a transformer
+    block's feed-forward over ``pixels`` tokens an image, ``channels`` wide
+    with an inner width ``inner``, its operands ``itemsize`` bytes wide;
+    None where the block keeps XLA's formula: a shape not in the table, or a
+    tile that does not divide the inner width or fit the VMEM budget. The
+    caller checks that the row tile divides its rows (batch × pixels)."""
+    tile = _FF_BY_SHAPE.get((pixels, channels, inner))
+    if (tile is None or inner % tile[1]
+            or _ff_vmem_bytes(tile, channels, itemsize) > _FF_VMEM_BUDGET):
+        return None
+    return tile
+
+
 def _flash_block_sizes(geometry):
     """The one BlockSizes every flash call site uses for a ``flash_block``
     geometry — forward and residuals variants must stay on the same tiling.

@@ -42,6 +42,7 @@ from ..controllers.base import (
     controller_touches,
 )
 from ..controllers.edit import inject_self_operands
+from ..kernels import geglu
 from ..obs import launches
 from .conditioning import Conditioning, context_of
 from .config import UNetConfig, unet_layout
@@ -502,10 +503,16 @@ def _apply_transformer_block(p: Params, x: jax.Array, context: jax.Array,
     x = _apply_attention(p["attn2"], p["ln2"], x, context, heads, ctx,
                          is_cross=True)
     with jax.named_scope("ff"):
-        h = nn.linear(p["ff_in"], nn.layer_norm(p["ln3"], x))
-        val, gate = jnp.split(h, 2, axis=-1)
-        x = x + nn.linear(p["ff_out"], val * nn.gelu(gate))
-    return x
+        normed = nn.layer_norm(p["ln3"], x)
+        # GEGLU in one kernel where the shape, the platform and the mesh
+        # allow it, else XLA's two products with the f32 (tokens, 2·inner)
+        # tensor between them.
+        how, tile = geglu.plan(x, p["ff_in"], p["ff_out"])
+        launches.note_ff_site(ctx.cursor, how, x.size // x.shape[-1],
+                              x.shape[-1], p["ff_out"]["kernel"].shape[0], tile)
+        if tile is None:
+            return geglu.feed_forward_formula(x, normed, p["ff_in"], p["ff_out"])
+        return geglu.geglu_feed_forward(x, normed, p["ff_in"], p["ff_out"], tile)
 
 
 def _apply_spatial_transformer(p: Params, x: jax.Array, context: jax.Array,

@@ -17,7 +17,9 @@ reader gets from one to the other for a program that really ran:
   self-attention site of the U-Net ran, as the model noted while the
   program was traced (``note_self_site``; ``Launch.self_sites``: per site
   its keys, head width, implementation and the flash kernel's geometry and
-  operand dtype),
+  operand dtype), how each transformer block's feed-forward ran
+  (``note_ff_site``; ``Launch.ff_sites``: per block its tokens, widths,
+  implementation and the GEGLU kernel's tile),
   the bytes of attention maps the controller's store holds
   (``note_store_bytes``; ``Launch.store_bytes``, and the gauge
   ``launch_store_bytes{module}`` of ``obs.metrics``), the U-Net's transformer
@@ -73,6 +75,22 @@ class SelfSite:
         return f"{self.keys}x{self.head_dim} {self.how}{tile}{width}"
 
 
+@dataclasses.dataclass(frozen=True)
+class FfSite:
+    """How one transformer block's GEGLU feed-forward of a traced program
+    runs."""
+
+    rows: int                   # tokens: batch × pixels
+    channels: int
+    inner: int
+    how: str                    # "kernel" | "formula" | "sharded"
+    tile: Optional[Tuple[int, int]] = None      # the kernel's, where it ran
+
+    def __str__(self):
+        tile = "" if self.tile is None else " " + "x".join(map(str, self.tile))
+        return f"{self.rows}x{self.channels}x{self.inner} {self.how}{tile}"
+
+
 @dataclasses.dataclass
 class Launch:
     """The first launch of one distinct program, kept abstract."""
@@ -87,6 +105,10 @@ class Launch:
     # Self-attention sites by layer index: "kernel" / "einsum" (untouched,
     # ``nn.fused_attention``'s two implementations), "edited", "sharded".
     self_sites: Dict[int, SelfSite] = dataclasses.field(default_factory=dict)
+    # Feed-forwards by block (keyed by the attention sites called through
+    # the block's end, which no other block of the program shares):
+    # "kernel" (``kernels.geglu``), "formula" (XLA), "sharded".
+    ff_sites: Dict[int, FfSite] = dataclasses.field(default_factory=dict)
     # Bytes of the controller's attention store in this program's carry (per
     # group, where the program runs groups), 0 where it keeps none.
     store_bytes: int = 0
@@ -128,6 +150,14 @@ class Launch:
                 + "".join(f"; {n} of {shape}" for shape, n in shapes.items())
                 + f"; controller store {self.store_bytes} bytes")
 
+    def describe_ff(self) -> str:
+        """One line for a log: feed-forwards by how, then each distinct
+        shape."""
+        counts = dict(collections.Counter(s.how for s in self.ff_sites.values()))
+        shapes = collections.Counter(str(s) for s in self.ff_sites.values())
+        return f"ff {counts}" + "".join(f"; {n} of {shape}"
+                                        for shape, n in shapes.items())
+
     def describe_model(self) -> str:
         """One line for a log: depth by level, decode chunks, weights."""
         parts = self.weights_bytes
@@ -150,6 +180,7 @@ class Launch:
 
 _launches: Dict[str, List[Launch]] = {}     # module -> distinct programs
 _traced_sites: Dict[int, SelfSite] = {}     # by site, since the last mark
+_traced_ff: Dict[int, FfSite] = {}          # by block, likewise
 _traced_store = 0                           # store bytes, since the last mark
 _traced_model = {}                          # unet_depth, decode_chunks, likewise
 
@@ -160,6 +191,7 @@ def built() -> int:
     jitted function. The sites noted from here on are that launch's."""
     global _traced_store
     _traced_sites.clear()
+    _traced_ff.clear()
     _traced_model.clear()
     _traced_store = 0
     return compile_ledger().programs
@@ -173,6 +205,17 @@ def note_self_site(site: int, how: str, keys: int, head_dim: int,
     as ``operand`` (a dtype's name). Keyed by site, so a body traced twice
     counts once."""
     _traced_sites[site] = SelfSite(keys, head_dim, how, geometry, operand)
+
+
+def note_ff_site(block: int, how: str, rows: int, channels: int, inner: int,
+                 tile=None) -> None:
+    """Trace time, from the model: the feed-forward of transformer block
+    ``block`` (the attention sites called through its end) over
+    ``rows`` tokens ``channels`` wide, inner width ``inner``, runs ``how``,
+    the GEGLU kernel tiled by ``tile``. Keyed by block, so a body traced
+    twice counts once."""
+    _traced_ff[block] = FfSite(rows, channels, inner, how,
+                               None if tile is None else tuple(tile))
 
 
 def note_store_bytes(n: int) -> None:
@@ -241,7 +284,8 @@ def _keep(fn, args, kwargs) -> None:
     if abstract is None:
         return
     launch = Launch("jit_" + fn.__name__, fn, *abstract,
-                    self_sites=dict(_traced_sites), store_bytes=_traced_store,
+                    self_sites=dict(_traced_sites), ff_sites=dict(_traced_ff),
+                    store_bytes=_traced_store,
                     **_traced_model)
     known = _launches.setdefault(launch.module, [])
     # Another thread's compile can make a warm call look like a first launch.
@@ -298,7 +342,7 @@ def _build(launch: Launch) -> None:
     # Whoever asked prints the scope tree (a traced run); this goes with it.
     print(f"launch {launch.module}: {len(index)} instructions from "
           f"{launch.built_from}; {launch.describe_sites()}; "
-          f"{launch.describe_model()}", file=sys.stderr)
+          f"{launch.describe_ff()}; {launch.describe_model()}", file=sys.stderr)
 
 
 def _compile_and_parse(launch: Launch, compiler_options=None):

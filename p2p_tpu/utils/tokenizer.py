@@ -202,6 +202,20 @@ def _strip_controls_pad_cjk(text: str) -> str:
     return "".join(out)
 
 
+def _split_on_specials(text: str, specials: Sequence[str]) -> List[str]:
+    """Split ``text`` around exact (case-sensitive) occurrences of the special
+    tokens, keeping them as their own pieces and dropping empty pieces.
+
+    HF's slow tokenizers do this on the raw text before any normalization
+    (``tokens_trie.split``), so a special token typed into a prompt maps to
+    its id and the text on either side of it is tokenized on its own."""
+    if not specials:
+        return [text] if text else []
+    pat = "(" + "|".join(_re_mod.escape(s) for s in
+                         sorted(specials, key=len, reverse=True)) + ")"
+    return [p for p in _re_mod.split(pat, text) if p]
+
+
 def _is_cjk(cp: int) -> bool:
     """CJK ideograph ranges (the set HF's BasicTokenizer space-pads)."""
     return (
@@ -251,7 +265,11 @@ class ClipBpeTokenizer:
         self.bpe_ranks = dict(zip(merges, range(len(merges))))
         self.byte_encoder = _bytes_to_unicode()
         self.byte_decoder = {v: k for k, v in self.byte_encoder.items()}
-        self.cache: Dict[str, str] = {}
+        self.specials = [s for s in ("<|startoftext|>", "<|endoftext|>")
+                         if s in self.encoder]
+        # A special spelled in other case survives to the word pattern after
+        # lower-casing; HF's BPE cache is seeded so it stays one token.
+        self.cache: Dict[str, str] = {s: s for s in self.specials}
         self.model_max_length = model_max_length
         self.bos_token_id = self.encoder.get("<|startoftext|>", 49406)
         self.eos_token_id = self.encoder.get("<|endoftext|>", 49407)
@@ -313,9 +331,13 @@ class ClipBpeTokenizer:
         # full CLIP vocab (all 256 byte symbols present) this never triggers.
         unk = self.eos_token_id
         ids = [self.bos_token_id]
-        for token in self._basic_clean(text):
-            token = "".join(self.byte_encoder[b] for b in token.encode("utf-8"))
-            ids.extend(self.encoder.get(t, unk) for t in self._bpe(token).split(" "))
+        for piece in _split_on_specials(text, self.specials):
+            if piece in self.specials:
+                ids.append(self.encoder[piece])
+                continue
+            for token in self._basic_clean(piece):
+                token = "".join(self.byte_encoder[b] for b in token.encode("utf-8"))
+                ids.extend(self.encoder.get(t, unk) for t in self._bpe(token).split(" "))
         ids.append(self.eos_token_id)
         return ids
 
@@ -366,6 +388,8 @@ class BertWordPieceTokenizer:
         self.eos_token_id = self.vocab["[SEP]"]
         self.pad_token_id = self.vocab["[PAD]"]
         self.unk_token_id = self.vocab["[UNK]"]
+        self.specials = [s for s in ("[PAD]", "[UNK]", "[CLS]", "[SEP]", "[MASK]")
+                         if s in self.vocab]
         self.max_chars_per_word = 100
 
     @classmethod
@@ -425,8 +449,12 @@ class BertWordPieceTokenizer:
 
     def encode(self, text: str) -> List[int]:
         ids = [self.bos_token_id]
-        for word in self._basic_tokenize(text):
-            ids.extend(self._wordpiece(word))
+        for piece in _split_on_specials(text, self.specials):
+            if piece in self.specials:
+                ids.append(self.vocab[piece])
+                continue
+            for word in self._basic_tokenize(piece):
+                ids.extend(self._wordpiece(word))
         ids.append(self.eos_token_id)
         return ids
 

@@ -39,6 +39,12 @@ budget. These compiles can, at about a second each and no chip time:
     two decode chunks, and arguments, temporaries and code within 15 GiB by
     XLA's own count (about three minutes).
 
+(h) the GEGLU feed-forward kernel (``kernels.geglu``) alone at `sdxl`'s two
+    block shapes, the ones ``nn.ff_block`` gives a tile, and at its 32²
+    block inside a ``dp``-partitioned program; in (g) every transformer
+    block is one such call and the program holds no f32 ``(tokens,
+    2·inner)`` product of ``ff_in``; in (e) every block keeps the formula.
+
 (f) two ResNet blocks of `sd14`'s first level (``unet._apply_resnet``, f32,
     320 wide at 64x64): GroupNorm's statistics leave the activation
     channels-minor, so no copy of it to a W-minor layout is compiled (PR 32).
@@ -355,6 +361,70 @@ def test_fused_edit_kernel_compiles_under_a_dp_mesh(topo, dp_mesh,
     _compile(program, ctrl_g, q, k, v, step)
 
 
+#: (rows, channels, inner, kernel dtype): `sdxl`'s two transformer block
+#: shapes, the ones the tile table gives the kernel (kernels stored in
+#: bfloat16), each compiled alone.
+FF_KERNEL_BLOCKS = [(16384, 640, 2560, jnp.bfloat16),
+                    (4096, 1280, 5120, jnp.bfloat16)]
+
+
+def _ff_id(block):
+    rows, channels, inner, dtype = block
+    return f"{rows}x{channels}x{inner}-{jnp.dtype(dtype).name}"
+
+
+def _ff_args(sharding, rows, channels, inner, kernel_dtype):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    x = sds((CFG_BATCH, rows // CFG_BATCH, channels), jnp.float32)
+    p_in = {"kernel": sds((channels, 2 * inner), kernel_dtype),
+            "bias": sds((2 * inner,), jnp.float32)}
+    p_out = {"kernel": sds((inner, channels), kernel_dtype),
+             "bias": sds((channels,), jnp.float32)}
+    return x, p_in, p_out
+
+
+@pytest.mark.parametrize("block", FF_KERNEL_BLOCKS, ids=_ff_id)
+def test_geglu_kernel_compiles(one_chip, block):
+    from p2p_tpu.kernels import geglu
+
+    tile = nn.ff_block(block[0] // CFG_BATCH, *block[1:3], 2)
+    x, p_in, p_out = _ff_args(one_chip, *block)
+    compiled = _compile(lambda x, n, a, b: geglu.geglu_feed_forward(
+        x, n, a, b, tile), x, x, p_in, p_out)
+    text = compiled.as_text()
+    kernel, = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # the normed rows reach the kernel in bfloat16, the output leaves in f32,
+    # and no (rows, 2·inner) or (rows, inner) tensor exists outside it
+    assert "bf16[%d,%d]" % (block[0], block[1]) in kernel
+    assert "f32[%d,%d]" % (block[0], 2 * block[2]) not in text
+    assert "[%d,%d]" % (block[0], block[2]) not in text
+
+
+def test_geglu_kernel_compiles_under_a_dp_mesh(topo, dp_mesh, monkeypatch):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from p2p_tpu.kernels import geglu
+
+    monkeypatch.setattr(nn, "_on_tpu", lambda: True)
+    block = (4096, 1280, 5120, jnp.bfloat16)       # `sdxl`'s 32² block
+    tile = nn.ff_block(block[0] // CFG_BATCH, *block[1:3], 2)
+    x, p_in, p_out = _ff_args(NamedSharding(dp_mesh, P()), *block)
+    xg, = _dp_groups(dp_mesh, x)
+
+    def program(xg, p_in, p_out):
+        def one_group(x):
+            how, _ = geglu.plan(x, p_in, p_out)
+            assert how == "kernel"
+            return geglu.geglu_feed_forward(x, x, p_in, p_out, tile)
+
+        return _groups(one_group, dp_mesh)(xg)
+
+    assert "all-gather" not in _compile(program, xg, p_in, p_out).as_text()
+
+
 def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
                                                                    monkeypatch):
     """`sd21.edit-replace` sends ``store=True`` and takes no store back, so
@@ -369,6 +439,7 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
     from p2p_tpu.engine.sampler import _text2image_jit
     from p2p_tpu.models import init_unet
     from p2p_tpu.models import vae as vae_mod
+    from p2p_tpu.obs import launches
     from p2p_tpu.ops import schedulers as sched_mod
 
     # the program asks the backend, which is the CPU's here, not the device
@@ -389,6 +460,7 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
     vae = jax.eval_shape(lambda: vae_mod.init_vae(key, cfg.vae))
     sched = sched_mod.schedule_from_config(STEPS, cfg.scheduler, kind="ddim")
     side = cfg.latent_size
+    launches.built()                           # the blocks noted from here on
     ctx = jnp.zeros((2, cfg.unet.context_len, cfg.unet.context_dim))
     args = _shapes((unet, vae, sched, ctx, ctx,
                     jnp.zeros((2, side, side, cfg.unet.in_channels)), ctrl,
@@ -406,9 +478,26 @@ def test_sd21_cell_program_runs_its_store_only_sites_on_the_kernel(one_chip,
                 for shape in ("4,10,2304,64", "4,5,9216,64", "2,1,9216,512")}
     # ten self sites in the loop, and the VAE's mid attention outside it
     assert by_shape == {"4,10,2304,64": 5, "4,5,9216,64": 5, "2,1,9216,512": 1}
+    # the tile table keeps every transformer block's feed-forward on XLA's
+    # formula here
+    assert _ff_counts() == {(36864, 320, 1280, "formula"): 5,
+                            (9216, 640, 2560, "formula"): 5,
+                            (2304, 1280, 5120, "formula"): 5,
+                            (576, 1280, 5120, "formula"): 1}
     assert len(kernels) == 11
     assert "f32[4,10,2304,2304]" not in text
     assert "f32[2,10,2304,2304]" not in text
+
+
+def _ff_counts():
+    """The traced program's feed-forwards: ``{(rows, channels, inner, how):
+    blocks}`` (``launches.note_ff_site``)."""
+    import collections
+
+    from p2p_tpu.obs import launches
+
+    return dict(collections.Counter((s.rows, s.channels, s.inner, s.how)
+                                    for s in launches._traced_ff.values()))
 
 
 def test_sdxl_cell_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
@@ -469,8 +558,14 @@ def test_sdxl_cell_program_compiles_and_fits_one_chip(one_chip, monkeypatch):
                            if re.search(r"= bf16\[%s\]" % shape, line))
                 for shape in ("4,20,1024,64", "4,10,4096,64", "1,1,16384,512")}
     assert by_shape == {"4,20,1024,64": 60, "4,10,4096,64": 10, "1,1,16384,512": 2}
-    assert len(kernels) == 72
+    # every transformer block's feed-forward is one GEGLU kernel call, and the
+    # f32 (tokens, 2·inner) product between XLA's two fusions is gone
+    assert _ff_counts() == {(4096, 1280, 5120, "kernel"): 60,
+                            (16384, 640, 2560, "kernel"): 10}
+    assert len([line for line in kernels if "geglu_feed_forward" in line]) == 70
+    assert len(kernels) == 142
     assert "f32[4,20,1024,1024]" not in text and "f32[4,10,4096,4096]" not in text
+    assert "f32[4,1024,10240]" not in text and "f32[4,4096,5120]" not in text
     assert len(re.findall(r" while\(", text)) == 1
     stats = compiled.memory_analysis()
     gib = 2.0 ** 30

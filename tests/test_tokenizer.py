@@ -109,6 +109,26 @@ def test_encode_matches_hf(tok_pair, text):
     assert got == want
 
 
+SPECIAL_PROMPTS = [
+    "<|endoftext|>",
+    "<|startoftext|>a cat<|endoftext|>",
+    "a<|endoftext|>b",
+    "!<|endoftext|>?",
+    "<|ENDOFTEXT|> cat",            # lower-cased to the special, as in HF
+    "<|endof\x00text|>",            # control char dropped after the split
+    "<|endoftext",
+]
+
+
+@pytest.mark.parametrize("text", SPECIAL_PROMPTS,
+                         ids=range(len(SPECIAL_PROMPTS)))
+def test_special_tokens_in_text_match_hf(tok_pair, text):
+    """A special token typed into a prompt maps to its id, as HF's added-
+    token split does, and the text around it is tokenized on its own."""
+    hf, ours = tok_pair
+    assert ours.encode(text) == hf(text)["input_ids"]
+
+
 def test_unpadded_encode_matches_hf(tok_pair):
     hf, ours = tok_pair
     for text in PROMPTS[:6]:
@@ -189,6 +209,23 @@ def test_bert_encode_matches_hf(bert_pair, text):
     want = hf(text, padding="max_length", max_length=77,
               truncation=True)["input_ids"]
     assert got == want
+
+
+BERT_SPECIAL_PROMPTS = [
+    "[UNK]",
+    "a[UNK]b",
+    "[CLS] cat [SEP] [PAD] [MASK]",
+    "[[MASK]]",
+    "[unk] [Unk]",                # only the exact spelling is special
+    "[UN\x00K]",                  # lower-cased before the control char goes
+]
+
+
+@pytest.mark.parametrize("text", BERT_SPECIAL_PROMPTS,
+                         ids=range(len(BERT_SPECIAL_PROMPTS)))
+def test_bert_special_tokens_in_text_match_hf(bert_pair, text):
+    hf, ours = bert_pair
+    assert ours.encode(text) == hf(text)["input_ids"]
 
 
 def test_bert_specials(bert_pair):
