@@ -7,7 +7,8 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.lib import check as check_mod
-from benchmarks.lib.window import closed_loop, controller, new_state, warm_up
+from benchmarks.lib.window import (closed_loop, controller, new_state,
+                                   trace_one_more, warm_up)
 
 
 def _call(run, state, i: int) -> dict:
@@ -39,6 +40,10 @@ def prepare(run):
 
 def window(run, state) -> None:
     closed_loop(run, lambda i: _call(run, state, i))
+
+
+def trace_again(run, state) -> None:
+    trace_one_more(run, lambda i: _call(run, state, i))
 
 
 def work(run, records) -> dict:

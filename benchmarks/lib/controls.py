@@ -7,7 +7,9 @@ the chip at the cells' own sizes, and the tests under ``tests/benchmark`` see
 The control is the program's own lower-precision path: bfloat16 arrays, the
 nearest precision below the float32 the configurations state, through the
 ``dtype`` argument of ``text2image`` and through the dtype of the arrays
-handed to ``sweep``.
+handed to ``sweep``: the floating leaves of whatever the conditioning is (an
+array, or a tree of context, pooled text and a caption's integer key mask,
+which keeps its type).
 """
 
 from __future__ import annotations
@@ -40,6 +42,15 @@ def _installed(text2image=None, sweep=None, phase1=None, phase2=None):
             setattr(m, n, original)
 
 
+def narrowed(tree):
+    """``tree`` with its floating leaves in bfloat16, the others as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(lambda x: x.astype(jnp.bfloat16)
+                        if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
+
+
 def bfloat16():
     """The control: every entry the drivers call runs in bfloat16."""
     import jax.numpy as jnp
@@ -49,18 +60,16 @@ def bfloat16():
 
     def sweep(orig):
         def run(pipe, context, latents, *a, **k):
-            images, final = orig(pipe, context.astype(jnp.bfloat16),
-                                 latents.astype(jnp.bfloat16), *a, **k)
+            images, final = orig(pipe, narrowed(context), narrowed(latents), *a, **k)
             return images, final.astype(jnp.float32)
         return run
 
     def phase1(orig):
         return lambda pipe, context, latents, *a, **k: orig(
-            pipe, context.astype(jnp.bfloat16), latents.astype(jnp.bfloat16), *a, **k)
+            pipe, narrowed(context), narrowed(latents), *a, **k)
 
     def phase2(orig):
-        return lambda pipe, context, *a, **k: orig(
-            pipe, context.astype(jnp.bfloat16), *a, **k)
+        return lambda pipe, context, *a, **k: orig(pipe, narrowed(context), *a, **k)
 
     return _installed(t2i, sweep, phase1, phase2)
 

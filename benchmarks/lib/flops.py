@@ -11,6 +11,14 @@ level (0: that level has no transformer; the mid block takes the last
 level's); ``text_encoder`` is one tower or a list of towers, summed;
 ``unet.addition_embed_in`` is the width of a vector embedded by two linear
 layers and added to the time embedding.
+
+A file has either a ``unet`` block or a ``transformer`` block: a
+transformer denoiser over patch tokens (diffusers' ``Transformer2DModel`` /
+``PixArtTransformer2DModel`` names, ``lib/pipeline.py`` states the contract),
+counted by ``transformer_forward_flops``. A text tower whose dict has
+``intermediate_size`` has a feed-forward that wide (in place of
+``ff_mult`` times the hidden size), gated where ``hidden_act`` begins with
+``gated-`` (T5's ``feed_forward_proj``): three matrices, not two.
 """
 
 from __future__ import annotations
@@ -95,11 +103,69 @@ def unet_sites(uc: dict):
              uc["block_out_channels"][lvl]) for place, lvl in out]
 
 
-def self_site_names(uc: dict):
+def self_site_names(block: dict):
     """The self sites' scope names in call order, as the program builds them:
     the place and the site's index among all attention sites (a block's self
-    site, then its cross site)."""
-    return [f"{place}{2 * i}" for i, (place, *_) in enumerate(unet_sites(uc))]
+    site, then its cross site). ``block`` is the file's ``unet`` or
+    ``transformer`` block; a transformer's place is ``block``: its block
+    ``i`` has the self site ``block<2i>`` and the cross site ``block<2i+1>``."""
+    if is_transformer(block):
+        return [f"block{2 * i}" for i in range(block["num_layers"])]
+    return [f"{place}{2 * i}" for i, (place, *_) in enumerate(unet_sites(block))]
+
+
+def is_transformer(block: dict) -> bool:
+    """Whether a denoiser block is a transformer's (it has patches)."""
+    return "patch_size" in block
+
+
+def denoiser(config: dict) -> dict:
+    """The file's denoiser block: ``transformer`` where it has one, else
+    ``unet``."""
+    return config["transformer"] if "transformer" in config else config["unet"]
+
+
+def transformer_tokens(tc: dict) -> int:
+    """Patch tokens of one row: the latent's side over the patch, squared."""
+    return (tc["sample_size"] // tc["patch_size"]) ** 2
+
+
+def transformer_block_flops(tc: dict, cross: bool = True) -> int:
+    """One transformer block for one row: self-attention over the patch
+    tokens, cross-attention over all ``context_len`` caption tokens (masked
+    keys are counted: the products run over all of them), the feed-forward
+    ``ff_mult`` wide (two matrices; a gated activation, ``geglu``, three).
+    The block's adaLN modulation adds a table to the time vector: no
+    product."""
+    p, c = transformer_tokens(tc), tc["num_attention_heads"] * tc["attention_head_dim"]
+    f = self_attention_flops(p, c)
+    if cross:
+        f += cross_attention_flops(p, c, tc["context_len"], tc["cross_attention_dim"])
+    matrices = 3 if tc["activation_fn"] == "geglu" else 2
+    return f + matrices * 2 * p * c * c * tc["ff_mult"]
+
+
+def transformer_forward_flops(tc: dict, cross: bool = True) -> int:
+    """One forward of a transformer denoiser for one row: the patch
+    embedding (a ``patch_size`` convolution of that stride), the caption
+    projection (two linear layers over the ``context_len`` tokens,
+    ``caption_channels`` in), the timestep MLP (256 sinusoids, two linear
+    layers) and adaLN-single's ``t_block`` (one linear layer to six times
+    the width), with ``use_additional_conditions`` the resolution (two rows)
+    and aspect-ratio (one row) embeddings a third of the width wide, then
+    ``num_layers`` blocks and the final projection to the patches'
+    ``out_channels``. ``cross=False`` leaves out the cross-attention and the
+    caption projection that only it reads (served from a cache)."""
+    p, c = transformer_tokens(tc), tc["num_attention_heads"] * tc["attention_head_dim"]
+    patch = tc["patch_size"] ** 2
+    f = 2 * p * tc["in_channels"] * patch * c                   # patch embedding
+    f += 2 * 256 * c + 2 * c * c + 2 * c * 6 * c                # time MLP, t_block
+    if tc["use_additional_conditions"]:
+        f += 3 * (2 * 256 * (c // 3) + 2 * (c // 3) ** 2)
+    if cross:
+        f += 2 * tc["context_len"] * (tc["caption_channels"] * c + c * tc["cross_attention_dim"])
+    f += tc["num_layers"] * transformer_block_flops(tc, cross)
+    return f + 2 * p * c * patch * tc["out_channels"]          # final layer
 
 
 def unet_forward_flops(uc: dict, cross: bool = True) -> int:
@@ -145,8 +211,12 @@ def text_encoder_flops(tc) -> int:
         return sum(text_encoder_flops(t) for t in tc)
     n, d, inner = (tc["max_position_embeddings"], tc["hidden_size"],
                    tc["attention_inner_dim"])
-    per_layer = (4 * 2 * n * d * inner + 2 * 2 * n * n * inner
-                 + 2 * 2 * n * d * d * tc["ff_mult"])
+    if "intermediate_size" in tc:
+        gated = tc["hidden_act"].startswith("gated-")
+        ff = (3 if gated else 2) * 2 * n * d * tc["intermediate_size"]
+    else:
+        ff = 2 * 2 * n * d * d * tc["ff_mult"]
+    per_layer = 4 * 2 * n * d * inner + 2 * 2 * n * n * inner + ff
     return tc["num_hidden_layers"] * per_layer
 
 
@@ -173,11 +243,13 @@ def decode_flops(vc: dict, latent_side: int) -> int:
 
 def work_flops(config: dict, unet_rows_full: int, unet_rows_cached: int,
                prompts: int, images: int) -> int:
-    """All the work of a window: U-Net forwards by rows of their batch (with
-    cross-attention, and past a gate without), prompts encoded, images
-    decoded."""
-    uc = config["unet"]
-    return (unet_rows_full * unet_forward_flops(uc)
-            + unet_rows_cached * unet_forward_flops(uc, cross=False)
+    """All the work of a window: denoiser forwards by rows of their batch
+    (with cross-attention, and past a gate without), prompts encoded, images
+    decoded. The ``unet_rows_*`` count rows of the denoiser, whichever block
+    the file has; the decode takes the latent's side from that block."""
+    block = denoiser(config)
+    forward = transformer_forward_flops if is_transformer(block) else unet_forward_flops
+    return (unet_rows_full * forward(block)
+            + unet_rows_cached * forward(block, cross=False)
             + prompts * text_encoder_flops(config["text_encoder"])
-            + images * decode_flops(config["vae"], uc["sample_size"]))
+            + images * decode_flops(config["vae"], block["sample_size"]))

@@ -53,6 +53,7 @@ class Run:
     clock: CompileClock
     spans: Spans = field(default_factory=Spans)
     records: list = field(default_factory=list)   # one dict per request
+    retakes: list = field(default_factory=list)   # traced calls after the window
     t_setup_done: float = 0.0
     window: tuple = (0.0, 0.0)                    # host clock
     traced: tuple = None                          # host clock, inside window
@@ -61,6 +62,7 @@ class Run:
     device: dict = field(default_factory=dict)
     driver: object = None                         # the traffic mix's driver module
     trace_dir: str = ""
+    trace_incomplete: str = None                  # why the trace is not read
 
     @property
     def done(self):
@@ -71,11 +73,13 @@ class Run:
         return self.driver.work(self, records)
 
     def traced_records(self):
-        """Requests that began and ended inside the traced window."""
+        """Requests that began and ended inside the traced window: calls of
+        the window, or the one traced again after it (``retakes``)."""
         if self.traced is None:
             return []
         lo, hi = self.traced
-        return [r for r in self.done if r["t_start"] >= lo and r["t_end"] <= hi]
+        return [r for r in self.done + self.retakes
+                if not r.get("failed") and r["t_start"] >= lo and r["t_end"] <= hi]
 
     # -- the profiler, driven by the driver's loop ---------------------------
 
@@ -114,6 +118,50 @@ def _watch_gc():
     return watch
 
 
+def read_trace(run) -> None:
+    """The profiler's trace of the traced calls, loaded and judged
+    (``judge_trace``)."""
+    from . import trace as trace_mod
+
+    run.trace_data = trace_mod.load(trace_mod.newest_xplane(run.trace_dir))
+    run.trace_window = trace_mod.window_of(run.trace_data)
+    lo, hi = run.trace_window
+    run.device["busy_s"] = trace_mod.busy_s(run.trace_data, lo, hi)
+    run.device["window_s"] = (hi - lo) / 1e9
+    judge_trace(run)
+
+
+def judge_trace(run) -> None:
+    """The loop of ``run.trace_data`` marked from the launched programs'
+    text (``lib/launched.py``), and the trace judged whole or not:
+    ``run.trace_incomplete`` says why not, or is None."""
+    from . import launched
+    from . import trace as trace_mod
+
+    loops = launched.program_loops(run)
+    trace_mod.mark_loops(run.trace_data, loops)
+    recs = run.traced_records()
+    steps = run.work_of(recs)["steps"] if recs else 0
+    lo, hi = run.trace_window
+    run.trace_incomplete = trace_mod.incomplete(run.trace_data, lo, hi, loops, steps)
+
+
+def read_traced_calls(run, state) -> None:
+    """``read_trace``, and where the trace is not whole one more call traced
+    by the driver (``trace_again``) and read in its place: a profiler that
+    lost events leaves the loop short, and the metrics of such a trace would
+    read low, 0 or nothing. A trace still not whole is printed as such."""
+    read_trace(run)
+    if run.trace_incomplete and hasattr(run.driver, "trace_again"):
+        print(f"trace not read ({run.trace_incomplete}): one more call traced",
+              file=sys.stderr)
+        run.driver.trace_again(run, state)
+        read_trace(run)
+    if run.trace_incomplete:
+        print(f"trace incomplete: {run.trace_incomplete}; its device_trace "
+              f"metrics are left out", file=sys.stderr)
+
+
 def device_info() -> dict:
     import jax
 
@@ -143,6 +191,20 @@ def find_cell(manifest: dict, workload: str, root: str = ROOT):
     traffic = load_json(os.path.join(root, manifest["paths"][0], "traffic",
                                      cell["name"] + ".json"))
     return cell, config, traffic
+
+
+def read_metrics(run, entries) -> dict:
+    """``{name: {"value", "unit"}}`` of the entries whose reader finds
+    something; none of those read from the device trace where the trace is
+    incomplete."""
+    metrics = {}
+    for m in entries:
+        if run.trace_incomplete and m["source"] == "device_trace":
+            continue
+        value = load_module("metrics", m["name"]).read(run)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
 
 
 def metrics_of(manifest: dict, cell: dict, group: str):
@@ -200,22 +262,12 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
     run.device = dict(info, memory_peak_bytes=memory_peak_bytes())
 
     if trace and run.traced is not None:
-        from . import trace as trace_mod
-
         if on_chip:
-            run.trace_data = trace_mod.load(trace_mod.newest_xplane(run.trace_dir))
-            run.trace_window = trace_mod.window_of(run.trace_data)
-            lo, hi = run.trace_window
-            run.device["busy_s"] = trace_mod.busy_s(run.trace_data, lo, hi)
-            run.device["window_s"] = (hi - lo) / 1e9
+            read_traced_calls(run, state)
         shutil.rmtree(run.trace_dir, ignore_errors=True)
 
     group = "per_layer" if trace else "end_to_end"
-    metrics = {}
-    for m in metrics_of(manifest, cell, group):
-        value = load_module("metrics", m["name"]).read(run)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    metrics = read_metrics(run, metrics_of(manifest, cell, group))
 
     print("request wall seconds:", " ".join(
         f"{r['t_end'] - r['t_start']:.3f}" for r in run.records), file=sys.stderr)
@@ -231,6 +283,8 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
         "metrics": metrics,
         "device": run.device,
     }
+    if run.trace_incomplete:
+        result["trace_incomplete"] = run.trace_incomplete
     if trace and run.trace_data is not None:
         from . import trace as trace_mod
 

@@ -17,8 +17,37 @@ import time
 from . import trace as T
 
 
+def program_text(module: str):
+    """The compiled text of ``module``'s newest program, lowered again from
+    the shapes its launch kept (a read of the executable in memory after a
+    launch in the same process); None without a launch."""
+    launch = newest(module)
+    if launch is None:
+        return None
+    t0 = time.monotonic()
+    text = launch.fn.lower(*launch.args, **launch.kwargs).compile().as_text()
+    print(f"program text of {module}: {len(text)} characters in "
+          f"{time.monotonic() - t0:.2f} s", file=sys.stderr)
+    return text
+
+
+def program_loops(run):
+    """``{module: lib.trace.Loops or None}`` for every module of the trace:
+    the loops of the program's text (``run.program_texts`` by module where a
+    recorded trace brings them, else the launch's), None where there is no
+    text and ``Op.loop`` stays the nesting's."""
+    recorded = getattr(run, "program_texts", None)
+    modules = {o.module for ops in run.trace_data.devices.values() for o in ops}
+    out = {}
+    for m in sorted(modules):
+        text = recorded.get(m) if recorded is not None else program_text(m)
+        out[m] = T.program_loops(text) if text else None
+    return out
+
+
 def loop_modules(run):
-    """The modules whose sampling loop ran in the traced window."""
+    """The modules whose sampling loop ran in the traced window (``Op.loop``
+    from the program's text, ``lib/trace.py``)."""
     if run.trace_data is None:
         return []
     lo, hi = run.trace_window

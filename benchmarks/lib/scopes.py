@@ -7,7 +7,7 @@ every program it launched, what is needed to map instruction names back to
 its ``jax.named_scope``s (``p2p_tpu/obs/launches.py:scope_index``, built from
 the compiled program's text when asked). ``load`` joins the two: every leaf
 operation of the traced window gets the scope of its instruction, its class
-among the step's five parts, and whether the fusion it ran as has members in
+among the step's parts, and whether the fusion it ran as has members in
 more than one scope. On a traced run the whole tree goes to stderr.
 
 A program that offers no index (any tree before PR 27) makes every reader
@@ -25,21 +25,33 @@ from types import SimpleNamespace
 
 from . import trace as T
 
-#: The step's five disjoint parts, by the metric that reports each.
-PARTS = ("resblock", "self_attn", "cross_attn", "ff", "outside_unet")
+#: The step's disjoint parts, by the metric that reports each; the sixth,
+#: ``embed_mod``, is a transformer denoiser's own and no metric reads it yet.
+PARTS = ("resblock", "self_attn", "cross_attn", "ff", "outside_unet", "embed_mod")
 _RESBLOCK = re.compile(
     r"^unet/(?:conv_in|conv_out|time_embed"
     r"|(?:down|mid|up)\d+/(?:res\d+|downsample|upsample|skip_concat))$")
-_FF = re.compile(r"^unet/(?:down|mid|up)\d+/attn\d+/(?:ff|proj_in|proj_out)$")
+_FF = re.compile(r"^unet/(?:down|mid|up)\d+/attn\d+/(?:ff|proj_in|proj_out)$"
+                 r"|^dit/block\d+/ff$")
 #: Under this share of scoped device time the index is not the program's
 #: that ran (stale cache entry, renamed instructions): nothing else is read.
 SCOPED_FLOOR_PCT = 90.0
 
 
 def part_of(scope) -> str:
-    """Which of the step's five parts a scope path belongs to. Whatever is
-    not the U-Net's (``sampler/...``, no scope at all, a path cut short of
-    a block) is the sampler's own: the five then sum to the step."""
+    """Which of the step's parts a scope path belongs to.
+
+    A U-Net's: ``unet/<place><level>/res<i>`` and the convolutions around
+    the levels are ``resblock``, ``.../attn<i>/{ff,proj_in,proj_out}``
+    ``ff``. A transformer denoiser's scopes are ``dit/block<i>/`` followed
+    by ``self_attn/<site>/{qkv,core,out}``, ``cross_attn/<site>/{qkv,core,out}``,
+    ``ff`` or ``modulate`` within a block, and ``dit/{patch_embed,
+    time_embed, caption_proj, final}`` outside the blocks; its ``ff`` is
+    ``ff`` and the rest of ``dit`` is ``embed_mod``, so nothing of it is the
+    sampler's. Either denoiser's attention is ``self_attn`` or
+    ``cross_attn``. Whatever is not the denoiser's (``sampler/...``, no scope
+    at all, a U-Net path cut short of a block) is the sampler's own: the
+    parts then sum to the step."""
     if not scope:
         return "outside_unet"
     if "/self_attn/" in scope:
@@ -50,6 +62,8 @@ def part_of(scope) -> str:
         return "ff"
     if _RESBLOCK.match(scope):
         return "resblock"
+    if scope == "dit" or scope.startswith("dit/"):
+        return "embed_mod"
     return "outside_unet"
 
 
@@ -65,9 +79,9 @@ def _straddles(scopes, level: int = 2) -> bool:
 class Row:
     op: T.Op
     scope: str            # "" where the index has no scope for it
-    part: str             # which of the step's five parts
+    part: str             # which of the step's parts
     ambiguous: bool       # a fusion whose members span two second-level scopes
-    crosses_parts: bool   # ... or two of the step's five parts
+    crosses_parts: bool   # ... or two of the step's parts
 
 
 @dataclass
@@ -81,7 +95,7 @@ class Scoped:
     scoped_pct: float = 0.0   # share of the window's device time with a scope
 
     def loop_ms_per_step(self, part: str):
-        """ms a step of the loop's device time in one of the five parts."""
+        """ms a step of the loop's device time in one of the parts."""
         if self.scoped_pct < SCOPED_FLOOR_PCT or not self.steps:
             return None
         ns = sum(r.op.dur for r in self.rows if r.op.loop and r.part == part)
@@ -272,7 +286,7 @@ def _print(run, scoped: Scoped, indexes, rss_mib: float) -> None:
         say(f"scope tree, {'loop' if loop else 'outside the loop'}: "
             f"{total / scoped.ndev / per / 1e6:.3f} {unit}; in fusions that span "
             f"second-level scopes {100 * amb / total:.2f} %, that span two of "
-            f"the five parts {100 * crs / total:.2f} %")
+            f"the parts {100 * crs / total:.2f} %")
         say(f"  {'scope':<58}{unit:>9} {'share%':>7} {'ambig%':>7} {'relay%':>7}")
         for path in sorted(agg):
             ns, ambiguous, relayout = agg[path]

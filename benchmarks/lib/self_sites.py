@@ -31,7 +31,7 @@ def classes(run, module: str):
     program; None where the program recorded none (laid over an older
     parent), or other sites than the configuration's layout has."""
     hows = launched.self_site_hows(run, module)
-    names = flops.self_site_names(run.config["unet"])
+    names = flops.self_site_names(flops.denoiser(run.config))
     if not hows or sorted(hows) != [2 * i for i in range(len(names))]:
         return None
     return {name: hows[2 * i] for i, name in enumerate(names)}

@@ -7,6 +7,15 @@ The trace is read once into plain lists (``load``); everything else is
 arithmetic on those lists, so it runs the same on a recorded fixture
 (``from_dict``) as on a fresh trace. Times are nanoseconds on the trace's own
 clock, which host and device planes share.
+
+Which operations belong to the sampling loop (``Op.loop``) is taken from
+the launched program where its text exists (``program_loops``,
+``mark_loops``: an instruction of a ``while``'s body or condition, or of a
+computation they call), and from nesting under a ``while`` event only where
+it does not, as in a recorded trace: an event the profiler lost, the
+``while``'s own among them, then changes nothing of the membership. A trace
+whose loop instructions do not add up to the traced calls' steps is not
+read (``incomplete``).
 """
 
 from __future__ import annotations
@@ -146,6 +155,119 @@ def mark_leaves(ops) -> None:
         o.loop = bool(open_loops)
         if o.category == "while":
             open_loops.append(o)
+
+
+# -- the loop, from the program's text ---------------------------------------
+
+_TEXT_COMP = re.compile(r"^(?:ENTRY )?%([^\s(]+) .*\{\s*$")
+_TEXT_INSTR = re.compile(r"^\s+(?:ROOT )?%([^\s=]+) = .*? ([a-z][a-z\-]*)\(")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|true_computation"
+                     r"|false_computation)=%([^\s,}]+)")
+_CALLED_SET = re.compile(r"(?:branch_computations|called_computations)=\{([^}]*)\}")
+_WHILE = re.compile(r"condition=%([^\s,}]+), body=%([^\s,}]+)")
+
+
+@dataclass
+class Loops:
+    """What a program's text says of its loops: every instruction that runs
+    inside one, and for each ``while`` the instructions of its body's own
+    computation (those run once an iteration) and whether the ``while``
+    itself runs inside another loop."""
+
+    names: set = field(default_factory=set)
+    bodies: dict = field(default_factory=dict)   # while -> [instruction]
+    nested: set = field(default_factory=set)     # whiles inside a loop
+
+
+def program_loops(hlo_text: str) -> Loops:
+    """``Loops`` of a compiled program's text (``compiled.as_text()``)."""
+    comps, calls, whiles, current = {}, {}, [], None
+    for line in hlo_text.splitlines():
+        m = _TEXT_INSTR.match(line)
+        if m is None:
+            c = _TEXT_COMP.match(line)
+            if c:
+                current = c.group(1)
+            continue
+        name, opcode = m.groups()
+        comps.setdefault(current, []).append(name)
+        called = calls.setdefault(current, set())
+        called.update(_CALLED.findall(line))
+        for group in _CALLED_SET.findall(line):
+            called.update(g.strip().lstrip("%") for g in group.split(",") if g.strip())
+        if opcode == "while":
+            w = _WHILE.search(line)
+            if w:
+                whiles.append((name, w.group(1), w.group(2)))
+    inside, todo = set(), [c for _, cond, body in whiles for c in (cond, body)]
+    while todo:
+        c = todo.pop()
+        if c not in inside:
+            inside.add(c)
+            todo += calls.get(c, ())
+    out = Loops(names={n for c in inside for n in comps.get(c, ())})
+    for name, _, body in whiles:
+        out.bodies[name] = comps.get(body, [])
+        if name in out.names:
+            out.nested.add(name)
+    return out
+
+
+def mark_loops(trace: Trace, loops: dict) -> None:
+    """``Op.loop`` from ``loops`` (``{module: Loops}``) for the operations of
+    a module that has them; the others keep their nesting."""
+    for ops in trace.devices.values():
+        for o in ops:
+            known = loops.get(o.module)
+            if known is not None:
+                o.loop = o.name in known.names
+
+
+def incomplete(trace: Trace, lo: float, hi: float, loops: dict, steps: int):
+    """Why the traced window's loop does not add up to ``steps`` (the traced
+    calls' denoising steps), or None where it does.
+
+    With a program's text: every instruction of a ``while``'s body that the
+    window shows is seen the same number of times (once an iteration), and
+    the outermost loops' iterations sum to ``steps``. Without one: the loop
+    instructions by nesting are seen ``steps`` times at the most and most
+    often. A profiler that lost events, the ``while``'s among them, fails
+    either: the body's instructions are seen fewer times, or none is in a
+    loop."""
+    seen, nested_loop = {}, set()
+    for ops in trace.devices.values():
+        for o in ops:
+            if lo <= o.start and o.end <= hi:
+                key = (o.module, o.name)
+                seen[key] = seen.get(key, 0) + 1
+                if o.loop and loops.get(o.module) is None:
+                    nested_loop.add(key)
+    ndev = max(1, len(trace.devices))
+    counted, by_nesting = 0, {}
+    for module, name in nested_loop:
+        by_nesting.setdefault(module, []).append(seen[(module, name)] // ndev)
+    for module, known in sorted(loops.items(), key=lambda mk: mk[0]):
+        if known is None:
+            continue
+        for w, body in sorted(known.bodies.items()):
+            counts = {seen[(module, n)] // ndev for n in body if (module, n) in seen}
+            if len(counts) > 1:
+                return (f"{module}: the body of {w} was seen {min(counts)} to "
+                        f"{max(counts)} times an instruction")
+            if counts and w not in known.nested:
+                counted += counts.pop()
+    for module, counts in sorted(by_nesting.items()):
+        modal = max(set(counts), key=counts.count)
+        if max(counts) > steps or modal != steps:
+            return (f"{module}: the loop's instructions by nesting were seen "
+                    f"{modal} times most often, {max(counts)} at most, of {steps} steps")
+        counted += steps
+    if steps and counted != steps:
+        modules = sorted({m for m, _ in seen if m})
+        return (f"the traced calls ran {steps} steps and the loops of "
+                f"{', '.join(modules)} in the trace {counted} iterations"
+                + ("" if counted else " (no operation of them is in a loop)"))
+    return None
 
 
 def union_ns(intervals, lo: float, hi: float) -> float:

@@ -78,3 +78,22 @@ def closed_loop(run, call) -> None:
     if tracing:
         run.stop_trace()
     run.window = (t0, time.monotonic())
+
+
+def trace_one_more(run, call) -> None:
+    """One more request after the window, traced on its own, in place of
+    traced calls whose trace is not whole. Its record goes to
+    ``run.retakes``: the window's requests, rate and check stay as they
+    were."""
+    i = len(run.records) + len(run.retakes)
+    run.start_trace()
+    t_start = time.monotonic()
+    try:
+        with run.spans("call", i):
+            rec = call(i)
+    except Exception as e:
+        traceback.print_exc()
+        rec = {"index": i, "failed": f"{type(e).__name__}: {e}"[:300]}
+    rec.update(t_start=t_start, t_end=time.monotonic())
+    run.stop_trace()
+    run.retakes.append(rec)

@@ -5,7 +5,13 @@ It stands beside ``peak_hbm_gib``, which reads the allocator's peak and does
 not see them (0.40 and 0.26 GiB over the weights where XLA counts 2.14 and
 4.67: PERF.md, Open questions): what a larger configuration's fit is
 reckoned from. Where more than one program's loop ran in the traced window,
-the largest. Read after the window, on traced runs only."""
+the largest. Read after the window, on traced runs only.
+
+The sampling program is the module with an operation in the loop, and the
+loop is the launched program's: an instruction of a ``while``'s body or
+condition in the module's compiled text (``lib/launched.py:program_loops``,
+``loop_modules``), so a trace that lost the ``while``'s event still names
+it."""
 
 from benchmarks.lib import launched
 

@@ -14,7 +14,14 @@ attention (sites under the 1,024 keys from which the program switches to
 the kernel), and would read nothing if another implementation took the
 kernel's place. ``model.self_attn_kernel_ms_per_step`` is the per-scope form
 of its denominator; keying this reader on the scope is an open question of
-PERF.md."""
+PERF.md.
+
+The loop is the launched program's: an operation is in it where its
+instruction belongs to a ``while``'s body or condition in the compiled
+text of its module (``lib/launched.py:program_loops``,
+``lib/trace.py:mark_loops``), by nesting under the trace's ``while`` event
+only where no text exists; a trace whose loop does not add up to the
+traced calls' steps is not read (``lib/trace.py:incomplete``)."""
 
 from benchmarks.lib import trace as T
 from benchmarks.lib.peaks import peaks_for

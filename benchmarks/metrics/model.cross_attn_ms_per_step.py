@@ -1,6 +1,6 @@
 """Model: device time a denoising step spends under ``**/cross_attn/**``, the
 prompt-to-prompt edit of the probabilities and the attention store included,
-in ms. One of five parts that sum to ``sampler.step_ms`` (``lib/scopes.py``)."""
+in ms. One of the parts that sum to ``sampler.step_ms`` (``lib/scopes.py``)."""
 
 from benchmarks.lib import scopes
 

@@ -1,5 +1,12 @@
 """Sampler: device time of the operations inside the sampling loop, per
-denoising step executed in the traced window, in ms (mean over devices)."""
+denoising step executed in the traced window, in ms (mean over devices).
+
+The loop is the launched program's: an operation is in it where its
+instruction belongs to a ``while``'s body or condition in the compiled
+text of its module (``lib/launched.py:program_loops``,
+``lib/trace.py:mark_loops``), by nesting under the trace's ``while`` event
+only where no text exists; a trace whose loop does not add up to the
+traced calls' steps is not read (``lib/trace.py:incomplete``)."""
 
 from benchmarks.lib import trace as T
 
