@@ -45,8 +45,9 @@ def _build_pipeline(args):
     import jax
 
     from .engine.sampler import Pipeline
-    from .models import init_text_encoder, init_unet
+    from .models import init_text_encoder
     from .models import vae as vae_mod
+    from .models.denoiser import init_denoiser
     from .utils.tokenizer import HashWordTokenizer
 
     cfg = _preset_config(args.preset)
@@ -54,12 +55,19 @@ def _build_pipeline(args):
         from .models.checkpoint import load_pipeline
 
         return load_pipeline(args.checkpoint, cfg)
-    tok = HashWordTokenizer(model_max_length=cfg.unet.context_len)
+    tok = HashWordTokenizer(vocab_size=cfg.towers[0].vocab_size,
+                            model_max_length=cfg.unet.context_len)
+    # The denoiser's and the autoencoder's draws as one program each: the
+    # same values, to the bit, as drawing leaf by leaf, without a compile per
+    # leaf shape. (The towers' scaled normal tables would round differently.)
+    def init(fn, seed, part):
+        return jax.jit(fn, static_argnums=1)(jax.random.PRNGKey(seed), part)
+
     return Pipeline(
         config=cfg,
-        unet_params=init_unet(jax.random.PRNGKey(0), cfg.unet),
+        unet_params=init(init_denoiser, 0, cfg.unet),
         text_params=init_text_encoder(jax.random.PRNGKey(1), cfg.text),
-        vae_params=vae_mod.init_vae(jax.random.PRNGKey(2), cfg.vae),
+        vae_params=init(vae_mod.init_vae, 2, cfg.vae),
         tokenizer=tok,
     )
 
@@ -306,9 +314,9 @@ def cmd_edit(args) -> int:
         with _metrics_session(args.metrics) as met, trace(args.profile):
             return _edit_batched(args, pipe, prompts, controller, out_dir,
                                  metrics=met)
-    from .models.config import unet_layout
+    from .models.config import denoiser_layout
 
-    layout = unet_layout(pipe.config.unet)
+    layout = denoiser_layout(pipe.config.unet)
     sched_spec = _schedule_spec(args)
     with _metrics_session(args.metrics) as met, trace(args.profile):
         for seed in args.seeds:
@@ -831,7 +839,7 @@ def build_parser() -> argparse.ArgumentParser:
         sp.add_argument("--preset",
                         choices=("tiny", "sd14", "sd21", "sd21base",
                                  "ldm256", "tiny_ldm", "tiny_v", "sdxl",
-                                 "tiny_xl"),
+                                 "tiny_xl", "pixart_sigma", "tiny_dit"),
                         default="tiny",
                         help="model family; sd21 is the 768-v v-prediction "
                              "variant the reference marks 'Not work' "
@@ -1207,7 +1215,8 @@ def build_parser() -> argparse.ArgumentParser:
                       "or --static: the jaxcheck static analyzer")
     c.add_argument("checkpoint_dir", nargs="?", default=None)
     c.add_argument("--preset", default=None,
-                   choices=("sd14", "sd21", "sd21base", "ldm256", "sdxl"))
+                   choices=("sd14", "sd21", "sd21base", "ldm256", "sdxl",
+                            "pixart_sigma"))
     c.add_argument("--static", action="store_true",
                    help="run the three-pass static analyzer instead (AST "
                         "lints + traced-program contracts + the "

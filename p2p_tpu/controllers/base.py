@@ -54,7 +54,7 @@ class AttnMeta:
     """
 
     layer_idx: int          # global index over all attention call sites
-    place: str              # 'down' | 'mid' | 'up'
+    place: str              # 'down' | 'mid' | 'up'; 'block' in a transformer
     is_cross: bool
     resolution: int         # spatial side length of the feature map (pixels = resolution²)
     heads: int
@@ -122,6 +122,14 @@ class AttnLayout:
     latent_size: Optional[int] = None
 
     @property
+    def denoiser(self) -> str:
+        """``"transformer"`` where every site is a transformer denoiser's
+        block (place ``"block"``, all at one token grid), else ``"unet"``."""
+        if self.metas and all(m.place == "block" for m in self.metas):
+            return "transformer"
+        return "unet"
+
+    @property
     def num_store_slots(self) -> int:
         return sum(1 for m in self.metas if m.store_slot is not None)
 
@@ -135,10 +143,15 @@ class AttnLayout:
         stands where 16² stands in SD-1.4's 64/32/16/8, a quarter of the
         latent's side (24 of SD-2.1's 96/48/24/12, 32 of SDXL's 128, whose
         attention levels are 64/32). A model with neither is an error here,
-        at controller build time, never an empty site list."""
+        at controller build time, never an empty site list. A transformer
+        denoiser's sites (place ``"block"``) all stand at its token grid,
+        and its defaults take that one resolution: the self window holds
+        every self site, LocalBlend's side is the grid's."""
         sides = sorted({m.resolution for m in self.metas})
         if PAPER_RESOLUTION in sides:
             return PAPER_RESOLUTION
+        if self.denoiser == "transformer":
+            return sides[0]
         level = (self.latent_size or sides[-1]) // 4
         if level not in sides:
             raise ValueError(
@@ -165,6 +178,9 @@ class AttnLayout:
             side = self.edit_resolution() << edit.self_max_pixels.up
             edit = edit.replace(self_max_pixels=side * side)
         if blend is not None:
+            if self.denoiser != "unet":
+                raise ValueError(f"LocalBlend: not supported for a "
+                                 f"{self.denoiser} denoiser (U-Net only)")
             if isinstance(blend.resolution, PaperLevel):
                 blend = blend.replace(
                     resolution=self.edit_resolution() << blend.resolution.up)

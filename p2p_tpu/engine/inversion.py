@@ -31,6 +31,7 @@ import numpy as np
 from ..models import vae as vae_mod
 from ..models.conditioning import context_of, with_context
 from ..models.config import PipelineConfig
+from ..models.denoiser import require_unet
 from ..models.unet import apply_unet, embed_added
 from ..ops import schedulers as sched_mod
 from ..utils import progress as progress_mod
@@ -284,6 +285,7 @@ def invert(
             "Run invert() with gate=None; apply --gate to plain "
             "generation/editing only.")
     cfg = pipe.config
+    require_unet(cfg.unet, "null-text inversion")
     gs = jnp.asarray(cfg.guidance_scale if guidance_scale is None else guidance_scale,
                      jnp.float32)
     if image.dtype == np.uint8:

@@ -43,10 +43,11 @@ from ..controllers.base import (
     init_store_state,
 )
 from ..models import vae as vae_mod
-from ..models.conditioning import cfg_rows, context_of, with_context
+from ..models.conditioning import (Caption, cfg_rows, context_of, live_keys,
+                                   with_context)
 from ..models.config import PipelineConfig
+from ..models.denoiser import denoise, prepare, require_unet
 from ..models.text_encoder import apply_text_encoder, apply_text_towers
-from ..models.unet import apply_unet, embed_added
 from ..obs import launches
 from ..obs.spans import span
 from ..ops import schedulers as sched_mod
@@ -75,12 +76,17 @@ class Pipeline:
 
 
 @partial(jax.jit, static_argnames=("cfg", "dtype"))
-def _encode_jit(text_params, cfg, ids, dtype, eos=None):
+def _encode_jit(text_params, cfg, ids, dtype, eos=None, mask=None):
     """``cfg`` is ``PipelineConfig.text``: one tower's configuration, or a
     tuple of them (then ``eos`` (B,) is each prompt's end-of-text position,
-    where the pooled text is read)."""
+    where the pooled text is read). A tower that attends under a key mask
+    (``mask`` (B, L) int32, a ``t5`` tower's) gives a ``Caption`` of its
+    hidden states and the mask."""
     if isinstance(cfg, tuple):
         return apply_text_towers(text_params, cfg, ids, eos, dtype=dtype)
+    if mask is not None:
+        return Caption(apply_text_encoder(text_params, cfg, ids, dtype=dtype,
+                                          mask=mask), mask)
     return apply_text_encoder(text_params, cfg, ids, dtype=dtype)
 
 
@@ -114,7 +120,8 @@ def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32):
     (``models.conditioning``): (B, L, D) hidden states
     (`/root/reference/ptp_utils.py:144-156`), or for a preset of several
     towers a ``Conditioning`` of their concatenated states and the pooled
-    text."""
+    text, or for a ``t5`` tower a ``Caption`` of its states and their key
+    mask: every token up to and including the first end-of-text id."""
     tok = pipe.tokenizer
     max_len = pipe.config.unet.context_len
     with span("entry.tokenize", prompts=len(prompts)):
@@ -132,6 +139,11 @@ def encode_prompts(pipe: Pipeline, prompts, dtype=jnp.float32):
             # first end-of-text id of each prompt (padding repeats it)
             kwargs["eos"] = stage_host(
                 (ids == tok.eos_token_id).argmax(axis=1).astype(np.int32))
+        elif pipe.config.text.arch == "t5":
+            # no end-of-text id before the key: the first one and all ahead
+            eos = ids == tok.eos_token_id
+            kwargs["mask"] = stage_host(
+                (np.cumsum(eos, axis=1) - eos == 0).astype(np.int32))
         mark = launches.built()
         out = _encode_jit(*args, **kwargs)
         launches.keep_if_built(mark, _encode_jit, args, kwargs)
@@ -403,7 +415,7 @@ def _make_cfg_body(
     ms_step = _make_ms_step(schedule, scheduler_kind)
     # What of the conditioning does not depend on the step, once ahead of
     # the scan (a conditioning with nothing of the kind comes back as it is).
-    context = embed_added(unet_params, cfg.unet, context)
+    context = prepare(unet_params, cfg.unet, context)
 
     def body(carry, scan_in):
         latents, state, ms, cache, resid = carry
@@ -426,7 +438,7 @@ def _make_cfg_body(
                                       states[:b].shape),
                      states[b:]], axis=0))
             latent_in = jnp.concatenate([latents] * 2, axis=0)
-        eps, state, out_cache = apply_unet(
+        eps, state, out_cache = denoise(
             unet_params, cfg.unet, latent_in, t, ctx,
             layout=layout, controller=controller, state=state, step=step,
             sp=sp, attn_cache=cache if held is None else held,
@@ -480,13 +492,13 @@ def _make_cond_body(
     that flip to reuse inside phase 2 keep storing until their step, and a
     segment with none closes over the cache."""
     ms_step = _make_ms_step(schedule, scheduler_kind)
-    context_cond = embed_added(unet_params, cfg.unet, context_cond)
+    context_cond = prepare(unet_params, cfg.unet, context_cond)
 
     def body(carry, scan_in):
         latents, ms, cache = carry
         step, t = scan_in
         progress_mod.emit_step(emit, step, phase="phase2", report=progress)
-        eps_text, _, out_cache = apply_unet(
+        eps_text, _, out_cache = denoise(
             unet_params, cfg.unet, latents, t, context_cond,
             layout=layout, controller=None, state=(), step=step, sp=sp,
             attn_cache=cache if held is None else held,
@@ -822,6 +834,7 @@ def text2image(
         num_steps = num_steps or cfg.num_steps
         scheduler = scheduler or cfg.scheduler.kind
         if uncond_embeddings is not None:
+            require_unet(cfg.unet, "uncond_embeddings (null-text inversion)")
             if scheduler != "ddim":
                 # PLMS scans T+1 steps (warm-up double-evaluation); per-step
                 # null-text embeddings are optimized against the DDIM trajectory
@@ -836,8 +849,8 @@ def text2image(
             gs = jnp.asarray(cfg.guidance_scale if guidance_scale is None else guidance_scale,
                              dtype=jnp.float32)
             if layout is None:
-                from ..models.config import unet_layout
-                layout = unet_layout(cfg.unet)
+                from ..models.config import denoiser_layout
+                layout = denoiser_layout(cfg.unet)
             controller = layout.resolve(controller)
             layout = layout.for_readers(controller, return_store)
             if rng is None:
@@ -903,6 +916,10 @@ def text2image(
             kwargs = dict(progress=progress, sp=sp, gate=gate_step,
                           metrics=metrics, reuse=reuse_sched, kernels=kernels)
             mark = launches.built()
+            keys = live_keys(context_uncond)
+            if keys is not None:
+                launches.note_caption_keys(keys + live_keys(context_cond),
+                                           cfg.unet.context_len)
             image, latents_out, state = _text2image_jit(*args, **kwargs)
             launches.keep_if_built(mark, _text2image_jit, args, kwargs)
         return image, x_t, state

@@ -387,10 +387,12 @@ def load_pipeline(checkpoint_dir: str, config, tokenizer=None):
 
     from ..engine.sampler import Pipeline
     from ..utils.tokenizer import ClipBpeTokenizer
+    from .denoiser import require_unet
     from .text_encoder import init_text_encoder
     from .unet import init_unet
     from . import vae as vae_mod
 
+    require_unet(config.unet, "the checkpoint key map")
     text = one_tower(config)
     unet_params = load_unet(init_unet(jax.random.PRNGKey(0), config.unet),
                             config.unet, _find_subdir(checkpoint_dir, ("unet",)))

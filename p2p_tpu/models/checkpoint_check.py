@@ -245,10 +245,12 @@ def check_checkpoint(dirpath: str, preset: str, config=None) -> Report:
     from .checkpoint import (ldm_text_encoder_entries, one_tower,
                              text_encoder_entries, unet_entries, vae_entries)
     from .config import PRESET_CONFIGS
+    from .denoiser import require_unet
     from .text_encoder import init_text_encoder
     from .unet import init_unet
 
     cfg = config if config is not None else PRESET_CONFIGS[preset]
+    require_unet(cfg.unet, "the checkpoint key map")
     text = one_tower(cfg)
     text_entries = (ldm_text_encoder_entries(text) if text.arch == "ldmbert"
                     else text_encoder_entries(text))

@@ -147,6 +147,82 @@ def unet_layout(cfg: UNetConfig, store_cfg: Optional[StoreConfig] = None
 
 
 @dataclasses.dataclass(frozen=True)
+class TransformerConfig:
+    """Shape config for a transformer denoiser over patch tokens (diffusers'
+    ``PixArtTransformer2DModel``): a patch embedding, ``num_layers`` blocks
+    of self-attention, cross-attention to the caption and a feed-forward,
+    each modulated by adaLN-single (one timestep MLP to six times the width,
+    a learned ``(6, width)`` table a block), and a final modulated layer.
+    The attribute names are the ones ``benchmarks/lib/pipeline.py`` reads."""
+
+    kind: str = "transformer"
+    sample_size: int = 128                 # latent side length
+    patch_size: int = 2
+    in_channels: int = 4
+    # Twice the latent's channels where the variance is learned: the first
+    # ``in_channels`` are ε, the rest are computed and dropped.
+    out_channels: int = 8
+    num_layers: int = 28
+    num_heads: int = 16
+    head_dim: int = 72
+    context_dim: int = 1152                # the caption's projected width
+    caption_channels: int = 4096           # the text tower's width
+    context_len: int = 300                 # caption tokens, under a key mask
+    norm_type: str = "ada_norm_single"
+    activation: str = "gelu-approximate"
+    ff_mult: int = 4
+    attention_bias: bool = True
+    use_additional_conditions: bool = False
+    interpolation_scale: int = 2           # of the 2-D sin-cos positions
+    freq_dim: int = 256                    # the timestep sinusoid's width
+    kernel_dtype: str = "float32"          # as ``UNetConfig.kernel_dtype``
+
+    @property
+    def width(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def grid(self) -> int:
+        """Tokens along a side: the latent's side over the patch."""
+        return self.sample_size // self.patch_size
+
+
+def transformer_attn_specs(cfg: TransformerConfig):
+    """Every attention call site of ``transformer.apply_transformer`` in call
+    order, as :func:`unet_attn_specs` gives them: per block its self site,
+    then its cross site, all at the one token grid. Site ``2i`` is block
+    ``i``'s self-attention and ``2i + 1`` its cross-attention; the place is
+    ``"block"``, so the sites are named ``block0`` ... ``block<2n-1>``."""
+    res, c = cfg.grid, cfg.width
+    specs = []
+    for _ in range(cfg.num_layers):
+        specs.append(("block", False, res, cfg.num_heads, res * res, c))
+        specs.append(("block", True, res, cfg.num_heads, cfg.context_len, c))
+    return specs
+
+
+def transformer_layout(cfg: TransformerConfig,
+                       store_cfg: Optional[StoreConfig] = None) -> AttnLayout:
+    """The layout of a transformer denoiser. Its one resolution is the token
+    grid's: the default store keeps nothing (no map is small enough to stand
+    for the pyramid's lower levels), and the layout's latent side is the
+    grid's, so a controller's defaults are taken against that one level."""
+    if store_cfg is None:
+        store_cfg = StoreConfig(max_pixels=0)
+    return build_layout(transformer_attn_specs(cfg), store_cfg,
+                        latent_size=cfg.grid)
+
+
+def denoiser_layout(cfg, store_cfg: Optional[StoreConfig] = None
+                    ) -> AttnLayout:
+    """The attention layout of a preset's denoiser (``PipelineConfig.unet``),
+    whichever its kind."""
+    if getattr(cfg, "kind", "unet") == "transformer":
+        return transformer_layout(cfg, store_cfg)
+    return unet_layout(cfg, store_cfg)
+
+
+@dataclasses.dataclass(frozen=True)
 class TextEncoderConfig:
     """CLIP-style causal text transformer (SD-1.4: ViT-L/14 text tower)."""
 
@@ -175,6 +251,13 @@ class TextEncoderConfig:
     # through a bias-free projection. None: the tower has no pooled output.
     projection_dim: Optional[int] = None
     kernel_dtype: str = "float32"          # as ``UNetConfig.kernel_dtype``
+    # T5's encoder (``arch="t5"``): a feed-forward of ``intermediate_size``
+    # in place of ``ff_mult`` times the width, and one relative-position
+    # bias of this many buckets up to this distance. None for every other
+    # tower, whose sizes these do not describe.
+    intermediate_size: Optional[int] = None
+    relative_attention_num_buckets: Optional[int] = None
+    relative_attention_max_distance: Optional[int] = None
 
     @property
     def inner_dim(self) -> int:
@@ -240,7 +323,9 @@ class PipelineConfig:
     """A full backend: text encoder + U-Net + VAE + scheduler defaults."""
 
     name: str
-    unet: UNetConfig
+    # The denoiser, of either kind: a ``UNetConfig``, or a
+    # ``TransformerConfig`` (``kind="transformer"``).
+    unet: Union[UNetConfig, "TransformerConfig"]
     # One tower's configuration, or a sequence of them whose outputs are
     # concatenated into one context (``Pipeline.text_params`` is then a list
     # of trees in the same order).
@@ -397,6 +482,43 @@ TINY_XL = PipelineConfig(
                         kernel_dtype="bfloat16"),
     image_size=96, num_steps=4, guidance_scale=5.0)
 
+# PixArt-Sigma at 1024² (arXiv 2403.04692; `PixArt-Sigma-XL-2-1024-MS`'s
+# transformer config.json and `pixart_sigma_sdxlvae_T5_diffusers`'s
+# text_encoder, vae and scheduler): a 28-block DiT over 2×2 patches of the
+# 128² latent (4,096 tokens of 1152, 16 heads of 72) with adaLN-single and a
+# learned variance, conditioned on T5-v1.1-XXL's encoder over 300 tokens
+# under a key mask, decoded by SDXL's autoencoder. Guidance 4.5 is the
+# published pipeline's default; DDIM over the checkpoint's linear betas.
+# Kernels are stored in bfloat16, as SDXL's: 5.4 B parameters.
+PIXART_SIGMA_TEXT = TextEncoderConfig(
+    vocab_size=32128, hidden_dim=4096, num_layers=24, num_heads=64,
+    max_length=300, activation="gated-gelu", causal=False,
+    attn_qkv_bias=False, arch="t5", kernel_dtype="bfloat16",
+    intermediate_size=10240, relative_attention_num_buckets=32,
+    relative_attention_max_distance=128)
+PIXART_SCHEDULER = SchedulerConfig(beta_start=0.0001, beta_end=0.02,
+                                   beta_schedule="linear")
+PIXART_SIGMA = PipelineConfig(
+    "pixart-sigma-1024", TransformerConfig(kernel_dtype="bfloat16"),
+    PIXART_SIGMA_TEXT, SDXL.vae, image_size=1024, guidance_scale=4.5,
+    scheduler=PIXART_SCHEDULER)
+
+# Tiny PixArt-shaped backend for tests: what `pixart_sigma` forces at toy
+# sizes — a transformer denoiser over 2×2 patches with adaLN-single and a
+# learned variance, a T5 tower (RMS norms, relative buckets, gated GELU)
+# under a caption key mask, bfloat16 kernels.
+TINY_DIT = PipelineConfig(
+    "tiny-dit",
+    TransformerConfig(sample_size=16, num_layers=2, num_heads=2, head_dim=8,
+                      context_dim=16, caption_channels=32, context_len=16,
+                      kernel_dtype="bfloat16"),
+    dataclasses.replace(PIXART_SIGMA_TEXT, hidden_dim=32, num_layers=2,
+                        num_heads=2, max_length=16, intermediate_size=48),
+    dataclasses.replace(TINY_VAE, scaling_factor=0.13025,
+                        kernel_dtype="bfloat16"),
+    image_size=64, num_steps=4, guidance_scale=4.5,
+    scheduler=PIXART_SCHEDULER)
+
 
 # The one preset-name → PipelineConfig resolution map (CLI commands,
 # `p2p-tpu check`, tools/parity_real_weights.py all resolve through it).
@@ -415,4 +537,6 @@ PRESET_CONFIGS = {
     "tiny_v": TINY_V,
     "sdxl": SDXL,
     "tiny_xl": TINY_XL,
+    "pixart_sigma": PIXART_SIGMA,
+    "tiny_dit": TINY_DIT,
 }

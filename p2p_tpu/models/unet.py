@@ -360,6 +360,99 @@ def _fused_edit_dispatch(ctx: _HookCtx, meta, q, k, v, scale):
                                 interpret=ctx.kernels.interpret)
 
 
+def attention_core(ctx: _HookCtx, meta, q: jax.Array, k: jax.Array,
+                   v: jax.Array, scale: float, mask=None) -> jax.Array:
+    """A site's ``softmax(q kᵀ · scale) v`` under its controller, whichever
+    implementation runs it: the controller's materialized maps at a touched
+    site, the flash kernel on the base row's q and k at a self site it only
+    injects into, the fused-edit kernel where the dispatch plan covers the
+    site, ring / all-to-all attention over a mesh, else
+    ``nn.fused_attention``. q, k, v: ``(B, heads, S, D)``; ``mask``: an
+    additive key bias broadcastable to ``(B, heads, P, K)`` (a caption's
+    padding at a transformer's cross sites), None elsewhere. Notes each self
+    site's implementation for the launch being traced."""
+    is_cross = meta.is_cross
+    pix, d_head = q.shape[2], q.shape[-1]
+    # How nn.fused_attention runs the site, from the shape alone: the
+    # tile is the one for the width the kernel is handed its operands in.
+    operand = nn.flash_operand_dtype(q.dtype)
+    geometry = (nn.flash_block(pix, d_head, operand.itemsize)
+                if nn.takes_flash_kernel(pix, d_head, operand.itemsize)
+                else None)
+    how = "einsum" if geometry is None else "kernel"
+    if controller_touches(ctx.controller, meta):
+        how = "edited"
+        out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
+        if (out is None and geometry is not None
+                and controller_only_injects(ctx.controller, meta)):
+            # The edit rows take the base row's map: the flash kernel on
+            # the base row's q and k over their own v, no map in memory.
+            # Where the einsum chain would run, the map exists anyway and
+            # the edit fuses into P·V, which a substitution of q and k
+            # does not (PERF.md §6): such a site stays below.
+            q, k = inject_self_operands(ctx.controller.edit, q, k, ctx.step)
+            out = nn.fused_attention(q, k, v, scale)
+        else:
+            geometry = None             # the fused-edit kernel's, or none
+            if out is None:
+                probs = nn.attention_probs(q, k, scale, mask)  # (B, heads, P, K) f32
+                ctx.state, probs = apply_attention_control(
+                    ctx.controller, meta, ctx.state, probs, ctx.step)
+                out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+    elif (ctx.sp is not None and not is_cross
+          and meta.pixels >= ctx.sp.min_pixels):
+        n = ctx.sp.mesh.shape[ctx.sp.axis]
+        if meta.pixels % n:
+            # Unsharded fallback is safe only when fused attention stays
+            # blockwise (``nn.flash_block`` has a geometry for the site).
+            # Otherwise the einsum path would materialize the O(P²)
+            # scores on one device — the blow-up SpConfig exists to
+            # avoid — so that case is an error, not a warning.
+            if nn.flash_block(meta.pixels, d_head,
+                              operand.itemsize) is None:
+                raise ValueError(
+                    f"sequence-parallel site {meta.layer_idx} has "
+                    f"{meta.pixels} pixels, not divisible by mesh axis "
+                    f"{ctx.sp.axis!r}={n}, and not flash-tileable locally; "
+                    f"choose a divisor axis size or raise SpConfig.min_pixels")
+            import warnings
+
+            warnings.warn(
+                f"sequence-parallel site {meta.layer_idx}: {meta.pixels} "
+                f"pixels not divisible by mesh axis {ctx.sp.axis!r}={n}; "
+                f"running this site unsharded (local flash)", stacklevel=2)
+            out = nn.fused_attention(q, k, v, scale)
+        elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
+            from ..parallel.alltoall import alltoall_self_attention
+
+            how, geometry = "sharded", None
+            out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
+                                          ctx.sp.axis)
+        else:
+            if ctx.sp.mode == "alltoall":
+                # Same user-visible note as the pixel-indivisible fallback
+                # above: someone benchmarking alltoall must not unknowingly
+                # measure ring (warnings module dedups per call site).
+                import warnings
+
+                warnings.warn(
+                    f"sequence-parallel site {meta.layer_idx}: "
+                    f"{q.shape[1]} heads not divisible by mesh axis "
+                    f"{ctx.sp.axis!r}={n}; alltoall falls back to ring "
+                    f"at this site", stacklevel=2)
+            from ..parallel.ring import ring_self_attention
+
+            how, geometry = "sharded", None
+            out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
+    else:
+        out = nn.fused_attention(q, k, v, scale, mask)
+    if not is_cross:
+        # the tile and operand width wherever the site reached the kernel
+        launches.note_self_site(meta.layer_idx, how, pix, d_head, geometry,
+                                operand.name if geometry else "")
+    return out
+
+
 def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
                     heads: int, ctx: _HookCtx, meta, is_cross: bool) -> jax.Array:
     mode = ctx.site_plan[meta.layer_idx]
@@ -395,83 +488,7 @@ def _attention_site(p: Params, ln: Params, x: jax.Array, context: jax.Array,
         q, k, v = split_heads(q), split_heads(k), split_heads(v)
 
     with jax.named_scope("core"):
-        # How nn.fused_attention runs the site, from the shape alone: the
-        # tile is the one for the width the kernel is handed its operands in.
-        operand = nn.flash_operand_dtype(q.dtype)
-        geometry = (nn.flash_block(pix, d_head, operand.itemsize)
-                    if nn.takes_flash_kernel(pix, d_head, operand.itemsize)
-                    else None)
-        how = "einsum" if geometry is None else "kernel"
-        if controller_touches(ctx.controller, meta):
-            how = "edited"
-            out = _fused_edit_dispatch(ctx, meta, q, k, v, scale)
-            if (out is None and geometry is not None
-                    and controller_only_injects(ctx.controller, meta)):
-                # The edit rows take the base row's map: the flash kernel on
-                # the base row's q and k over their own v, no map in memory.
-                # Where the einsum chain would run, the map exists anyway and
-                # the edit fuses into P·V, which a substitution of q and k
-                # does not (PERF.md §6, PR 37): such a site stays below.
-                q, k = inject_self_operands(ctx.controller.edit, q, k, ctx.step)
-                out = nn.fused_attention(q, k, v, scale)
-            else:
-                geometry = None             # the fused-edit kernel's, or none
-                if out is None:
-                    probs = nn.attention_probs(q, k, scale)    # (B, heads, P, K) f32
-                    ctx.state, probs = apply_attention_control(
-                        ctx.controller, meta, ctx.state, probs, ctx.step)
-                    out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
-        elif (ctx.sp is not None and not is_cross
-              and meta.pixels >= ctx.sp.min_pixels):
-            n = ctx.sp.mesh.shape[ctx.sp.axis]
-            if meta.pixels % n:
-                # Unsharded fallback is safe only when fused attention stays
-                # blockwise (``nn.flash_block`` has a geometry for the site).
-                # Otherwise the einsum path would materialize the O(P²)
-                # scores on one device — the blow-up SpConfig exists to
-                # avoid — so that case is an error, not a warning.
-                if nn.flash_block(meta.pixels, d_head,
-                                  operand.itemsize) is None:
-                    raise ValueError(
-                        f"sequence-parallel site {meta.layer_idx} has "
-                        f"{meta.pixels} pixels, not divisible by mesh axis "
-                        f"{ctx.sp.axis!r}={n}, and not flash-tileable locally; "
-                        f"choose a divisor axis size or raise SpConfig.min_pixels")
-                import warnings
-
-                warnings.warn(
-                    f"sequence-parallel site {meta.layer_idx}: {meta.pixels} "
-                    f"pixels not divisible by mesh axis {ctx.sp.axis!r}={n}; "
-                    f"running this site unsharded (local flash)", stacklevel=2)
-                out = nn.fused_attention(q, k, v, scale)
-            elif ctx.sp.mode == "alltoall" and q.shape[1] % n == 0:
-                from ..parallel.alltoall import alltoall_self_attention
-
-                how, geometry = "sharded", None
-                out = alltoall_self_attention(q, k, v, scale, ctx.sp.mesh,
-                                              ctx.sp.axis)
-            else:
-                if ctx.sp.mode == "alltoall":
-                    # Same user-visible note as the pixel-indivisible fallback
-                    # above: someone benchmarking alltoall must not unknowingly
-                    # measure ring (warnings module dedups per call site).
-                    import warnings
-
-                    warnings.warn(
-                        f"sequence-parallel site {meta.layer_idx}: "
-                        f"{q.shape[1]} heads not divisible by mesh axis "
-                        f"{ctx.sp.axis!r}={n}; alltoall falls back to ring "
-                        f"at this site", stacklevel=2)
-                from ..parallel.ring import ring_self_attention
-
-                how, geometry = "sharded", None
-                out = ring_self_attention(q, k, v, scale, ctx.sp.mesh, ctx.sp.axis)
-        else:
-            out = nn.fused_attention(q, k, v, scale)
-        if not is_cross:
-            # the tile and operand width wherever the site reached the kernel
-            launches.note_self_site(meta.layer_idx, how, pix, d_head, geometry,
-                                    operand.name if geometry else "")
+        out = attention_core(ctx, meta, q, k, v, scale)
 
     with jax.named_scope("out"):
         out = out.transpose(0, 2, 1, 3).reshape(b, pix, heads * d_head)

@@ -23,8 +23,10 @@ reader gets from one to the other for a program that really ran:
   the bytes of attention maps the controller's store holds
   (``note_store_bytes``; ``Launch.store_bytes``, and the gauge
   ``launch_store_bytes{module}`` of ``obs.metrics``), the U-Net's transformer
-  blocks per site group by level (``note_unet_depth``) and the chunks the
-  decode takes its batch in (``note_decode_chunks``). The bytes of the
+  blocks per site group by level (``note_unet_depth``), the chunks the
+  decode takes its batch in (``note_decode_chunks``), the denoiser's kind
+  (``note_denoiser``) and, for a transformer attending to a masked caption,
+  the caption keys each row attends to (``note_caption_keys``). The bytes of the
   weights the program is handed, by part and dtype, are read off the kept
   shapes (``Launch.weights_bytes``).
 - :func:`scope_index` lowers, compiles and parses that program lazily, once,
@@ -117,6 +119,12 @@ class Launch:
     unet_depth: Tuple[int, ...] = ()
     # Chunks the autoencoder's decode takes its batch in; 0: no decode.
     decode_chunks: int = 0
+    # The denoiser's kind ("unet" | "transformer"); "" where none ran.
+    denoiser: str = ""
+    # Caption keys each row of the batch attends to under its key mask, of
+    # ``caption_len``, where the denoiser attends to a masked caption.
+    caption_keys: Tuple[int, ...] = ()
+    caption_len: int = 0
 
     @property
     def weights_bytes(self) -> Dict[str, Dict[str, int]]:
@@ -137,6 +145,11 @@ class Launch:
                     by[str(leaf.dtype)] += leaf.size * leaf.dtype.itemsize
                 out[name[:-len("_params")]] = dict(by)
         return out
+
+    @property
+    def self_window_sites(self) -> int:
+        """Self sites the controller injects into or reads (``edited``)."""
+        return sum(s.how == "edited" for s in self.self_sites.values())
 
     @property
     def self_site_counts(self) -> Dict[str, int]:
@@ -164,7 +177,12 @@ class Launch:
         weights = "; ".join(
             f"{part} " + " ".join(f"{_SHORT.get(d, d)}:{n}" for d, n in sorted(by.items()))
             for part, by in parts.items())
-        return (f"transformer depth by level {self.unet_depth}; "
+        head = ""
+        if self.denoiser == "transformer":
+            head = (f"denoiser transformer; self window "
+                    f"{self.self_window_sites} of {len(self.self_sites)} sites; "
+                    f"caption keys {self.caption_keys} of {self.caption_len}; ")
+        return (head + f"transformer depth by level {self.unet_depth}; "
                 f"decode_chunks {self.decode_chunks}; weights_bytes "
                 f"{sum(n for by in parts.values() for n in by.values())}"
                 f" ({weights})")
@@ -230,6 +248,18 @@ def note_unet_depth(depth: Tuple[int, ...]) -> None:
     """Trace time, from the model: the U-Net being traced has ``depth[l]``
     transformer blocks a site group at level ``l``."""
     _traced_model["unet_depth"] = tuple(depth)
+
+
+def note_denoiser(kind: str) -> None:
+    """Trace time, from the samplers' denoiser: its kind."""
+    _traced_model["denoiser"] = kind
+
+
+def note_caption_keys(keys: Tuple[int, ...], length: int) -> None:
+    """Host, before the launch: the caption keys each row of its batch
+    attends to under the key mask, of ``length``."""
+    _traced_model["caption_keys"] = tuple(keys)
+    _traced_model["caption_len"] = int(length)
 
 
 def note_decode_chunks(n: int) -> None:

@@ -43,7 +43,7 @@ PROFILE_FORMAT = "p2p-workload-profile/v1"
 
 #: An attention site name as it appears inside named_scope paths and HLO
 #: op metadata: ``cross_attn/down3``, ``self_attn/mid0``, ...
-SITE_RE = re.compile(r"(cross_attn|self_attn)/(?:down|mid|up)\d+")
+SITE_RE = re.compile(r"(cross_attn|self_attn)/(?:down|mid|up|block)\d+")
 
 _PLACE = r"(?:down|mid|up)\d+"
 #: The program's whole scope vocabulary (docs/OBSERVABILITY.md, "Scope
@@ -51,12 +51,15 @@ _PLACE = r"(?:down|mid|up)\d+"
 #: matches, so whatever JAX appends below a scope (primitive names, inner
 #: function names) is dropped.
 SCOPE_RE = re.compile(
-    r"(?<![\w.])(?:text_encoder(?:/(?:tower\d+|pool))?"
+    r"(?<![\w.])(?:text_encoder(?:/(?:tower\d+|pool|t5(?:/layer\d+)?))?"
     r"|sampler/(?:cfg|scheduler_step|controller_step)"
     r"|unet(?:/(?:time_embed|add_embed|conv_in|conv_out"
     rf"|{_PLACE}(?:/(?:res\d+|downsample|upsample|skip_concat"
     r"|attn\d+(?:/(?:proj_in|proj_out|ff"
     rf"|(?:self_attn|cross_attn)/{_PLACE}(?:/(?:qkv|core|out))?))?))?))?"
+    r"|dit(?:/(?:patch_embed|time_embed|caption_proj|final"
+    r"|block\d+(?:/(?:ff|modulate"
+    r"|(?:self_attn|cross_attn)/block\d+(?:/(?:qkv|core|out))?))?))?"
     r"|vae\.decode(?:/(?:conv_in|mid|up\d+|conv_out))?)(?![\w.])")
 
 # HLO-text structure: a computation header opens a ``{`` block (its

@@ -181,8 +181,8 @@ def sweep(
     with span("entry.sweep"):
         cfg = pipe.config
         if layout is None:
-            from ..models.config import unet_layout
-            layout = unet_layout(cfg.unet)
+            from ..models.config import denoiser_layout
+            layout = denoiser_layout(cfg.unet)
         controllers = layout.resolve(controllers)
         # a sweep returns no store: slots for LocalBlend's maps alone
         layout = layout.for_readers(controllers)
@@ -366,8 +366,8 @@ def _phase_args(pipe, num_steps: int, scheduler: str, gate,
     with span("entry.prepare"):
         cfg = pipe.config
         if layout is None:
-            from ..models.config import unet_layout
-            layout = unet_layout(cfg.unet)
+            from ..models.config import denoiser_layout
+            layout = denoiser_layout(cfg.unet)
         controllers = layout.resolve(controllers)
         # as in ``sweep``; phase 1's full controller and phase 2's slice name
         # the same blend, so both programs get the hand-off store's shapes

@@ -169,7 +169,7 @@ def carry_template(pipe, prep):
     from ..controllers.base import init_store_state
     from ..engine.sampler import PhaseCarry
     from ..models.conditioning import zeros_for
-    from ..models.config import unet_layout
+    from ..models.config import denoiser_layout
     from ..models.unet import init_attn_cache
     from ..ops import schedulers as sched_mod
 
@@ -178,7 +178,7 @@ def carry_template(pipe, prep):
     ctrl = prep.controller
     # the pool programs' layout (``parallel.sweep._phase_args``): the store
     # that crosses the hand-off holds LocalBlend's maps and nothing else
-    layout = unet_layout(cfg.unet).for_readers(ctrl)
+    layout = denoiser_layout(cfg.unet).for_readers(ctrl)
     lat = jnp.zeros((b,) + pipe.latent_shape, jnp.float32)
     state = init_store_state(layout, b)
     sched = getattr(prep, "schedule", None)

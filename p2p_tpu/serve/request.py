@@ -305,10 +305,12 @@ def prepare(req: Request, pipe) -> PreparedRequest:
     from ..cli import controller_from_opts
     from ..engine import reuse as reuse_mod
     from ..engine.sampler import resolve_reuse
-    from ..models.config import unet_layout
+    from ..models.config import denoiser_layout
+    from ..models.denoiser import require_unet
     from ..ops import schedulers as sched_mod
 
-    layout = unet_layout(pipe.config.unet)
+    require_unet(pipe.config.unet, "the serve engine")
+    layout = denoiser_layout(pipe.config.unet)
     controller = None
     if req.target is not None:
         # resolved here, at admission: a LocalBlend side the model keeps no

@@ -236,6 +236,10 @@ FLASH_ROWS = [(4096, 8, 40, jnp.float32), (1024, 8, 80, jnp.float32),
               (2304, 2, 256, jnp.bfloat16),
               (4096, 10, 64, jnp.float32), (1024, 20, 64, jnp.float32),
               (16384, 1, 512, jnp.float32)]
+#: PixArt-Sigma at 1024x1024: its 28 self sites, 16 heads of 72 at 64x64
+#: tokens, forward only (no backward or ring path runs a transformer
+#: denoiser).
+DIT_ROW = (4096, 16, 72, jnp.float32)
 #: The 64x64 self site in bf16, which the mesh cases run, and its local chunks
 #: under parallel/ring.py at sp = 2 and 4 (the residuals kernel runs on those).
 FLASH_SITE = FLASH_ROWS[2]
@@ -260,7 +264,7 @@ def _row(one_chip, row, batch=CFG_BATCH, residuals=False):
                                           pixels, d_head, dtype)
 
 
-@pytest.mark.parametrize("row", FLASH_ROWS, ids=_row_id)
+@pytest.mark.parametrize("row", FLASH_ROWS + [DIT_ROW], ids=_row_id)
 def test_flash_forward_compiles(one_chip, row):
     geometry, scale, qkv = _row(one_chip, row)
     compiled = _compile(

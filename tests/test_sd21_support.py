@@ -23,6 +23,7 @@ from p2p_tpu.controllers.base import PaperLevel, controller_touches
 from p2p_tpu.engine import inversion, sampler
 from p2p_tpu.engine.sampler import Pipeline, text2image
 from p2p_tpu.models import LDM256, SD14, SD14_HR, SD21, SD21_BASE, TINY, TINY_LDM, TINY_V
+from p2p_tpu.models import unet as unet_mod
 from p2p_tpu.models.config import SchedulerConfig, unet_layout
 from p2p_tpu.obs import launches
 from p2p_tpu.ops import schedulers as sched_mod
@@ -155,7 +156,8 @@ def test_v_model_samples_the_epsilon_models_path(tiny_pipe, monkeypatch, schedul
     images of the model that returns that ε."""
     images = {}
     for kind in ("epsilon", "v_prediction"):
-        monkeypatch.setattr(sampler, "apply_unet", _analytic_unet(kind))
+        # the samplers reach the U-Net through models/denoiser.denoise
+        monkeypatch.setattr(unet_mod, "apply_unet", _analytic_unet(kind))
         pipe = _named(tiny_pipe, f"analytic-{scheduler}-{kind}", kind)
         out, _, _ = text2image(pipe, ["a cat", "a dog"], None, num_steps=6,
                                scheduler=scheduler, rng=jax.random.PRNGKey(3))
